@@ -33,9 +33,14 @@ def main():
     args = ap.parse_args()
 
     import jax
-    jax.config.update("jax_platforms", "cpu")   # virtual ring on CPU hosts
-    from paddle_tpu.framework.jax_compat import pin_cpu_devices
-    pin_cpu_devices(args.ring)
+    if args.smoke:      # a virtual ring on CPU; otherwise the host's chips
+        jax.config.update("jax_platforms", "cpu")
+        from paddle_tpu.framework.jax_compat import pin_cpu_devices
+        pin_cpu_devices(args.ring)
+    if len(jax.devices()) < args.ring:
+        raise SystemExit(f"needs {args.ring} devices, has "
+                         f"{len(jax.devices())} (--smoke runs on virtual "
+                         "CPU devices)")
 
     import paddle_tpu as paddle
     import paddle_tpu.distributed as dist

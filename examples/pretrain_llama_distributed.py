@@ -7,8 +7,8 @@ shard the batch over dp, and jit the whole train step — GSPMD lowers the
 sharding constraints into the all-reduces/all-gathers the reference
 issues through NCCL by hand.
 
-Runs anywhere: on a CPU-only host it self-provisions 8 virtual devices
-(same mechanism the driver's multichip dryrun uses).
+With ``--smoke`` it runs on 8 virtual CPU devices; without, on the
+host's own devices (it needs dp x mp of them).
 
     python examples/pretrain_llama_distributed.py --smoke
 """
@@ -31,12 +31,13 @@ def main():
 
     n = args.dp * args.mp
     import jax
-    # Demo default: n virtual CPU devices, provisioned BEFORE first
-    # backend use.  On a real TPU slice with >= n chips, drop these two
-    # lines — everything below is device-count-generic.
-    jax.config.update("jax_platforms", "cpu")
-    from paddle_tpu.framework.jax_compat import pin_cpu_devices
-    pin_cpu_devices(n)
+    if args.smoke:      # n virtual CPU devices; otherwise the host's chips
+        jax.config.update("jax_platforms", "cpu")
+        from paddle_tpu.framework.jax_compat import pin_cpu_devices
+        pin_cpu_devices(n)
+    if len(jax.devices()) < n:
+        raise SystemExit(f"needs {n} devices, has {len(jax.devices())} "
+                         "(--smoke runs on virtual CPU devices)")
 
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F

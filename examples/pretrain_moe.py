@@ -27,9 +27,13 @@ def main():
 
     n = args.dp * args.ep
     import jax
-    jax.config.update("jax_platforms", "cpu")   # virtual mesh on CPU hosts
-    from paddle_tpu.framework.jax_compat import pin_cpu_devices
-    pin_cpu_devices(n)
+    if args.smoke:      # n virtual CPU devices; otherwise the host's chips
+        jax.config.update("jax_platforms", "cpu")
+        from paddle_tpu.framework.jax_compat import pin_cpu_devices
+        pin_cpu_devices(n)
+    if len(jax.devices()) < n:
+        raise SystemExit(f"needs {n} devices, has {len(jax.devices())} "
+                         "(--smoke runs on virtual CPU devices)")
 
     import paddle_tpu as paddle
     import paddle_tpu.nn.functional as F

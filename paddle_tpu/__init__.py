@@ -20,7 +20,7 @@ import os as _os
 
 if _os.environ.get("PADDLE_TPU_HELPER_CPU", "").lower() not in ("", "0", "false"):
     # launcher-marked helper rank: pin the CPU backend before anything can
-    # touch (and hang on) a sick accelerator plugin (framework/backend_guard)
+    # claim the chip its trainer holds (framework/backend_guard)
     from .framework.backend_guard import pin_cpu as _pin_cpu
     _pin_cpu()
 

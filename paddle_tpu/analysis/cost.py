@@ -2,8 +2,8 @@
 jaxpr (ISSUE 10 tentpole, part 3).
 
 The ROADMAP's standing instruction — "report the MFU ladder every
-round" — had no automated source: the BENCH_tpu_opportunistic MFU
-numbers were computed by hand from parameter counts.  This module walks
+round" — had no automated source: earlier MFU numbers were computed
+by hand from parameter counts.  This module walks
 the SAME traced jaxpr the program auditor walks (``program_audit``'s
 plumbing, ``engine_program_spec`` for the serving programs) and prices
 every equation:
@@ -43,12 +43,13 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 import jax
+from jax.extend.core import ClosedJaxpr
 
 from .program_audit import _aval_of, _nbytes, _subjaxprs_of
 
 __all__ = [
     "CostEstimate", "estimate_jaxpr", "estimate_callable",
-    "estimate_engine", "peak_flops", "record_mfu",
+    "estimate_engine", "peak_flops", "peak_source", "record_mfu",
     "publish_engine_cost", "PEAK_FLOPS_BY_DEVICE",
 ]
 
@@ -130,8 +131,8 @@ class CostEstimate:
 
 
 # ---------------------------------------------------------------- pricing
-def _closed_of(j, jcore):
-    return j.jaxpr if isinstance(j, jcore.ClosedJaxpr) else j
+def _closed_of(j):
+    return j.jaxpr if isinstance(j, ClosedJaxpr) else j
 
 
 def _avals(vars_):
@@ -204,21 +205,20 @@ def _jaxpr_cost(jaxpr, by_prim: Dict[str, Tuple[float, float]],
                 scale: float = 1.0) -> Tuple[float, float]:
     """Recursive walk: leaf primitives priced by the rules above;
     control flow weighted (scan × trip count, cond = max branch)."""
-    from jax import core as jcore
     flops = 0.0
     nbytes = 0.0
 
     for eqn in jaxpr.eqns:
         name = eqn.primitive.name
         if name == "scan":
-            body = _closed_of(eqn.params["jaxpr"], jcore)
+            body = _closed_of(eqn.params["jaxpr"])
             trips = float(eqn.params.get("length", 1) or 1)
             f, b = _jaxpr_cost(body, by_prim, scale * trips)
             flops += f
             nbytes += b
             continue
         if name == "cond":
-            branches = [_closed_of(br, jcore)
+            branches = [_closed_of(br)
                         for br in eqn.params.get("branches", ())]
             if branches:
                 costs = []
@@ -238,7 +238,7 @@ def _jaxpr_cost(jaxpr, by_prim: Dict[str, Tuple[float, float]],
             # backward in one sub-jaxpr — price it fully, or remat'd
             # training programs are underpriced by the whole recompute
             # (FLOPs and HBM both)
-            f, b = _jaxpr_cost(_closed_of(eqn.params["jaxpr"], jcore),
+            f, b = _jaxpr_cost(_closed_of(eqn.params["jaxpr"]),
                                by_prim, scale)
             flops += f
             nbytes += b
@@ -252,14 +252,14 @@ def _jaxpr_cost(jaxpr, by_prim: Dict[str, Tuple[float, float]],
             key = next((k for k in ("fun_jaxpr", "call_jaxpr", "jaxpr")
                         if k in eqn.params), None)
             if key is not None:
-                f, b = _jaxpr_cost(_closed_of(eqn.params[key], jcore),
+                f, b = _jaxpr_cost(_closed_of(eqn.params[key]),
                                    by_prim, scale)
                 flops += f
                 nbytes += b
                 continue
         subs = []
         for val in eqn.params.values():
-            subs.extend(_subjaxprs_of(val, jcore))
+            subs.extend(_subjaxprs_of(val))
         if subs:
             # pjit / while / shard_map / pallas_call bodies: each
             # sub-jaxpr priced once (a while's unknown trip count
@@ -318,21 +318,48 @@ def estimate_engine(engine, mode: str = "decode", sample=None,
     return estimate_jaxpr(closed, name=meta["name"], publish=publish)
 
 
-def peak_flops(default: Optional[float] = None) -> float:
-    """The peak FLOP/s MFU divides by: the ``PADDLE_TPU_PEAK_FLOPS``
-    env var when set, else the per-device-kind table on TPU, else the
-    fixed CPU-CI nominal (``DEFAULT_PEAK_FLOPS``)."""
+def by_device_kind(table: Dict[str, float], kind: str, what: str,
+                   env_name: Optional[str] = None) -> float:
+    """Longest-prefix match of a TPU ``device_kind`` in a peaks table.
+    A TPU that is not in the table is an error, never a default: a
+    utilization against an assumed peak is not a measurement."""
+    hits = [p for p in table if kind.startswith(p)]
+    if not hits:
+        raise ValueError(
+            f"no {what} known for TPU device kind {kind!r}: add it to "
+            f"the table with its source"
+            + (f", or set {env_name}" if env_name else ""))
+    return table[max(hits, key=len)]
+
+
+def _peak_and_source(default: Optional[float] = None) -> Tuple[float, str]:
     env = os.environ.get("PADDLE_TPU_PEAK_FLOPS")
     if env:
-        return float(env)
-    try:
-        kind = jax.devices()[0].device_kind
-        for prefix, peak in PEAK_FLOPS_BY_DEVICE.items():
-            if kind.startswith(prefix):
-                return peak
-    except Exception:
-        pass
-    return DEFAULT_PEAK_FLOPS if default is None else default
+        return float(env), "env"
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return (DEFAULT_PEAK_FLOPS if default is None else default,
+                "cpu_nominal")
+    return (by_device_kind(PEAK_FLOPS_BY_DEVICE, dev.device_kind,
+                           "peak FLOP/s", "PADDLE_TPU_PEAK_FLOPS"),
+            f"table:{dev.device_kind}")
+
+
+def peak_flops(default: Optional[float] = None) -> float:
+    """The peak FLOP/s utilization divides by: the
+    ``PADDLE_TPU_PEAK_FLOPS`` env var when set; on a TPU the
+    per-device-kind table (an unknown kind raises); off the TPU the
+    fixed CPU-CI nominal (``DEFAULT_PEAK_FLOPS``), which makes the
+    ratio a stable relative number for CPU tests and nothing more."""
+    return _peak_and_source(default)[0]
+
+
+def peak_source() -> str:
+    """Where :func:`peak_flops` takes its value from: ``"env"``, the
+    table row (``"table:<device kind>"``) or ``"cpu_nominal"`` — printed
+    beside every ratio so that a number against the CPU nominal is never
+    read as a utilization of a device."""
+    return _peak_and_source()[1]
 
 
 def record_mfu(achieved_flops: float, window_seconds: float,
@@ -396,6 +423,7 @@ def publish_engine_cost(engine, mode: str = "decode",
         "decode_seconds": dec_sum,
         "decode_steps": dec_n,
         "peak_flops": pk,
+        "peak_source": "argument" if peak is not None else peak_source(),
         "mfu": mfu,
     }
     if sa is not None:

@@ -40,6 +40,7 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 import jax
 import jax.tree_util as jtu
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 __all__ = [
     "Finding", "ProgramAudit", "audit_jaxpr", "audit_callable",
@@ -172,37 +173,34 @@ def _shape_str(aval) -> str:
 
 
 def _eqn_location(eqn) -> Tuple[str, int]:
-    """Best-effort user path:line from an equation's source info."""
-    try:
-        from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name, int(frame.start_line)
-    except Exception:
-        pass
-    return "", 0
+    """User path:line from an equation's source info ("", 0 where the
+    equation carries no user frame)."""
+    from jax._src import source_info_util
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
+        return "", 0
+    return frame.file_name, int(frame.start_line)
 
 
 def _walk_eqns(jaxpr) -> Iterable[Any]:
     """Every equation in the jaxpr, recursing into call/control-flow
     sub-jaxprs (pjit bodies, scan/while/cond branches)."""
-    from jax import core as jcore
     for eqn in jaxpr.eqns:
         yield eqn
         for val in eqn.params.values():
-            for sub in _subjaxprs_of(val, jcore):
+            for sub in _subjaxprs_of(val):
                 yield from _walk_eqns(sub)
 
 
-def _subjaxprs_of(val, jcore):
-    if isinstance(val, jcore.ClosedJaxpr):
+def _subjaxprs_of(val):
+    if isinstance(val, ClosedJaxpr):
         return [val.jaxpr]
-    if isinstance(val, jcore.Jaxpr):
+    if isinstance(val, Jaxpr):
         return [val]
     if isinstance(val, (tuple, list)):
         out = []
         for v in val:
-            out.extend(_subjaxprs_of(v, jcore))
+            out.extend(_subjaxprs_of(v))
         return out
     return []
 

@@ -10,7 +10,7 @@ both statically, the same way ``analysis.cost`` made FLOPs/MFU free:
   1. **Collective extraction + pricing.**  Two complementary tiers:
 
      * the *jaxpr walk* finds explicit collective eqns
-       (``psum``/``psum2``/``pmax``/``pmin``, ``all_gather``,
+       (``psum``/``psum_invariant``/``pmax``/``pmin``, ``all_gather``,
        ``reduce_scatter``, ``ppermute``, ``all_to_all``) inside
        ``shard_map``/``pjit``/``scan`` sub-jaxprs, resolving mesh-axis
        sizes from the enclosing ``shard_map`` mesh and multiplying by
@@ -74,8 +74,10 @@ import re
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
+from jax.core import DropVar
+from jax.extend.core import Literal
 
-from .cost import _closed_of
+from .cost import _closed_of, by_device_kind
 from .program_audit import (Finding, SEVERITY_WARNING,
                             _aval_of, _nbytes, _shape_str, _eqn_location,
                             _subjaxprs_of)
@@ -110,7 +112,7 @@ DEFAULT_LINK_BANDWIDTH = 1.0e10
 
 #: jaxpr collective primitive -> canonical collective kind
 _JAXPR_COLLECTIVES: Dict[str, str] = {
-    "psum": "all_reduce", "psum2": "all_reduce",
+    "psum": "all_reduce", "psum_invariant": "all_reduce",
     "pmax": "all_reduce", "pmin": "all_reduce",
     "all_gather": "all_gather", "all_gather_invariant": "all_gather",
     "reduce_scatter": "reduce_scatter", "psum_scatter": "reduce_scatter",
@@ -328,19 +330,17 @@ class SpmdAudit:
 # ------------------------------------------------------------- bandwidth
 def link_bandwidth(default: Optional[float] = None) -> float:
     """ICI bytes/s the analytic collective time divides by: the
-    ``PADDLE_TPU_ICI_BYTES_PER_S`` env var when set, else the
-    per-device-kind table on TPU, else the fixed CPU-CI nominal."""
+    ``PADDLE_TPU_ICI_BYTES_PER_S`` env var when set; on a TPU the
+    per-device-kind table (an unknown kind raises); off the TPU the
+    fixed CPU-CI nominal."""
     env = os.environ.get("PADDLE_TPU_ICI_BYTES_PER_S")
     if env:
         return float(env)
-    try:
-        kind = jax.devices()[0].device_kind
-        for prefix, bw in LINK_BANDWIDTH_BY_DEVICE.items():
-            if kind.startswith(prefix):
-                return bw
-    except Exception:   # noqa: BLE001 — no backend yet
-        pass
-    return DEFAULT_LINK_BANDWIDTH if default is None else default
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return DEFAULT_LINK_BANDWIDTH if default is None else default
+    return by_device_kind(LINK_BANDWIDTH_BY_DEVICE, dev.device_kind,
+                          "ICI bandwidth", "PADDLE_TPU_ICI_BYTES_PER_S")
 
 
 def price_collective(kind: str, payload_bytes: float, group_size: int,
@@ -414,7 +414,6 @@ def collectives_from_jaxpr(closed, bandwidth: Optional[float] = None
     tier).  Returns ``(collectives, mesh_axes)`` where mesh_axes is the
     union of every enclosing shard_map mesh seen.  Scan bodies multiply
     the execution count by the trip count and mark ``in_scan``."""
-    from jax import core as jcore
     bw = link_bandwidth() if bandwidth is None else float(bandwidth)
     out: List[CollectiveCost] = []
     seen_axes: Dict[str, int] = {}
@@ -450,12 +449,12 @@ def collectives_from_jaxpr(closed, bandwidth: Optional[float] = None
                 if mesh is not None:
                     inner_axes.update(_mesh_shape(mesh))
                     seen_axes.update(_mesh_shape(mesh))
-                walk(_closed_of(eqn.params["jaxpr"], jcore), inner_axes, scale,
+                walk(_closed_of(eqn.params["jaxpr"]), inner_axes, scale,
                      in_scan)
                 continue
             if name == "scan":
                 trips = float(eqn.params.get("length", 1) or 1)
-                walk(_closed_of(eqn.params["jaxpr"], jcore), mesh_axes,
+                walk(_closed_of(eqn.params["jaxpr"]), mesh_axes,
                      scale * trips, True)
                 continue
             if name == "while":
@@ -464,10 +463,10 @@ def collectives_from_jaxpr(closed, bandwidth: Optional[float] = None
                 for key in ("body_jaxpr", "cond_jaxpr"):
                     sub = eqn.params.get(key)
                     if sub is not None:
-                        walk(_closed_of(sub, jcore), mesh_axes, scale, True)
+                        walk(_closed_of(sub), mesh_axes, scale, True)
                 continue
             for val in eqn.params.values():
-                for sub in _subjaxprs_of(val, jcore):
+                for sub in _subjaxprs_of(val):
                     walk(sub, mesh_axes, scale, in_scan)
 
     walk(getattr(closed, "jaxpr", closed), {}, 1.0, False)
@@ -633,7 +632,6 @@ def estimate_peak_hbm(closed, donated_avals=(), arg_leaves=()) -> float:
     estimate: ``predicted >= measured`` is the train_bench assertion,
     and the gate verdict it feeds treats the prediction as the
     pessimistic planner."""
-    from jax import core as jcore
     jaxpr = getattr(closed, "jaxpr", closed)
     donate_pool = _donation_pool(donated_avals)
     local_by_var: Dict[Any, int] = {}
@@ -679,16 +677,16 @@ def estimate_peak_hbm(closed, donated_avals=(), arg_leaves=()) -> float:
         last_use: Dict[Any, int] = {}
         for i, eqn in enumerate(jpr.eqns):
             for v in eqn.invars:
-                if not isinstance(v, jcore.Literal):
+                if not isinstance(v, Literal):
                     last_use[v] = i
         kept = set(v for v in jpr.outvars
-                   if not isinstance(v, jcore.Literal))
+                   if not isinstance(v, Literal))
 
         peak = permanent + sum(live.values())
         for i, eqn in enumerate(jpr.eqns):
             subs = []
             for val in eqn.params.values():
-                subs.extend(_subjaxprs_of(val, jcore))
+                subs.extend(_subjaxprs_of(val))
             base = permanent + sum(live.values())
             if subs:
                 # A sub-jaxpr's internal peak stacks on the caller's
@@ -707,7 +705,7 @@ def estimate_peak_hbm(closed, donated_avals=(), arg_leaves=()) -> float:
                 if eqn.primitive.name in ("scan", "while"):
                     loop_out_bytes = sum(
                         var_bytes(v) for v in eqn.outvars
-                        if not isinstance(v, jcore.DropVar))
+                        if not isinstance(v, DropVar))
                 for sub in subs:
                     sub_invars = list(getattr(sub, "invars", ()))
                     if eqn.primitive.name == "scan":
@@ -728,17 +726,17 @@ def estimate_peak_hbm(closed, donated_avals=(), arg_leaves=()) -> float:
                 body = getattr(subs[0], "jaxpr", subs[0])
                 for gv, lv in zip(eqn.outvars,
                                   getattr(body, "outvars", ())):
-                    if not isinstance(gv, jcore.DropVar):
+                    if not isinstance(gv, DropVar):
                         local_by_var[gv] = var_bytes(lv)
             # allocate outputs
             for v in eqn.outvars:
-                if isinstance(v, jcore.DropVar):
+                if isinstance(v, DropVar):
                     continue
                 live[v] = local_by_var.get(v, var_bytes(v))
             peak = max(peak, permanent + sum(live.values()))
             # free dead intermediates (and donated/freeable inputs)
             for v in eqn.invars:
-                if isinstance(v, jcore.Literal) or v in kept:
+                if isinstance(v, Literal) or v in kept:
                     continue
                 if last_use.get(v) == i:
                     live.pop(v, None)
@@ -837,7 +835,6 @@ def _check_implicit_reshard(closed, arg_leaves, findings: List[Finding],
     the caller operand exactly, so a scan's per-trip xs slices (whose
     rank differs from the stacked operand) never inherit a spec that
     would misalign the comparison."""
-    from jax import core as jcore
     jaxpr = getattr(closed, "jaxpr", closed)
     init = {}
     for var, leaf in zip(jaxpr.invars, arg_leaves):
@@ -866,7 +863,7 @@ def _check_implicit_reshard(closed, arg_leaves, findings: List[Finding],
         for eqn in jpr.eqns:
             if eqn.primitive.name == "sharding_constraint":
                 var = eqn.invars[0]
-                if isinstance(var, jcore.Literal):
+                if isinstance(var, Literal):
                     continue
                 src = by_var.get(var)
                 dst = eqn.params.get("sharding")
@@ -902,11 +899,11 @@ def _check_implicit_reshard(closed, arg_leaves, findings: List[Finding],
                 continue
             subs = []
             for val in eqn.params.values():
-                subs.extend(_subjaxprs_of(val, jcore))
+                subs.extend(_subjaxprs_of(val))
             if not subs:
                 continue
             operands = [v for v in eqn.invars
-                        if not isinstance(v, jcore.Literal)]
+                        if not isinstance(v, Literal)]
             for sub in subs:
                 sub_map = {}
                 for sv, ov in zip(getattr(sub, "invars", ()), operands):

@@ -243,7 +243,7 @@ def all_gather(tensor_list: Optional[List[Tensor]], tensor: Tensor,
 
 def _host_world():
     """Cross-process world size from the launcher env contract — does NOT
-    touch the jax backend (spawned helpers may have a wedged plugin)."""
+    touch the jax backend (spawned helpers must not claim the chip)."""
     import os
     return int(os.environ.get("PADDLE_TRAINERS_NUM", "1"))
 

@@ -47,10 +47,8 @@ def _sharding_mesh(axis="sharding", degree=None):
 
 
 def _offload_sharding(ns):
-    """Host-memory variant of a NamedSharding (ZeRO-offload residency);
-    unchanged on single-memory backends (host == device there)."""
-    from ...framework.jax_compat import to_memory_kind
-    return to_memory_kind(ns, "pinned_host")
+    """Host-memory variant of a NamedSharding (ZeRO-offload residency)."""
+    return ns.with_memory_kind("pinned_host")
 
 
 def _apply_offload(optimizer):

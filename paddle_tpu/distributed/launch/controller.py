@@ -114,6 +114,14 @@ class LogWatcher:
         self._thread.join(timeout=2)
 
 
+def _host_has_tpu() -> bool:
+    """TPU device nodes on this host — looked up on the file system so
+    the launcher itself never initializes a backend (and takes a chip)."""
+    import glob
+    return bool(glob.glob("/dev/accel[0-9]*")
+                or glob.glob("/dev/vfio/[0-9]*"))
+
+
 class LocalController:
     """Spawn + supervise N local ranks (reference:
     launch/controllers/collective.py).
@@ -122,7 +130,16 @@ class LocalController:
     terminated) and ``run`` returns that rank's exit code — a hung fleet is
     worse than a failed one (comm_task_manager discipline).  With
     ``elastic_level >= 1`` the job is relaunched up to ``max_restarts``
-    times (reference elastic manager's RESTART decision)."""
+    times (reference elastic manager's RESTART decision).
+
+    On a TPU host every rank inherits the whole host's chips, and a chip
+    belongs to one process at a time.  With ``helper_cpu_only`` (the
+    default) rank 0 is the one process that reaches them — it can drive
+    all of them, SPMD — and ranks > 0 are pinned to the CPU
+    (``PADDLE_TPU_HELPER_CPU``): they are host-side helpers, not
+    trainers.  Without it, several ranks on one TPU host cannot work —
+    the second to initialize fails or hangs — so that is refused; giving
+    each rank its own chip is not implemented."""
 
     def __init__(self, script: str, script_args=None, nproc: int = 1,
                  master: Optional[str] = None, log_dir: Optional[str] = None,
@@ -142,6 +159,13 @@ class LocalController:
         self.max_restarts = max_restarts
         self.watch_rank0 = watch_rank0 and log_dir is not None
         self.helper_cpu_only = helper_cpu_only
+        if not helper_cpu_only and nproc > 1 and _host_has_tpu():
+            raise RuntimeError(
+                f"{nproc} ranks on one TPU host would each take every "
+                f"chip of the host, and a chip belongs to one process: "
+                f"run one rank for the host's chips (one process drives "
+                f"them all) or keep helper_cpu_only=True so only rank 0 "
+                f"reaches them")
         self.procs: List[ProcContext] = []
         self._store = None   # node-rendezvous store (multi-host only)
 
@@ -198,8 +222,8 @@ class LocalController:
                 # connect as a client, not re-bind the port
                 env["PADDLE_MASTER_BOUND"] = "1"
             if self.helper_cpu_only and rank > 0:
-                # worker ranks beyond 0 are host-level helpers: never let a
-                # wedged accelerator plugin hang them
+                # worker ranks beyond 0 are host-level helpers: rank 0
+                # is the one process that reaches the host's chips
                 # (framework/backend_guard.py)
                 env["PADDLE_TPU_HELPER_CPU"] = "1"
             log = os.path.join(self.log_dir, f"workerlog.{rank}") \

@@ -1,132 +1,28 @@
-"""Version adapters for the jax API surface this framework targets.
+"""The few jax names this tree reaches through one module.
 
-The codebase targets the current jax API (top-level ``jax.shard_map``
-with ``check_vma=``); older jaxlib images (<= 0.4.x) ship it as
-``jax.experimental.shard_map.shard_map`` with ``check_rep=``.  Import
-``shard_map`` from here so both resolve to the same callable.
+Written for the one installation there is (jax 0.9 / jaxlib 0.9):
+``shard_map`` is ``jax.shard_map`` (``check_vma=``), the mapped axis
+size is ``jax.lax.axis_size``, and every backend exposes the
+``device`` / ``pinned_host`` memory kinds, so offload code names them
+directly.  What stays here is the TP-mesh constructor and the virtual
+CPU device pin that tests and CPU-only helper processes use.
 """
 from __future__ import annotations
 
-import inspect
+import jax as _jax
+# jax.export is a submodule that is not an attribute until imported;
+# call sites write ``jax.export.symbolic_shape(...)``
+import jax.export  # noqa: F401
+from jax import shard_map
+from jax.lax import axis_size
 
-try:                                    # jax >= 0.5
-    from jax import shard_map as _shard_map
-except ImportError:                     # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-if "check_vma" in inspect.signature(_shard_map).parameters:
-    shard_map = _shard_map
-else:
-    def shard_map(f, *args, check_vma=None, **kwargs):
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(f, *args, **kwargs)
-
-# jax.export: a real submodule on every supported version, but only
-# auto-exposed as an attribute on newer jax — import it so call sites
-# can keep writing ``jax.export.symbolic_shape(...)``
-import jax.export  # noqa: E402,F401
-
-import jax as _jax  # noqa: E402
-
-if hasattr(_jax.lax, "axis_size"):
-    def axis_size(axis_name):
-        return _jax.lax.axis_size(axis_name)
-else:
-    def axis_size(axis_name):
-        # the classic idiom: psum of a static 1 folds to the axis size
-        return _jax.lax.psum(1, axis_name)
-
-
-# pallas-TPU compiler params were renamed TPUCompilerParams ->
-# CompilerParams; alias the old spelling forward (same signature)
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-    if not hasattr(_pltpu, "CompilerParams") \
-            and hasattr(_pltpu, "TPUCompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
-except Exception:           # no pallas on this backend: kernels gate off
-    pass
-
-
-# -- memory spaces ------------------------------------------------------
-# Current jax exposes 'device'/'pinned_host' memory kinds on every
-# backend; older CPU backends expose a single 'unpinned_host' space and
-# reject both names.  Offload/streaming code asks these helpers instead
-# of hard-coding kind names, so on a single-memory backend host offload
-# degrades to a no-op (host and device memory coincide).
-
-import functools as _functools  # noqa: E402
-
-
-@_functools.lru_cache(maxsize=1)
-def memory_kinds():
-    """Memory kinds addressable by the default local device."""
-    try:
-        return frozenset(
-            m.kind for m in _jax.local_devices()[0].addressable_memories())
-    except Exception:
-        return frozenset()
-
-
-@_functools.lru_cache(maxsize=1)
-def default_memory_kind():
-    try:
-        return _jax.local_devices()[0].default_memory().kind
-    except Exception:
-        return "device"
-
-
-def is_compute_memory(kind) -> bool:
-    """True when ``kind`` names the backend's compute/default memory —
-    i.e. an array with this kind is NOT host-offloaded."""
-    return kind in (None, "device") or kind == default_memory_kind()
-
-
-def to_memory_kind(sharding, kind):
-    """``sharding.with_memory_kind(kind)`` where the backend supports
-    that space; the sharding unchanged where it does not."""
-    if kind in memory_kinds():
-        return sharding.with_memory_kind(kind)
-    return sharding
-
-
-def register_compile_listener(callback) -> bool:
-    """Subscribe ``callback(event_name, duration_secs, **kw)`` to jax's
-    monitoring duration events (backend compiles fire one per XLA
-    compile on every supported jax).  Returns False on builds without
-    ``jax.monitoring`` — callers degrade to no compile telemetry."""
-    try:
-        from jax import monitoring as _monitoring
-        _monitoring.register_event_duration_secs_listener(callback)
-        return True
-    except Exception:
-        return False
+from .backend_guard import backend_initialized
 
 
 def pin_cpu_devices(n: int) -> None:
-    """Provision ``n`` virtual CPU devices pre-init.  Current jax has a
-    config option; older jax only honors the XLA host-platform flag (an
-    env var read at first backend touch, so it must be set before)."""
-    import os
-    try:
-        _jax.config.update("jax_num_cpu_devices", int(n))
-    except AttributeError:      # "Unrecognized config option" pre-0.5
-        flags = os.environ.get("XLA_FLAGS", "")
-        if "xla_force_host_platform_device_count" not in flags:
-            os.environ["XLA_FLAGS"] = (
-                f"{flags} --xla_force_host_platform_device_count={int(n)}"
-            ).strip()
-
-
-def _backend_initialized() -> bool:
-    """True once ANY XLA backend client exists — past this point the
-    virtual-CPU-device knobs are read-only for the process."""
-    try:
-        from jax._src import xla_bridge as _xb
-        return bool(getattr(_xb, "_backends", None))
-    except Exception:   # noqa: BLE001 — private surface moved: assume live
-        return True
+    """Provision ``n`` virtual CPU devices; must run before the first
+    backend touch of the process."""
+    _jax.config.update("jax_num_cpu_devices", int(n))
 
 
 def make_tp_mesh(n: int):
@@ -145,7 +41,7 @@ def make_tp_mesh(n: int):
     n = int(n)
     if n < 1:
         raise ValueError(f"tp degree must be >= 1, got {n}")
-    if n > 1 and not _backend_initialized():
+    if n > 1 and not backend_initialized():
         pin_cpu_devices(max(n, 2))
     devs = _jax.devices()
     if len(devs) < n:
@@ -157,7 +53,4 @@ def make_tp_mesh(n: int):
     return _jax.sharding.Mesh(_np.asarray(devs[:n]), ("tensor",))
 
 
-__all__ = ["shard_map", "axis_size", "memory_kinds",
-           "default_memory_kind", "is_compute_memory", "to_memory_kind",
-           "register_compile_listener", "pin_cpu_devices",
-           "make_tp_mesh"]
+__all__ = ["shard_map", "axis_size", "pin_cpu_devices", "make_tp_mesh"]

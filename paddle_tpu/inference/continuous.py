@@ -671,10 +671,11 @@ class ContinuousBatchingEngine:
         # batched survivor replay (ISSUE 9 satellite) is verified
         # bit-exact on CPU; on TPU its k == 0 round runs a different
         # attention kernel than the original prefill and the
-        # accumulation order has NOT been re-verified (ROADMAP capture-
-        # window item), so the unset default keeps the ISSUE 8
-        # bit-exact recovery contract: batched everywhere but TPU.
-        # Explicit True/False overrides either way.
+        # accumulation order has NOT been verified there (PR 24 ran the
+        # engine on the chip but not a recovery; still open), so the
+        # unset default keeps the ISSUE 8 bit-exact recovery contract:
+        # batched everywhere but TPU.  Explicit True/False overrides
+        # either way.
         if replay_batch is None:
             replay_batch = jax.default_backend() != "tpu"
         self.replay_batch = bool(replay_batch)

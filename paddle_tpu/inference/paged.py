@@ -1315,10 +1315,8 @@ class JittedPagedDecoder:
     def _build_multi(self):
         """Jitted N-step GREEDY decode: lax.scan over the single-step
         body with the page pools as carry — N tokens per host dispatch
-        instead of one.  On a tunnelled deployment each dispatch costs
-        milliseconds of RPC latency; fusing the loop removes all but one
-        of those round trips per chunk (and on local hardware removes
-        N-1 host synchronizations)."""
+        instead of one: fusing the loop removes N-1 host
+        synchronizations per chunk."""
         import jax
         from jax import lax
 

@@ -125,6 +125,10 @@ class _ServerLifecycle:
         return time.monotonic() - self._started_at
 
     def start(self):
+        # programs compile on the first request after this: place the
+        # persistent compilation cache before any of them
+        from ..framework.compile_cache import configure_compile_cache
+        configure_compile_cache()
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         daemon=True)
         self._thread.start()

@@ -679,7 +679,7 @@ def _mp_worker_boot(payload, wid, index_q, out_q):
 
     Must be a module-level function (spawn pickles the target).  Pins the CPU
     backend before unpickling the payload — workers never need the
-    accelerator, and a wedged TPU plugin must not hang the fleet
+    accelerator, and must not claim the chip their trainer holds
     (framework/backend_guard.py docstring).
     """
     from paddle_tpu.framework.backend_guard import helper_process_init

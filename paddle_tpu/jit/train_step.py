@@ -52,9 +52,8 @@ def _keep(arr):
 
 
 def _is_offloaded(sh):
-    from ..framework.jax_compat import is_compute_memory
     return sh is not None and \
-        not is_compute_memory(getattr(sh, "memory_kind", None))
+        getattr(sh, "memory_kind", None) not in (None, "device")
 
 
 def _pin(x, sh):
@@ -71,32 +70,15 @@ def _to_compute(x, sh):
     """Stream an offloaded operand into device memory for the update."""
     if x is None or not _is_offloaded(sh):
         return x
-    return jax.device_put(x, _compat_device_kind(sh))
-
-
-def _compat_device_kind(sh):
-    from ..framework.jax_compat import to_memory_kind
-    return to_memory_kind(sh, "device")
+    return jax.device_put(x, sh.with_memory_kind("device"))
 
 
 def _device_kind(sh):
     """The device-memory variant of a sharding (grads never offload —
     they are consumed immediately by the fused update)."""
     if _is_offloaded(sh):
-        return _compat_device_kind(sh)
+        return sh.with_memory_kind("device")
     return sh
-
-
-def _copy(arr):
-    """jnp.copy drops a non-default memory kind; restore it so offloaded
-    optimizer state stays in host memory."""
-    if arr is None:
-        return None
-    out = jnp.copy(arr)
-    sh = _keep(arr)
-    if _is_offloaded(sh):
-        out = jax.device_put(out, sh)
-    return out
 
 
 class TrainStep:
@@ -136,14 +118,17 @@ class TrainStep:
                                if not getattr(p, "trainable", True)]
         opt = optimizer
         opt._ensure_state(self._train_params)
-        # copies, not references: the compiled step donates these buffers,
-        # and donating the model's/optimizer's own arrays would leave them
-        # holding deleted buffers until sync()
+        # params are copies, not references: the compiled step donates
+        # these buffers, and donating the model's own arrays would leave it
+        # holding deleted buffers until sync().  The optimizer state is
+        # MOVED, not copied: slots + f32 masters are 12 B/param under
+        # AdamW, and holding them twice is what stops a model sized to the
+        # device from fitting; sync() hands them back.
         self._arrays = [jnp.copy(p._data) for p in self._train_params]
-        self._states = {s: [_copy(opt._accumulators[s][id(p)])
+        self._states = {s: [opt._accumulators[s].pop(id(p))
                             for p in self._train_params]
                         for s in opt._state_slots}
-        self._masters = [_copy(opt._master_weights.get(id(p)))
+        self._masters = [opt._master_weights.pop(id(p), None)
                          for p in self._train_params]
         self._update_fn = opt._functional_update_fn(self._train_params)
         # accumulate in fp32 whenever a master weight exists: summing k
@@ -156,7 +141,7 @@ class TrainStep:
             z = jnp.zeros_like(src)
             sh = _keep(src)
             if _is_offloaded(sh):
-                z = jax.device_put(z, _compat_device_kind(sh))
+                z = jax.device_put(z, sh.with_memory_kind("device"))
             return z
 
         self._grad_accum = [

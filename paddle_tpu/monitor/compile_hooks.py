@@ -9,11 +9,15 @@ the first compile of a signature counts too, which is exactly what a
 serving warm-up wants to see go to zero in the measured window
 (tools/serve_bench.py surfaces the deltas).
 
-jax builds without ``jax.monitoring`` degrade to a no-op through
-``framework.jax_compat.register_compile_listener`` (returns False; the
-metrics then simply never move).  This module must stay lazily
-importable: nothing here touches jax until ``install_compile_hooks()``
-is called, preserving the registry's importable-before-jax contract.
+The event also fires when jax's persistent compilation cache serves
+the executable from disk (checked on jax 0.9: a disk hit emits
+``compile_time_saved_sec`` / ``cache_retrieval_time_sec`` and then the
+same ``backend_compile_duration``), so the counter reads the same with
+a warm or a cold disk cache — only the seconds shrink.
+
+This module must stay lazily importable: nothing here touches jax
+until ``install_compile_hooks()`` is called, preserving the registry's
+importable-before-jax contract.
 """
 from __future__ import annotations
 
@@ -43,16 +47,14 @@ def _on_event_duration(event: str, duration: float, **kw) -> None:
 
 
 def install_compile_hooks() -> bool:
-    """Idempotently subscribe to jax's compile events.  Returns True
-    when the listener is (already) installed, False on jax builds with
-    no monitoring hook (telemetry degrades to zeros, nothing breaks)."""
+    """Idempotently subscribe to jax's compile events; returns True
+    (the value callers already read)."""
     global _installed
     with _lock:
         if _installed:
             return True
-        from ..framework.jax_compat import register_compile_listener
-        if not register_compile_listener(_on_event_duration):
-            return False
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_event_duration)
         # materialize the series now so a snapshot taken before the
         # first compile still carries explicit zeros
         counter("jit_recompile_count", _RECOMPILE_HELP)

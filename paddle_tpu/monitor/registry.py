@@ -337,8 +337,8 @@ def _default_dump_path() -> str:
 
 
 def dump(path: Optional[str] = None) -> str:
-    """Append one JSON line with the current snapshot (the same
-    append-only audit-trail style as tools/tpu_probe_log.jsonl)."""
+    """Append one JSON line with the current snapshot (an append-only
+    audit trail)."""
     path = path or _default_dump_path()
     rec = {"ts": round(time.time(), 1),
            "iso": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),

@@ -51,10 +51,12 @@ def _choose_flash_impl(q, k, causal) -> str:
     key = (f"flash_attention:{tuple(q.shape)}:{tuple(k.shape)}:"
            f"{q.dtype}:{causal}")
     if isinstance(q, jax.core.Tracer):
-        return _autotune.lookup(key) or heuristic
+        hit = _autotune.lookup(key)
+        return _autotune.note(key, hit or heuristic,
+                              "cached" if hit else "heuristic")
     if heuristic == "pallas":
         # don't risk OOM timing the XLA candidate on huge scores
-        return "pallas"
+        return _autotune.note(key, "pallas", "heuristic")
     return _autotune.autotune(
         key,
         {"xla": lambda: _mha_ref_bshd(q, k, k, causal),

@@ -16,28 +16,34 @@ import json
 import os
 import threading
 import time
-from typing import Callable, Dict, Optional
+import warnings
+from typing import Callable, Dict, List, Optional, Tuple
 
+from ..framework.compile_cache import CACHE_ROOT
+
+# winners live beside the compilation cache, inside the checkout: the
+# program a traced call compiles depends on them, so it must not depend
+# on a file in the user's home
 _CACHE_PATH = os.environ.get(
-    "PADDLE_TPU_AUTOTUNE_CACHE",
-    os.path.expanduser("~/.cache/paddle_tpu/autotune.json"))
+    "PADDLE_TPU_AUTOTUNE_CACHE", os.path.join(CACHE_ROOT, "autotune.json"))
 
 _lock = threading.Lock()
 _cache: Optional[Dict[str, str]] = None
 _enabled = True
 _device_tag: Optional[str] = None
+# what this process chose, and which candidates it could not run —
+# read by chip_smoke.py to print the choices and to fail on a refusal
+_decisions: Dict[str, Tuple[str, str]] = {}
+_failures: List[Tuple[str, str, str]] = []
 
 
 def _get_device_tag() -> str:
     """Winners are only valid for the device they were measured on."""
     global _device_tag
     if _device_tag is None:
-        try:
-            import jax
-            d = jax.devices()[0]
-            _device_tag = f"{d.platform}/{getattr(d, 'device_kind', '?')}"
-        except Exception:
-            _device_tag = "unknown"
+        import jax
+        d = jax.devices()[0]
+        _device_tag = f"{d.platform}/{d.device_kind}"
     return _device_tag
 
 
@@ -88,6 +94,28 @@ def lookup(key: str) -> Optional[str]:
         return _load().get(_full_key(key))
 
 
+def note(key: str, impl: str, source: str) -> str:
+    """Record that ``key`` took ``impl`` (``source``: "cached",
+    "measured", "default" or the caller's own word); returns ``impl``."""
+    with _lock:
+        _decisions[key] = (impl, source)
+    return impl
+
+
+def decisions() -> Dict[str, Tuple[str, str]]:
+    """{key: (implementation, source)} for every choice this process
+    made through :func:`select` / :func:`autotune` / :func:`note`."""
+    with _lock:
+        return dict(_decisions)
+
+
+def failures() -> List[Tuple[str, str, str]]:
+    """(key, candidate, error) for every candidate that failed to
+    compile or run while being measured in this process."""
+    with _lock:
+        return list(_failures)
+
+
 def record(key: str, winner: str) -> None:
     with _lock:
         _load()[_full_key(key)] = winner
@@ -113,9 +141,10 @@ def select(key: str, arr, candidates: Dict[str, Callable],
     eagerly on TPU measure-and-cache; elsewhere the default."""
     import jax
     if isinstance(arr, jax.core.Tracer):
-        return lookup(key) or default
+        hit = lookup(key)
+        return note(key, hit or default, "cached" if hit else "default")
     if tpu_only and jax.default_backend() != "tpu":
-        return default
+        return note(key, default, "default")
     return autotune(key, candidates, default)
 
 
@@ -124,18 +153,23 @@ def autotune(key: str, candidates: Dict[str, Callable],
     """Winner for ``key``: cached if known; measured now if enabled and all
     candidates are runnable; else ``default``."""
     if not _enabled:
-        return default
+        return note(key, default, "default")
     hit = lookup(key)
     if hit in candidates:
-        return hit
+        return note(key, hit, "cached")
     timings = {}
     for name, fn in candidates.items():
         try:
             timings[name] = _time_one(fn)
-        except Exception:
-            continue             # candidate not runnable for this shape
+        except Exception as e:  # noqa: BLE001 — any refusal is reported
+            # the candidate loses, but never in silence: a kernel the
+            # compiler refuses is a defect, not a slow kernel
+            with _lock:
+                _failures.append((key, name, repr(e)))
+            warnings.warn(f"autotune {key}: candidate {name!r} failed "
+                          f"to compile or run: {e!r}")
     if not timings:
-        return default
+        return note(key, default, "default")
     winner = min(timings, key=timings.get)
     record(key, winner)
-    return winner
+    return note(key, winner, "measured")

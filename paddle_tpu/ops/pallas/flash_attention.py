@@ -473,10 +473,7 @@ def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_kv=1024):
 
 # ----------------------------------------------------------- public entry
 def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -126,7 +126,25 @@ def _rope_kernel(q_ref, k_ref, cos_ref, sin_ref, oq_ref, ok_ref, *, half):
     rot(k_ref, ok_ref)
 
 
-def fused_rope_pallas(q, k, cos, sin, block_s=512, interpret=None):
+#: what one rope grid step may hold in VMEM: three quarters of Mosaic's
+#: 16 MiB scoped limit, the rest left to the compiler's own scratch
+_ROPE_VMEM_BUDGET = 12 << 20
+
+
+def _rope_block_s(s, h, kvh, d, itemsize):
+    """Sequence rows per grid step, sized from the shape.  A row costs
+    its q and k blocks in and out, double-buffered (heads pad to the
+    dtype's sublane packing: 8 rows of f32, 16 of bf16), plus about
+    three f32 copies of the wider of the two while it is rotated
+    (measured against the v5e compiler's own VMEM report)."""
+    sub = 32 // itemsize
+    h_p, kvh_p = _ceil_to(h, sub), _ceil_to(kvh, sub)
+    per_row = (h_p + kvh_p) * d * itemsize * 4 + 3 * max(h_p, kvh_p) * d * 4
+    rows = max(8, _ROPE_VMEM_BUDGET // per_row // 8 * 8)
+    return min(rows, _ceil_to(s, 8))
+
+
+def fused_rope_pallas(q, k, cos, sin, interpret=None):
     """Rotate q and k in ONE kernel.  q: (b, s, h, d), k: (b, s, kvh, d);
     cos/sin: (s, d/2) already sliced to the position window."""
     if interpret is None:
@@ -134,7 +152,7 @@ def fused_rope_pallas(q, k, cos, sin, block_s=512, interpret=None):
     b, s, h, d = q.shape
     kvh = k.shape[2]
     half = d // 2
-    block_s = min(block_s, _ceil_to(s, 8))
+    block_s = _rope_block_s(s, h, kvh, d, q.dtype.itemsize)
     s_p = _ceil_to(s, block_s)
     if s_p != s:
         pad = ((0, 0), (0, s_p - s), (0, 0), (0, 0))

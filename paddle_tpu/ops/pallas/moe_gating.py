@@ -38,9 +38,10 @@ def _argmax_rows(x):
     only — Mosaic has no argmax primitive on every supported jax)."""
     E = x.shape[1]
     m = jnp.max(x, axis=1, keepdims=True)
-    col = lax.broadcasted_iota(jnp.float32, x.shape, 1)
-    # float reduce: Mosaic only lowers float reductions; E is far below
-    # f32's exact-integer range
+    # tpu.iota yields integers only: build it as int32, convert after.
+    # The reduce stays float (Mosaic only lowers float reductions); E is
+    # far below f32's exact-integer range
+    col = lax.broadcasted_iota(jnp.int32, x.shape, 1).astype(jnp.float32)
     return jnp.min(jnp.where(x == m, col, float(E)),
                    axis=1).astype(jnp.int32)
 

@@ -575,14 +575,14 @@ class PagedKVCache:
         store = jnp.int8 if self.kv_quant else dtype
         shape = (kv_heads, total_pages, page_size, head_dim)
         sshape = (kv_heads, total_pages, page_size, 1)
-        self.k_pages = [self._place(jnp.zeros(shape, store))
+        self.k_pages = [self._zeros(shape, store)
                         for _ in range(num_layers)]
-        self.v_pages = [self._place(jnp.zeros(shape, store))
+        self.v_pages = [self._zeros(shape, store)
                         for _ in range(num_layers)]
         if self.kv_quant:
-            self.k_scales = [self._place(jnp.zeros(sshape, jnp.float32))
+            self.k_scales = [self._zeros(sshape, jnp.float32)
                              for _ in range(num_layers)]
-            self.v_scales = [self._place(jnp.zeros(sshape, jnp.float32))
+            self.v_scales = [self._zeros(sshape, jnp.float32)
                              for _ in range(num_layers)]
         else:
             self.k_scales = []
@@ -606,13 +606,12 @@ class PagedKVCache:
         # from a REAL donated-buffer loss (survivors need replay)
         self.generation = 0
 
-    def _place(self, a):
-        """Commit a pool buffer to the cache's mesh placement (identity
-        for the 1-chip cache)."""
-        if self._pool_sharding is None:
-            return a
-        import jax as _jax
-        return _jax.device_put(a, self._pool_sharding)
+    def _zeros(self, shape, dtype):
+        """A zeroed pool buffer created IN its placement: sharded over
+        the cache's mesh directly (never built whole on the default
+        device first — at real pool sizes that transit alone can
+        exhaust one chip), on the default device for the 1-chip cache."""
+        return jnp.zeros(shape, dtype, device=self._pool_sharding)
 
     # ------------------------------------------------------- bookkeeping
     def _decref_seq(self, page: int) -> bool:
@@ -726,18 +725,18 @@ class PagedKVCache:
         # the same mesh, or the next compiled call would silently
         # re-replicate them (and the decoder's pinned input shardings
         # would force a transfer per dispatch)
-        self.k_pages = [self._place(jnp.zeros(shape, dtype))
+        self.k_pages = [self._zeros(shape, dtype)
                         for _ in range(self.num_layers)]
-        self.v_pages = [self._place(jnp.zeros(shape, dtype))
+        self.v_pages = [self._zeros(shape, dtype)
                         for _ in range(self.num_layers)]
         if self.kv_quant:
             # the scale pools are part of the KV state: a rebuild zeroes
             # them too, and the survivor replay re-registers each page's
             # scales alongside its int8 values
             sshape = (self.kv_heads, self.total_pages, self.page_size, 1)
-            self.k_scales = [self._place(jnp.zeros(sshape, jnp.float32))
+            self.k_scales = [self._zeros(sshape, jnp.float32)
                              for _ in range(self.num_layers)]
-            self.v_scales = [self._place(jnp.zeros(sshape, jnp.float32))
+            self.v_scales = [self._zeros(sshape, jnp.float32)
                              for _ in range(self.num_layers)]
         while self._prefix_index:
             _, entry = self._prefix_index.popitem(last=False)

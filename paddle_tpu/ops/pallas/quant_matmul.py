@@ -102,10 +102,7 @@ def weight_only_matmul_xla(x, w_q, scale):
 
 
 def _use_pallas():
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+    return jax.default_backend() == "tpu"
 
 
 @jax.custom_vjp

@@ -23,6 +23,10 @@ if "xla_force_host_platform_device_count" not in _flags:
 import jax
 
 jax.config.update("jax_platforms", "cpu")
+# entry points under test (servers, bench tools) place the persistent
+# compilation cache in the checkout; tests neither write there nor
+# depend on what an earlier run left
+jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np
 import pytest

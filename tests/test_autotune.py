@@ -38,19 +38,27 @@ class TestAutotuneCache:
         assert calls == {"fast": 0, "slow": 0}
         assert autotune.lookup("k1") == "fast"
 
-    def test_failing_candidate_skipped(self, tmp_path, monkeypatch):
+    def test_failing_candidate_loses_and_is_reported(self, tmp_path,
+                                                     monkeypatch):
         monkeypatch.setattr(autotune, "_CACHE_PATH",
                             str(tmp_path / "at2.json"))
         monkeypatch.setattr(autotune, "_cache", None)
+        monkeypatch.setattr(autotune, "_failures", [])
+        monkeypatch.setattr(autotune, "_decisions", {})
 
         import jax.numpy as jnp
 
         def boom():
-            raise MemoryError
+            raise MemoryError("refused")
 
-        assert autotune.autotune(
-            "k2", {"boom": boom, "ok": lambda: jnp.zeros(2)},
-            default="boom") == "ok"
+        with pytest.warns(UserWarning, match="candidate 'boom' failed"):
+            assert autotune.autotune(
+                "k2", {"boom": boom, "ok": lambda: jnp.zeros(2)},
+                default="boom") == "ok"
+        # which, and why — never skipped in silence
+        assert autotune.failures() == [("k2", "boom",
+                                        "MemoryError('refused')")]
+        assert autotune.decisions() == {"k2": ("ok", "measured")}
 
     def test_disabled_returns_default(self, monkeypatch):
         monkeypatch.setattr(autotune, "_enabled", False)

@@ -251,3 +251,85 @@ class TestMfuPlumbing:
         snap = monitor.snapshot()
         assert snap["mfu"]["series"][0]["value"] == pytest.approx(0.5)
         assert cost.record_mfu(1.0, 0.0, peak=1e12) is None
+
+
+class _FakeDevice:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+class TestPeaksAreNeverAssumed:
+    """A utilization against an assumed peak is not a measurement: a
+    TPU kind that is not in a table raises, in every table."""
+
+    def test_longest_prefix_wins(self):
+        table = {"TPU v5": 459.0, "TPU v5 lite": 197.0}
+        assert cost.by_device_kind(table, "TPU v5 lite", "peak") == 197.0
+        assert cost.by_device_kind(table, "TPU v5p", "peak") == 459.0
+
+    @pytest.mark.parametrize("which", ["peak_flops", "link_bandwidth",
+                                       "bench"])
+    def test_unknown_tpu_kind_raises(self, monkeypatch, which):
+        monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS", raising=False)
+        monkeypatch.delenv("PADDLE_TPU_ICI_BYTES_PER_S", raising=False)
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [_FakeDevice("tpu", "TPU v99")])
+        if which == "peak_flops":
+            fn = cost.peak_flops
+        elif which == "link_bandwidth":
+            from paddle_tpu.analysis import spmd
+            fn = spmd.link_bandwidth
+        else:
+            import os
+            import sys
+            sys.path.insert(0, os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))))
+            import bench
+            fn = bench._peak_tflops
+        with pytest.raises(ValueError, match="TPU v99"):
+            fn()
+
+    def test_known_kind_and_cpu_nominal_name_their_source(self, monkeypatch):
+        monkeypatch.delenv("PADDLE_TPU_PEAK_FLOPS", raising=False)
+        assert cost.peak_source() == "cpu_nominal"
+        assert cost.peak_flops() == cost.DEFAULT_PEAK_FLOPS
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
+        assert cost.peak_flops() == 197e12
+        assert cost.peak_source() == "table:TPU v5 lite"
+        monkeypatch.setenv("PADDLE_TPU_PEAK_FLOPS", "5e12")
+        assert (cost.peak_flops(), cost.peak_source()) == (5e12, "env")
+
+
+class TestBenchNeedsTheChip:
+    def test_exits_nonzero_without_a_tpu(self):
+        import os
+        import subprocess
+        import sys
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        res = subprocess.run(
+            [sys.executable, os.path.join(repo, "bench.py")], cwd=repo,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"},
+            capture_output=True, text=True, timeout=300)
+        assert res.returncode != 0
+        assert res.stdout.strip() == ""       # no result is printed
+        assert "no TPU" in res.stderr
+
+    def test_a_config_that_raises_ends_the_run(self, monkeypatch):
+        import os
+        import sys
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+        import bench
+        monkeypatch.setattr(jax, "devices",
+                            lambda *a: [_FakeDevice("tpu", "TPU v5 lite")])
+        monkeypatch.setattr(
+            "paddle_tpu.framework.compile_cache.configure_compile_cache",
+            lambda: "unused")
+
+        def boom():
+            raise RuntimeError("config failed")
+
+        monkeypatch.setattr(bench, "bench_resnet_cifar", boom)
+        with pytest.raises(RuntimeError, match="config failed"):
+            bench.main()

@@ -74,6 +74,25 @@ class TestLocalController:
         """)
         assert LocalController(script, nproc=2, watch_rank0=False).run() == 0
 
+    @pytest.mark.parametrize("has_tpu,helper_cpu_only,nproc,refused", [
+        (True, False, 2, True),     # every rank would take every chip
+        (True, True, 2, False),     # only rank 0 reaches the chips
+        (True, False, 1, False),    # one process drives the host's chips
+        (False, False, 2, False),   # no chip to fight over
+    ])
+    def test_several_chip_ranks_on_one_tpu_host_are_refused(
+            self, tmp_path, monkeypatch, has_tpu, helper_cpu_only, nproc,
+            refused):
+        from paddle_tpu.distributed.launch import controller
+        monkeypatch.setattr(controller, "_host_has_tpu", lambda: has_tpu)
+        script = _script(tmp_path, "pass")
+        kw = dict(nproc=nproc, helper_cpu_only=helper_cpu_only)
+        if refused:
+            with pytest.raises(RuntimeError, match="one process"):
+                LocalController(script, **kw)
+        else:
+            LocalController(script, **kw)
+
     def test_launch_main_multiproc(self, tmp_path):
         from paddle_tpu.distributed.launch.main import main
         script = _script(tmp_path, """

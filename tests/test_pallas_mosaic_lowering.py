@@ -1,17 +1,20 @@
-"""Mosaic lowering validation for every Pallas kernel (VERDICT r4 item 2).
+"""Every Pallas kernel, compiled by the TPU's own compiler for a described
+``v5e:2x2`` chip — the one file of chip compiles (rehearsal 3 of the
+on-chip-measurement guide).
 
-The chip is usually unreachable, so until now the kernels only ever ran
-under ``interpret=True`` — which does not model Mosaic's tiling, memory
-spaces, or grid constraints.  ``jax.export.export(..., platforms=['tpu'])``
-runs the full Pallas→Mosaic MLIR lowering pipeline for an abstract TPU
-target on a CPU-only host: every kernel here must (a) lower without error
-at REAL model shapes (LLaMA-110M attention geometry, bf16) and (b) actually
-embed a Mosaic ``tpu_custom_call`` — a silent fall-through to the XLA
-reference path would otherwise pass vacuously.
+The compiler is installed in the sandbox and compiles for a chip that is
+described, not attached: it refuses what the chip would refuse (a block
+that does not fit scoped VMEM, an op Mosaic's verifier rejects, a slice
+off the tiling), which interpret mode never sees.  Each case compiles
+the kernel itself at the shapes the repo runs — the ``llama_7b`` widths
+of ``chip_smoke.py`` and the LLaMA-110M geometry of ``bench.py`` — and
+asserts a Mosaic ``tpu_custom_call`` in the compiled program, so a
+silent fall-through to an XLA path cannot pass.  Nothing runs: results
+are checked in interpret mode elsewhere and on the chip by the smoke.
 
-Reference bar: the reference ships hardware-validated attention kernels
-(paddle/phi/kernels/gpu/flash_attn_kernel.cu via dynload/flashattn.cc);
-this is the strongest no-hardware equivalent available.
+The topology, the shardings and the mesh are built inside module-scoped
+fixtures, never at import: only one process may load the TPU's library,
+and every xdist worker imports this file.
 """
 import functools
 import math
@@ -20,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import export
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 from paddle_tpu.ops.pallas.flash_attention import (
     flash_attention_backward,
@@ -34,156 +38,329 @@ from paddle_tpu.ops.pallas.fused_norm_rope import (
     fused_rope_pallas,
     rms_norm_pallas,
 )
+from paddle_tpu.ops.pallas.moe_gating import topk_gating_pallas
 from paddle_tpu.ops.pallas.paged_attention import _decode_pallas
+from paddle_tpu.ops.pallas.quant_matmul import (
+    w8a8_matmul_pallas,
+    weight_only_matmul_pallas,
+)
 
-# LLaMA-110M attention geometry (the bench headline config)
+BF16, F32, I32, I8 = jnp.bfloat16, jnp.float32, jnp.int32, jnp.int8
+
+# llama_7b widths (chip_smoke.py) and the LLaMA-110M geometry (bench.py)
+H7, D7, HID7, FFN7 = 32, 128, 4096, 11008
 B, H, KVH, S, D = 2, 12, 4, 1024, 64
-BF16 = jnp.bfloat16
 
 
-def sds(*shape, dtype=BF16):
-    return jax.ShapeDtypeStruct(shape, dtype)
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any refusal means no compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-def lower_tpu(fn, *args):
-    """AOT-lower ``fn`` for an abstract TPU target; assert Mosaic went in."""
-    exp = export.export(jax.jit(fn), platforms=["tpu"])(*args)
-    mlir = exp.mlir_module()
-    assert "tpu_custom_call" in mlir, (
-        "no Mosaic custom call in the exported module — the Pallas path "
-        "was not taken")
-    return mlir
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def tensor_mesh(topo):
+    return Mesh(np.asarray(topo.devices[:4]), ("tensor",))
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without a chip; keep these silent."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+
+
+@pytest.fixture(scope="module")
+def chip(one_chip, no_compile_cache):
+    """``chip.compile(fn, (shape, dtype), ...)``: compile ``fn`` for the
+    described chip; assert Mosaic went in; return the program text."""
+    class Chip:
+        @staticmethod
+        def sds(shape, dtype=BF16, sharding=one_chip):
+            return jax.ShapeDtypeStruct(tuple(shape), dtype,
+                                        sharding=sharding)
+
+        def compile(self, fn, *specs):
+            args = [s if isinstance(s, jax.ShapeDtypeStruct)
+                    else self.sds(*s) for s in specs]
+            text = jax.jit(fn).lower(*args).compile().as_text()
+            assert "tpu_custom_call" in text, (
+                "no Mosaic custom call in the compiled program — the "
+                "Pallas path was not taken")
+            return text
+
+    return Chip()
+
+
+def _paged_specs(chip, *, kvh, heads, d, batch, pages, page, table, nq=1,
+                 int8=False, ragged=False, sharding=None, pool_sharding=None):
+    """Argument specs of ``_decode_pallas`` in call order."""
+    kw = {} if sharding is None else {"sharding": sharding}
+    pkw = kw if pool_sharding is None else {"sharding": pool_sharding}
+    q = (batch, heads, d) if nq == 1 else (batch, nq, heads, d)
+    pool = (kvh, pages, page, d)
+    specs = [chip.sds(q, BF16, **kw),
+             chip.sds(pool, I8 if int8 else BF16, **pkw),
+             chip.sds(pool, I8 if int8 else BF16, **pkw),
+             chip.sds((batch,), I32, **kw),
+             chip.sds((batch, table), I32, **kw)]
+    if int8:
+        specs += [chip.sds((kvh, pages, page, 1), F32, **pkw)] * 2
+    if ragged:
+        specs += [chip.sds((batch,), I32, **kw)]
+    return specs
+
+
+def _paged_fn(d, *, nq=1, int8=False, ragged=False):
+    scale = 1.0 / math.sqrt(d)
+
+    def fn(q, kp, vp, lens, tabs, *rest):
+        kw = {}
+        if int8:
+            kw["k_scales"], kw["v_scales"] = rest[0], rest[1]
+        if ragged:
+            kw["q_lens"] = rest[-1]
+        return _decode_pallas(q, kp, vp, lens, tabs, scale,
+                              interpret=False, n_query=nq, **kw)
+    return fn
+
+
+# ----------------------------------------------------- paged attention
+class TestPagedAttentionLowering:
+    def test_decode_110m(self, chip):
+        chip.compile(_paged_fn(D), *_paged_specs(
+            chip, kvh=KVH, heads=H, d=D, batch=8, pages=256, page=16,
+            table=16))
+
+    @pytest.mark.parametrize("kvh", [32, 8], ids=["mha", "gqa32_8"])
+    def test_decode_llama_7b(self, chip, kvh):
+        chip.compile(_paged_fn(D7), *_paged_specs(
+            chip, kvh=kvh, heads=H7, d=D7, batch=8, pages=2056, page=16,
+            table=256))
+
+    @pytest.mark.parametrize("kvh,nq", [(32, 128), (8, 64)],
+                             ids=["mha_chunk128", "gqa32_8_span64"])
+    def test_ragged_bf16_llama_7b(self, chip, kvh, nq):
+        chip.compile(_paged_fn(D7, nq=nq, ragged=True), *_paged_specs(
+            chip, kvh=kvh, heads=H7, d=D7, batch=8, pages=2056, page=16,
+            table=256, nq=nq, ragged=True))
+
+    @pytest.mark.parametrize("page", [16, 32])
+    def test_ragged_int8_llama_7b(self, chip, page):
+        chip.compile(
+            _paged_fn(D7, nq=128, int8=True, ragged=True), *_paged_specs(
+                chip, kvh=32, heads=H7, d=D7, batch=8, pages=1024,
+                page=page, table=128, nq=128, int8=True, ragged=True))
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("page", [8, 32, 64, 128])
+    def test_page_sizes_other_than_16(self, chip, page, int8):
+        # pool tiling is (16,128) for bf16 and (32,128) for int8: a page
+        # that is not a whole tile must still compile
+        chip.compile(_paged_fn(D7, int8=int8), *_paged_specs(
+            chip, kvh=32, heads=H7, d=D7, batch=8, pages=512, page=page,
+            table=32, int8=int8))
+
+    @pytest.mark.parametrize("kvh,nq,ragged",
+                             [(32, 3, False), (8, 5, False), (32, 2, True)],
+                             ids=["rows3", "rows20", "rows2_ragged"])
+    def test_query_rows_not_a_multiple_of_8(self, chip, kvh, nq, ragged):
+        # rows = n_query * group rides as the q block's sublane dim
+        chip.compile(_paged_fn(D7, nq=nq, ragged=ragged), *_paged_specs(
+            chip, kvh=kvh, heads=H7, d=D7, batch=8, pages=512, page=16,
+            table=32, nq=nq, ragged=ragged))
+
+    def test_ragged_under_shard_map_on_four_chips(self, chip, tensor_mesh):
+        """The TP serving layout: q and the pools sharded on the (kv-)head
+        axis of a 4-device mesh of the described chips, the ragged kernel
+        per shard inside ``shard_map``."""
+        from jax import shard_map
+        rep = NamedSharding(tensor_mesh, P())
+        heads = NamedSharding(tensor_mesh, P(None, None, "tensor", None))
+        pool = NamedSharding(tensor_mesh, P("tensor"))
+        kernel = _paged_fn(D7, nq=128, ragged=True)
+        fn = shard_map(
+            kernel, mesh=tensor_mesh,
+            in_specs=(P(None, None, "tensor", None), P("tensor"),
+                      P("tensor"), P(), P(), P()),
+            out_specs=P(None, None, "tensor", None), check_vma=False)
+        text = chip.compile(
+            fn, chip.sds((8, 128, H7, D7), BF16, sharding=heads),
+            chip.sds((32, 2056, 16, D7), BF16, sharding=pool),
+            chip.sds((32, 2056, 16, D7), BF16, sharding=pool),
+            chip.sds((8,), I32, sharding=rep),
+            chip.sds((8, 256), I32, sharding=rep),
+            chip.sds((8,), I32, sharding=rep))
+        # per chip: a quarter of the heads — no gather of the pools
+        assert "all-gather" not in text
+
+
+# ----------------------------------------------------- flash attention
+def _flash_bwd(causal, d):
+    scale = 1.0 / math.sqrt(d)
+
+    def fn(q, k, v, out, lse, do):
+        return flash_attention_backward(q, k, v, out, lse, do, causal,
+                                        scale, interpret=False)
+    return fn
 
 
 class TestFlashAttentionLowering:
     @pytest.mark.parametrize("causal", [False, True])
-    def test_forward(self, causal):
+    def test_forward(self, chip, causal):
         fn = functools.partial(flash_attention_forward, causal=causal,
                                interpret=False)
-        lower_tpu(fn, sds(B, H, S, D), sds(B, H, S, D), sds(B, H, S, D))
+        chip.compile(fn, *[((B, H, S, D),)] * 3)
 
-    def test_forward_gqa(self):
+    def test_forward_gqa(self, chip):
         fn = functools.partial(flash_attention_forward, causal=True,
                                interpret=False)
-        lower_tpu(fn, sds(B, H, S, D), sds(B, KVH, S, D), sds(B, KVH, S, D))
+        chip.compile(fn, ((B, H, S, D),), ((B, KVH, S, D),),
+                     ((B, KVH, S, D),))
 
-    def test_forward_unaligned_seq(self):
+    def test_forward_unaligned_seq(self, chip):
         # 1000 tokens: exercises the pad-to-block path under Mosaic
         fn = functools.partial(flash_attention_forward, causal=True,
                                interpret=False)
-        lower_tpu(fn, sds(B, H, 1000, D), sds(B, H, 1000, D),
-                  sds(B, H, 1000, D))
+        chip.compile(fn, *[((B, H, 1000, D),)] * 3)
 
     @pytest.mark.parametrize("causal", [False, True])
-    def test_backward(self, causal):
-        scale = 1.0 / math.sqrt(D)
+    def test_backward(self, chip, causal):
+        chip.compile(_flash_bwd(causal, D), *[((B, H, S, D),)] * 4,
+                     ((B, H, S), F32), ((B, H, S, D),))
 
-        def fn(q, k, v, out, lse, do):
-            return flash_attention_backward(q, k, v, out, lse, do,
-                                            causal, scale,
-                                            interpret=False)
+    def test_backward_gqa(self, chip):
+        chip.compile(_flash_bwd(True, D), ((B, H, S, D),),
+                     ((B, KVH, S, D),), ((B, KVH, S, D),), ((B, H, S, D),),
+                     ((B, H, S), F32), ((B, H, S, D),))
 
-        lower_tpu(fn, sds(B, H, S, D), sds(B, H, S, D), sds(B, H, S, D),
-                  sds(B, H, S, D), sds(B, H, S, dtype=jnp.float32),
-                  sds(B, H, S, D))
+    @pytest.mark.parametrize("kvh", [32, 8], ids=["mha", "gqa32_8"])
+    def test_forward_and_backward_llama_7b(self, chip, kvh):
+        q, kv = ((2, H7, 2048, D7),), ((2, kvh, 2048, D7),)
+        fwd = functools.partial(flash_attention_forward, causal=True,
+                                interpret=False)
+        chip.compile(fwd, q, kv, kv)
+        chip.compile(_flash_bwd(True, D7), q, kv, kv, q,
+                     ((2, H7, 2048), F32), q)
 
-    def test_backward_gqa(self):
-        scale = 1.0 / math.sqrt(D)
-
-        def fn(q, k, v, out, lse, do):
-            return flash_attention_backward(q, k, v, out, lse, do,
-                                            True, scale, interpret=False)
-
-        lower_tpu(fn, sds(B, H, S, D), sds(B, KVH, S, D),
-                  sds(B, KVH, S, D), sds(B, H, S, D),
-                  sds(B, H, S, dtype=jnp.float32), sds(B, H, S, D))
+    def test_compiled_program_carries_the_kernel_payload(self, chip):
+        fn = functools.partial(flash_attention_forward, causal=True,
+                               interpret=False)
+        text = chip.compile(fn, *[((B, H, S, D),)] * 3)
+        # a real kernel at these shapes is tens of KB of serialized MLIR
+        assert len(text) > 10_000
 
 
 class TestFlashMaskLowering:
     @pytest.mark.parametrize("ncol", [1, 2, 4])
-    def test_forward(self, ncol):
+    def test_forward(self, chip, ncol):
         def fn(q, k, v, se):
             return flashmask_attention_forward(q, k, v, se, causal=True,
                                                interpret=False)
 
-        lower_tpu(fn, sds(B, H, S, D), sds(B, H, S, D), sds(B, H, S, D),
-                  sds(B, 1, S, ncol, dtype=jnp.int32))
+        chip.compile(fn, *[((B, H, S, D),)] * 3, ((B, 1, S, ncol), I32))
 
-    def test_backward(self):
+    def test_backward(self, chip):
         def fn(q, k, v, out, lse, do, se):
             return flashmask_attention_backward(
                 q, k, v, out, lse, do, se, causal=True, interpret=False)
 
-        lower_tpu(fn, sds(B, H, S, D), sds(B, H, S, D), sds(B, H, S, D),
-                  sds(B, H, S, D), sds(B, H, S, dtype=jnp.float32),
-                  sds(B, H, S, D), sds(B, 1, S, 2, dtype=jnp.int32))
+        chip.compile(fn, *[((B, H, S, D),)] * 4, ((B, H, S), F32),
+                     ((B, H, S, D),), ((B, 1, S, 2), I32))
+
+    def test_forward_and_backward_head_dim_128(self, chip):
+        q = ((2, H7, 2048, D7),)
+
+        def fwd(q_, k, v, se):
+            return flashmask_attention_forward(q_, k, v, se, causal=True,
+                                               interpret=False)
+
+        def bwd(q_, k, v, out, lse, do, se):
+            return flashmask_attention_backward(
+                q_, k, v, out, lse, do, se, causal=True, interpret=False)
+
+        chip.compile(fwd, q, q, q, ((2, 1, 2048, 2), I32))
+        chip.compile(bwd, q, q, q, q, ((2, H7, 2048), F32), q,
+                     ((2, 1, 2048, 2), I32))
 
 
-class TestPagedDecodeLowering:
-    def test_decode(self):
-        batch, pages, page_size, max_pages = 8, 256, 16, 16
-        scale = 1.0 / math.sqrt(D)
-
-        def fn(q, kp, vp, lens, tabs):
-            return _decode_pallas(q, kp, vp, lens, tabs, scale,
-                                  interpret=False)
-
-        lower_tpu(fn, sds(batch, H, D),
-                  sds(KVH, pages, page_size, D),
-                  sds(KVH, pages, page_size, D),
-                  sds(batch, dtype=jnp.int32),
-                  sds(batch, max_pages, dtype=jnp.int32))
-
-
+# ------------------------------------------------------- rmsnorm + rope
 class TestFusedNormRopeLowering:
-    def test_rmsnorm(self):
+    def test_rmsnorm(self, chip):
         fn = functools.partial(rms_norm_pallas, interpret=False)
-        lower_tpu(fn, sds(B * S, 768), sds(768))
+        chip.compile(fn, ((B * S, 768),), ((768,),))
 
-    def test_rmsnorm_3d_f32(self):
+    def test_rmsnorm_3d_f32(self, chip):
         fn = functools.partial(rms_norm_pallas, interpret=False)
-        lower_tpu(fn, sds(B, S, 768, dtype=jnp.float32),
-                  sds(768, dtype=jnp.float32))
+        chip.compile(fn, ((B, S, 768), F32), ((768,), F32))
 
-    def test_rope(self):
+    def test_rmsnorm_llama_7b(self, chip):
+        fn = functools.partial(rms_norm_pallas, interpret=False)
+        chip.compile(fn, ((2, 2048, HID7),), ((HID7,),))
+
+    def test_rope(self, chip):
         fn = functools.partial(fused_rope_pallas, interpret=False)
-        lower_tpu(fn, sds(B, S, H, D), sds(B, S, KVH, D),
-                  sds(S, D // 2, dtype=jnp.float32),
-                  sds(S, D // 2, dtype=jnp.float32))
+        chip.compile(fn, ((B, S, H, D),), ((B, S, KVH, D),),
+                     ((S, D // 2), F32), ((S, D // 2), F32))
+
+    @pytest.mark.parametrize("kvh,dtype,seq",
+                             [(32, BF16, 2048), (8, BF16, 2048),
+                              (32, F32, 2048), (32, BF16, 1)],
+                             ids=["mha_bf16", "gqa32_8_bf16", "mha_f32",
+                                  "one_token"])
+    def test_rope_llama_7b(self, chip, kvh, dtype, seq):
+        # 32 heads x 128 at block_s=512 asked 32 MB of scoped VMEM against
+        # a 16 MB limit; the block is sized from the shape now
+        fn = functools.partial(fused_rope_pallas, interpret=False)
+        chip.compile(fn, ((2, seq, H7, D7), dtype),
+                     ((2, seq, kvh, D7), dtype),
+                     ((seq, D7 // 2), F32), ((seq, D7 // 2), F32))
 
 
-class TestLoweredProgramSanity:
-    def test_forward_module_has_grid_and_scratch(self):
-        """The exported module is a real Mosaic program: serialized kernel
-        payload present and non-trivial (not a stub custom call)."""
-        fn = functools.partial(flash_attention_forward, causal=True,
-                               interpret=False)
-        mlir = lower_tpu(fn, sds(B, H, S, D), sds(B, H, S, D),
-                         sds(B, H, S, D))
-        # Mosaic payloads are serialized into the custom call backend
-        # config; a real kernel at these shapes is tens of KB of MLIR
-        assert len(mlir) > 10_000
-
-
+# ------------------------------------------------------------ MoE gating
 class TestMoEGatingLowering:
     @pytest.mark.parametrize("top_k", [1, 2])
-    def test_gating(self, top_k):
-        from paddle_tpu.ops.pallas.moe_gating import topk_gating_pallas
-
+    def test_gating(self, chip, top_k):
+        # tpu.iota takes integers only: the float iota failed Mosaic's
+        # verifier until _argmax_rows converted after
         fn = functools.partial(topk_gating_pallas, top_k=top_k,
                                capacity=128, normalize=True,
                                interpret=False)
-        lower_tpu(fn, sds(4096, 64, dtype=jnp.float32))
+        chip.compile(fn, ((4096, 64), F32))
 
 
+# ---------------------------------------------------------- int8 matmuls
 class TestQuantMatmulLowering:
-    @pytest.mark.parametrize("shape", [(1, 768, 2048),    # decode step
-                                       (8192, 768, 32000)])  # lm head
-    def test_weight_only_matmul(self, shape):
-        from paddle_tpu.ops.pallas.quant_matmul import (
-            weight_only_matmul_pallas)
+    @pytest.mark.parametrize("shape", [(1, 768, 2048),      # decode step
+                                       (8192, 768, 32000),  # lm head
+                                       (8, HID7, FFN7),     # 7b up-proj
+                                       (1024, FFN7, HID7)],  # 7b down-proj
+                             ids=str)
+    def test_weight_only_matmul(self, chip, shape):
         m, k, n = shape
-        lower_tpu(
+        chip.compile(
             functools.partial(weight_only_matmul_pallas, interpret=False),
-            sds(m, k), sds(k, n, dtype=jnp.int8),
-            sds(n, dtype=jnp.float32))
+            ((m, k),), ((k, n), I8), ((n,), F32))
+
+    @pytest.mark.parametrize("shape", [(8, HID7, FFN7), (1024, FFN7, HID7)],
+                             ids=str)
+    def test_w8a8_matmul(self, chip, shape):
+        m, k, n = shape
+        chip.compile(
+            functools.partial(w8a8_matmul_pallas, out_dtype=BF16,
+                              interpret=False),
+            ((m, k), I8), ((m, 1), F32), ((k, n), I8), ((n,), F32))

@@ -1,7 +1,7 @@
 """Int8 weight-only matmul Pallas kernel (ops/pallas/quant_matmul.py)
 vs its XLA oracle, through the interpreter on CPU (Mosaic lowering is
-covered by test_pallas_mosaic_lowering.py; on-device execution by
-tools/pallas_tpu_validate.py).
+covered by test_pallas_mosaic_lowering.py; the kernels of the main
+paths run on the chip in chip_smoke.py's kernel phase).
 
 Reference capability: fused weight-only linear,
 paddle/phi/kernels/fusion/gpu (weight-only linear family) behind

@@ -99,7 +99,7 @@ class TestBarrierReuse:
 
 def _rank_proc(rank, world, port, results):
     from paddle_tpu.framework.backend_guard import helper_process_init
-    helper_process_init()   # survive a wedged TPU plugin in spawned children
+    helper_process_init()   # spawned children never claim the chip
     store = TCPStore("127.0.0.1", port, is_master=False, world_size=world,
                      timeout=20)
     store.set(f"rank/{rank}", pickle.dumps({"rank": rank}))

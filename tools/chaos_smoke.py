@@ -480,7 +480,10 @@ def run_hard_kill() -> dict:
     journal_dir = os.path.join(work, "journal")
     portfile = os.path.join(work, "port")
     logf = open(os.path.join(work, "child.log"), "ab")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # CPU-only children that are killed mid-run: never on the chip, and
+    # no persistent compile cache (the server's start() would place one)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false")
 
     def spawn(delay):
         if os.path.exists(portfile):
@@ -663,7 +666,10 @@ def run_fleet_kill() -> dict:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     work = tempfile.mkdtemp(prefix="chaos-fleet-")
     logf = open(os.path.join(work, "children.log"), "ab")
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # CPU-only children that are killed mid-run: never on the chip, and
+    # no persistent compile cache (the server's start() would place one)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="false")
 
     def spawn(name, delay, tp=1):
         jdir = os.path.join(work, name, "journal")

@@ -59,8 +59,8 @@ def run(out_path: str, repeats: int = 50) -> dict:
     results = {}
     for name, fn in _bench_cases().items():
         jax.block_until_ready(fn()._data)       # compile + warm
-        # min-of-N: robust against dispatch-latency noise (remote tunnels,
-        # host jitter) — the reference gate compares medians for the same
+        # min-of-N: robust against dispatch-latency noise (host jitter)
+        # — the reference gate compares medians for the same
         # reason (check_op_benchmark_result.py)
         best = float("inf")
         for _ in range(repeats):

@@ -469,6 +469,7 @@ def run_bench(model=None, sharers: int = 6, uniques: int = 3,
         "program_hbm_bytes": cost_est.hbm_bytes,
         "flops_per_token": flops_per_token,
         "peak_flops": peak,
+        "peak_source": _cost.peak_source(),
         "mfu": mfu,
         # SPMD/memory audit (ISSUE 11): the tier-3 field group — the
         # static HBM verdict and the compute-vs-communication roofline
@@ -1116,7 +1117,7 @@ def run_tp_lane(argv) -> int:
     # so the virtual CPU devices must be provisioned now, while the
     # backend is still un-initialized (no-op on real multi-chip hosts
     # and under the test suite's pre-split conftest)
-    if tp > 1 and not _jc._backend_initialized():
+    if tp > 1 and not _jc.backend_initialized():
         _jc.pin_cpu_devices(max(tp, 2))
     vocab = _int_arg(argv, "vocab", 64)
     hidden = _int_arg(argv, "hidden", 32)
@@ -2026,6 +2027,8 @@ def _fault_plan_arg(argv):
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from paddle_tpu.framework.compile_cache import configure_compile_cache
+    configure_compile_cache()
     if "--scenario-matrix" in argv:
         # heterogeneous-workload lane (ISSUE 7): chat + RAG + offline
         # batch through the scheduler, one JSON line per class plus a

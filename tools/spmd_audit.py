@@ -9,8 +9,7 @@ printing JSON:
     bandwidth-table path still executes):
 
       - `dp_allreduce`: a shard_map gradient-sync psum — the data-
-        parallel shape whose 8-device weak-scaling efficiency measured
-        0.122 (BENCH_r03);
+        parallel shape;
       - `tp_matmul`: a row-parallel matmul whose partial products psum
         on the 'tensor' axis — the TP-fleet shape the ROADMAP gates on.
 

@@ -246,6 +246,7 @@ def run_bench(k: int = 4, dispatches: int = 4, single_steps: int = 8,
         "program_flops": cost_est.flops,
         "program_hbm_bytes": cost_est.hbm_bytes,
         "peak_flops": peak,
+        "peak_source": _cost.peak_source(),
         "mfu": mfu,
         # SPMD/memory audit (ISSUE 11): static HBM verdict (fused
         # program) + predicted-vs-measured on the single-step program
@@ -279,6 +280,8 @@ def _int_arg(argv, name, default):
 
 def main(argv=None) -> int:
     argv = sys.argv[1:] if argv is None else argv
+    from paddle_tpu.framework.compile_cache import configure_compile_cache
+    configure_compile_cache()
     out = run_bench(k=_int_arg(argv, "k", 4),
                     dispatches=_int_arg(argv, "dispatches", 4),
                     single_steps=_int_arg(argv, "single-steps", 8),
