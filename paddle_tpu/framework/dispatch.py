@@ -27,6 +27,7 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.tree_util as jtu
 
+from ..monitor.span import span as _span
 from . import dtype as dtypes
 from . import tape as _tape
 from .flags import get_flag
@@ -113,10 +114,9 @@ def _run_post_op_hooks(name, result):
         h(name, result)
 
 
-# Host-event recorder hook, installed while a Profiler is in a RECORD state:
-# records one span per eager op (reference: RecordEvent spans auto-inserted by
-# eager_gen.py:322).  None when profiling is off, so the hot path pays one
-# attribute read.
+# Set while a Profiler is in a RECORD state: one host span per eager op
+# (reference: RecordEvent spans auto-inserted by eager_gen.py:322).  None
+# when profiling is off, so the hot path pays one attribute read.
 _prof_recorder = None
 
 
@@ -127,13 +127,9 @@ def set_profiler_recorder(rec) -> None:
 
 def call_op(name: str, fn: Callable, args: tuple, kwargs: dict):
     """Execute ``fn`` (a pure jax-array function) with tape recording."""
-    rec = _prof_recorder
-    if rec is not None:
-        start = rec.now_ns()
-        try:
+    if _prof_recorder is not None:
+        with _span("op::" + name):
             return _call_op_impl(name, fn, args, kwargs)
-        finally:
-            rec.push("op::" + name, start, rec.now_ns())
     return _call_op_impl(name, fn, args, kwargs)
 
 

@@ -2362,45 +2362,46 @@ class ContinuousBatchingEngine:
             if spec:
                 drafts = self._propose_drafts(active)
             nchunks = len(chunks)
-            seq_ids, rows, ctxs, nds = [], [], [], []
-            for req, target, k, n, _last in chunks:
-                seq_ids.append(req.seq_id)
-                rows.append(np.asarray(target[k:k + n], np.int32))
-                ctxs.append(k)
-                nds.append(0)
-            for i, r in enumerate(active):
-                seq_ids.append(r.seq_id)
-                if spec:
-                    row = np.empty(k_spec + 1, np.int32)
-                    row[0] = r.generated[-1]
-                    row[1:] = drafts[i]
-                    nds.append(k_spec)
-                else:
-                    row = np.asarray([r.generated[-1]], np.int32)
+            with monitor.span("engine/build"):
+                seq_ids, rows, ctxs, nds = [], [], [], []
+                for req, target, k, n, _last in chunks:
+                    seq_ids.append(req.seq_id)
+                    rows.append(np.asarray(target[k:k + n], np.int32))
+                    ctxs.append(k)
                     nds.append(0)
-                rows.append(row)
-                ctxs.append(self.cache.length(r.seq_id))
-            if self.sample_on_device:
-                b = len(seq_ids)
-                seeds = np.zeros(b, np.uint32)
-                temps = np.ones(b, np.float32)
-                flags = np.zeros(b, bool)
-                # the draw counter is computed IN-PROGRAM per row
-                # (ctx + span - drafts + accept), so chunk-final,
-                # decode and verify draws all land on the row's
-                # absolute token position — the replay-stable counter
-                # contract.  Intermediate chunk rows draw nothing.
-                live = [req if last else None
-                        for req, _t, _k, _n, last in chunks] + active
-                for i, r in enumerate(live):
-                    if r is None:
-                        continue
-                    seeds[i] = r.seed
-                    temps[i] = max(r.temperature, 1e-6)
-                    flags[i] = r.do_sample
-                sampling = (seeds, temps, flags)
-            else:
-                sampling = None
+                for i, r in enumerate(active):
+                    seq_ids.append(r.seq_id)
+                    if spec:
+                        row = np.empty(k_spec + 1, np.int32)
+                        row[0] = r.generated[-1]
+                        row[1:] = drafts[i]
+                        nds.append(k_spec)
+                    else:
+                        row = np.asarray([r.generated[-1]], np.int32)
+                        nds.append(0)
+                    rows.append(row)
+                    ctxs.append(self.cache.length(r.seq_id))
+                if self.sample_on_device:
+                    b = len(seq_ids)
+                    seeds = np.zeros(b, np.uint32)
+                    temps = np.ones(b, np.float32)
+                    flags = np.zeros(b, bool)
+                    # the draw counter is computed IN-PROGRAM per row
+                    # (ctx + span - drafts + accept), so chunk-final,
+                    # decode and verify draws all land on the row's
+                    # absolute token position — the replay-stable counter
+                    # contract.  Intermediate chunk rows draw nothing.
+                    live = [req if last else None
+                            for req, _t, _k, _n, last in chunks] + active
+                    for i, r in enumerate(live):
+                        if r is None:
+                            continue
+                        seeds[i] = r.seed
+                        temps[i] = max(r.temperature, 1e-6)
+                        flags[i] = r.do_sample
+                    sampling = (seeds, temps, flags)
+                else:
+                    sampling = None
             self._wedged.clear()
             t0 = self._step_started_at = time.monotonic()
             try:
@@ -2420,6 +2421,7 @@ class ContinuousBatchingEngine:
                         "decode_step",
                         seq_ids=[r.seq_id for r in active])
                 hist = _decode_step_s if active else _prefill_s
+                t_disp = _tracer.now_ns() if t_tr else 0
                 with monitor.span("engine/ragged_step", histogram=hist):
                     self._count_dispatch("ragged")
                     out, accept = self._decoder.ragged_step(
@@ -2445,133 +2447,139 @@ class ContinuousBatchingEngine:
             if self._active:
                 self._decode_step()
             return
-        self._unified_failures = 0
-        now_ns = _tracer.now_ns() if _tracer.enabled and t_tr else 0
-        # ---- chunk rows: the legacy _prefill_chunk bookkeeping
-        completed: List[_Request] = []
-        for i, (req, _target, k, n, last) in enumerate(chunks):
-            req.prefill_pos = k + n
-            req.chunks_done += 1
-            self._sched.note_chunk(req)
-            if _tracer.enabled and t_tr:
-                _tracer.step_record(
-                    "prefill_chunk", self.steps, t_tr, now_ns,
-                    request=req.request_id, tokens=n, pos=k,
-                    cls=req.priority)
-                _tracer.request_event(req.request_id, "prefill_chunk",
-                                      tokens=n, pos=k,
-                                      chunk=req.chunks_done)
-            if last:
-                completed.append(req)
-                self._finish_prefill(req, out[i], sampling is not None)
-        # ---- decode/verify rows: the legacy _decode_step retirement
-        still, retired = [], []
-        accepted_emitted = 0
-        if active:
-            srows = []
-            d_idx = ([i for i, r in enumerate(active) if r.use_draft]
-                     if spec else [])
-            for i, r in enumerate(active):
-                if spec:
-                    a = int(accept[nchunks + i])
-                    # page-granular partial rollback, both caches —
-                    # the _exec_spec_step contract
-                    new_len = lens_before[r.seq_id][0] + a + 1
-                    self.cache.truncate(r.seq_id, new_len)
-                    if r.use_draft:
-                        self.draft_cache.truncate(r.seq_id, new_len)
-                    srows.append(_SpecRow(out[nchunks + i], a,
-                                          drafts[i]))
-                else:
-                    srows.append(out[nchunks + i])
-            if spec:
-                self._last_spec = (
-                    k_spec * len(d_idx),
-                    sum(int(accept[nchunks + i]) for i in d_idx))
-                if d_idx:
-                    _spec_proposed.inc(k_spec * len(d_idx))
-                    _spec_accepted.inc(self._last_spec[1])
-                    rejected = 0
-                    for i in d_idx:
-                        _spec_accept_len.observe(
-                            int(accept[nchunks + i]))
-                        rejected += int(accept[nchunks + i]) < k_spec
-                    if rejected:
-                        _spec_rollback.inc(rejected)
-                _spec_draft_pages.set(self.draft_cache.pinned_pages)
-            else:
-                self._last_spec = (0, 0)
-            if _tracer.enabled and t_tr:
-                comp: dict = {}
-                for r in active:
-                    comp[r.priority] = comp.get(r.priority, 0) + 1
-                prop, acc = self._last_spec
-                _tracer.step_record(
-                    "decode", self.steps, t_tr, now_ns,
-                    batch=len(active), classes=comp,
-                    spec_proposed=prop, spec_accepted=acc, poisoned=0,
-                    requests=[r.request_id for r in active])
-            _tokens_total.inc(len(active))
-            on_device = self.sample_on_device
-            for r, row in zip(active, srows):
-                if _tracer.enabled:
-                    if isinstance(row, _SpecRow):
-                        _tracer.request_event(
-                            r.request_id, "verify_step",
-                            step=self.steps, accept=int(row.accept))
+        with monitor.span("engine/commit"):
+            self._unified_failures = 0
+            now_ns = _tracer.now_ns() if _tracer.enabled and t_tr else 0
+            if now_ns:
+                # what the decoder says it dispatched: the (rows, span,
+                # table) bucket against the real tokens and contexts
+                _tracer.step_record("dispatch", self.steps, t_disp,
+                                    now_ns, **self._decoder.last_dispatch)
+            # ---- chunk rows: the legacy _prefill_chunk bookkeeping
+            completed: List[_Request] = []
+            for i, (req, _target, k, n, last) in enumerate(chunks):
+                req.prefill_pos = k + n
+                req.chunks_done += 1
+                self._sched.note_chunk(req)
+                if _tracer.enabled and t_tr:
+                    _tracer.step_record(
+                        "prefill_chunk", self.steps, t_tr, now_ns,
+                        request=req.request_id, tokens=n, pos=k,
+                        cls=req.priority)
+                    _tracer.request_event(req.request_id, "prefill_chunk",
+                                          tokens=n, pos=k,
+                                          chunk=req.chunks_done)
+                if last:
+                    completed.append(req)
+                    self._finish_prefill(req, out[i], sampling is not None)
+            # ---- decode/verify rows: the legacy _decode_step retirement
+            still, retired = [], []
+            accepted_emitted = 0
+            if active:
+                srows = []
+                d_idx = ([i for i, r in enumerate(active) if r.use_draft]
+                         if spec else [])
+                for i, r in enumerate(active):
+                    if spec:
+                        a = int(accept[nchunks + i])
+                        # page-granular partial rollback, both caches —
+                        # the _exec_spec_step contract
+                        new_len = lens_before[r.seq_id][0] + a + 1
+                        self.cache.truncate(r.seq_id, new_len)
+                        if r.use_draft:
+                            self.draft_cache.truncate(r.seq_id, new_len)
+                        srows.append(_SpecRow(out[nchunks + i], a,
+                                              drafts[i]))
                     else:
-                        _tracer.request_event(r.request_id,
-                                              "decode_step",
-                                              step=self.steps)
-                eos_hit = (r.eos_token_id is not None
-                           and r.generated[-1] == r.eos_token_id)
-                if eos_hit or len(r.generated) >= r.max_new_tokens:
-                    retired.append(r)
-                    continue
-                if isinstance(row, _SpecRow):
-                    done = False
-                    for t in row.drafts[:row.accept]:
-                        r.generated.append(int(t))
-                        accepted_emitted += 1
-                        if (r.eos_token_id is not None
-                                and int(t) == r.eos_token_id) \
-                                or len(r.generated) >= r.max_new_tokens:
-                            done = True
-                            break
-                    if done:
+                        srows.append(out[nchunks + i])
+                if spec:
+                    self._last_spec = (
+                        k_spec * len(d_idx),
+                        sum(int(accept[nchunks + i]) for i in d_idx))
+                    if d_idx:
+                        _spec_proposed.inc(k_spec * len(d_idx))
+                        _spec_accepted.inc(self._last_spec[1])
+                        rejected = 0
+                        for i in d_idx:
+                            _spec_accept_len.observe(
+                                int(accept[nchunks + i]))
+                            rejected += int(accept[nchunks + i]) < k_spec
+                        if rejected:
+                            _spec_rollback.inc(rejected)
+                    _spec_draft_pages.set(self.draft_cache.pinned_pages)
+                else:
+                    self._last_spec = (0, 0)
+                if _tracer.enabled and t_tr:
+                    comp: dict = {}
+                    for r in active:
+                        comp[r.priority] = comp.get(r.priority, 0) + 1
+                    prop, acc = self._last_spec
+                    _tracer.step_record(
+                        "decode", self.steps, t_tr, now_ns,
+                        batch=len(active), classes=comp,
+                        spec_proposed=prop, spec_accepted=acc, poisoned=0,
+                        requests=[r.request_id for r in active])
+                _tokens_total.inc(len(active))
+                on_device = self.sample_on_device
+                for r, row in zip(active, srows):
+                    if _tracer.enabled:
+                        if isinstance(row, _SpecRow):
+                            _tracer.request_event(
+                                r.request_id, "verify_step",
+                                step=self.steps, accept=int(row.accept))
+                        else:
+                            _tracer.request_event(r.request_id,
+                                                  "decode_step",
+                                                  step=self.steps)
+                    eos_hit = (r.eos_token_id is not None
+                               and r.generated[-1] == r.eos_token_id)
+                    if eos_hit or len(r.generated) >= r.max_new_tokens:
                         retired.append(r)
                         continue
-                    out_row = row.out
-                else:
-                    out_row = row
-                r.next_token = (int(out_row) if on_device
-                                else self._pick(r, out_row))
-                still.append(r)
-            if accepted_emitted:
-                _tokens_total.inc(accepted_emitted)
-            if self.journal is not None:
-                for r in still:
-                    self._jrows.append(
-                        (r.request_id,
-                         list(r.generated[jlens[id(r)]:]),
-                         r.next_token))
-        with self._cond:
+                    if isinstance(row, _SpecRow):
+                        done = False
+                        for t in row.drafts[:row.accept]:
+                            r.generated.append(int(t))
+                            accepted_emitted += 1
+                            if (r.eos_token_id is not None
+                                    and int(t) == r.eos_token_id) \
+                                    or len(r.generated) >= r.max_new_tokens:
+                                done = True
+                                break
+                        if done:
+                            retired.append(r)
+                            continue
+                        out_row = row.out
+                    else:
+                        out_row = row
+                    r.next_token = (int(out_row) if on_device
+                                    else self._pick(r, out_row))
+                    still.append(r)
+                if accepted_emitted:
+                    _tokens_total.inc(accepted_emitted)
+                if self.journal is not None:
+                    for r in still:
+                        self._jrows.append(
+                            (r.request_id,
+                             list(r.generated[jlens[id(r)]:]),
+                             r.next_token))
+            with self._cond:
+                if active:
+                    self.steps += 1
+                    for r in retired:
+                        self._retire_locked(r)
+                    self._active = still
+                    if not still:
+                        self._free_pads_locked()
+                for r in completed:
+                    if r in self._prefilling:
+                        self._prefilling.remove(r)
+                        self._active.append(r)
+                self._cond.notify_all()
             if active:
-                self.steps += 1
-                for r in retired:
-                    self._retire_locked(r)
-                self._active = still
-                if not still:
-                    self._free_pads_locked()
-            for r in completed:
-                if r in self._prefilling:
-                    self._prefilling.remove(r)
-                    self._active.append(r)
-            self._cond.notify_all()
-        if active:
-            _active_seqs.set(len(still))
-        for r in retired:
-            r.done.set()
+                _active_seqs.set(len(still))
+            for r in retired:
+                r.done.set()
 
     def _pick(self, req, logits_row) -> int:
         from .paged import sample_token
@@ -3425,7 +3433,8 @@ class ContinuousBatchingEngine:
                     # shedding the first arrivals of the next burst
                     if self._brownout:
                         self._set_brownout_locked(0, 0.0)
-                    self._cond.wait(timeout=0.5)
+                    with monitor.span("engine/wait"):
+                        self._cond.wait(timeout=0.5)
                 if self._stop:
                     self._free_pads_locked()
                     stopped = (self._sched.pop_all() + self._prefilling
@@ -3438,77 +3447,91 @@ class ContinuousBatchingEngine:
                         self._cache_result_locked(r)
                         r.done.set()
                     return
-            try:
-                with self._cond:
-                    reaped = self._reap_locked()
-                    # closed-loop overload protection (ISSUE 19): one
-                    # controller evaluation per iteration — the ladder
-                    # first (its level gates this iteration's sheds),
-                    # then the TPOT trigger (its freed slot is visible
-                    # to the admission pass below)
-                    self._update_brownout_locked()
-                    self._tpot_preempt_locked()
-                    self._admit_locked()
-                    plan = self._plan_chunks_locked()
-                    # snapshot barrier (ISSUE 8): a waiting snapshot()
-                    # reads its consistent between-steps cut before the
-                    # next device batch opens (the wait releases the
-                    # lock; nothing below mutates what was planned)
-                    while self._snap_waiters and not self._stop:
-                        self._cond.wait(0.1)
-                    self._stepping = bool(plan) or bool(self._active)
-            except BaseException as e:  # noqa: BLE001 — scheduler fault
-                # a bug in admission/reaping must fail the in-flight
-                # requests LOUDLY, never kill this thread silently and
-                # leave every waiter blocked on a dead engine
-                self._fail_all(e)
-                continue
-            for r in reaped:
-                r.done.set()
-            # TPOT signal (ISSUE 19): for an active row one iteration
-            # is one output token, so the whole iteration's wall time —
-            # chunks included — is the per-token latency the budget is
-            # judged against.  Scheduler-thread only, like _disp_n.
-            had_active = bool(self._active)
-            t_iter = time.perf_counter()
-            try:
-                if self._legacy_iteration():
-                    # legacy composition: at most ~a chunk budget of
-                    # prefill dispatches, then ONE decode step for
-                    # everything active (ISSUE 7 interleaving);
-                    # per-chunk failures quarantine only their own
-                    # request (ISSUE 4 discipline carried over)
-                    self._run_chunks(plan)     # device work: outside lock
-                    if self._active:
-                        self._decode_step()
-                else:
-                    # unified ragged step (ISSUE 17): the chunk plan's
-                    # spans + every active row in ONE compiled dispatch
-                    if self.prefill_chunk_tokens is None and plan:
-                        # unchunked: full-prompt spans would give the
-                        # ragged program an unbounded (rows, max-span)
-                        # bucket space — every novel prompt length a
-                        # recompile.  Keep whole-prompt prefill on the
-                        # legacy length-bucketed program and fold only
-                        # the active rows (span 1 or k+1: bounded)
-                        # into the ragged dispatch.
-                        self._run_chunks(plan)
-                        plan = ()
-                    self._unified_step(plan)
-            except BaseException as e:  # noqa: BLE001 — fail loudly, not hang
-                self._fail_all(e)
-            finally:
-                if had_active:
-                    dt = time.perf_counter() - t_iter
-                    self._step_ewma = (dt if self._step_ewma is None
-                                       else 0.7 * self._step_ewma
-                                       + 0.3 * dt)
-                # ISSUE 13: the iteration's coalesced journal record —
-                # admitted ids + per-row emissions — enqueued ONCE per
-                # loop pass (rows for requests _fail_all just retired
-                # are ignored at replay: their retire precedes them)
+            # one iteration; the unified step's is one ``engine/step
+            # <index>`` span on the profiler's clock, <index> being the
+            # ``index`` its step-ring records carry
+            if self._legacy_iteration():
+                self._iteration(True)
+            else:
+                with monitor.span(f"engine/step {self.steps}"):
+                    self._iteration(False)
+
+    def _iteration(self, legacy: bool) -> None:
+        """One pass of the scheduler thread: the scheduling pass under
+        the lock (``engine/schedule``), the device work outside it, the
+        journal flush (``engine/commit``)."""
+        try:
+            with monitor.span("engine/schedule"), self._cond:
+                reaped = self._reap_locked()
+                # closed-loop overload protection (ISSUE 19): one
+                # controller evaluation per iteration — the ladder
+                # first (its level gates this iteration's sheds),
+                # then the TPOT trigger (its freed slot is visible
+                # to the admission pass below)
+                self._update_brownout_locked()
+                self._tpot_preempt_locked()
+                self._admit_locked()
+                plan = self._plan_chunks_locked()
+                # snapshot barrier (ISSUE 8): a waiting snapshot()
+                # reads its consistent between-steps cut before the
+                # next device batch opens (the wait releases the
+                # lock; nothing below mutates what was planned)
+                while self._snap_waiters and not self._stop:
+                    self._cond.wait(0.1)
+                self._stepping = bool(plan) or bool(self._active)
+        except BaseException as e:  # noqa: BLE001 — scheduler fault
+            # a bug in admission/reaping must fail the in-flight
+            # requests LOUDLY, never kill this thread silently and
+            # leave every waiter blocked on a dead engine
+            self._fail_all(e)
+            return
+        for r in reaped:
+            r.done.set()
+        # TPOT signal (ISSUE 19): for an active row one iteration
+        # is one output token, so the whole iteration's wall time —
+        # chunks included — is the per-token latency the budget is
+        # judged against.  Scheduler-thread only, like _disp_n.
+        had_active = bool(self._active)
+        t_iter = time.perf_counter()
+        try:
+            if legacy:
+                # legacy composition: at most ~a chunk budget of
+                # prefill dispatches, then ONE decode step for
+                # everything active (ISSUE 7 interleaving);
+                # per-chunk failures quarantine only their own
+                # request (ISSUE 4 discipline carried over)
+                self._run_chunks(plan)     # device work: outside lock
+                if self._active:
+                    self._decode_step()
+            else:
+                # unified ragged step (ISSUE 17): the chunk plan's
+                # spans + every active row in ONE compiled dispatch
+                if self.prefill_chunk_tokens is None and plan:
+                    # unchunked: full-prompt spans would give the
+                    # ragged program an unbounded (rows, max-span)
+                    # bucket space — every novel prompt length a
+                    # recompile.  Keep whole-prompt prefill on the
+                    # legacy length-bucketed program and fold only
+                    # the active rows (span 1 or k+1: bounded)
+                    # into the ragged dispatch.
+                    self._run_chunks(plan)
+                    plan = ()
+                self._unified_step(plan)
+        except BaseException as e:  # noqa: BLE001 — fail loudly, not hang
+            self._fail_all(e)
+        finally:
+            if had_active:
+                dt = time.perf_counter() - t_iter
+                self._step_ewma = (dt if self._step_ewma is None
+                                   else 0.7 * self._step_ewma
+                                   + 0.3 * dt)
+            # ISSUE 13: the iteration's coalesced journal record —
+            # admitted ids + per-row emissions — enqueued ONCE per
+            # loop pass (rows for requests _fail_all just retired
+            # are ignored at replay: their retire precedes them)
+            with monitor.span("engine/commit"):
                 self._journal_flush_step()
-                if self._stepping:
-                    with self._cond:
-                        self._stepping = False
-                        self._cond.notify_all()
+            if self._stepping:
+                with self._cond:
+                    self._stepping = False
+                    self._cond.notify_all()

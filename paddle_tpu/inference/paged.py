@@ -23,6 +23,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import monitor
 from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
@@ -479,6 +480,7 @@ class JittedPagedDecoder:
         self._programs = {}              # (mode, sample) -> jitted fn
         self._program_fns = {}           # (mode, sample) -> raw traced fn
         self._jitted_multi = None        # built on first multi_step use
+        self.last_dispatch = None        # ragged_step's last bucket
 
     # -------------------------------------------------- compiled programs
     def _param_arrays(self):
@@ -1234,83 +1236,95 @@ class JittedPagedDecoder:
         hatch.  The CALLER rolls verify rows back to their accepted
         length with ``cache.truncate`` (same contract as
         :meth:`verify`)."""
-        b = len(seq_ids)
-        ns = [len(r) for r in rows]
-        if b == 0 or min(ns) < 1:
-            raise ValueError("every row needs at least one token")
-        nds = [0] * b if n_drafts is None else [int(x) for x in n_drafts]
-        before = []
-        for sid, k, n, nd in zip(seq_ids, ctxs, ns, nds):
-            if nd and n != nd + 1:
-                raise ValueError(
-                    f"verify row for {sid!r} must be 1 fed token + "
-                    f"{nd} drafts, got {n} tokens")
-            if cache.length(sid) != int(k):
-                raise ValueError(
-                    f"sequence {sid!r} is at length {cache.length(sid)}, "
-                    f"expected the cached context length {k}")
-            if int(k) + n > self.max_position:
-                raise ValueError(
-                    f"context {k} + span {n} exceeds "
-                    f"max_position_embeddings ({self.max_position})")
-            before.append(int(k))
-        # all-or-nothing page reservation with PER-ROW counts: a
-        # mid-batch exhaustion must not strand earlier rows' pages
-        cache.allocate_batch_atomic(seq_ids, ns)
-        # span bucket: clamp by the deepest context (the
-        # batch_context_prefill discipline) so the round-up never walks
-        # pad positions past the rope table on its own
-        s_b = max(max(ns),
-                  min(next_pow2(max(ns)),
-                      self.max_position - max(int(k) for k in ctxs)))
-        b_b = next_pow2(b)
-        ids = np.zeros((b_b, s_b), np.int32)
-        pg = np.full((b_b, s_b), cache.total_pages, np.int32)  # drop
-        sl = np.zeros((b_b, s_b), np.int32)
-        for i, (sid, row, n) in enumerate(zip(seq_ids, rows, ns)):
-            ids[i, :n] = np.asarray(row, np.int32)
-            rpg, rsl = cache.plan_write([sid], n)
-            pg[i, :n] = rpg
-            sl[i, :n] = rsl
-            cache.advance([sid], n)
-        needed = max(len(cache._seq_pages.get(sid, ()))
-                     for sid in seq_ids)
-        W = max(next_pow2(needed), self.min_table_pages)
-        tabs = np.zeros((b_b, W), np.int32)
-        for i, sid in enumerate(seq_ids):
-            t = cache._seq_pages[sid]
-            tabs[i, :len(t)] = t
-        ctx_arr = np.zeros(b_b, np.int32)
-        ctx_arr[:b] = np.asarray([int(k) for k in ctxs], np.int32)
-        ql = np.ones(b_b, np.int32)          # pad rows: 1-token span,
-        ql[:b] = np.asarray(ns, np.int32)    # ctx 0, dropped scatter
-        nd_arr = np.zeros(b_b, np.int32)
-        nd_arr[:b] = np.asarray(nds, np.int32)
-        if sampling is not None and b_b != b:
-            seeds, temps, flags = sampling
-            pad = b_b - b
-            sampling = (
-                np.concatenate([np.asarray(seeds, np.uint32),
-                                np.zeros(pad, np.uint32)]),
-                np.concatenate([np.asarray(temps, np.float32),
-                                np.ones(pad, np.float32)]),
-                np.concatenate([np.asarray(flags, bool),
-                                np.zeros(pad, bool)]))
-        sample, s_args = self._verify_sampling_args(sampling)
-        try:
-            _maybe_lose_buffers(cache, seq_ids)
-            out, accept, *pools = self._program("ragged", sample)(
-                self._param_arrays(), jnp.asarray(ids),
-                jnp.asarray(ctx_arr), jnp.asarray(ql),
-                jnp.asarray(pg.reshape(-1)), jnp.asarray(sl.reshape(-1)),
-                jnp.asarray(tabs), jnp.asarray(nd_arr), s_args,
-                *self._pool_args(cache), self._wscale_args())
-        except BaseException:
-            self._recover_pools(cache)
-            self._rollback_lengths(cache, seq_ids, before)
-            raise
-        self._store_pools(cache, *pools)
-        return np.asarray(out)[:b], np.asarray(accept)[:b]
+        with monitor.span("engine/build"):
+            b = len(seq_ids)
+            ns = [len(r) for r in rows]
+            if b == 0 or min(ns) < 1:
+                raise ValueError("every row needs at least one token")
+            nds = ([0] * b if n_drafts is None
+                   else [int(x) for x in n_drafts])
+            before = []
+            for sid, k, n, nd in zip(seq_ids, ctxs, ns, nds):
+                if nd and n != nd + 1:
+                    raise ValueError(
+                        f"verify row for {sid!r} must be 1 fed token + "
+                        f"{nd} drafts, got {n} tokens")
+                if cache.length(sid) != int(k):
+                    raise ValueError(
+                        f"sequence {sid!r} is at length "
+                        f"{cache.length(sid)}, expected the cached "
+                        f"context length {k}")
+                if int(k) + n > self.max_position:
+                    raise ValueError(
+                        f"context {k} + span {n} exceeds "
+                        f"max_position_embeddings ({self.max_position})")
+                before.append(int(k))
+            # all-or-nothing page reservation with PER-ROW counts: a
+            # mid-batch exhaustion must not strand earlier rows' pages
+            cache.allocate_batch_atomic(seq_ids, ns)
+            # span bucket: clamp by the deepest context (the
+            # batch_context_prefill discipline) so the round-up never
+            # walks pad positions past the rope table on its own
+            s_b = max(max(ns),
+                      min(next_pow2(max(ns)),
+                          self.max_position - max(int(k) for k in ctxs)))
+            b_b = next_pow2(b)
+            ids = np.zeros((b_b, s_b), np.int32)
+            pg = np.full((b_b, s_b), cache.total_pages, np.int32)  # drop
+            sl = np.zeros((b_b, s_b), np.int32)
+            for i, (sid, row, n) in enumerate(zip(seq_ids, rows, ns)):
+                ids[i, :n] = np.asarray(row, np.int32)
+                rpg, rsl = cache.plan_write([sid], n)
+                pg[i, :n] = rpg
+                sl[i, :n] = rsl
+                cache.advance([sid], n)
+            needed = max(len(cache._seq_pages.get(sid, ()))
+                         for sid in seq_ids)
+            W = max(next_pow2(needed), self.min_table_pages)
+            tabs = np.zeros((b_b, W), np.int32)
+            for i, sid in enumerate(seq_ids):
+                t = cache._seq_pages[sid]
+                tabs[i, :len(t)] = t
+            ctx_arr = np.zeros(b_b, np.int32)
+            ctx_arr[:b] = np.asarray([int(k) for k in ctxs], np.int32)
+            ql = np.ones(b_b, np.int32)          # pad rows: 1-token span,
+            ql[:b] = np.asarray(ns, np.int32)    # ctx 0, dropped scatter
+            nd_arr = np.zeros(b_b, np.int32)
+            nd_arr[:b] = np.asarray(nds, np.int32)
+            if sampling is not None and b_b != b:
+                seeds, temps, flags = sampling
+                pad = b_b - b
+                sampling = (
+                    np.concatenate([np.asarray(seeds, np.uint32),
+                                    np.zeros(pad, np.uint32)]),
+                    np.concatenate([np.asarray(temps, np.float32),
+                                    np.ones(pad, np.float32)]),
+                    np.concatenate([np.asarray(flags, bool),
+                                    np.zeros(pad, bool)]))
+        # what this dispatch computes against what it was asked for: the
+        # engine writes it into the step ring as the ``dispatch`` record
+        self.last_dispatch = {
+            "rows": b, "rows_padded": b_b, "span_padded": s_b,
+            "tokens": sum(ns), "ctx_tokens": sum(before) + sum(ns),
+            "table_pages": W, "page_size": cache.page_size}
+        with monitor.span("engine/dispatch"):
+            sample, s_args = self._verify_sampling_args(sampling)
+            try:
+                _maybe_lose_buffers(cache, seq_ids)
+                out, accept, *pools = self._program("ragged", sample)(
+                    self._param_arrays(), jnp.asarray(ids),
+                    jnp.asarray(ctx_arr), jnp.asarray(ql),
+                    jnp.asarray(pg.reshape(-1)),
+                    jnp.asarray(sl.reshape(-1)),
+                    jnp.asarray(tabs), jnp.asarray(nd_arr), s_args,
+                    *self._pool_args(cache), self._wscale_args())
+            except BaseException:
+                self._recover_pools(cache)
+                self._rollback_lengths(cache, seq_ids, before)
+                raise
+        with monitor.span("engine/fetch"):
+            self._store_pools(cache, *pools)
+            return np.asarray(out)[:b], np.asarray(accept)[:b]
 
     def _build_multi(self):
         """Jitted N-step GREEDY decode: lax.scan over the single-step

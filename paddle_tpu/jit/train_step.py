@@ -242,11 +242,16 @@ class TrainStep:
                         in_tree, [wrap_array(a) for a in in_leaves])
                     labels = jtu.tree_unflatten(
                         label_tree, [wrap_array(a) for a in label_leaves])
-                    with no_grad(), cast_ctx():
+                    # the three scopes are names in the compiled
+                    # program's metadata (a device trace splits the step
+                    # by them); the arithmetic does not change
+                    with no_grad(), cast_ctx(), \
+                            jax.named_scope("train/model"):
                         outputs = model(*inputs)
                     outs = outputs if isinstance(outputs, (list, tuple)) \
                         else (outputs,)
-                    loss = loss_fn(outputs, *labels)
+                    with jax.named_scope("train/loss"):
+                        loss = loss_fn(outputs, *labels)
                     out_arrays = [o._data for o in outs
                                   if isinstance(o, Tensor)]
                     return loss._data.astype(jnp.float32), out_arrays
@@ -280,34 +285,36 @@ class TrainStep:
                     clipped = grad_clip(pairs)
                 return [g._data for _, g in clipped]
 
-            if K == 1:
-                grads = apply_clip(grads)
-                new_arrays, new_states, new_masters = update_fn(
-                    lr, stepno, arrays, grads, states, masters)
-                new_accum = accum
-            else:
-                # accumulate; the k-th call applies the averaged update and
-                # resets the accumulators — both arms of ONE compiled cond
-                summed = [a + g for a, g in zip(accum, grads)]
+            with jax.named_scope("train/optimizer"):
+                if K == 1:
+                    grads = apply_clip(grads)
+                    new_arrays, new_states, new_masters = update_fn(
+                        lr, stepno, arrays, grads, states, masters)
+                    new_accum = accum
+                else:
+                    # accumulate; the k-th call applies the averaged update
+                    # and resets the accumulators — both arms of ONE
+                    # compiled cond
+                    summed = [a + g for a, g in zip(accum, grads)]
 
-                def do_update(operand):
-                    arrays_, states_, masters_, summed_ = operand
-                    # back to the grad dtype the update rule expects (the
-                    # K=1 path feeds raw param-dtype grads)
-                    denom = K if self.accumulate_avg else 1
-                    avg = apply_clip([(g / denom).astype(a.dtype)
-                                      for g, a in zip(summed_, arrays_)])
-                    na, ns, nm = update_fn(lr, stepno, arrays_, avg,
-                                           states_, masters_)
-                    return na, ns, nm, [jnp.zeros_like(g) for g in summed_]
+                    def do_update(operand):
+                        arrays_, states_, masters_, summed_ = operand
+                        # back to the grad dtype the update rule expects (the
+                        # K=1 path feeds raw param-dtype grads)
+                        denom = K if self.accumulate_avg else 1
+                        avg = apply_clip([(g / denom).astype(a.dtype)
+                                          for g, a in zip(summed_, arrays_)])
+                        na, ns, nm = update_fn(lr, stepno, arrays_, avg,
+                                               states_, masters_)
+                        return na, ns, nm, [jnp.zeros_like(g) for g in summed_]
 
-                def skip_update(operand):
-                    arrays_, states_, masters_, summed_ = operand
-                    return arrays_, states_, masters_, summed_
+                    def skip_update(operand):
+                        arrays_, states_, masters_, summed_ = operand
+                        return arrays_, states_, masters_, summed_
 
-                new_arrays, new_states, new_masters, new_accum = \
-                    jax.lax.cond(apply_flag, do_update, skip_update,
-                                 (arrays, states, masters, summed))
+                    new_arrays, new_states, new_masters, new_accum = \
+                        jax.lax.cond(apply_flag, do_update, skip_update,
+                                     (arrays, states, masters, summed))
             # pin outputs to their INITIAL placements: donated-buffer steps
             # otherwise drift to whatever GSPMD chose (e.g. ZeRO-1 params
             # silently becoming sharded after one step, erasing the

@@ -1,15 +1,31 @@
-"""Lightweight span tracing bridging the metrics registry and the
-profiler's host recorder.
+"""The one host-span primitive: a block timed once, written to every
+sink.
 
-A ``span`` times a block once and fans the measurement out to both
-consumers: a Histogram observation (always, metrics are unconditional)
-and a profiler ``HostEvent`` (only while a Profiler has the recorder in
-a RECORD state — the push is a no-op otherwise, matching RecordEvent's
-contract in profiler/record.py).
+A ``span`` fans its measurement out to three consumers:
+
+  * a Histogram observation (always — metrics are unconditional), on
+    ``time.perf_counter``;
+  * a profiler ``HostEvent`` (only while a Profiler or a capture window
+    has the recorder in a RECORD state — the push is a no-op
+    otherwise), on ``time.perf_counter_ns``;
+  * a ``jax.profiler.TraceAnnotation`` of the same name, once jax is
+    imported: under a ``jax.profiler`` trace the span lands on the
+    ``/host:CPU`` plane of the ``.xplane.pb``, on the profiler's own
+    clock beside the device's operations, so an idle gap on the device
+    can be attributed to what the host was doing in it.  With no trace
+    running the annotation is a TraceMe that records nothing (a quarter
+    of a microsecond).  A reader of the trace sees the bare name only,
+    so an identifier it must see goes into the name
+    (``engine/step 17``).
+
+``profiler.RecordEvent`` and the eager-op spans of
+``framework.dispatch`` go through this class, so there is one path from
+a host span to every sink.
 """
 from __future__ import annotations
 
 import functools
+import sys
 import time
 from typing import Optional
 
@@ -18,18 +34,32 @@ from .registry import Histogram
 
 __all__ = ["span"]
 
+_TraceAnnotation = None
+
+
+def _annotation(name: str):
+    """jax's TraceMe of this name, or None while jax is not imported
+    (``paddle_tpu.monitor`` stays importable before jax)."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        if "jax" not in sys.modules:
+            return None
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name)
+
 
 class span:
     """``with span("collective/all_reduce", histogram=h, kind="all_reduce"):``
 
     Times the block; observes elapsed seconds into ``histogram`` (with
-    the given labels) and records a host event named ``name`` for the
-    profiler timeline.  Usable as a decorator.  ``elapsed`` holds the
-    measured seconds after exit.
+    the given labels), records a host event named ``name`` for the
+    profiler timeline and annotates jax's trace with it.  Usable as a
+    decorator.  ``elapsed`` holds the measured seconds after exit.
     """
 
     __slots__ = ("name", "histogram", "labels", "elapsed",
-                 "_t0", "_start_ns")
+                 "_t0", "_start_ns", "_ann")
 
     def __init__(self, name: str, histogram: Optional[Histogram] = None,
                  **labels):
@@ -39,16 +69,23 @@ class span:
         self.elapsed: Optional[float] = None
         self._t0 = None
         self._start_ns = None
+        self._ann = None
 
     def __enter__(self):
         rec = get_recorder()
         if rec.enabled:
             self._start_ns = rec.now_ns()
+        ann = self._ann = _annotation(self.name)
+        if ann is not None:
+            ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.elapsed = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         if self.histogram is not None:
             self.histogram.observe(self.elapsed, **self.labels)
         if self._start_ns is not None:

@@ -20,10 +20,16 @@ Design constraints:
     request table caps its size (oldest evicted), and the engine-step
     ring is a fixed ``deque``; overflow increments
     ``trace_dropped_events_total`` instead of growing;
-  * **one clock** — timestamps are ``time.perf_counter_ns()``, the
-    same clock the profiler's Python recorder stamps ``HostEvent``s
-    with, so ``export_chrome_trace`` merges span/host events onto the
-    request/step tracks without skew arithmetic;
+  * **one clock per sink** — the request timelines, the step ring and
+    the recorder's ``HostEvent``s are stamped with
+    ``time.perf_counter_ns()``, so ``export_chrome_trace`` merges
+    span/host events onto the request/step tracks without skew
+    arithmetic; a ``jax.profiler`` trace has its own time base, which
+    ``monitor.span`` reaches through a ``TraceAnnotation`` (the device's
+    operations are on that one).  What joins the two is not a timestamp
+    but the step index: the ring's records carry ``index``, the trace
+    the span ``engine/step <index>``; histograms use
+    ``time.perf_counter``;
   * **stdlib only** — importable before jax, like the rest of
     ``paddle_tpu.monitor``.
 

@@ -146,6 +146,7 @@ def flash_attention_forward(q, k, v, causal=False, scale=None,
         block_kv=block_kv, kv_seq_len=sk, causal_offset=sk - sq)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
@@ -340,6 +341,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
         block_kv=block_kv, q_seq_len=sq, causal_offset=sk - sq)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_attention_bwd_dkv",
         grid=(b, h, n_kv, n_q),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),
@@ -382,6 +384,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
         block_kv=block_kv, kv_seq_len=sk, causal_offset=sk - sq)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_attention_bwd_dq",
         grid=(b, h, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d),

@@ -241,6 +241,7 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_attention_ragged" if ragged else "paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, d),
                                        q.dtype),

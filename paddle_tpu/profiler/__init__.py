@@ -1,14 +1,15 @@
 """paddle_tpu.profiler — profiling API (SURVEY #72/#34).
 
-Host spans via a native C++ thread-local recorder; device timelines via
-jax.profiler (XPlane); scheduler/RecordEvent/export surface mirrors the
-reference (python/paddle/profiler/).
+Host spans via ``monitor.span`` (the Python recorder for the chrome
+export, jax's TraceMe under a ``jax.profiler`` trace); device timelines
+via jax.profiler (XPlane); scheduler/RecordEvent/export surface mirrors
+the reference (python/paddle/profiler/).
 """
 from .profiler import (  # noqa: F401
     Profiler, ProfilerState, ProfilerTarget, make_scheduler,
     export_chrome_tracing, load_profiler_result,
 )
-from .record import RecordEvent, record_function, is_native_recorder  # noqa: F401
+from .record import RecordEvent, record_function  # noqa: F401
 from .statistics import SortedKeys  # noqa: F401
 from .timer import benchmark  # noqa: F401
 

@@ -4,8 +4,8 @@ Capability parity with the reference's Profiler
 (reference: python/paddle/profiler/profiler.py:358 — ProfilerState scheduler
 ``make_scheduler:129``, ``export_chrome_tracing:227``, summary statistics).
 
-TPU-native: host spans come from the C++ host tracer
-(paddle_tpu/native/host_tracer.cc); device timelines come from XLA via
+TPU-native: host spans come from ``monitor.span`` (``record.py``'s
+recorder, and jax's TraceMe); device timelines come from XLA via
 ``jax.profiler`` (XPlane/TensorBoard), started alongside when
 ``ProfilerTarget.TPU`` is requested.  Chrome-trace JSON is emitted for host
 events so the scheduler/export API surface matches the reference.

@@ -1,17 +1,17 @@
-"""Host event recording: RecordEvent spans + the recorder backends.
+"""Host event recording: RecordEvent spans + the recorder.
 
 Capability parity with the reference's RecordEvent/HostEventRecorder
 (reference: paddle/phi/api/profiler/host_event_recorder.h:231, RAII spans
-auto-inserted by codegen eager_gen.py:322).  The native backend is a C++
-thread-local recorder (paddle_tpu/native/host_tracer.cc); a pure-Python
-recorder is the fallback when no toolchain is available.
+auto-inserted by codegen eager_gen.py:322).  The recorder keeps the
+``paddle.profiler`` API and the chrome export working; the native
+thread-local recorder is jax's TraceMe, which every ``RecordEvent`` also
+reaches through ``monitor.span``.
 """
 from __future__ import annotations
 
-import ctypes
 import threading
 import time
-from typing import Dict, List, NamedTuple, Optional
+from typing import List, NamedTuple
 
 
 class HostEvent(NamedTuple):
@@ -22,7 +22,8 @@ class HostEvent(NamedTuple):
 
 
 class _PyRecorder:
-    """Pure-Python fallback recorder (lock per push; fine for fallback)."""
+    """The host recorder: a list of ``HostEvent`` under a lock, stamped
+    with ``time.perf_counter_ns``."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -48,114 +49,37 @@ class _PyRecorder:
         return out
 
 
-class _NativeRecorder:
-    """ctypes bridge to the C++ host tracer."""
-
-    def __init__(self, lib):
-        self._lib = lib
-        lib.pt_register_name.restype = ctypes.c_uint32
-        lib.pt_register_name.argtypes = [ctypes.c_char_p]
-        lib.pt_push_event.argtypes = [ctypes.c_uint32, ctypes.c_uint64,
-                                      ctypes.c_uint64]
-        lib.pt_now_ns.restype = ctypes.c_uint64
-        lib.pt_drain.restype = ctypes.c_uint64
-        lib.pt_read.restype = ctypes.c_uint64
-        lib.pt_read.argtypes = [ctypes.POINTER(ctypes.c_uint32),
-                                ctypes.POINTER(ctypes.c_uint64),
-                                ctypes.POINTER(ctypes.c_uint64),
-                                ctypes.POINTER(ctypes.c_uint64),
-                                ctypes.c_uint64]
-        lib.pt_name.restype = ctypes.c_char_p
-        lib.pt_name.argtypes = [ctypes.c_uint32]
-        self._name_ids: Dict[str, int] = {}
-        self._id_names: Dict[int, str] = {}
-        self.enabled = False
-
-    def enable(self, on: bool) -> None:
-        self._lib.pt_tracer_enable(1 if on else 0)
-        self.enabled = on
-
-    def now_ns(self) -> int:
-        return self._lib.pt_now_ns()
-
-    def _name_id(self, name: str) -> int:
-        nid = self._name_ids.get(name)
-        if nid is None:
-            nid = self._lib.pt_register_name(name.encode())
-            self._name_ids[name] = nid
-            self._id_names[nid] = name
-        return nid
-
-    def push(self, name: str, start_ns: int, end_ns: int) -> None:
-        if not self.enabled:
-            return
-        self._lib.pt_push_event(self._name_id(name), start_ns, end_ns)
-
-    def collect(self) -> List[HostEvent]:
-        # Two-phase atomic drain: pt_drain moves events into staging and
-        # returns the exact staged count; pt_read copies out that many.
-        n = int(self._lib.pt_drain())
-        if n == 0:
-            return []
-        ids = (ctypes.c_uint32 * n)()
-        tids = (ctypes.c_uint64 * n)()
-        starts = (ctypes.c_uint64 * n)()
-        ends = (ctypes.c_uint64 * n)()
-        got = int(self._lib.pt_read(ids, tids, starts, ends, n))
-        out = []
-        for i in range(got):
-            nid = int(ids[i])
-            name = self._id_names.get(nid)
-            if name is None:
-                name = self._lib.pt_name(nid).decode()
-                self._id_names[nid] = name
-            out.append(HostEvent(name, int(tids[i]), int(starts[i]),
-                                 int(ends[i])))
-        return out
+_recorder = _PyRecorder()
 
 
-_recorder = None
-_recorder_lock = threading.Lock()
-
-
-def get_recorder():
-    """The process-wide host recorder (native if buildable, else Python)."""
-    global _recorder
-    if _recorder is None:
-        with _recorder_lock:
-            if _recorder is None:
-                try:
-                    from ..native import load_native
-                    _recorder = _NativeRecorder(load_native("host_tracer"))
-                except Exception:
-                    _recorder = _PyRecorder()
+def get_recorder() -> _PyRecorder:
+    """The process-wide host recorder."""
     return _recorder
-
-
-def is_native_recorder() -> bool:
-    return isinstance(get_recorder(), _NativeRecorder)
 
 
 class RecordEvent:
     """User span: ``with RecordEvent("io"): ...`` (reference:
-    python/paddle/profiler/utils.py RecordEvent).  Records only while a
-    Profiler is in a RECORD state (or after explicit ``begin()``)."""
+    python/paddle/profiler/utils.py RecordEvent).  A ``monitor.span``
+    under the reference's name: the recorder keeps it only while a
+    Profiler is in a RECORD state at ``begin()``, a ``jax.profiler``
+    trace whenever one is running."""
 
     def __init__(self, name: str, event_type: str = "UserDefined"):
         self.name = name
         self.event_type = event_type
-        self._start = None
+        self._span = None
 
     def begin(self):
-        rec = get_recorder()
-        self._start = rec.now_ns()
+        # imported here: monitor.span imports this module's recorder
+        from ..monitor.span import span
+        self._span = span(self.name)
+        self._span.__enter__()
 
     def end(self):
-        if self._start is None:
+        if self._span is None:
             return
-        rec = get_recorder()
-        rec.push(self.name, self._start, rec.now_ns())
-        self._start = None
+        self._span.__exit__(None, None, None)
+        self._span = None
 
     def __enter__(self):
         self.begin()

@@ -265,6 +265,39 @@ class TestFlashAttentionLowering:
         assert len(text) > 10_000
 
 
+class TestKernelNames:
+    """The four kernels of the main paths carry their ``name=``: the
+    compiled instruction is named after it, and a device trace shows
+    each custom call under that name (benchmark ``breakdown.device_ops``,
+    ``kernel.*`` metrics)."""
+
+    @pytest.fixture(scope="class")
+    def programs(self, chip):
+        fwd = functools.partial(flash_attention_forward, causal=True,
+                                interpret=False)
+        bwd = chip.compile(_flash_bwd(True, D), *[((B, H, S, D),)] * 4,
+                           ((B, H, S), F32), ((B, H, S, D),))
+        return {
+            "paged_attention_ragged": chip.compile(
+                _paged_fn(D7, nq=8, ragged=True), *_paged_specs(
+                    chip, kvh=8, heads=H7, d=D7, batch=8, pages=512,
+                    page=16, table=64, nq=8, ragged=True)),
+            "flash_attention_fwd": chip.compile(fwd,
+                                                *[((B, H, S, D),)] * 3),
+            "flash_attention_bwd_dkv": bwd,
+            "flash_attention_bwd_dq": bwd,
+        }
+
+    @pytest.mark.parametrize("name", [
+        "paged_attention_ragged", "flash_attention_fwd",
+        "flash_attention_bwd_dkv", "flash_attention_bwd_dq"])
+    def test_instruction_is_named_after_the_kernel(self, programs, name):
+        calls = [ln for ln in programs[name].splitlines()
+                 if "tpu_custom_call" in ln and f"%{name}" in ln]
+        assert calls, f"no custom call named {name}"
+        assert f"/{name}/pallas_call" in calls[0]      # and its op_name
+
+
 class TestFlashMaskLowering:
     @pytest.mark.parametrize("ncol", [1, 2, 4])
     def test_forward(self, chip, ncol):

@@ -1,4 +1,4 @@
-"""Profiler subsystem tests: native recorder, scheduler, export, timer."""
+"""Profiler subsystem tests: recorder, scheduler, export, timer."""
 import json
 import os
 
@@ -10,15 +10,10 @@ from paddle_tpu.profiler import (
     Profiler, ProfilerState, ProfilerTarget, RecordEvent, SortedKeys,
     make_scheduler, export_chrome_tracing, load_profiler_result,
 )
-from paddle_tpu.profiler.record import get_recorder, is_native_recorder
+from paddle_tpu.profiler.record import get_recorder
 
 
 class TestRecorder:
-    def test_native_backend_builds(self):
-        # The C++ recorder must compile in this image (g++ is baked in);
-        # fall back silently only where no toolchain exists.
-        assert is_native_recorder()
-
     def test_span_capture(self):
         rec = get_recorder()
         rec.enable(True)

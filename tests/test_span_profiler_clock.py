@@ -1,0 +1,312 @@
+"""Spans on the profiler's clock (ISSUE 27): ``monitor.span`` is the one
+host-span primitive and also writes a ``jax.profiler.TraceAnnotation``,
+the serving iteration's phases are such spans under one ``engine/step
+<index>``, every ragged dispatch leaves one ``dispatch`` record in the
+step ring, the compile hooks keep the seconds of each compile phase, and
+``TrainStep`` names its model / loss / optimizer scopes.  No timing
+assertion anywhere: only names, nesting and counts."""
+import glob
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import monitor
+from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+from paddle_tpu.profiler import RecordEvent
+
+PHASES = ("engine/schedule", "engine/build", "engine/dispatch",
+          "engine/fetch", "engine/commit")
+
+
+def host_events(trace_dir):
+    """[(name, start_ns, end_ns)] of every event of the trace's
+    ``/host:CPU`` plane."""
+    from jax.profiler import ProfileData
+    files = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert files, "the profiler wrote no trace"
+    out = []
+    for plane in ProfileData.from_file(files[-1]).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            out += [(ev.name, int(ev.start_ns),
+                     int(ev.start_ns + ev.duration_ns))
+                    for ev in line.events]
+    return out
+
+
+def named(events, name):
+    return sorted((s, e) for n, s, e in events if n == name)
+
+
+def inside(inner, outer):
+    return outer[0] <= inner[0] and inner[1] <= outer[1]
+
+
+class TestSpanUnderTrace:
+    @pytest.fixture(scope="class")
+    def events(self, tmp_path_factory):
+        d = str(tmp_path_factory.mktemp("span_trace"))
+        jax.profiler.start_trace(d)
+        try:
+            with monitor.span("outer/span"):
+                with monitor.span("inner/span"):
+                    jnp.ones(8).block_until_ready()
+                with RecordEvent("inner/record_event"):
+                    pass
+            with monitor.span("after/span"):
+                pass
+        finally:
+            jax.profiler.stop_trace()
+        return host_events(d)
+
+    @pytest.mark.parametrize("name", ["inner/span", "inner/record_event"])
+    def test_nested_on_the_host_plane(self, events, name):
+        (outer,), (inner,) = named(events, "outer/span"), named(events, name)
+        assert inside(inner, outer)
+
+    def test_sequential_spans_do_not_nest(self, events):
+        (outer,), (after,) = (named(events, "outer/span"),
+                              named(events, "after/span"))
+        assert after[0] >= outer[1]
+        (a,), (b,) = (named(events, "inner/span"),
+                      named(events, "inner/record_event"))
+        assert b[0] >= a[1]
+
+    def test_no_trace_running_costs_nothing_visible(self):
+        h = monitor.histogram("span_clock_test_seconds", "test")
+        with monitor.span("quiet/span", histogram=h) as sp:
+            pass
+        assert sp.elapsed is not None and h.sum_count()[1] >= 1
+
+    def test_record_event_reaches_the_recorder_through_span(self):
+        from paddle_tpu.profiler.record import get_recorder
+        rec = get_recorder()
+        rec.collect()
+        rec.enable(True)
+        try:
+            ev = RecordEvent("explicit")
+            ev.begin()
+            ev.end()
+            ev.end()                      # a second end is a no-op
+        finally:
+            rec.enable(False)
+        assert [e.name for e in rec.collect()] == ["explicit"]
+
+
+def tiny_model():
+    paddle.seed(0)
+    cfg = LlamaConfig(vocab_size=64, hidden_size=32, intermediate_size=64,
+                      num_hidden_layers=2, num_attention_heads=4,
+                      num_key_value_heads=2, max_position_embeddings=128)
+    return LlamaForCausalLM(cfg)
+
+
+class TestEngineStepUnderTrace:
+    @pytest.fixture(scope="class")
+    def run(self, tmp_path_factory):
+        """A tiny engine serves three prompts (chunked prefill + decode)
+        under a jax.profiler trace and a capture window."""
+        from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+        d = str(tmp_path_factory.mktemp("engine_trace"))
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, 64, (n,)).astype(np.int32)
+                   for n in (5, 19, 9)]
+        lens = []
+        monitor.start_capture(host_events=False)
+        jax.profiler.start_trace(d)
+        try:
+            # the engine stops inside the trace: the scheduler thread has
+            # left its last ``engine/step`` span before the trace ends
+            with ContinuousBatchingEngine(
+                    tiny_model(), total_pages=128, page_size=8, max_batch=4,
+                    prefill_chunk_tokens=8) as eng:
+                real = eng._decoder.ragged_step
+
+                def watched(cache, seq_ids, *a, **kw):
+                    out = real(cache, seq_ids, *a, **kw)
+                    lens.append(sum(cache.length(s) for s in seq_ids))
+                    return out
+                eng._decoder.ragged_step = watched
+                for r in [eng.submit(p, max_new_tokens=6) for p in prompts]:
+                    r.result(timeout=300)
+        finally:
+            jax.profiler.stop_trace()
+            monitor.stop_capture()
+        records = monitor.get_tracer().step_records()
+        return host_events(d), records, lens
+
+    def test_one_step_span_and_one_record_per_dispatch(self, run):
+        events, records, lens = run
+        disp = [r for r in records if r["kind"] == "dispatch"]
+        assert disp and len(disp) == len(lens)
+        steps = {}
+        for n, s, e in events:
+            m = re.fullmatch(r"engine/step (\d+)", n)
+            if m:
+                steps.setdefault(int(m.group(1)), []).append((s, e))
+        per_index = {}
+        for r in disp:
+            per_index[r["index"]] = per_index.get(r["index"], 0) + 1
+        # a step span without a dispatch is an iteration that had
+        # nothing to run; every dispatch has its span, index for index
+        for index, n in per_index.items():
+            assert len(steps.get(index, ())) >= n, (index, steps.keys())
+
+    def test_phases_disjoint_ordered_and_inside_their_step(self, run):
+        events, records, _ = run
+        steps = sorted((s, e) for n, s, e in events
+                       if n.startswith("engine/step "))
+        phases = sorted((s, e, n) for n, s, e in events if n in PHASES)
+        dispatching = 0
+        for step in steps:
+            mine = [(s, e, n) for s, e, n in phases if inside((s, e), step)]
+            names = [n for _s, _e, n in mine]
+            for (_s0, e0, _n0), (s1, _e1, _n1) in zip(mine, mine[1:]):
+                assert s1 >= e0, names          # disjoint
+            if "engine/dispatch" not in names:
+                continue
+            dispatching += 1
+            order = [PHASES.index(n) for n in names]
+            assert order == sorted(order), names
+            assert set(names) == set(PHASES), names
+        assert dispatching == len(
+            [r for r in records if r["kind"] == "dispatch"])
+        # every phase of a dispatching iteration lies in some step span
+        for s, e, n in phases:
+            if n in ("engine/build", "engine/dispatch", "engine/fetch"):
+                assert any(inside((s, e), st) for st in steps), n
+
+    def test_dispatch_record_fields(self, run):
+        _events, records, lens = run
+        disp = [r for r in records if r["kind"] == "dispatch"]
+        for r, after in zip(disp, lens):
+            assert 1 <= r["rows"] <= r["rows_padded"]
+            assert r["tokens"] <= r["rows_padded"] * r["span_padded"]
+            assert r["tokens"] >= r["rows"]
+            assert r["ctx_tokens"] == after
+            assert r["ctx_tokens"] <= (r["rows_padded"] * r["table_pages"]
+                                       * r["page_size"])
+            assert r["page_size"] == 8
+        assert any(r["span_padded"] > 1 for r in disp)     # a chunk step
+        assert any(r["span_padded"] == 1 for r in disp)    # a decode step
+
+    def test_dispatch_interval_lies_inside_the_steps_other_records(self, run):
+        _events, records, _ = run
+        by_index = {}
+        for r in records:
+            by_index.setdefault(r["index"], []).append(r)
+        for rs in by_index.values():
+            others = [r for r in rs if r["kind"] != "dispatch"]
+            for d in (r for r in rs if r["kind"] == "dispatch"):
+                assert any(o["start_ns"] <= d["start_ns"]
+                           and d["end_ns"] <= o["end_ns"] for o in others)
+
+    def test_idle_wait_is_a_span(self):
+        import time
+        from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+        from paddle_tpu.profiler.record import get_recorder
+        rec = get_recorder()
+        rec.collect()
+        rec.enable(True)
+        seen = []
+        try:
+            with ContinuousBatchingEngine(tiny_model(), total_pages=32,
+                                          page_size=8, max_batch=2) as eng:
+                eng.submit(np.asarray([1, 2, 3], np.int32),
+                           max_new_tokens=2).result(timeout=300)
+                # with nothing to do the loop sits in the wait, which
+                # closes every half second: poll, do not time
+                deadline = time.monotonic() + 60
+                while (time.monotonic() < deadline
+                       and "engine/wait" not in seen):
+                    time.sleep(0.05)
+                    seen += [e.name for e in rec.collect()]
+        finally:
+            rec.enable(False)
+        assert "engine/wait" in seen
+
+
+class TestCompilePhaseCounters:
+    NAMES = ("jit_trace_seconds_total", "jit_lower_seconds_total",
+             "jit_backend_compile_seconds_total")
+
+    @staticmethod
+    def totals():
+        out = {}
+        for name, m in monitor.snapshot().items():
+            if m["type"] == "counter":
+                out[name] = sum(s["value"] for s in m["series"])
+        return out
+
+    def test_series_exist_before_the_first_compile(self):
+        monitor.install_compile_hooks()
+        now = self.totals()
+        for name in self.NAMES + ("jit_recompile_count",):
+            assert name in now
+
+    def test_a_fresh_jit_raises_all_three_and_counts_one_program(self):
+        monitor.install_compile_hooks()
+        x = jnp.arange(7.0)                     # its own programs first
+        before = self.totals()
+
+        @jax.jit
+        def fresh(a):
+            return jnp.tanh(a) * 3.0 + jnp.sum(a)
+
+        fresh(x).block_until_ready()
+        after = self.totals()
+        for name in self.NAMES:
+            assert after[name] > before[name], name
+        assert after["jit_recompile_count"] - before[
+            "jit_recompile_count"] == 1
+        before = after
+        fresh(x).block_until_ready()            # served by the jit cache
+        after = self.totals()
+        for name in self.NAMES + ("jit_recompile_count",):
+            assert after[name] == before[name], name
+
+    def test_nested_events_are_not_counted_twice(self):
+        import time
+        from paddle_tpu.monitor import compile_hooks as ch
+        ch._local.done = []
+        assert ch._own_seconds(0.001) == pytest.approx(0.001)
+        # an event that began before two finished ones contains them
+        ch._local.done = [(time.time() - 0.5, 0.2), (time.time() - 0.2, 0.1)]
+        assert ch._own_seconds(1.0) == pytest.approx(0.7)
+        # a later sibling contains nothing
+        assert ch._own_seconds(0.0001) == pytest.approx(0.0001)
+        assert len(ch._local.done) == 2
+
+
+class TestTrainStepScopes:
+    @pytest.fixture(scope="class")
+    def text(self):
+        import paddle_tpu.nn as nn
+        import paddle_tpu.optimizer as optim
+        from paddle_tpu.jit.train_step import TrainStep
+
+        paddle.seed(0)
+        model = nn.Sequential(nn.Linear(8, 16), nn.ReLU(), nn.Linear(16, 4))
+        opt = optim.AdamW(learning_rate=1e-2, parameters=model.parameters())
+        step = TrainStep(model, lambda out, y: ((out - y) ** 2).mean(), opt)
+        x = paddle.to_tensor(np.ones((2, 8), np.float32))
+        y = paddle.to_tensor(np.zeros((2, 4), np.float32))
+        step([x], [y])
+        in_sds, label_sds, treedefs = step._last_sig
+        return step._lower(in_sds, label_sds, treedefs,
+                           as_avals=True).as_text(debug_info=True)
+
+    @pytest.mark.parametrize("scope", ["train/model", "train/loss",
+                                       "train/optimizer"])
+    def test_scope_in_the_lowered_text(self, text, scope):
+        assert scope in text
+
+    def test_backward_ops_carry_the_forward_scope(self, text):
+        assert re.search(r"transpose\(jvp\(train/model\)\)", text)
