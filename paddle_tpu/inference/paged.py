@@ -28,10 +28,11 @@ from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
-                                          dequantize_kv, paged_attention,
+                                          dequantize_kv, kv_tokens_walked,
+                                          paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
-                                          quantize_kv)
+                                          quantize_kv, walk_block_pages)
 from ..testing import faults as _faults
 
 
@@ -1302,11 +1303,20 @@ class JittedPagedDecoder:
                     np.concatenate([np.asarray(flags, bool),
                                     np.zeros(pad, bool)]))
         # what this dispatch computes against what it was asked for: the
-        # engine writes it into the step ring as the ``dispatch`` record
+        # engine writes it into the step ring as the ``dispatch`` record.
+        # ``kv_tokens_walked`` is what the paged kernel walks for these
+        # rows: each row's context in whole blocks, by the kernel's own
+        # rule (pad rows are one token long)
+        mc = self.model.config
+        block = cache.page_size * walk_block_pages(
+            cache.page_size, cache.head_dim,
+            s_b * (mc.num_attention_heads // mc.num_key_value_heads),
+            cache.k_pages[0].dtype)
         self.last_dispatch = {
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
             "tokens": sum(ns), "ctx_tokens": sum(before) + sum(ns),
-            "table_pages": W, "page_size": cache.page_size}
+            "table_pages": W, "page_size": cache.page_size,
+            "kv_tokens_walked": kv_tokens_walked(ctx_arr + ql, block)}
         with monitor.span("engine/dispatch"):
             sample, s_args = self._verify_sampling_args(sampling)
             try:

@@ -7,13 +7,22 @@ in fixed-size pages, a per-sequence block table maps logical positions to
 pages, decode attends one query token against the paged cache.
 
 TPU-native design (see /opt/skills/guides/pallas_guide.md):
-  - the decode kernel is a Pallas grid (batch, kv_heads, pages) with the
-    page axis sequential; the page table rides in as a SCALAR-PREFETCH
-    argument so each page's BlockSpec index_map points the pipeline DMA at
-    the right page (pltpu.PrefetchScalarGridSpec) — the same mechanism
-    jax's production paged_attention kernel uses;
-  - online softmax in VMEM scratch across pages; pages past a sequence's
-    length are predicated off (@pl.when), the tail page is column-masked;
+  - the decode kernel is a Pallas grid (batch, kv_heads): one grid step
+    per (row, kv head), which WALKS THE ROW'S OWN CONTEXT in blocks of
+    several pages (``walk_block_pages``: 128-512 tokens, from the shapes
+    and a VMEM budget) — ``ceil(length / block)`` blocks whatever the
+    page table's width, so a table pinned wide for a compile-free window
+    costs what a tight one costs;
+  - the pools stay in HBM (``memory_space=pl.ANY``); the lengths and the
+    page table ride in as SCALAR-PREFETCH arguments, and the kernel
+    starts one asynchronous copy a page (``pltpu.make_async_copy``) into
+    a VMEM buffer two blocks deep: the next block's pages are in flight
+    while this block's scores and products are computed.  Nothing is
+    fetched past a row's length — the same discipline as jax's
+    production paged_attention kernel, with per-row query spans and the
+    int8 scale pools besides;
+  - online softmax in VMEM scratch across blocks; the tail block is
+    column-masked (scores) and its unfetched slots zeroed (values);
   - GQA: the q-head group of each kv head computes together (group x
     head_dim MXU tiles);
   - off-TPU the same math runs as gather + dense masked attention (the
@@ -69,12 +78,70 @@ def dequantize_kv(q, scale, dtype):
 
 
 # ------------------------------------------------------------------ kernel
-def _decode_kernel(lens_ref, tabs_ref, q_ref, k_ref, v_ref, *rest,
-                   scale, page_size, n_query=1, group=1,
+#: what one block of the walk may take of VMEM: the float32 score block
+#: (rows x block tokens), and the double K and V buffers with their scale
+#: buffers.  Both sized for the v5e's 16 MB of scoped VMEM with room for
+#: the score block's temporaries (mask, exponentials, their bf16 copy).
+_SCORE_BLOCK_BYTES = 1 << 20
+_KV_BUFFER_BYTES = 2 << 20
+_MAX_BLOCK_TOKENS = 512
+
+
+def _round_up(x, m):
+    return -(-x // m) * m
+
+
+def walk_block_pages(page_size, head_dim, rows, kv_dtype):
+    """Pages one block of the kernel's walk holds, from shapes alone: as
+    many as keep the score block (``rows`` x tokens, float32) and the
+    double-buffered K and V pages (with the int8 mode's scale pages) inside
+    their VMEM budgets, at most 512 tokens, at least one page.  ``rows`` is
+    ``n_query * group``.  Whole multiples of 128 tokens where that many
+    fit, so the score block's lane axis is unpadded.  The table's width is
+    NOT an input: a row's blocks are cut the same whatever table carries
+    them, which is what makes a pinned table free and its results
+    bit-identical to a tight one's."""
+    item = jnp.dtype(kv_dtype).itemsize
+    lanes = _round_up(head_dim, 128)
+    # VMEM tiles: 8 sublanes of 32 bits, so 8 / 16 / 32 rows by itemsize
+    page_bytes = _round_up(page_size, 32 // item) * lanes * item
+    if item == 1:                       # + the (page_size, 1) f32 scales
+        page_bytes += _round_up(page_size, 8) * 128 * 4
+    by_kv = _KV_BUFFER_BYTES // (4 * page_bytes)     # K, V x two slots
+    by_score = _SCORE_BLOCK_BYTES // (4 * _round_up(rows, 8) * page_size)
+    pages = max(1, min(_MAX_BLOCK_TOKENS // page_size, by_kv, by_score))
+    per_128 = 128 // math.gcd(128, page_size)        # pages to 128 tokens
+    if pages >= per_128:
+        pages -= pages % per_128
+    return pages
+
+
+def kv_tokens_walked(lengths, block_tokens):
+    """KV positions the kernel walks for rows of these ``lengths``: every
+    row costs its context rounded up to whole blocks,
+    ``ceil(length / block) * block`` — the rule ``_decode_kernel``'s loop
+    bound applies per (row, kv head), here on the host for the dispatch
+    record (``kernel.paged_attn.walk_useful``)."""
+    lengths = np.asarray(lengths, np.int64)
+    return int((-(-lengths // block_tokens) * block_tokens).sum())
+
+
+def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
+                   scale, page_size, block_pages, n_query=1, group=1,
                    quantized=False, ragged=False):
     """Online-softmax paged attention for ``n_query`` query tokens per
-    sequence.  ``n_query == 1`` is the classic decode step; n_query > 1
-    is the RAGGED MULTI-QUERY verify path (speculative decoding): the
+    sequence, one grid step per (row, kv head).  The step WALKS THE ROW'S
+    OWN CONTEXT: ``ceil(length / block)`` blocks of ``block_pages`` pages,
+    whatever the table's width.  The pools stay in HBM; the kernel reads
+    the page indices from the scalar-prefetched table and starts one
+    asynchronous copy a page into a VMEM buffer, two buffers deep, so the
+    next block's pages are in flight while this block's scores and
+    products are computed.  Only pages that hold context are fetched:
+    nothing is issued past the row's length, the tail block's unfetched
+    slots are masked by column (scores) and zeroed (values).
+
+    ``n_query == 1`` is the classic decode step; n_query > 1 is the
+    RAGGED MULTI-QUERY verify path (speculative decoding): the
     block's tokens are already scattered into the pages, ``lens`` counts
     them, and query ``s`` of the block attends causally to
     ``cols < length - (n_query - 1 - s)`` — per-row, per-query limits,
@@ -89,31 +156,52 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_ref, v_ref, *rest,
     mixing decode rows (qlen 1), prefill/chunk spans, and verify
     blocks.
 
-    ``quantized`` (ISSUE 9): the K/V page blocks arrive as INT8 with
-    per-slot f32 scale blocks riding alongside — dequantization happens
+    ``quantized`` (ISSUE 9): the K/V pages arrive as INT8 with their
+    per-slot f32 scale pages copied alongside — dequantization happens
     here in VMEM right before the MXU dots, so full-precision KV never
     round-trips HBM (the whole point of the int8 storage mode)."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
+         m_scr, l_scr, acc_scr) = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
+        ks_buf = vs_buf = None
     b = pl.program_id(0)
-    p = pl.program_id(2)
-    n_pages = pl.num_programs(2)
-
-    @pl.when(p == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    h = pl.program_id(1)
+    block = block_pages * page_size
+    d = k_buf.shape[-1]
 
     length = lens_ref[0, b] if ragged else lens_ref[b]
-    valid = p * page_size < length
+    # the pages that hold the row's context (never past the table)
+    n_pages = jnp.minimum(pl.cdiv(length, page_size), tabs_ref.shape[1])
+    n_blocks = pl.cdiv(n_pages, block_pages)
 
-    @pl.when(valid)
-    def _compute():
-        q = q_ref[0, 0]                         # (n_query*group, d)
-        k = k_ref[0, 0]                         # (page_size, d)
+    def each_page_copy(blk, slot, act):
+        """``act`` on every copy of block ``blk``'s pages into buffer
+        ``slot`` — the same descriptors to start and to wait on."""
+        first = blk * block_pages
+
+        def body(i, carry):
+            page = tabs_ref[b, first + i]
+            act(pltpu.make_async_copy(k_hbm.at[h, page], k_buf.at[slot, i],
+                                      sems.at[slot]))
+            act(pltpu.make_async_copy(v_hbm.at[h, page], v_buf.at[slot, i],
+                                      sems.at[slot]))
+            if quantized:
+                # a page's scale block travels with its page
+                act(pltpu.make_async_copy(ks_hbm.at[h, page],
+                                          ks_buf.at[slot, i],
+                                          sems.at[slot]))
+                act(pltpu.make_async_copy(vs_hbm.at[h, page],
+                                          vs_buf.at[slot, i],
+                                          sems.at[slot]))
+            return carry
+
+        lax.fori_loop(0, jnp.minimum(block_pages, n_pages - first), body, 0)
+
+    def load(buf, s_buf, slot, dtype):
+        """Buffer ``slot`` as (block tokens, d) in the compute dtype."""
+        x = buf[slot]                           # (block_pages, page, d)
         if quantized:
             # per-slot dequant in VMEM: int8 page * (page_size, 1)
             # scale, ROUNDED through the compute dtype — the same
@@ -121,11 +209,35 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_ref, v_ref, *rest,
             # bf16 model's decode sees bit-identical K/V to what
             # prefill's fake-quant round-trip and the XLA gathers
             # produced (the exactness invariant)
-            k = (k.astype(jnp.float32) * ks_ref[0, 0]).astype(q.dtype)
+            x = x.astype(jnp.float32) * s_buf[slot][:, :, :1]
+        elif page_size % (32 // x.dtype.itemsize):
+            # a page that is not whole tiles of its dtype folds into
+            # the token axis as float32, whose 8-row tile it does fill
+            x = x.astype(jnp.float32)
+        return x.reshape(block, d).astype(dtype)
+
+    m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        each_page_copy(0, 0, lambda c: c.start())
+
+    def walk(blk, carry):
+        slot = blk % 2
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            each_page_copy(blk + 1, 1 - slot, lambda c: c.start())
+
+        each_page_copy(blk, slot, lambda c: c.wait())
+
+        q = q_ref[0, 0]                         # (n_query*group, d)
+        k = load(k_buf, ks_buf, slot, q.dtype)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        cols = p * page_size + lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
+        cols = blk * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # row r serves query position r // group of the block; its
         # causal window ends (n_query - 1 - qpos) tokens short of the
         # full length (the later block tokens it must not see)
@@ -148,25 +260,28 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.broadcast_to(
             alpha * l_scr[:, :1] + jnp.sum(pexp, axis=1, keepdims=True),
             l_scr.shape)
-        if quantized:
-            # same rounding rule as k above, then the SAME dot the
-            # full-precision path runs on its pages
-            v = (v_ref[0, 0].astype(jnp.float32)
-                 * vs_ref[0, 0]).astype(q.dtype)
-        else:
-            v = v_ref[0, 0]
+        # same rounding rule as k above, then the SAME dot the
+        # full-precision path runs on its pages; slots past the length
+        # were never fetched and hold whatever the buffer held — their
+        # weights are exact zeros, and so must they be (0 * NaN)
+        v = load(v_buf, vs_buf, slot, q.dtype)
+        toks = blk * block + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        v = jnp.where(toks < length, v, jnp.zeros_like(v))
         acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
             pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_next, m_scr.shape)
+        return carry
 
-    @pl.when(p == n_pages - 1)
-    def _finalize():
-        l = l_scr[:, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    lax.fori_loop(0, n_blocks, walk, 0)
+
+    l = l_scr[:, :1]
+    l_safe = jnp.where(l == 0.0, 1.0, l)
+    o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("scale", "interpret", "n_query"))
 def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                    interpret=False, n_query=1, k_scales=None,
                    v_scales=None, q_lens=None):
@@ -174,15 +289,23 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
     (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
     ``q_lens`` (batch,) int32 selects the RAGGED kernel: per-row query
-    spans left-aligned in the n_query bucket (ISSUE 17)."""
+    spans left-aligned in the n_query bucket (ISSUE 17).
+
+    The grid is (batch, kv_heads); the pools are handed over whole and
+    stay in HBM, and each grid step walks its row's context in blocks of
+    :func:`walk_block_pages` pages (see ``_decode_kernel``).
+
+    Jitted, so that a program's layers, which all call it at the same
+    shapes, share ONE traced and lowered kernel: a serving engine builds
+    a program a (rows, span) bucket, each of them every layer deep."""
     if n_query == 1:
         batch, q_heads, d = q.shape
     else:
         batch, _nq, q_heads, d = q.shape
     kv_heads, _tot, page_size, _d = k_pages.shape
     group = q_heads // kv_heads
-    max_pages = page_tables.shape[1]
     rows = n_query * group
+    block_pages = walk_block_pages(page_size, d, rows, k_pages.dtype)
 
     # (batch, q_heads, d) -> (batch, kv_heads, group, d): the kv-head
     # group rides as its own FULL axis so the q block's trailing dims
@@ -197,6 +320,22 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
              .transpose(0, 2, 1, 3, 4).reshape(batch, kv_heads, rows, d)
 
     quantized = k_scales is not None
+    # Mosaic slices a page out of a pool only if the pool's lane axis is
+    # whole 128-lane tiles.  A head_dim that is not (64) is zero-padded
+    # to one — exact: the extra q.k terms are zeros, the extra output
+    # columns are dropped — and the (page_size, 1) scale blocks are
+    # broadcast over a tile.  Both are XLA copies of a pool per call
+    # (the scale pools' was already there: the one-lane layout was
+    # re-tiled for the kernel on every call).
+    lanes = _round_up(d, 128)
+    if lanes != d:
+        pad = [(0, 0)] * 3 + [(0, lanes - d)]
+        q4, k_pages, v_pages = (jnp.pad(x, pad)
+                                for x in (q4, k_pages, v_pages))
+    if quantized:
+        k_scales, v_scales = (
+            jnp.broadcast_to(x, x.shape[:-1] + (128,))
+            for x in (k_scales, v_scales))
     ragged = q_lens is not None
     if ragged:
         # both length kinds ride in ONE (2, batch) scalar-prefetch
@@ -205,50 +344,48 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
         lengths = jnp.stack([jnp.asarray(lengths, jnp.int32),
                              jnp.asarray(q_lens, jnp.int32)])
     kernel = functools.partial(_decode_kernel, scale=scale,
-                               page_size=page_size, n_query=n_query,
+                               page_size=page_size,
+                               block_pages=block_pages, n_query=n_query,
                                group=group, quantized=quantized,
                                ragged=ragged)
-    in_specs = [
-        pl.BlockSpec((1, 1, rows, d),
-                     lambda b, h, p, lens, tabs: (b, h, 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, p, lens, tabs: (h, tabs[b, p], 0, 0)),
-        pl.BlockSpec((1, 1, page_size, d),
-                     lambda b, h, p, lens, tabs: (h, tabs[b, p], 0, 0)),
-    ]
+    q_spec = pl.BlockSpec((1, 1, rows, lanes),
+                          lambda b, h, lens, tabs: (b, h, 0, 0))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [q_spec, hbm, hbm]
     inputs = [lengths, page_tables, q4, k_pages, v_pages]
+    # two buffers a pool: one block computing, the next in flight
+    page_buf = pltpu.VMEM((2, block_pages, page_size, lanes),
+                          k_pages.dtype)
+    scratch = [page_buf, page_buf]
     if quantized:
-        # the per-slot scale blocks pipeline through the SAME
-        # table-indexed DMA as their pages
-        in_specs += [
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, p, lens, tabs: (h, tabs[b, p], 0, 0)),
-            pl.BlockSpec((1, 1, page_size, 1),
-                         lambda b, h, p, lens, tabs: (h, tabs[b, p], 0, 0)),
-        ]
+        in_specs += [hbm, hbm]
         inputs += [k_scales, v_scales]
+        scale_buf = pltpu.VMEM((2, block_pages, page_size, 128),
+                               jnp.float32)
+        scratch += [scale_buf, scale_buf]
+    scratch += [
+        pltpu.SemaphoreType.DMA((2,)),          # one a buffer slot
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, 128), jnp.float32),
+        pltpu.VMEM((rows, lanes), jnp.float32),
+    ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # lengths, page_tables
-        grid=(batch, kv_heads, max_pages),
+        grid=(batch, kv_heads),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, rows, d),
-                               lambda b, h, p, lens, tabs: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, 128), jnp.float32),
-            pltpu.VMEM((rows, d), jnp.float32),
-        ],
+        out_specs=q_spec,
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         name="paged_attention_ragged" if ragged else "paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, d),
+        out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, lanes),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(*inputs)
+    )(*inputs)[..., :d]
     if n_query == 1:
         return out.reshape(batch, q_heads, d)
     return out.reshape(batch, kv_heads, n_query, group, d) \

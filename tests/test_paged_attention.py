@@ -10,7 +10,8 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.ops.pallas.paged_attention import (
     PagedKVCache, paged_attention, paged_attention_multi,
-    paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla)
+    paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla,
+    kv_tokens_walked, quantize_kv, walk_block_pages)
 from paddle_tpu.ops.pallas.flash_attention import mha_reference
 from paddle_tpu.ops.pallas.fused_norm_rope import (
     rms_norm_pallas, rms_norm_xla, fused_rope_pallas, fused_rope_xla)
@@ -256,6 +257,188 @@ class TestRaggedPagedAttention:
         assert len(cache._free) == free_before
         assert len(cache._seq_pages[0]) == 2
         assert len(cache._seq_pages[1]) == 2
+
+
+def _walk_pools(rng, kvh, pages, page, d, kv):
+    """Random pools in one storage mode: ``(k, v, scale kwargs)``."""
+    k = rng.standard_normal((kvh, pages, page, d)).astype("float32")
+    v = rng.standard_normal((kvh, pages, page, d)).astype("float32")
+    if kv == "int8":
+        (k8, ks), (v8, vs) = quantize_kv(jnp.asarray(k)), \
+            quantize_kv(jnp.asarray(v))
+        return k8, v8, {"k_scales": ks, "v_scales": vs}
+    dt = jnp.bfloat16 if kv == "bf16" else jnp.float32
+    return jnp.asarray(k, dt), jnp.asarray(v, dt), {}
+
+
+def _walk_call(mode, q, kp, vp, lens, q_lens, tabs, scale, kw,
+               oracle=False):
+    """One of the three entries, kernel (interpreted) or XLA oracle."""
+    if mode == "decode":
+        if oracle:
+            return _decode_xla(q[:, 0], kp, vp, lens, tabs, scale, **kw)
+        return paged_attention(q[:, 0], kp, vp, lens, tabs,
+                               interpret=True, **kw)
+    if mode == "multi":
+        if oracle:
+            return _multi_xla(q, kp, vp, lens, tabs, scale, **kw)
+        return paged_attention_multi(q, kp, vp, lens, tabs,
+                                     interpret=True, **kw)
+    if oracle:
+        return _ragged_xla(q, kp, vp, lens, q_lens, tabs, scale, **kw)
+    return paged_attention_ragged(q, kp, vp, lens, q_lens, tabs,
+                                  interpret=True, **kw)
+
+
+def _real_queries(mode, out, q_lens):
+    """The queries a caller keeps: every one but a ragged row's pads."""
+    out = np.asarray(out.astype(jnp.float32))
+    if mode != "ragged":
+        return out
+    keep = np.arange(out.shape[1])[None, :] < np.asarray(q_lens)[:, None]
+    return out[keep]
+
+
+class TestContextWalk:
+    """The kernel walks a row's own context in blocks of several pages
+    fetched by its own copies (ISSUE 28): the block edges, rows of
+    different depths in one call, every entry and storage mode, and the
+    table's width costing nothing and changing nothing."""
+
+    MODES = ["decode", "multi", "ragged"]
+    KVS = ["bf16", "int8"]
+
+    @staticmethod
+    def _case(rng, mode, kv, lens, *, q_heads=8, kvh=2, d=128, page=16,
+              table=None, span=4, q_lens=None):
+        n = len(lens)
+        need = [-(-int(L) // page) for L in lens]
+        table = table or max(need)
+        pages = sum(need) + 3
+        kp, vp, kw = _walk_pools(rng, kvh, pages, page, d, kv)
+        # every row's pages scattered over the pool; slots past a
+        # row's pages point at page 0, as the engine's tables do
+        perm = rng.permutation(pages)
+        tabs = np.zeros((n, table), np.int32)
+        at = 0
+        for i, k in enumerate(need):
+            tabs[i, :k] = perm[at:at + k]
+            at += k
+        nq = 1 if mode == "decode" else span
+        qdt = jnp.float32 if kv == "int8" else kp.dtype
+        q = jnp.asarray(rng.standard_normal((n, nq, q_heads, d)), qdt)
+        if q_lens is None:
+            q_lens = [min(nq, int(L)) for L in lens]
+        return (q, kp, vp, jnp.asarray(lens, jnp.int32),
+                jnp.asarray(q_lens, jnp.int32), jnp.asarray(tabs),
+                1.0 / np.sqrt(d), kw)
+
+    @pytest.mark.parametrize("kv", KVS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_lengths_around_a_block_edge(self, mode, kv):
+        """1, block - 1, block, block + 1 and the full table, one batch."""
+        rng = np.random.default_rng(28)
+        span = 1 if mode == "decode" else 4
+        block = 16 * walk_block_pages(
+            16, 128, span * 4, jnp.int8 if kv == "int8" else jnp.bfloat16)
+        assert block == 512
+        lens = [1, block - 1, block, block + 1, 40 * 16]
+        # a multi-query row needs its whole block in the cache
+        if mode == "multi":
+            lens[0] = span
+        args = self._case(rng, mode, kv, lens, table=40, span=span)
+        out = _walk_call(mode, *args)
+        ref = _walk_call(mode, *args, oracle=True)
+        tol = 2e-2 if kv == "bf16" else 2e-4
+        np.testing.assert_allclose(_real_queries(mode, out, args[4]),
+                                   _real_queries(mode, ref, args[4]),
+                                   rtol=tol, atol=tol)
+
+    @pytest.mark.parametrize("kv", KVS)
+    def test_mixed_depths_and_spans_with_pad_rows(self, kv):
+        """A chunk span, a verify block, decode rows two blocks deep and
+        the engine's length-1 pad rows in ONE ragged call."""
+        rng = np.random.default_rng(29)
+        lens = [700, 130, 1030, 1, 1, 37, 1, 512]
+        q_lens = [16, 5, 1, 1, 1, 1, 1, 16]
+        args = self._case(rng, "ragged", kv, lens, span=16,
+                          q_lens=q_lens, table=128)
+        out = _walk_call("ragged", *args)
+        ref = _walk_call("ragged", *args, oracle=True)
+        tol = 2e-2 if kv == "bf16" else 2e-4
+        np.testing.assert_allclose(_real_queries("ragged", out, args[4]),
+                                   _real_queries("ragged", ref, args[4]),
+                                   rtol=tol, atol=tol)
+        assert not np.isnan(np.asarray(out.astype(jnp.float32))).any()
+
+    @pytest.mark.parametrize("mode", MODES)
+    def test_group_of_three_and_head_dim_64(self, mode):
+        """Shapes off the tiles: 6 query heads over 2 KV heads of 64
+        (the pool's lane axis is padded to a tile for the copies),
+        float32 pages of 8 tokens."""
+        rng = np.random.default_rng(30)
+        lens = [3, 64, 65, 200]
+        args = self._case(rng, mode, "f32", lens, q_heads=6, kvh=2, d=64,
+                          page=8, span=3)
+        out = _walk_call(mode, *args)
+        ref = _walk_call(mode, *args, oracle=True)
+        np.testing.assert_allclose(_real_queries(mode, out, args[4]),
+                                   _real_queries(mode, ref, args[4]),
+                                   rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("kv", KVS)
+    @pytest.mark.parametrize("mode", MODES)
+    def test_pinned_table_is_bit_identical_to_a_tight_one(self, mode, kv):
+        """THE PINNING PROPERTY: the same rows through a table of exactly
+        the pages they need and through one padded to 256 pages give the
+        same bits — a row's blocks are cut from its length, never from
+        the table."""
+        rng = np.random.default_rng(31)
+        span = 1 if mode == "decode" else 4
+        lens = [4, 300, 513, 900]
+        q, kp, vp, lengths, q_lens, tabs, scale, kw = self._case(
+            rng, mode, kv, lens, span=span)
+        assert tabs.shape[1] == -(-900 // 16)
+        wide = jnp.zeros((len(lens), 256), jnp.int32) \
+            .at[:, :tabs.shape[1]].set(tabs)
+        tight = _walk_call(mode, q, kp, vp, lengths, q_lens, tabs, scale,
+                           kw)
+        pinned = _walk_call(mode, q, kp, vp, lengths, q_lens, wide, scale,
+                            kw)
+        np.testing.assert_array_equal(
+            np.asarray(tight.astype(jnp.float32)),
+            np.asarray(pinned.astype(jnp.float32)))
+
+    def test_table_one_page_wide(self):
+        rng = np.random.default_rng(32)
+        args = self._case(rng, "ragged", "bf16", [5, 16], span=2)
+        assert args[5].shape[1] == 1
+        out = _walk_call("ragged", *args)
+        ref = _walk_call("ragged", *args, oracle=True)
+        np.testing.assert_allclose(_real_queries("ragged", out, args[4]),
+                                   _real_queries("ragged", ref, args[4]),
+                                   rtol=2e-2, atol=2e-2)
+
+    @pytest.mark.parametrize("page,d,rows,dtype,pages", [
+        (16, 128, 4, jnp.bfloat16, 32),      # the cell's decode step
+        (16, 128, 512, jnp.bfloat16, 32),    # its chunk step, span 128
+        (16, 128, 512, jnp.int8, 32),
+        (16, 128, 1024, jnp.bfloat16, 16),   # the score block binds
+        (16, 128, 8192, jnp.bfloat16, 2),
+        (16, 128, 1 << 16, jnp.bfloat16, 1),  # down to one page a block
+        (128, 128, 4, jnp.bfloat16, 4),
+        (8, 64, 3, jnp.float32, 64),
+        (16, 512, 4, jnp.float32, 16),       # the page buffers bind
+        (48, 128, 4, jnp.bfloat16, 8),       # 384 tokens: whole 128s
+    ])
+    def test_block_rule_reads_shapes_only(self, page, d, rows, dtype,
+                                          pages):
+        assert walk_block_pages(page, d, rows, dtype) == pages
+
+    def test_tokens_walked_is_the_context_in_whole_blocks(self):
+        assert kv_tokens_walked([1, 511, 512, 513, 0], 512) == \
+            512 + 512 + 512 + 1024
+        assert kv_tokens_walked(np.asarray([4096] * 8), 512) == 8 * 4096
 
 
 class TestFusedNormRope:

@@ -167,6 +167,27 @@ class TestPagedAttentionLowering:
                 page=page, table=128, nq=128, int8=True, ragged=True))
 
     @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("nq", [1, 64, 128],
+                             ids=["decode", "span64", "span128"])
+    def test_benchmark_cell_mistral_7b(self, chip, nq, int8):
+        # `mistral7b.serve.closed8`'s own programs: 8 rows, 32/8 heads of
+        # 128, a table pinned at 256 over 4,096 pages of 16.  The block
+        # of the walk is sized from these shapes: one that overruns
+        # scoped VMEM fails here, before it fails on the chip
+        chip.compile(
+            _paged_fn(D7, nq=nq, int8=int8, ragged=nq > 1), *_paged_specs(
+                chip, kvh=8, heads=H7, d=D7, batch=8, pages=4096, page=16,
+                table=256, nq=nq, int8=int8, ragged=nq > 1))
+
+    @pytest.mark.parametrize("nq", [256, 512], ids=["span256", "span512"])
+    def test_spans_past_the_score_block_budget(self, chip, nq):
+        # rows = span x 4: the block shrinks (256 tokens, then 128) so the
+        # float32 score block stays at 1 MB
+        chip.compile(_paged_fn(D7, nq=nq, ragged=True), *_paged_specs(
+            chip, kvh=8, heads=H7, d=D7, batch=2, pages=4096, page=16,
+            table=256, nq=nq, ragged=True))
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
     @pytest.mark.parametrize("page", [8, 32, 64, 128])
     def test_page_sizes_other_than_16(self, chip, page, int8):
         # pool tiling is (16,128) for bf16 and (32,128) for int8: a page

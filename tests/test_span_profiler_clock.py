@@ -109,6 +109,8 @@ def tiny_model():
 
 
 class TestEngineStepUnderTrace:
+    row_lengths = []        # per dispatch, each real row's length after it
+
     @pytest.fixture(scope="class")
     def run(self, tmp_path_factory):
         """A tiny engine serves three prompts (chunked prefill + decode)
@@ -132,6 +134,8 @@ class TestEngineStepUnderTrace:
                 def watched(cache, seq_ids, *a, **kw):
                     out = real(cache, seq_ids, *a, **kw)
                     lens.append(sum(cache.length(s) for s in seq_ids))
+                    type(self).row_lengths.append(
+                        [cache.length(s) for s in seq_ids])
                     return out
                 eng._decoder.ragged_step = watched
                 for r in [eng.submit(p, max_new_tokens=6) for p in prompts]:
@@ -196,6 +200,24 @@ class TestEngineStepUnderTrace:
             assert r["page_size"] == 8
         assert any(r["span_padded"] > 1 for r in disp)     # a chunk step
         assert any(r["span_padded"] == 1 for r in disp)    # a decode step
+
+    def test_dispatch_record_counts_what_the_kernel_walks(self, run):
+        """``kv_tokens_walked`` is the paged kernel's own block rule
+        applied to the dispatch's padded rows (a pad row is one token
+        long): ``kernel.paged_attn.walk_useful`` divides by it."""
+        from paddle_tpu.ops.pallas.paged_attention import (
+            kv_tokens_walked, walk_block_pages)
+        _events, records, _ = run
+        disp = [r for r in records if r["kind"] == "dispatch"]
+        assert len(disp) == len(self.row_lengths)
+        for r, rows in zip(disp, self.row_lengths):
+            # tiny_model: 4 query heads over 2 KV heads of 8, f32 pages
+            block = r["page_size"] * walk_block_pages(
+                r["page_size"], 8, r["span_padded"] * 2, np.float32)
+            padded = rows + [1] * (r["rows_padded"] - r["rows"])
+            assert r["kv_tokens_walked"] == kv_tokens_walked(padded, block)
+            assert r["kv_tokens_walked"] >= r["ctx_tokens"]
+            assert r["kv_tokens_walked"] % block == 0
 
     def test_dispatch_interval_lies_inside_the_steps_other_records(self, run):
         _events, records, _ = run
