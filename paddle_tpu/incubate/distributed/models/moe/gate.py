@@ -1,4 +1,5 @@
-"""MoE gates: naive top-k, GShard top-2, Switch top-1.
+"""MoE gates: naive top-k, GShard top-2, Switch top-1, and the sigmoid
+top-k router with a selection bias (no capacity, no drops).
 
 Capability parity: python/paddle/incubate/distributed/models/moe/gate/ in the
 reference (base_gate.py BaseGate, naive_gate.py NaiveGate, gshard_gate.py
@@ -26,8 +27,8 @@ import jax.numpy as jnp
 
 from .....framework.dispatch import def_op
 from .....framework import random as _random
-from .....nn.layer.layers import Layer
-from .....nn.initializer import XavierNormal
+from .....nn.layer.layers import Layer, ParamAttr
+from .....nn.initializer import Constant, XavierNormal
 
 
 def moe_capacity(top_k, num_tokens, num_expert, factor):
@@ -259,3 +260,58 @@ class SwitchGate(NaiveGate):
             noise = noise * (2 * self.switch_eps) + (1.0 - self.switch_eps)
             logits = logits * noise
         return logits
+
+
+@def_op("moe_sigmoid_topk")
+def _sigmoid_topk(logits, bias, top_k, renormalize, scaling):
+    """Scores sigma(logits) in float32; the ``top_k`` largest of
+    score + bias are chosen (the bias steers the choice only); the
+    weights are the chosen SCORES, divided by their sum if
+    ``renormalize``, times ``scaling``.  Returns (expert ids [T, k]
+    int32, weights [T, k] float32).  Only the weights carry gradient."""
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    _, idx = jax.lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if renormalize:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling
+
+
+class SigmoidTopKGate(BaseGate):
+    """The DeepSeek-V3 / Kimi router: one sigmoid score an expert, top-k
+    over score + ``e_score_correction_bias`` (float32, ``trainable=False``:
+    the published models move it with a load-balancing rule outside the
+    gradient), weights renormalised over the chosen and scaled.  No
+    capacity and no balance loss: every assignment is kept, so it is
+    routed by ``MoELayer``'s held-experts path (``route_no_drop``) and
+    has no dense combine/dispatch form."""
+
+    def __init__(self, d_model, num_expert, world_size=1, topk=8,
+                 renormalize=True, routed_scaling_factor=1.0):
+        super().__init__(num_expert, world_size)
+        self.d_model = d_model
+        self.top_k = topk
+        self.renormalize = renormalize
+        self.routed_scaling_factor = float(routed_scaling_factor)
+        self.gate_weight = self.create_parameter(
+            [d_model, self.tot_expert], attr=XavierNormal())
+        # not trained, but a parameter and not a buffer: a compiled step
+        # takes parameters as arguments and closes over buffers, and a
+        # value that differs from seed to seed inside the program is a
+        # compile-cache miss on every run
+        self.e_score_correction_bias = self.create_parameter(
+            [self.tot_expert], dtype="float32",
+            attr=ParamAttr(initializer=Constant(0.0), trainable=False))
+
+    def route_no_drop(self, x):
+        """x [T, d_model] -> (expert ids [T, k], weights [T, k])."""
+        logits = x.matmul(self.gate_weight)
+        return _sigmoid_topk(logits, self.e_score_correction_bias,
+                             self.top_k, self.renormalize,
+                             self.routed_scaling_factor)
+
+    def forward(self, x):
+        raise NotImplementedError(
+            "SigmoidTopKGate keeps every assignment and has no "
+            "[tokens, experts, capacity] form: use it in a MoELayer "
+            "built with held_experts=(first, count)")

@@ -20,11 +20,25 @@ the expert axis over an 'ep' mesh axis.  Use ``ExpertFFN`` (stacked weights)
 token-sharded einsum and the expert-sharded FFN into the same ICI all_to_all
 the reference issues by hand.  A list of arbitrary per-expert Layers also
 works (loop, replicated weights) for eager/single-host use.
+
+The held-range contract (``held_experts=(first, count)``): the layer is one
+expert-parallel rank's share.  The gate still scores the WHOLE expert set
+(``gate.tot_expert``), the layer holds experts ``first .. first + count - 1``
+(``experts`` is a ``SwiGLUExperts`` of ``count``), keeps the assignments that
+fall on them, computes every one of those and returns the partial sum, plus
+the shared expert if it has one.  Nothing can be dropped: every token goes
+through every held expert, weighted 0 where it did not choose it (a share
+holds about as many experts as a token chooses, so the rows any routing may
+ask for, tokens x min(top_k, count), ARE that dense product; a share much
+wider than top_k would want rows sorted by expert instead).  What the other
+ranks' experts would add is NOT here: summing it across ranks is the
+exchange a multi-chip deployment adds around this layer.
 """
 from __future__ import annotations
 
 from typing import List, Optional, Sequence, Union
 
+import jax
 import jax.numpy as jnp
 
 from .....framework.dispatch import def_op
@@ -100,6 +114,47 @@ def _expert_ffn(x, w1, b1, w2, b2, activation):
     return y
 
 
+@def_op("moe_held_experts")
+def _held_experts(x, idx, weight, first, w_gate, w_up, w_down):
+    """Every token through every HELD expert's SwiGLU, each product
+    weighted by the token's routing weight for that expert: 0 where the
+    token did not choose it.  x [T, M]; idx, weight [T, k] (ids over the
+    whole expert set); stacked weights [count, M, H] / [count, H, M].
+    Returns (y [T, M], counts [slots, held, rows, most held by one
+    expert] as float32).  A token picks an expert at most once, so the
+    rows a share may have to compute under ANY routing are T x
+    min(k, count); with count <= k that is this dense product, whose
+    time does not depend on the routing."""
+    count = w_gate.shape[0]
+    chose = idx[:, :, None] == first + jnp.arange(count)        # [T, k, E]
+    w = jnp.sum(jnp.where(chose, weight[:, :, None], 0.0), axis=1)
+    h = (jax.nn.silu(jnp.einsum("tm,emh->teh", x, w_gate))
+         * jnp.einsum("tm,emh->teh", x, w_up))
+    y = jnp.einsum("teh,ehm->tm", (h * w[:, :, None]).astype(x.dtype),
+                   w_down)
+    per_expert = jnp.sum(chose, axis=(0, 1))
+    stats = jnp.stack([jnp.asarray(idx.size), jnp.sum(per_expert),
+                       jnp.asarray(x.shape[0] * count),
+                       jnp.max(per_expert)]).astype(jnp.float32)
+    return y, stats
+
+
+class SwiGLUExperts(Layer):
+    """``num_expert`` bias-free SwiGLU experts, weights stacked on a
+    leading expert axis (the container of the held-experts path)."""
+
+    def __init__(self, num_expert, d_model, d_hidden, weight_attr=None):
+        super().__init__()
+        self.num_expert = num_expert
+        attr = weight_attr if weight_attr is not None else XavierNormal()
+        self.gate_proj = self.create_parameter(
+            [num_expert, d_model, d_hidden], attr=attr)
+        self.up_proj = self.create_parameter(
+            [num_expert, d_model, d_hidden], attr=attr)
+        self.down_proj = self.create_parameter(
+            [num_expert, d_hidden, d_model], attr=attr)
+
+
 class ExpertFFN(Layer):
     """All experts' FFN weights stacked on a leading expert axis — the
     TPU-native expert container (shardable over 'ep', batched on the MXU)."""
@@ -129,15 +184,27 @@ class MoELayer(Layer):
     ``experts``: an ExpertFFN (stacked fast path), or a list of Layers (one
     per expert — the full global expert set).  ``gate``: a BaseGate instance
     or config dict {"type": "gshard"|"switch"|"naive", "top_k": k}.
+
+    ``held_experts=(first, count)``: this layer is a share (module
+    docstring): ``experts`` is a ``SwiGLUExperts`` of ``count``, ``gate`` a
+    gate with ``route_no_drop`` over the whole set (``SigmoidTopKGate``).
+    ``shared_expert``: a Layer every token also goes through, added once.
+    After a forward of a share, ``last_routing`` holds the counts
+    [slots, held, (token, expert) rows computed, most held by one expert].
     """
 
     def __init__(self, d_model: int,
-                 experts: Union[ExpertFFN, Sequence[Layer]],
+                 experts: Union[ExpertFFN, "SwiGLUExperts", Sequence[Layer]],
                  gate=None, moe_group=None, mp_group=None,
-                 recompute_interval=0, recompute_ctx=None):
+                 recompute_interval=0, recompute_ctx=None,
+                 held_experts=None, shared_expert=None):
         super().__init__()
         self.d_model = d_model
-        if isinstance(experts, ExpertFFN):
+        self.held_experts = (None if held_experts is None
+                             else (int(held_experts[0]), int(held_experts[1])))
+        self.shared_expert = shared_expert
+        self.last_routing = None
+        if isinstance(experts, (ExpertFFN, SwiGLUExperts)):
             self.experts = experts
             self.num_expert = experts.num_expert
         else:
@@ -151,8 +218,12 @@ class MoELayer(Layer):
         if isinstance(gate, dict):
             kind = gate.get("type", "gshard")
             topk = gate.get("top_k", 2 if kind != "switch" else 1)
-            # The gate sees the full expert set (world_size=1): expert
-            # parallelism is a placement, not a partition of the gate.
+            # a gate built from a dict routes over exactly the experts
+            # this layer holds (world_size=1); a share of a wider gate
+            # passes the gate itself and held_experts=(first, count)
+            assert self.held_experts is None, (
+                "held_experts needs the gate instance that routes over the "
+                "whole expert set, not a config dict")
             if kind == "naive":
                 gate = NaiveGate(d_model, self.num_expert, 1, topk=topk)
             elif kind == "switch":
@@ -169,9 +240,22 @@ class MoELayer(Layer):
             else:
                 gate = GShardGate(d_model, self.num_expert, 1, topk=topk)
         assert isinstance(gate, BaseGate)
-        assert gate.tot_expert == self.num_expert, (
-            f"gate routes over {gate.tot_expert} experts but layer holds "
-            f"{self.num_expert}")
+        if self.held_experts is None:
+            assert gate.tot_expert == self.num_expert, (
+                f"the gate routes over {gate.tot_expert} experts and the "
+                f"layer holds {self.num_expert}: a layer that holds a share "
+                "of them says which with held_experts=(first, count)")
+        else:
+            first, count = self.held_experts
+            assert isinstance(self.experts, SwiGLUExperts) \
+                and hasattr(gate, "route_no_drop"), (
+                    "a share of the experts is a SwiGLUExperts routed by a "
+                    "gate with route_no_drop (SigmoidTopKGate)")
+            assert count == self.num_expert and first >= 0 \
+                and first + count <= gate.tot_expert, (
+                    f"held_experts=({first}, {count}) must name the "
+                    f"{self.num_expert} experts this layer holds, inside "
+                    f"the {gate.tot_expert} the gate routes over")
         self.gate = gate
 
     @property
@@ -195,9 +279,23 @@ class MoELayer(Layer):
         from .....tensor.manipulation import concat
         return concat(outs, axis=0)                      # [E, C, M]
 
+    def _forward_share(self, tokens):
+        first, _ = self.held_experts
+        with jax.named_scope("moe/router"):
+            idx, w = self.gate.route_no_drop(tokens)
+        with jax.named_scope("moe/experts"):
+            y, self.last_routing = _held_experts(
+                tokens, idx, w, first, self.experts.gate_proj,
+                self.experts.up_proj, self.experts.down_proj)
+            if self.shared_expert is not None:
+                y = y + self.shared_expert(tokens)
+        return y
+
     def forward(self, x: Tensor) -> Tensor:
         orig_shape = x.shape
         tokens = x.reshape([-1, self.d_model])
+        if self.held_experts is not None:
+            return self._forward_share(tokens).reshape(orig_shape)
         use_recompute = self.recompute_interval > 0 and self.training
         if (isinstance(self.gate, NaiveGate)
                 and type(self.gate).forward is NaiveGate.forward):
@@ -227,6 +325,11 @@ def shard_moe_layer(layer: MoELayer, mesh: ProcessMesh, axis: str = "ep"):
 
     Requires the stacked ``ExpertFFN`` expert container; a Python list of
     arbitrary expert Layers has no shardable expert axis."""
+    if layer.held_experts is not None:
+        raise ValueError(
+            f"this MoELayer already holds a share of the experts "
+            f"(held_experts={layer.held_experts}): it is one rank's part, "
+            "there is no whole expert axis left to shard")
     if not isinstance(layer.experts, ExpertFFN):
         raise NotImplementedError(
             "expert parallelism needs stacked expert weights: build the "

@@ -863,6 +863,23 @@ class TrainStep:
         self._program_text_cache = (key, text)
         return text
 
+    def compiled_text(self) -> Optional[str]:
+        """The whole-step program as the backend compiled it (optimised
+        HLO text) — available after the first call.  Each instruction's
+        ``metadata={op_name=...}`` carries the ``jax.named_scope`` path
+        it came from (``train/model/...``, ``train/loss``,
+        ``train/optimizer``), which a device trace's bare instruction
+        names do not: this text maps one to the other.  Lowered from
+        avals like ``program_text``; the compile is the step's own, so
+        with a persistent compilation cache it is read back, not
+        redone."""
+        sig = getattr(self, "_last_sig", None)
+        if self._compiled is None or sig is None:
+            return None
+        in_sds, label_sds, treedefs = sig
+        return self._lower(in_sds, label_sds, treedefs,
+                           as_avals=True).compile().as_text()
+
     # ------------------------------------------------------------------- sync
     def sync(self):
         """Write the functional state back into the model Parameters and the

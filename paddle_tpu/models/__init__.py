@@ -1,4 +1,5 @@
-"""Model zoo: LLaMA (flagship), BERT; vision models in paddle_tpu.vision."""
+"""Model zoo: LLaMA (flagship), LLaMA-MoE, Kimi-Linear (KDA + MLA + sigmoid
+MoE), BERT; vision models in paddle_tpu.vision."""
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_7b, llama_small,
     shard_llama,
@@ -6,6 +7,10 @@ from .llama import (  # noqa: F401
 from .llama_moe import (  # noqa: F401
     LlamaMoeConfig, LlamaMoeDecoderLayer, LlamaMoeForCausalLM,
     LlamaMoeModel, shard_llama_moe,
+)
+from .kimi_linear import (  # noqa: F401
+    KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
+    KimiDeltaAttention, KimiMLAttention,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForMaskedLM,

@@ -129,6 +129,7 @@ def flash_attention_forward(q, k, v, causal=False, scale=None,
         scale = 1.0 / math.sqrt(q.shape[-1])
     b, h, sq, d = q.shape
     kv_h, sk = k.shape[1], k.shape[2]
+    dv = v.shape[-1]          # the values' own width (latent attention)
     block_q = min(block_q, _ceil_to(sq, 128))
     block_kv = min(block_kv, _ceil_to(sk, 128))
     sq_p, sk_p = _ceil_to(sq, block_q), _ceil_to(sk, block_kv)
@@ -153,23 +154,23 @@ def flash_attention_forward(q, k, v, causal=False, scale=None,
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
             pl.BlockSpec((1, 1, block_kv, d),
                          lambda b_, h_, qi, ki: (b_, h_ // group, ki, 0)),
-            pl.BlockSpec((1, 1, block_kv, d),
+            pl.BlockSpec((1, 1, block_kv, dv),
                          lambda b_, h_, qi, ki: (b_, h_ // group, ki, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
             pl.BlockSpec((1, 1, block_q, 128),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, h, sq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((b, h, sq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq_p, 128), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -315,6 +316,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
     """
     b, h, sq, d = q.shape
     kv_h, sk = k.shape[1], k.shape[2]
+    dv_w = v.shape[-1]        # values (and do, dv) may be narrower than q/k
     group = h // kv_h
     k_full = jnp.repeat(k, group, axis=1) if group != 1 else k
     v_full = jnp.repeat(v, group, axis=1) if group != 1 else v
@@ -348,9 +350,9 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
                          lambda b_, h_, ki, qi: (b_, h_, qi, 0)),   # q
             pl.BlockSpec((1, 1, block_kv, d),
                          lambda b_, h_, ki, qi: (b_, h_, ki, 0)),   # k
-            pl.BlockSpec((1, 1, block_kv, d),
+            pl.BlockSpec((1, 1, block_kv, dv_w),
                          lambda b_, h_, ki, qi: (b_, h_, ki, 0)),   # v
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv_w),
                          lambda b_, h_, ki, qi: (b_, h_, qi, 0)),   # do
             pl.BlockSpec((1, 1, block_q, 128),
                          lambda b_, h_, ki, qi: (b_, h_, qi, 0)),   # lse
@@ -360,18 +362,18 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
         out_specs=[
             pl.BlockSpec((1, 1, block_kv, d),
                          lambda b_, h_, ki, qi: (b_, h_, ki, 0)),
-            pl.BlockSpec((1, 1, block_kv, d),
+            pl.BlockSpec((1, 1, block_kv, dv_w),
                          lambda b_, h_, ki, qi: (b_, h_, ki, 0)),
         ],
         out_shape=[
             # f32 so the GQA group sum below accumulates in full precision
             # (the XLA fallback sums the group in f32 too)
             jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, sk_p, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, sk_p, dv_w), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_kv, d), jnp.float32),
-            pltpu.VMEM((block_kv, d), jnp.float32),
+            pltpu.VMEM((block_kv, dv_w), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
@@ -391,9 +393,9 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),   # q
             pl.BlockSpec((1, 1, block_kv, d),
                          lambda b_, h_, qi, ki: (b_, h_, ki, 0)),   # k
-            pl.BlockSpec((1, 1, block_kv, d),
+            pl.BlockSpec((1, 1, block_kv, dv_w),
                          lambda b_, h_, qi, ki: (b_, h_, ki, 0)),   # v
-            pl.BlockSpec((1, 1, block_q, d),
+            pl.BlockSpec((1, 1, block_q, dv_w),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),   # do
             pl.BlockSpec((1, 1, block_q, 128),
                          lambda b_, h_, qi, ki: (b_, h_, qi, 0)),   # lse
@@ -415,7 +417,7 @@ def flash_attention_backward(q, k, v, out, lse, do, causal, scale,
     dv = dv[:, :, :sk, :]
     if group != 1:
         dk = dk.reshape(b, kv_h, group, sk, d).sum(axis=2)
-        dv = dv.reshape(b, kv_h, group, sk, d).sum(axis=2)
+        dv = dv.reshape(b, kv_h, group, sk, dv_w).sum(axis=2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -443,7 +445,7 @@ def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_kv=1024):
     n_blocks = sk_p // block_kv
 
     k_blocks = k_full.reshape(b, h, n_blocks, block_kv, d).transpose(2, 0, 1, 3, 4)
-    v_blocks = v_full.reshape(b, h, n_blocks, block_kv, d).transpose(2, 0, 1, 3, 4)
+    v_blocks = v_full.reshape(b, h, n_blocks, block_kv, -1).transpose(2, 0, 1, 3, 4)
 
     rows = jnp.arange(sq)[:, None]
 
@@ -467,10 +469,10 @@ def _bwd_blockwise(q, k, v, out, lse, do, causal, scale, block_kv=1024):
     dq, (dk_blocks, dv_blocks) = lax.scan(
         body, dq0, (jnp.arange(n_blocks), k_blocks, v_blocks))
     dk = dk_blocks.transpose(1, 2, 0, 3, 4).reshape(b, h, sk_p, d)[:, :, :sk]
-    dv = dv_blocks.transpose(1, 2, 0, 3, 4).reshape(b, h, sk_p, d)[:, :, :sk]
+    dv = dv_blocks.transpose(1, 2, 0, 3, 4).reshape(b, h, sk_p, -1)[:, :, :sk]
     if group != 1:
         dk = dk.reshape(b, kv_h, group, sk, d).sum(axis=2)
-        dv = dv.reshape(b, kv_h, group, sk, d).sum(axis=2)
+        dv = dv.reshape(b, kv_h, group, sk, -1).sum(axis=2)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 

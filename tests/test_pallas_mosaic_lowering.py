@@ -278,6 +278,19 @@ class TestFlashAttentionLowering:
         chip.compile(_flash_bwd(True, D7), q, kv, kv, q,
                      ((2, H7, 2048), F32), q)
 
+    def test_forward_and_backward_latent_attention_widths(self, chip):
+        """Kimi-Linear's MLA at the cell's size: 192-wide scores (128
+        nope + 64 shared), 128-wide values, 32 heads, 8,192 tokens."""
+        qk, v = ((1, 32, 8192, 192),), ((1, 32, 8192, 128),)
+        fwd = functools.partial(flash_attention_forward, causal=True,
+                                scale=192 ** -0.5, interpret=False)
+        chip.compile(fwd, qk, qk, v)
+
+        def bwd(q, k, v, out, lse, do):
+            return flash_attention_backward(q, k, v, out, lse, do, True,
+                                            192 ** -0.5, interpret=False)
+        chip.compile(bwd, qk, qk, v, v, ((1, 32, 8192), F32), v)
+
     def test_compiled_program_carries_the_kernel_payload(self, chip):
         fn = functools.partial(flash_attention_forward, causal=True,
                                interpret=False)
