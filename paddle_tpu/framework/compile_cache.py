@@ -24,8 +24,8 @@ def configure_compile_cache() -> str:
     """Point jax's persistent compilation cache at its directory and
     return that directory.  Called by every entry point that compiles
     for the chip (chip_smoke.py, bench.py, the bench tools, the servers'
-    ``start``) — never at package import, and tests turn the cache off
-    (tests/conftest.py)."""
+    ``start``) — never at package import, and tests place it in a temp
+    dir of the session through the variable (tests/conftest.py)."""
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env

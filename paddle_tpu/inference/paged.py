@@ -28,8 +28,8 @@ from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
-                                          dequantize_kv, kv_tokens_walked,
-                                          paged_attention,
+                                          append_rows, dequantize_kv,
+                                          kv_tokens_walked, paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
                                           quantize_kv, walk_block_pages)
@@ -268,12 +268,13 @@ class _TracedPagedContext:
     """Paged-attention driver for the JITTED decode/prefill steps: page
     pools, (page, slot) write targets, lengths and tables are all TRACED
     values carried through one compiled program — no host bookkeeping
-    inside.  Scatters are functional updates on the carried pools
-    (donated at the jit boundary, so XLA writes in place).
+    inside.  Appends are ``append_rows`` on the carried pools (donated
+    at the jit boundary: the rows are written in place, and a pool keeps
+    one layout from the boundary to the paged kernel and back out).
 
     Prefill mode: ``pg``/``sl`` are (batch*seq,) flat targets — pad
-    positions carry an out-of-bounds page index, which jax scatter DROPS
-    (mode 'drop' is the .at[] default), so a right-padded bucketed
+    positions carry an out-of-bounds page index (``total_pages``), which
+    ``append_rows`` DROPS for every head, so a right-padded bucketed
     prompt never writes garbage KV; attention is dense causal flash over
     the padded batch (pads sit to the RIGHT of every real token, so
     causality keeps them out of real tokens' windows).
@@ -309,23 +310,22 @@ class _TracedPagedContext:
         slot, per head) and the scale pools scatter alongside; returns
         the values attention must consume — the round-tripped ones, so
         every consumer sees exactly what the pages hold."""
-        kp, vp = self.k_pages[layer], self.v_pages[layer]
+        pg, sl = self.pg, self.sl
         if self.k_scales is not None:
             k8, ksc = quantize_kv(ks)
             v8, vsc = quantize_kv(vs)
-            self.k_scales[layer] = \
-                self.k_scales[layer].at[:, self.pg, self.sl].set(ksc)
-            self.v_scales[layer] = \
-                self.v_scales[layer].at[:, self.pg, self.sl].set(vsc)
-            self.k_pages[layer] = kp.at[:, self.pg, self.sl].set(k8)
-            self.v_pages[layer] = vp.at[:, self.pg, self.sl].set(v8)
-            return (dequantize_kv(k8, ksc, ks.dtype),
-                    dequantize_kv(v8, vsc, vs.dtype))
-        self.k_pages[layer] = \
-            kp.at[:, self.pg, self.sl].set(ks.astype(kp.dtype))
-        self.v_pages[layer] = \
-            vp.at[:, self.pg, self.sl].set(vs.astype(vp.dtype))
-        return ks, vs
+            self.k_scales[layer] = append_rows(self.k_scales[layer], pg, sl,
+                                               ksc)
+            self.v_scales[layer] = append_rows(self.v_scales[layer], pg, sl,
+                                               vsc)
+            ks_att = dequantize_kv(k8, ksc, ks.dtype)
+            vs_att = dequantize_kv(v8, vsc, vs.dtype)
+            ks, vs = k8, v8
+        else:
+            ks_att, vs_att = ks, vs
+        self.k_pages[layer] = append_rows(self.k_pages[layer], pg, sl, ks)
+        self.v_pages[layer] = append_rows(self.v_pages[layer], pg, sl, vs)
+        return ks_att, vs_att
 
     def _layer_scales(self, layer):
         if self.k_scales is None:

@@ -25,6 +25,10 @@ TPU-native design (see /opt/skills/guides/pallas_guide.md):
     column-masked (scores) and its unfetched slots zeroed (values);
   - GQA: the q-head group of each kv head computes together (group x
     head_dim MXU tiles);
+  - the append (``append_rows``) keeps a pool in the one layout the
+    kernels read: a Pallas call aliased onto the pool stages the page a
+    new row belongs to, selects the row into its slot and writes the
+    page back, real positions only — no pool-sized copy in a step;
   - off-TPU the same math runs as gather + dense masked attention (the
     correctness reference).
 
@@ -613,14 +617,175 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
 
 
 # ------------------------------------------------------------- page cache
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_pages(pool, pages, slots, vals):
-    """One scatter for a whole step's writes (all sequences at once).
-    The pool buffer is DONATED so XLA updates it in place instead of
-    copying the full pool per write — the per-sequence .at[].set loop
-    this replaces copied ~the whole pool batch x layers times per
-    decoded token."""
-    return pool.at[:, pages, slots].set(vals.astype(pool.dtype))
+#: positions a grid step of ``_append_kernel`` takes (its new rows ride
+#: in as one pipelined block: APPEND_BLOCK x kv_heads x last float32)
+APPEND_BLOCK = 128
+
+
+def _append_kernel(pg_ref, sl_ref, vals_ref, pool_in, pool_out, page_buf,
+                   cur_ref, sem, *, n_pages):
+    """Write the REAL positions of one block of ``APPEND_BLOCK`` positions
+    into the pool, which stays in HBM and is this call's own output
+    (``input_output_aliases``).  A slot is narrower than the pool's tile
+    (two bf16 slots share a sublane), so a slot is never copied alone:
+    the page that holds it is STAGED in VMEM — all kv heads' tiles of it
+    in one copy — the position's row is selected into its slot, and the
+    page goes back when the walk moves on to another page (or ends).
+    Positions of one row are consecutive slots, so a 128-token chunk
+    stages 8 or 9 pages and a decode row one.  Out-of-range positions
+    (pads) cost a scalar compare and nothing else."""
+    del pool_in                     # the same buffer as ``pool_out``
+    step, last_step = pl.program_id(0), pl.num_programs(0) - 1
+    kv_heads, page_size, last = page_buf.shape
+
+    @pl.when(step == 0)
+    def _():
+        cur_ref[0] = -1
+
+    def page_copy(page, back):
+        hbm = pool_out.at[:, page]
+        src, dst = (page_buf, hbm) if back else (hbm, page_buf)
+        return pltpu.make_async_copy(src, dst, sem)
+
+    def put_back():
+        @pl.when(cur_ref[0] >= 0)
+        def _():
+            copy = page_copy(cur_ref[0], back=True)
+            copy.start()
+            copy.wait()
+
+    slot_of = lax.broadcasted_iota(jnp.int32, (page_size, last), 0)
+
+    def body(i, carry):
+        t = step * APPEND_BLOCK + i
+        page, slot = pg_ref[t], sl_ref[t]
+        real = ((page >= 0) & (page < n_pages)
+                & (slot >= 0) & (slot < page_size))
+
+        @pl.when(real & (page != cur_ref[0]))
+        def _():
+            put_back()
+            copy = page_copy(page, back=False)
+            copy.start()
+            copy.wait()
+            cur_ref[0] = page
+
+        @pl.when(real)
+        def _():
+            new = vals_ref[i]                       # (kv_heads, last) f32
+            for h in range(kv_heads):
+                row = jnp.broadcast_to(new[h:h + 1], (page_size, last))
+                held = page_buf[h].astype(jnp.float32)
+                page_buf[h] = jnp.where(slot_of == slot, row,
+                                        held).astype(page_buf.dtype)
+        return carry
+
+    lax.fori_loop(0, APPEND_BLOCK, body, 0)
+
+    @pl.when(step == last_step)
+    def _():
+        put_back()
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _append_pallas(pool, pages, slots, vals, interpret=False):
+    """``_append_kernel`` over blocks of ``APPEND_BLOCK`` positions.
+    Jitted like ``_decode_pallas``: a serving program appends twice a
+    layer, and traced and lowered afresh each time the 32 programs of an
+    engine cost 100 s of set-up more (the kernel body is lowered to
+    Mosaic at every call site)."""
+    kv_heads, n_pages, page_size, last = pool.shape
+    tokens = vals.shape[1]
+    pad = -tokens % APPEND_BLOCK
+    # pad positions carry an out-of-range page: the kernel skips them
+    pages = jnp.pad(pages, (0, pad), constant_values=n_pages)
+    slots = jnp.pad(slots, (0, pad))
+    # (tokens, kv_heads, last) float32: a position is one leading index
+    # and one whole float32 tile per 8 heads (exact for bf16 and int8)
+    rows = jnp.pad(jnp.swapaxes(vals.astype(pool.dtype), 0, 1)
+                   .astype(jnp.float32), ((0, pad), (0, 0), (0, 0)))
+    return pl.pallas_call(
+        functools.partial(_append_kernel, n_pages=n_pages),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=((tokens + pad) // APPEND_BLOCK,),
+            in_specs=[
+                pl.BlockSpec((APPEND_BLOCK, kv_heads, last),
+                             lambda i, pg, sl: (i, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[
+                pltpu.VMEM((kv_heads, page_size, last), pool.dtype),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA(()),
+            ]),
+        out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+        # operands: pages, slots, rows, pool -> the pool is the output
+        input_output_aliases={3: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        name="kv_append_rows", interpret=interpret,
+    )(pages, slots, rows, pool)
+
+
+def _append_xla(pool, pages, slots, vals):
+    """The append as ONE row scatter over the pool viewed as
+    ``[kv_heads * pages * page_size, last]``: the scattered axis is
+    outermost and the window is the lane axis alone, so the (donated)
+    pool is updated in the layout it arrives in.  A position that is out
+    of range would be page 0 of the NEXT head in this view: it is sent
+    past the end of the flat pool, which ``mode="drop"`` discards."""
+    kv_heads, n_pages, page_size, last = pool.shape
+    rows = n_pages * page_size
+    assert kv_heads * rows < 2 ** 31, "flat row index overflows int32"
+    ok = ((pages >= 0) & (pages < n_pages)
+          & (slots >= 0) & (slots < page_size))
+    row = jnp.arange(kv_heads, dtype=jnp.int32)[:, None] * rows \
+        + (pages * page_size + slots)[None, :]
+    row = jnp.where(ok[None, :], row, kv_heads * rows)
+    flat = pool.reshape(kv_heads * rows, last).at[row.reshape(-1)].set(
+        vals.astype(pool.dtype).reshape(-1, last), mode="drop")
+    return flat.reshape(pool.shape)
+
+
+def append_rows(pool, pages, slots, vals, interpret=False):
+    """THE append: write ``vals[:, t]`` to ``pool[:, pages[t], slots[t]]``
+    for every position ``t``.  ``pool`` (kv_heads, pages, page_size,
+    last) — a bf16/f32/int8 K or V pool, or a ``[..., 1]`` scale pool;
+    ``vals`` (kv_heads, tokens, last); ``pages``/``slots`` (tokens,).
+
+    The pool keeps ONE layout from the jit boundary through this call to
+    the paged kernels and back out, so a donated pool is written in
+    place.  (Indexing ``pool[:, pg, sl]`` put the kv-head axis, which is
+    major to both scattered axes, inside the scatter's window: XLA's TPU
+    scatter then re-laid the whole pool out before the scatter and back
+    after it, two pool-sized copies a pool a step.)
+
+    A position whose page or slot is out of range — pad positions carry
+    page ``total_pages`` — is DROPPED for every head and never reaches a
+    real slot.
+
+    On the TPU, a pool whose pages are whole tiles of its dtype goes
+    through ``_append_kernel`` (one staged page a run of positions, so
+    the cost follows the REAL positions of a step); any other pool — a
+    ``[..., 1]`` scale pool, int8 pages of 16 slots — and every other
+    backend takes the row scatter ``_append_xla``, whose cost follows
+    the positions a step is padded to."""
+    kv_heads, n_pages, page_size, last = pool.shape
+    pages = pages.astype(jnp.int32)
+    slots = slots.astype(jnp.int32)
+    whole_tiles = (last % 128 == 0
+                   and page_size % (32 // pool.dtype.itemsize) == 0)
+    if whole_tiles and (_use_pallas() or interpret):
+        return _append_pallas(pool, pages, slots, vals, interpret=interpret)
+    return _append_xla(pool, pages, slots, vals)
+
+
+# the eager cache's append: the pool buffer is DONATED, so the step's
+# rows are written in place instead of into a copy of the pool
+_append_rows_donated = jax.jit(append_rows, donate_argnums=(0,),
+                               static_argnames=("interpret",))
 
 
 class _PrefixEntry:
@@ -1105,13 +1270,13 @@ class PagedKVCache:
             # context's in-program scatter)
             ks, ksc = quantize_kv(ks)
             vs, vsc = quantize_kv(vs)
-            self.k_scales[layer] = _scatter_pages(
+            self.k_scales[layer] = _append_rows_donated(
                 self.k_scales[layer], pg, sl, ksc)
-            self.v_scales[layer] = _scatter_pages(
+            self.v_scales[layer] = _append_rows_donated(
                 self.v_scales[layer], pg, sl, vsc)
-        self.k_pages[layer] = _scatter_pages(self.k_pages[layer], pg, sl,
-                                             ks)
-        self.v_pages[layer] = _scatter_pages(self.v_pages[layer], pg, sl,
-                                             vs)
+        self.k_pages[layer] = _append_rows_donated(
+            self.k_pages[layer], pg, sl, ks)
+        self.v_pages[layer] = _append_rows_donated(
+            self.v_pages[layer], pg, sl, vs)
         if layer == self.num_layers - 1:
             self.advance(seq_ids, n)

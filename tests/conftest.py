@@ -6,6 +6,7 @@ rendezvous, we give XLA 8 host devices and exercise the same SPMD code paths
 (shard_map/pjit/collectives) in-process.
 """
 import os
+import shutil
 import tempfile
 
 # keep the kernel-autotune cache out of the user's home and isolated per
@@ -20,16 +21,36 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+# One compilation cache for the SESSION, in a temp dir of its own: the
+# suite compiles the same tiny programs over and over (every engine a
+# test builds jits its own copies; every xdist worker and every child
+# process starts cold), and the tier-1 run sits at its time limit
+# (1,472 s without the cache, 1,103 s with it, six workers).  The
+# directory is named after the process that runs the session (an xdist
+# worker's parent), so the workers share it, and that process removes
+# it when the session ends: tests neither write into the checkout nor
+# depend on what an earlier run left.  With the variable set, the entry
+# points under test (servers, bench tools: framework/compile_cache.py)
+# set no directory of their own, and their child processes share it.
+_SESSION_OWNER = (os.getppid() if "PYTEST_XDIST_WORKER" in os.environ
+                  else os.getpid())
+_SESSION_CACHE = os.path.join(
+    tempfile.gettempdir(), f"paddle_tpu_test_jax_cache_{_SESSION_OWNER}")
+os.environ["JAX_COMPILATION_CACHE_DIR"] = _SESSION_CACHE
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
+
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-# entry points under test (servers, bench tools) place the persistent
-# compilation cache in the checkout; tests neither write there nor
-# depend on what an earlier run left
-jax.config.update("jax_enable_compilation_cache", False)
 
 import numpy as np
 import pytest
+
+
+def pytest_sessionfinish(session):
+    if _SESSION_OWNER == os.getpid():
+        shutil.rmtree(_SESSION_CACHE, ignore_errors=True)
 
 
 def pytest_configure(config):

@@ -193,8 +193,15 @@ class TestCompileCachePlacement:
                                  cwd=REPO).returncode
         assert ignored == 0, ".cache/ must be listed in .gitignore"
 
-    def test_tests_run_with_the_cache_off(self):
-        assert jax.config.jax_enable_compilation_cache is False
+    def test_tests_cache_in_a_temp_dir_of_the_session(self):
+        # conftest gives the session one cache of its own, outside the
+        # checkout, and removes it at the end: nothing an earlier run
+        # left is read, nothing is written beside the sources
+        import tempfile
+        path = jax.config.jax_compilation_cache_dir
+        assert path == os.environ["JAX_COMPILATION_CACHE_DIR"]
+        assert path.startswith(tempfile.gettempdir())
+        assert not path.startswith(REPO)
 
     def test_autotune_winners_live_in_the_same_directory(self):
         # conftest points the tests' own cache at a temp file through the

@@ -388,9 +388,16 @@ class TestFleetFailover:
                 router_url(router) + f"/result/{rid}", timeout=30)
             return payload
 
-        wait_for(lambda: all(
-            result(rid).get("generated_tokens", 0) >= 2
-            for rid in bodies), timeout=300, msg="all 4 mid-decode")
+        try:
+            wait_for(lambda: all(
+                result(rid).get("generated_tokens", 0) >= 2
+                for rid in bodies), timeout=300, msg="all 4 mid-decode")
+        except AssertionError as e:
+            # say where each stream stood: a stream that FINISHED before
+            # the others were two tokens in reads 0 here for good
+            raise AssertionError(f"{e}: " + repr({
+                rid: {k: v for k, v in result(rid).items()
+                      if k != "output_ids"} for rid in bodies})) from e
         states = {rid: result(rid) for rid in bodies}
         assert all(s.get("status") == "pending"
                    for s in states.values())

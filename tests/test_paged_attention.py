@@ -9,7 +9,7 @@ import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu.ops.pallas.paged_attention import (
-    PagedKVCache, paged_attention, paged_attention_multi,
+    PagedKVCache, append_rows, paged_attention, paged_attention_multi,
     paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla,
     kv_tokens_walked, quantize_kv, walk_block_pages)
 from paddle_tpu.ops.pallas.flash_attention import mha_reference
@@ -297,6 +297,90 @@ def _real_queries(mode, out, q_lens):
         return out
     keep = np.arange(out.shape[1])[None, :] < np.asarray(q_lens)[:, None]
     return out[keep]
+
+
+class TestAppendRows:
+    """``append_rows`` (ISSUE 30) against the indexing it replaced,
+    ``pool.at[:, pg, sl].set(vals)``, bit for bit, in both of its forms
+    (the row scatter, and the kernel that stages a page, interpreted) —
+    and the hazard of the scatter's flat row view: a pad position's
+    out-of-range page must not land in page 0 of the next kv head."""
+
+    KVH, PAGES, PAGE, D = 4, 12, 16, 128
+
+    @pytest.fixture(scope="class")
+    def append(self):
+        return jax.jit(append_rows, static_argnames=("interpret",))
+
+    def _targets(self, rng, shape):
+        """(pages, slots, real) of one step: ``decode`` is 8 rows of one
+        token, ``chunk`` 2 rows of 24 positions of which row 0 holds 24
+        real tokens and row 1 one, ``weave`` 150 positions that
+        alternate between two rows' pages (every position leaves the
+        page the one before it wrote).  Every other position is a pad at
+        page ``total_pages`` with a NON-zero slot.  One real position is
+        the pool's very last slot; page 0 takes none."""
+        rows, span, real_per_row = {"decode": (8, 1, [1] * 6 + [0, 0]),
+                                    "chunk": (2, 24, [24, 1]),
+                                    "weave": (2, 75, [40, 40])}[shape]
+        pg = np.full((rows, span), self.PAGES, np.int32)
+        sl = np.asarray(rng.integers(1, self.PAGE, (rows, span)), np.int32)
+        # page 0 takes no real position: a pad that leaks lands there
+        free = list(1 + rng.permutation(self.PAGES - 2))
+        for r, n in enumerate(real_per_row):
+            start = int(rng.integers(0, self.PAGE))
+            pos = start + np.arange(n)
+            own = [free.pop() for _ in range(int(pos[-1]) // self.PAGE + 1)
+                   ] if n else []
+            pg[r, :n] = [own[p] for p in pos // self.PAGE]
+            sl[r, :n] = pos % self.PAGE
+        real = pg < self.PAGES
+        # the last page's last slot takes one real position
+        r = int(np.argmax(real.sum(1) >= 1))
+        pg[r, 0], sl[r, 0] = self.PAGES - 1, self.PAGE - 1
+        if shape == "weave":        # row 0's and row 1's positions in turn
+            pg, sl, real = (a.T for a in (pg, sl, real))
+        return pg.reshape(-1), sl.reshape(-1), real.reshape(-1)
+
+    @pytest.mark.parametrize("shape", ["decode", "chunk", "weave"])
+    @pytest.mark.parametrize("kv", ["bf16", "bf16-kernel", "f32-kernel",
+                                    "int8"])
+    def test_matches_indexed_set_and_drops_pads(self, append, kv, shape):
+        rng = np.random.default_rng(30)
+        pg, sl, real = self._targets(rng, shape)
+        assert real.any() and not real.all() and (sl[~real] > 0).all()
+        full = (self.KVH, self.PAGES, self.PAGE, self.D)
+        vals = jnp.asarray(rng.standard_normal(
+            (self.KVH, pg.size, self.D)), jnp.float32)
+        if kv == "int8":
+            v8, vsc = quantize_kv(vals)
+            cases = [(jnp.asarray(rng.integers(-127, 128, full), jnp.int8),
+                      v8),
+                     (jnp.asarray(rng.random(full[:3] + (1,)), jnp.float32),
+                      vsc)]
+        else:
+            dtype = jnp.float32 if kv.startswith("f32") else jnp.bfloat16
+            cases = [(jnp.asarray(rng.standard_normal(full), dtype), vals)]
+        for pool, new in cases:
+            want = pool.at[:, pg, sl].set(new.astype(pool.dtype))
+            got = append(pool, jnp.asarray(pg), jnp.asarray(sl), new,
+                         interpret=kv.endswith("kernel"))
+            assert got.dtype == pool.dtype and got.shape == pool.shape
+            np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                          np.asarray(want, np.float32))
+            got, pool = np.asarray(got, np.float32), np.asarray(
+                pool, np.float32)
+            # exactly the real positions moved (for every head) ...
+            touched = np.zeros(full[:3], bool)
+            touched[:, pg[real], sl[real]] = True
+            np.testing.assert_array_equal(got[~touched], pool[~touched])
+            np.testing.assert_array_equal(
+                got[:, pg[real], sl[real]],
+                np.asarray(new.astype(want.dtype), np.float32)[:, real])
+            # ... the last page's last slot among them, and no pad row
+            # in page 0 of the next head (where its flat index points)
+            assert touched[:, -1, -1].all() and not touched[:, 0].any()
+            np.testing.assert_array_equal(got[:, 0], pool[:, 0])
 
 
 class TestContextWalk:

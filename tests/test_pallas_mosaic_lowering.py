@@ -16,8 +16,10 @@ The topology, the shardings and the mesh are built inside module-scoped
 fixtures, never at import: only one process may load the TPU's library,
 and every xdist worker imports this file.
 """
+import collections
 import functools
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -39,7 +41,9 @@ from paddle_tpu.ops.pallas.fused_norm_rope import (
     rms_norm_pallas,
 )
 from paddle_tpu.ops.pallas.moe_gating import topk_gating_pallas
-from paddle_tpu.ops.pallas.paged_attention import _decode_pallas
+from paddle_tpu.ops.pallas import paged_attention
+from paddle_tpu.ops.pallas.paged_attention import (_decode_pallas,
+                                                   append_rows)
 from paddle_tpu.ops.pallas.quant_matmul import (
     w8a8_matmul_pallas,
     weight_only_matmul_pallas,
@@ -228,6 +232,91 @@ class TestPagedAttentionLowering:
             chip.sds((8,), I32, sharding=rep))
         # per chip: a quarter of the heads — no gather of the pools
         assert "all-gather" not in text
+
+
+# ------------------------------------------------------- the KV append
+class TestAppendRowsLowering:
+    """One layer of the serving step at the benchmark cell's shapes
+    (ISSUE 30): ``append_rows`` for K and V, then the ragged kernel, the
+    pools donated.  The compiled program must hold each pool in ONE
+    layout from the jit boundary to the Pallas calls and back out: the
+    only instructions that produce a pool-sized array are the appends
+    themselves, which write in place — the ``kv_append_rows`` kernel for
+    a pool whose pages are whole tiles (bf16), the row scatter's fusion
+    for one whose are not (int8 pages of 16 slots).  (Indexing
+    ``pool[:, pg, sl]`` compiled to two pool-sized ``copy`` a pool: the
+    scatter's window held the kv-head axis, so XLA re-laid the pool out
+    and back.)"""
+
+    KVH, PAGES, PAGE, ROWS, SPAN, TABLE = 8, 4096, 16, 8, 128, 256
+
+    @staticmethod
+    def _pool_sized(text, count, dtype):
+        """{opcode: n} of the instructions whose result is ``dtype`` with
+        ``count`` elements."""
+        ops = collections.Counter()
+        for dt, dims, op in re.findall(
+                r"^\s*(?:ROOT )?%?[\w.\-]+ = (\w+)\[([\d,]*)\]\S* "
+                r"([\w\-]+)\(", text, re.M):
+            if dt == dtype and math.prod(
+                    int(d) for d in dims.split(",") if d) == count:
+                ops[op] += 1
+        return ops
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    def test_no_pool_sized_copy_in_a_layer(self, chip, monkeypatch, int8):
+        # ``_use_pallas`` asks the default backend, which is the CPU here
+        monkeypatch.setattr(paged_attention, "_use_pallas", lambda: True)
+        tokens = self.ROWS * self.SPAN
+        pdt = I8 if int8 else BF16
+        pool = chip.sds((self.KVH, self.PAGES, self.PAGE, D7), pdt)
+        scale_pool = chip.sds((self.KVH, self.PAGES, self.PAGE, 1), F32)
+        pools = [pool, pool] + ([scale_pool, scale_pool] if int8 else [])
+        new = [chip.sds((self.KVH, tokens, D7), pdt)] * 2 + (
+            [chip.sds((self.KVH, tokens, 1), F32)] * 2 if int8 else [])
+        kernel = _paged_fn(D7, nq=self.SPAN, int8=int8, ragged=True)
+
+        n = len(pools)
+
+        def layer(*args):
+            pg, sl = args[n:n + 2]
+            q, lens, tabs, q_lens = args[2 * n + 2:]
+            out = [append_rows(p, pg, sl, v)
+                   for p, v in zip(args[:n], args[n + 2:2 * n + 2])]
+            return (kernel(q, out[0], out[1], lens, tabs, *out[2:], q_lens),
+                    *out)
+
+        text = jax.jit(layer, donate_argnums=tuple(range(n))).lower(
+            *pools, chip.sds((tokens,), I32), chip.sds((tokens,), I32), *new,
+            chip.sds((self.ROWS, self.SPAN, H7, D7), BF16),
+            chip.sds((self.ROWS,), I32),
+            chip.sds((self.ROWS, self.TABLE), I32),
+            chip.sds((self.ROWS,), I32)).compile().as_text()
+        assert "tpu_custom_call" in text
+        assert ("kv_append_rows" in text) == (not int8)
+        # every pool is updated in the buffer it arrived in
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        aliased = {int(p) for p in re.findall(r"\((\d+), \{\}",
+                                              alias.group(1))}
+        assert aliased >= set(range(n)), alias.group(0)
+        ops = self._pool_sized(
+            text, self.KVH * self.PAGES * self.PAGE * D7,
+            "s8" if int8 else "bf16")
+        if int8:
+            # the two scatter fusions, and the ConcatBitcast custom call
+            # through which XLA stages the K pool for the kernel whatever
+            # form the append takes; the ``[..., 1]`` scale pools (2 MB)
+            # are still re-laid out around their scatter (PERF.md
+            # section 7) and are not counted here
+            assert ops["scatter"] == 2 and ops["fusion"] == 2, ops
+            allowed = {"parameter", "bitcast", "scatter", "fusion",
+                       "custom-call"}
+        else:
+            assert ops["custom-call"] == 2, ops     # K's append and V's
+            allowed = {"parameter", "bitcast", "custom-call"}
+        assert set(ops) <= allowed, (
+            f"a pool-sized array is produced by {set(ops) - allowed}: the "
+            f"pool changes layout inside the step ({dict(ops)})")
 
 
 # ----------------------------------------------------- flash attention
