@@ -20,6 +20,9 @@ def main():
     if args.smoke:
         import jax
         jax.config.update("jax_platforms", "cpu")
+        # every new cache length is a new shape to the eager speculative
+        # generator: a handful of tokens shows each part running
+        args.max_new_tokens = min(args.max_new_tokens, 4)
 
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM

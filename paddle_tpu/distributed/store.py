@@ -182,7 +182,12 @@ class _NativeClient:
         return self._lib.pt_store_wait(self._fd, key, timeout_ms) == 0
 
     def check(self, key: bytes) -> bool:
-        return self._lib.pt_store_check(self._fd, key) == 1
+        rc = self._lib.pt_store_check(self._fd, key)
+        if rc < 0:
+            # a lost connection is not "the key is absent": a receiver
+            # that polls check() would wait on a dead store for good
+            raise ConnectionError("store connection lost")
+        return rc == 1
 
     def close(self):
         if self._fd >= 0:

@@ -7,7 +7,10 @@ rendezvous, we give XLA 8 host devices and exercise the same SPMD code paths
 """
 import os
 import shutil
+import signal
 import tempfile
+import threading
+import traceback
 
 # keep the kernel-autotune cache out of the user's home and isolated per
 # test session — unconditional, so an exported PADDLE_TPU_AUTOTUNE_CACHE
@@ -58,6 +61,37 @@ def pytest_configure(config):
         "markers",
         "slow: long-running tests excluded from the tier-1 gate "
         "(-m 'not slow')")
+
+
+_TEST_LIMIT_S = 300.0
+
+
+@pytest.fixture(autouse=True)
+def _time_limit(request):
+    """A hang costs one test 300 s, not the suite its 1,470: when the
+    timer fires, the test fails with its name and the stack it stood
+    at.  A guard, not a budget — no test should come near it.  (SIGALRM
+    reaches the main thread, where pytest and its xdist workers run the
+    tests; a test blocked inside one C call fails when that returns.)"""
+    if threading.current_thread() is not threading.main_thread():
+        yield
+        return
+    limit = _TEST_LIMIT_S
+
+    def over(signum, frame):
+        pytest.fail(
+            f"{request.node.nodeid} ran over the {limit:g} s a test may "
+            "take; it stood at:\n"
+            + "".join(traceback.format_stack(frame, limit=12)),
+            pytrace=False)
+
+    previous = signal.signal(signal.SIGALRM, over)
+    signal.setitimer(signal.ITIMER_REAL, limit)
+    try:
+        yield
+    finally:
+        signal.setitimer(signal.ITIMER_REAL, 0)
+        signal.signal(signal.SIGALRM, previous)
 
 
 @pytest.fixture(autouse=True)

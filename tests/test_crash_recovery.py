@@ -95,15 +95,17 @@ def install_at_step_boundary(eng, plan):
 
 
 def submit_and_ripen(eng, prompts, max_new_tokens, submit_kw=None,
-                     min_generated=2):
+                     min_generated=2, delay_s=0.01):
     """Submit every prompt and wait until ALL rows are mid-decode
     (>= min_generated tokens, none finished) — the deterministic
     setup point for injecting a mid-decode device fault.  A mild
     decode delay is installed first so the mid-decode window is wide
     enough that the poll below cannot miss it on a fast machine; the
-    caller's own plan (or the autouse clear) replaces it."""
+    caller's own plan (or the autouse clear) replaces it.  A caller
+    whose next move takes longer than a few steps (stopping a server)
+    passes the ``delay_s`` that keeps the rows mid-decode meanwhile."""
     faults.install(faults.FaultPlan(
-        [{"site": "decode_step", "kind": "delay", "delay_s": 0.01}]))
+        [{"site": "decode_step", "kind": "delay", "delay_s": delay_s}]))
     submit_kw = submit_kw or [{} for _ in prompts]
     reqs = [eng.submit(p, max_new_tokens=max_new_tokens, **kw)
             for p, kw in zip(prompts, submit_kw)]
@@ -794,10 +796,19 @@ class TestJournalRecovery:
         srvA = GenerationServer(model, total_pages=64, page_size=8,
                                 max_batch=4, journal_dir=jdir).start()
         try:
-            reqs = submit_and_ripen(srvA._engine, prompts, 12)
+            # stop() closes the HTTP listener BEFORE it stops the
+            # engine; at 0.01 s a step the ten tokens left were done
+            # (and retired in the journal) by then on a loaded host,
+            # and srvB found nothing to restore.  0.25 s a step holds
+            # both rows mid-decode for two seconds and more.
+            reqs = submit_and_ripen(srvA._engine, prompts, 12,
+                                    delay_s=0.25)
             rids = [r.request_id for r in reqs]
         finally:
             srvA.stop()     # engine hard-stops: journals no retirement
+            faults.clear()
+        assert not any(r.error is None and r.done.is_set() for r in reqs), \
+            "a row finished before the stop: nothing left to resume"
         srvB = GenerationServer(model, total_pages=64, page_size=8,
                                 max_batch=4, journal_dir=jdir).start()
         try:

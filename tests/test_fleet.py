@@ -62,7 +62,8 @@ def wait_for(cond, timeout=60.0, msg="condition"):
         if cond():
             return
         time.sleep(0.005)
-    raise AssertionError(f"timed out waiting for {msg}")
+    raise AssertionError(
+        f"timed out waiting for {msg() if callable(msg) else msg}")
 
 
 def http_json(url, body=None, timeout=60.0):
@@ -376,9 +377,15 @@ class TestFleetFailover:
         for t in [post_async(router, b, warm_outs) for b in warm]:
             t.join(timeout=300)
 
+        # HOLD "all four mid-decode at once": every decode step of
+        # either replica sleeps 1 s, so the shortest stream (fo-draft:
+        # 32 tokens, at most spec_tokens + 1 a step) stays pending for
+        # some ten seconds after its second token — the four sequential
+        # polls below need a fraction of that even on a loaded host.
+        # The plan is cleared right after the kill.
         faults.install(faults.FaultPlan(
             [{"site": "decode_step", "kind": "delay",
-              "delay_s": 0.05}]))
+              "delay_s": 1.0}]))
         outs: dict = {}
         threads = [post_async(router, bodies[rid], outs)
                    for rid in bodies]
@@ -388,19 +395,25 @@ class TestFleetFailover:
                 router_url(router) + f"/result/{rid}", timeout=30)
             return payload
 
-        try:
-            wait_for(lambda: all(
-                result(rid).get("generated_tokens", 0) >= 2
-                for rid in bodies), timeout=300, msg="all 4 mid-decode")
-        except AssertionError as e:
-            # say where each stream stood: a stream that FINISHED before
-            # the others were two tokens in reads 0 here for good
-            raise AssertionError(f"{e}: " + repr({
-                rid: {k: v for k, v in result(rid).items()
-                      if k != "output_ids"} for rid in bodies})) from e
-        states = {rid: result(rid) for rid in bodies}
-        assert all(s.get("status") == "pending"
-                   for s in states.values())
+        states: dict = {}
+
+        def all_mid_decode():
+            for rid in bodies:
+                states[rid] = {k: v for k, v in result(rid).items()
+                               if k != "output_ids"}
+            # a stream that finished can never read "pending, two
+            # tokens in" again: fail now, not after the timeout
+            done = [rid for rid in bodies
+                    if states[rid].get("status") == "done"]
+            assert not done, (
+                f"{done} finished before all four were mid-decode "
+                f"(the hold is too short): {states}")
+            return all(s.get("status") == "pending"
+                       and s.get("generated_tokens", 0) >= 2
+                       for s in states.values())
+
+        wait_for(all_mid_decode, timeout=120,
+                 msg=lambda: f"all 4 mid-decode: {states}")
         owners = [states[rid]["replica"] for rid in bodies]
         victim = max(set(owners), key=owners.count)
         fo_before = monitor.get_registry().get(
@@ -433,11 +446,21 @@ class TestFleetFailover:
 
     def test_fleet_health_reports_dead_replica(self, fleet):
         sup, router = fleet
+        # its own corpse: the kill test above leaves one when it ran
+        # and got that far, and this test must not lean on that
+        def a_corpse():
+            return any(r.state == Replica.DEAD
+                       for r in sup.replicas.values())
+
+        if not a_corpse():
+            sup.kill(sup.routable_replicas()[0].name)
+        wait_for(a_corpse,
+                 msg="the probes to declare the killed replica dead")
         status, payload, _ = http_json(router_url(router) + "/health")
         assert status == 200
         states = {name: r["state"]
                   for name, r in payload["replicas"].items()}
-        assert "dead" in states.values()       # the kill above
+        assert "dead" in states.values()
         assert payload["routable"] >= 1
         assert payload["status"] == "ok"
 

@@ -6,6 +6,7 @@ random (bad) draft, with the target as its own draft (100% acceptance,
 exercising the all-accepted cache gap-fill), and across eos cuts.
 """
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.inference import SpeculativeGenerator
@@ -26,17 +27,27 @@ def _prompt(n=7, seed=0):
 
 
 class TestSpeculativeGreedyExactness:
-    def test_matches_target_greedy_with_bad_draft(self):
+    # 8 new tokens, not 24: the generator's caches are concat caches,
+    # so every cache length is a new shape and every eager op on it a
+    # new compile (some 17 a round) — more rounds add compiles, not
+    # cases: with this draft every round rejects
+    NEW_TOKENS = 8
+
+    @pytest.fixture(scope="class")
+    def bad_draft(self):
         target, draft = _model(4, 0), _model(2, 99)
         x = _prompt()
-        ref = target.generate(x, max_new_tokens=24)
-        for k in (1, 2, 4, 7):
-            gen = SpeculativeGenerator(target, draft,
-                                       num_speculative_tokens=k)
-            got = gen.generate(x, max_new_tokens=24)
-            np.testing.assert_array_equal(np.asarray(ref), got,
-                                          err_msg=f"k={k}")
-            assert gen.last_stats["rounds"] >= 1
+        return target, draft, x, np.asarray(
+            target.generate(x, max_new_tokens=self.NEW_TOKENS))
+
+    @pytest.mark.parametrize("k", [1, 2, 4, 7])
+    def test_matches_target_greedy_with_bad_draft(self, bad_draft, k):
+        target, draft, x, ref = bad_draft
+        gen = SpeculativeGenerator(target, draft,
+                                   num_speculative_tokens=k)
+        got = gen.generate(x, max_new_tokens=self.NEW_TOKENS)
+        np.testing.assert_array_equal(ref, got)
+        assert gen.last_stats["rounds"] >= 1
 
     def test_self_draft_accepts_everything(self):
         # draft == target: every proposal must be accepted; the

@@ -309,7 +309,10 @@ class TestFusedRunStepsDp:
         paddle.seed(0)
         net = nn.Sequential(nn.Linear(64, 128), nn.ReLU(),
                             nn.Linear(128, 8))
-        dp = dist.DataParallel(net)
+        # its OWN 8-way dp mesh: left to the default, DataParallel
+        # takes whatever mesh another file of this worker last set
+        dp = dist.DataParallel(net, mesh=dist.ProcessMesh(
+            np.arange(jax.device_count()), ["dp"]))
         opt = optim.SGD(learning_rate=1e-2,
                         parameters=net.parameters())
         step = TrainStep(dp, lambda out, y: F.cross_entropy(out, y),

@@ -155,14 +155,15 @@ class TestVisionAdditions:
         out = pipeline(img)
         assert out.shape == (3, 20, 20)
 
-    def test_model_factories(self):
+    @pytest.mark.parametrize(
+        "factory", ["resnext50_32x4d", "shufflenet_v2_x0_5", "densenet169"])
+    def test_model_factories(self, factory):
         import paddle_tpu.vision.models as M
         x = t(rs.randn(1, 3, 32, 32).astype(np.float32))
-        for f in (M.resnext50_32x4d, M.shufflenet_v2_x0_5,
-                  M.densenet169):
-            m = f(num_classes=7)
-            m.eval()
-            assert m(x).shape == [1, 7]
+        # one compiled forward, not a compile for every layer's shape
+        m = paddle.jit.to_static(getattr(M, factory)(num_classes=7))
+        m.eval()
+        assert m(x).shape == [1, 7]
 
 
 class TestStaticCompat:

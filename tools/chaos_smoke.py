@@ -574,7 +574,11 @@ def run_hard_kill() -> dict:
                 seed=b["seed"]).result(timeout=600)
 
     checks, details = {}, {}
-    proc = spawn(delay=0.1)
+    # 0.25 s a decode step HOLDS "all four mid-decode" for seconds (12
+    # tokens, the draft row's 24 at three a step): the polls below need
+    # a fraction of that on a loaded host, and the child is killed
+    # anyway, so the delay costs the lane nothing
+    proc = spawn(delay=0.25)
     try:
         port = wait_port(proc)
         # greedy first: its prefill registers the shared prefix, so
@@ -712,11 +716,13 @@ def run_fleet_kill() -> dict:
         "fk-prefix": shared + rng.integers(0, 64, (5,)).tolist(),
         "fk-draft": rng.integers(0, 64, (6,)).tolist(),
     }
-    # budgets are WIDE (vs the hard-kill lane's 12): the two replicas
-    # decode independently, so the kill window must stay open until
-    # the SLOWEST replica's streams have >= 2 tokens while the fastest
-    # has not finished — speculative rows advance ~spec_k+1 per step,
-    # so the draft row gets the widest budget
+    # budgets are WIDE (vs the hard-kill lane's 12) and a decode step
+    # takes 0.25 s: the two replicas decode independently, so the kill
+    # window must stay open until the SLOWEST replica's streams have
+    # >= 2 tokens while the fastest has not finished — four seconds
+    # and more of "all four pending", where four polls through the
+    # router take a fraction of one.  Speculative rows advance
+    # ~spec_k+1 per step, so the draft row gets the widest budget
     bodies = {
         rid: {"input_ids": [prompts[rid]], "max_new_tokens": 24,
               "request_id": rid, "seed": 200 + i}
@@ -725,7 +731,7 @@ def run_fleet_kill() -> dict:
     bodies["fk-greedy"]["draft"] = False
     bodies["fk-prefix"]["draft"] = False
     bodies["fk-draft"]["draft"] = True
-    bodies["fk-draft"]["max_new_tokens"] = 32
+    bodies["fk-draft"]["max_new_tokens"] = 48
 
     # the uninterrupted-run oracle over the same seeded weights
     from paddle_tpu.inference.continuous import ContinuousBatchingEngine
@@ -753,7 +759,7 @@ def run_fleet_kill() -> dict:
         # replica to the fleet — probes, migration and bit-exact
         # failover must not notice the mesh behind it
         for name, tp in (("r0", 1), ("r1", 2)):
-            proc, jdir, port = spawn(name, delay=0.1, tp=tp)
+            proc, jdir, port = spawn(name, delay=0.25, tp=tp)
             procs[name] = proc
             sup.add_replica(name, f"http://127.0.0.1:{port}",
                             journal_dir=jdir, proc=proc)
