@@ -349,16 +349,20 @@ def plain_logits(model, ids):
     return np.asarray(out, np.float32)
 
 
-def paged_last_logits(decoder, model, row, page_size, mesh=None):
+def paged_last_logits(decoder, model, row, page_size, chunk, mesh=None):
     """Last-position logits of ``row`` from the RAGGED paged program
-    (its ``sampling=None`` logits escape hatch) over a scratch cache."""
+    (its ``sampling=None`` logits escape hatch) over a scratch cache,
+    fed ``chunk`` tokens a step as the engine feeds a prompt (its
+    decoder packs a step to the engine's token bound and takes no
+    more)."""
     from paddle_tpu.ops.pallas.paged_attention import PagedKVCache
+    row = np.asarray(row, np.int32)
     pages = -(-len(row) // page_size) + 1
     cache = PagedKVCache.from_model(model, total_pages=pages,
                                     page_size=page_size, mesh=mesh)
-    out, _ = decoder.ragged_step(cache, ["logits-check"],
-                                 [np.asarray(row, np.int32)], [0],
-                                 sampling=None)
+    for k in range(0, len(row), chunk):
+        out, _ = decoder.ragged_step(cache, ["logits-check"],
+                                     [row[k:k + chunk]], [k], sampling=None)
     return np.asarray(out, np.float32)[0]
 
 
@@ -574,7 +578,7 @@ def phase_serve(cfg, seed, *, param_dtype, total_pages, page_size,
         _logits_agree("[serve] paged prefill vs plain forward, "
                       f"{len(row)} tokens",
                       paged_last_logits(engine._decoder, model, row,
-                                        page_size),
+                                        page_size, chunk_tokens),
                       plain_logits(model, row[None])[0, -1], logits_tol)
         for path in ("/health", "/metrics", "/debug/cost"):
             status, body = _http(base + path)
@@ -700,7 +704,8 @@ def phase_four_serve(cfg, seed, *, param_dtype, total_pages, page_size,
             base = f"http://{server.host}:{server.port}"
             toks = [_generate(base, p, new_tokens) for p in prompts]
             logits = [paged_last_logits(engine._decoder, model, p,
-                                        page_size, mesh=engine.mesh)
+                                        page_size, chunk_tokens,
+                                        mesh=engine.mesh)
                       for p in prompts]
             return toks, logits
         finally:

@@ -696,10 +696,13 @@ class ContinuousBatchingEngine:
         self.cache = PagedKVCache.from_model(
             model, total_pages=total_pages, page_size=page_size,
             kv_dtype=kv_quant, mesh=self.mesh)
+        self.draft_model = draft_model
+        self.spec_k = int(spec_tokens)
         from .paged import JittedPagedDecoder
         self._decoder = JittedPagedDecoder(
             model, min_table_pages=min_table_pages, quantize=quantize,
-            mesh=self.mesh, tp_quant_collectives=self.tp_quant_collectives)
+            mesh=self.mesh, tp_quant_collectives=self.tp_quant_collectives,
+            step_tokens=self._step_token_bound())
         _quant_enabled_g.set(int(quantize is not None))
         _kv_quant_enabled_g.set(int(kv_quant is not None))
         _kv_quant_pool_bytes_g.set(self.cache.kv_pool_bytes)
@@ -708,8 +711,6 @@ class ContinuousBatchingEngine:
         # speculative decoding (ISSUE 6): the draft gets its own
         # decoder + page pool; proposals/verification share the target's
         # bucketing so steady-state serving stays compile-free
-        self.draft_model = draft_model
-        self.spec_k = int(spec_tokens)
         if draft_model is not None:
             if self.spec_k < 1:
                 raise ValueError("spec_tokens must be >= 1")
@@ -1968,6 +1969,25 @@ class ContinuousBatchingEngine:
             self._finalize_admission_locked(req)
             self._prefilling.append(req)
         _queue_depth.set(len(self._sched))
+
+    def _step_token_bound(self) -> Optional[int]:
+        """The most tokens one unified step can carry, which the ragged
+        programs' dense layers are packed to (None: no bound, monolithic
+        prefill hands a step whole prompts).  A step's rows are at most
+        ``max_batch``, padded by the decoder to a power of two with
+        one-token rows.  ``_plan_chunks_locked`` never splits a chunk
+        to fit the leftover budget, so prompt tails summing to under
+        one chunk and then a full chunk can share a step:
+        ``2 * chunk - 1`` prefill tokens, in at least one row; every
+        other row decodes one token or, beside a draft model, verifies
+        ``spec_k + 1``."""
+        chunk = self.prefill_chunk_tokens
+        if chunk is None:
+            return None
+        from .paged import next_pow2
+        per_row = self.spec_k + 1 if self._spec else 1
+        return (max(2 * chunk - 1, per_row)
+                + (next_pow2(self.max_batch) - 1) * per_row)
 
     def _plan_chunks_locked(self) -> List:
         """Caller holds ``self._cond``.  (request, n_tokens) prefill

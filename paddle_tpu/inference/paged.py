@@ -16,6 +16,7 @@ is the follow-up that needs it).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional, Sequence
 
@@ -28,7 +29,8 @@ from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
-                                          append_rows, dequantize_kv,
+                                          _round_up, append_rows,
+                                          dequantize_kv,
                                           kv_tokens_walked, paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
@@ -215,6 +217,42 @@ def next_pow2(n: int) -> int:
     return b
 
 
+# ------------------------------------------------ the ragged step's pack
+# The ragged program's dense layers run over the step's tokens PACKED
+# along one axis — row 0's span, then row 1's, ... then pad — and only
+# the paged kernel sees the (rows, span) rectangle.  ``off[r]`` is where
+# row ``r`` starts on the packed axis; ``off=None`` says the packed axis
+# is as long as the rectangle and every row keeps its place in it (a
+# reshape).  Both ways are jitted: a program's layers all call them at
+# the same shapes and share one traced and lowered body.
+
+@functools.partial(jax.jit, static_argnames=("span",))
+def _rows_of_packed(x, off, span):
+    """``x[T, ...]`` packed -> ``[rows, span, ...]``: row ``r`` is the
+    ``span`` entries from ``off[r]`` on.  Past the row's own tokens that
+    is its successors' (or pad): garbage the caller masks or discards."""
+    if off is None:
+        return x.reshape((-1, span) + x.shape[1:])
+    at = off[:, None] + jnp.arange(span, dtype=jnp.int32)[None, :]
+    return x[jnp.minimum(at, x.shape[0] - 1)]
+
+
+@functools.partial(jax.jit, static_argnames=("tokens",))
+def _packed_of_rows(x, off, tokens):
+    """``x[rows, span, ...]`` -> ``[tokens, ...]`` packed: position ``t``
+    takes row ``r``'s column ``t - off[r]``, ``r`` the last row that
+    starts at or before ``t``.  Past the step's tokens that is the last
+    row's tail: garbage the caller masks or discards."""
+    rows, span = x.shape[:2]
+    flat = x.reshape((rows * span,) + x.shape[2:])
+    if off is None:
+        return flat
+    at = jnp.arange(tokens, dtype=jnp.int32)
+    row = jnp.sum(at[:, None] >= off[None, :], axis=1) - 1
+    col = jnp.minimum(at - off[row], span - 1)
+    return flat[row * span + col]
+
+
 class _PagedContext:
     """Per-forward attention driver handed down to attention layers.
 
@@ -283,11 +321,21 @@ class _TracedPagedContext:
     the batch's tokens are a prompt SUFFIX whose page-aligned prefix KV
     already sits in the pages ``tables`` points at — suffix K/V scatter
     into fresh pages exactly as in prefill, but attention runs over
-    [gathered prefix; suffix] so the cached tokens are visible."""
+    [gathered prefix; suffix] so the cached tokens are visible.
+
+    Ragged mode (``q_lens`` and ``span`` set): the model runs over the
+    step's tokens PACKED along the batch axis, one token a "row" —
+    ``q``/``k``/``v`` arrive as (tokens, 1, heads, d) and ``pg``/``sl``
+    are (tokens,), row ``r``'s ``q_lens[r]`` tokens standing together
+    from ``row_off[r]`` on (``row_off=None``: the packed axis is the
+    whole rectangle, row-major).  The append takes the packed rows as
+    they come; only the paged kernel's call goes to the (rows, ``span``)
+    rectangle, each row's span LEFT-aligned in it, and its output comes
+    back packed.  ``lens``, ``q_lens`` and ``tables`` are per ROW."""
 
     def __init__(self, k_pages, v_pages, pg, sl, lens=None, tables=None,
                  prefill=False, prefix_lens=None, k_scales=None,
-                 v_scales=None, q_lens=None):
+                 v_scales=None, q_lens=None, row_off=None, span=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
         # int8 KV mode (ISSUE 9): parallel per-slot scale pools carried
@@ -301,7 +349,9 @@ class _TracedPagedContext:
         self.tables = tables
         self.prefill = prefill
         self.prefix_lens = prefix_lens  # (b,) traced, prefix-prefill only
-        self.q_lens = q_lens            # (b,) traced, ragged step only
+        self.q_lens = q_lens            # (rows,) traced, ragged step only
+        self.row_off = row_off          # (rows,) a row's start when packed
+        self.span = span                # the rectangle's width (static)
         self.layer_idx = 0
 
     def _scatter(self, layer, ks, vs):
@@ -357,12 +407,18 @@ class _TracedPagedContext:
             return out
         # ragged unified step (ISSUE 17): every row attends its OWN
         # left-aligned span — decode rows, chunk spans and verify
-        # blocks mix in one kernel call with per-row traced lengths
+        # blocks mix in one kernel call with per-row traced lengths.
+        # The packed tokens (b of them, s == 1) go to the kernel's
+        # (rows, span) rectangle for this call alone and come back
+        # packed; the pad queries' finite garbage comes back with them
+        # and is discarded by the program's tail
         if self.q_lens is not None:
-            out = paged_attention_ragged(q._data, kp, vp, self.lens,
+            rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
+            out = paged_attention_ragged(rect, kp, vp, self.lens,
                                          self.q_lens, self.tables,
                                          k_scales=ksc, v_scales=vsc)
-            return wrap_array(out)
+            return wrap_array(
+                _packed_of_rows(out, self.row_off, b)[:, None])
         # decode / verify: s tokens per row scatter flat (s == 1 is the
         # classic decode step; s > 1 is the speculative verify block)
         if s == 1:
@@ -395,6 +451,13 @@ class JittedPagedDecoder:
     calibration.  An int8 cache (``PagedKVCache(kv_dtype="int8")``)
     composes orthogonally: its scale pools are donated through every
     program beside the data pools.
+
+    ``step_tokens`` is a caller's promise about ``ragged_step``: no
+    step carries more tokens.  The ragged programs then run everything
+    but the paged kernel over that many packed positions instead of
+    the whole (rows, span) rectangle (``packed_tokens``); the engine
+    derives it from its planner's options
+    (``ContinuousBatchingEngine._step_token_bound``).
     """
 
     #: per-mode donated arg positions (page pools + scale pools) —
@@ -407,7 +470,8 @@ class JittedPagedDecoder:
 
     def __init__(self, model, min_table_pages: int = 1,
                  quantize: Optional[str] = None, mesh=None,
-                 tp_quant_collectives: bool = False):
+                 tp_quant_collectives: bool = False,
+                 step_tokens: Optional[int] = None):
         from ..quantization.serving import SERVING_QUANT_MODES
         if quantize not in SERVING_QUANT_MODES:
             raise ValueError(
@@ -478,12 +542,26 @@ class JittedPagedDecoder:
         # scenario-matrix serving lane runs mixed short/long traffic
         # compile-free this way
         self.min_table_pages = max(1, int(min_table_pages))
+        # the most tokens (pad rows' one each included) the caller will
+        # ever hand one ``ragged_step``: the width the ragged programs'
+        # dense layers are packed to (``packed_tokens``).  None = no
+        # promise: they compute the whole (rows, span) rectangle
+        self.step_tokens = None if step_tokens is None else int(step_tokens)
         self._programs = {}              # (mode, sample) -> jitted fn
         self._program_fns = {}           # (mode, sample) -> raw traced fn
         self._jitted_multi = None        # built on first multi_step use
         self.last_dispatch = None        # ragged_step's last bucket
 
     # -------------------------------------------------- compiled programs
+    def packed_tokens(self, rows: int, span: int) -> int:
+        """Positions the dense layers of the (rows, span) ragged program
+        compute: the rectangle, or ``step_tokens`` rounded up to the
+        bf16 tile where that is less (never under one a row).  A
+        function of the program's key, so it adds no key."""
+        if self.step_tokens is None:
+            return rows * span
+        return min(rows * span, max(rows, _round_up(self.step_tokens, 16)))
+
     def _param_arrays(self):
         """The param operands a program call ships: the model's arrays,
         with quantized Linears' weights replaced by their int8 twins —
@@ -762,33 +840,70 @@ class JittedPagedDecoder:
                 """Ragged UNIFIED serving step (ISSUE 17): one compiled
                 dispatch processes a batch mixing decode rows
                 (q_len 1), prefill/chunk spans, and speculative verify
-                blocks (q_len = nd + 1).  Each row's span sits
-                LEFT-aligned in the (B, S) bucket; ``ctx_lens`` is the
-                pre-write cached length (doubling as the per-row rope
-                offset), ``q_lens`` the span length, ``nd`` the draft
-                count (0 for non-verify rows, which makes the accept
-                arithmetic degenerate to 'pick the last real token').
+                blocks (q_len = nd + 1).  The operands are the (B, S)
+                rectangle, each row's span LEFT-aligned in it;
+                ``ctx_lens`` is the pre-write cached length (the row's
+                first rope position), ``q_lens`` the span length,
+                ``nd`` the draft count (0 for non-verify rows, which
+                makes the accept arithmetic degenerate to 'pick the
+                last real token').
+
+                The program runs the model over ONE axis of
+                ``packed_tokens(B, S)`` positions, as (T, 1): embedding,
+                projections, feed-forward, norms, the head and the
+                argmax compute T positions, the append takes them as
+                they come, and only the paged kernel's call is handed
+                the rectangle (``_TracedPagedContext.attend``).  Where
+                T is less than B x S the step's tokens are PACKED onto
+                it — row 0's span, row 1's, ..., then pad (id 0, the
+                dropped page, position 0); where it is not, the axis is
+                the rectangle itself, row-major, nothing moved.
                 Accept lengths and the output token's position select
                 ON DEVICE, so the host boundary stays (B,) ids + (B,)
                 accepts whatever the batch mixes."""
                 saved = self._swap_params(param_arrays, wscales)
                 try:
-                    ctx = _TracedPagedContext(k_pages, v_pages, pg, sl,
-                                              ctx_lens + q_lens, tables,
-                                              q_lens=q_lens,
-                                              k_scales=k_scales,
-                                              v_scales=v_scales)
+                    b, s = ids.shape
+                    t = self.packed_tokens(b, s)
+                    # ids, write targets and rope positions of the
+                    # rectangle, taken to the packed axis together
+                    cols = jnp.stack(
+                        [ids, pg.reshape(b, s), sl.reshape(b, s),
+                         ctx_lens[:, None]
+                         + jnp.arange(s, dtype=jnp.int32)[None, :]], axis=-1)
+                    if t < b * s:
+                        # the pack bites: rows stand together from the
+                        # start, and past the step's tokens stands pad
+                        off = (jnp.cumsum(q_lens) - q_lens).astype(jnp.int32)
+                        real = jnp.arange(t, dtype=jnp.int32) \
+                            < jnp.sum(q_lens)
+                        cols = jnp.where(
+                            real[:, None], _packed_of_rows(cols, off, t),
+                            jnp.asarray([0, k_pages[0].shape[1], 0, 0],
+                                        jnp.int32))
+                    else:
+                        # the packed axis holds the whole rectangle:
+                        # every row keeps its place, the host's pads too
+                        off = None
+                        cols = _packed_of_rows(cols, off, t)
+                    ctx = _TracedPagedContext(
+                        k_pages, v_pages, cols[:, 1], cols[:, 2],
+                        ctx_lens + q_lens, tables, q_lens=q_lens,
+                        row_off=off, span=s, k_scales=k_scales,
+                        v_scales=v_scales)
                     with no_grad():
-                        hidden = model.model(wrap_array(ids), ctx_lens,
-                                             paged_ctx=ctx)
+                        hidden = model.model(wrap_array(cols[:, :1]),
+                                             cols[:, 3], paged_ctx=ctx)
                         logits = model._logits_of(hidden)
-                    lg = logits._data.astype(jnp.float32)   # (B, S, V)
-                    targets = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    lg = logits._data[:, 0].astype(jnp.float32)  # (T, V)
+                    # each row's own targets, back in the rectangle (a
+                    # row's tail holds its successors': never read)
+                    targets = _rows_of_packed(
+                        jnp.argmax(lg, axis=-1).astype(jnp.int32), off, s)
                     # verify-row accept arithmetic, gated to the first
                     # nd positions so chunk/decode rows (nd == 0) can
                     # never 'accept' their own prompt tokens
-                    j = jnp.arange(1, ids.shape[1],
-                                   dtype=jnp.int32)[None, :]
+                    j = jnp.arange(1, s, dtype=jnp.int32)[None, :]
                     match = ((ids[:, 1:] == targets[:, :-1])
                              & (j <= nd[:, None])).astype(jnp.int32)
                     accept = jnp.sum(jnp.cumprod(match, axis=1),
@@ -802,8 +917,9 @@ class JittedPagedDecoder:
                         ids_out = jnp.take_along_axis(
                             targets, sel[:, None], axis=1)[:, 0]
                         return ids_out, accept, *pools
-                    lg_sel = jnp.take_along_axis(
-                        lg, sel[:, None, None], axis=1)[:, 0]
+                    first = off if off is not None else \
+                        jnp.arange(b, dtype=jnp.int32) * s
+                    lg_sel = lg[first + sel]                    # (B, V)
                     if sample == "draw":
                         seeds, temps, flags = sampling
                         # absolute position of the emitted token —
@@ -1218,13 +1334,20 @@ class JittedPagedDecoder:
         by ``n_drafts[i]`` draft proposals.  All rows run through the
         single "ragged" program: per-row traced context lengths, span
         lengths and draft counts, so ANY mix compiles once per
-        (B, S, W) bucket.
+        (B, S, W) bucket — rows, span and table width, each rounded up
+        to a power of two.
 
-        Spans right-pad to a power-of-two bucket (pad positions scatter
-        to the dropped out-of-bounds page; the ragged kernel clamps pad
+        The (B, S) bucket is what the host hands over and what the
+        paged kernel sees: spans right-pad to S (the kernel clamps pad
         queries at the row's kv length — finite garbage, discarded) and
         the batch pads with ctx-0 single-token rows exactly like
-        ``batch_context_prefill``.  Page allocation is all-or-nothing
+        ``batch_context_prefill``.  Everything else in the program runs
+        over ``packed_tokens(B, S)`` positions: the rectangle's, or
+        where ``step_tokens`` is less the step's tokens packed to that
+        (pad positions there scatter to the dropped out-of-bounds
+        page), so a step may not carry more than that:
+        with ``step_tokens`` set, a step over it raises ``ValueError``
+        before any page is reserved.  Page allocation is all-or-nothing
         across the batch (per-row counts), and on ANY failure the
         donated pools recover and every length rolls back to ``ctxs``
         so the engine can replay or decompose the step.
@@ -1260,9 +1383,6 @@ class JittedPagedDecoder:
                         f"context {k} + span {n} exceeds "
                         f"max_position_embeddings ({self.max_position})")
                 before.append(int(k))
-            # all-or-nothing page reservation with PER-ROW counts: a
-            # mid-batch exhaustion must not strand earlier rows' pages
-            cache.allocate_batch_atomic(seq_ids, ns)
             # span bucket: clamp by the deepest context (the
             # batch_context_prefill discipline) so the round-up never
             # walks pad positions past the rope table on its own
@@ -1270,6 +1390,18 @@ class JittedPagedDecoder:
                       min(next_pow2(max(ns)),
                           self.max_position - max(int(k) for k in ctxs)))
             b_b = next_pow2(b)
+            # what the program's dense layers are packed to; the pad
+            # rows' one token each is packed with the rest
+            t_b = self.packed_tokens(b_b, s_b)
+            if sum(ns) + b_b - b > t_b:
+                raise ValueError(
+                    f"a step of {sum(ns)} tokens in {b} rows (padded to "
+                    f"{b_b}) exceeds the {t_b} positions this decoder's "
+                    f"ragged programs pack (step_tokens="
+                    f"{self.step_tokens})")
+            # all-or-nothing page reservation with PER-ROW counts: a
+            # mid-batch exhaustion must not strand earlier rows' pages
+            cache.allocate_batch_atomic(seq_ids, ns)
             ids = np.zeros((b_b, s_b), np.int32)
             pg = np.full((b_b, s_b), cache.total_pages, np.int32)  # drop
             sl = np.zeros((b_b, s_b), np.int32)
@@ -1304,9 +1436,11 @@ class JittedPagedDecoder:
                                     np.zeros(pad, bool)]))
         # what this dispatch computes against what it was asked for: the
         # engine writes it into the step ring as the ``dispatch`` record.
-        # ``kv_tokens_walked`` is what the paged kernel walks for these
-        # rows: each row's context in whole blocks, by the kernel's own
-        # rule (pad rows are one token long)
+        # ``rows_padded`` x ``span_padded`` is the paged kernel's query
+        # rectangle, ``tokens_padded`` the positions every other layer
+        # computes.  ``kv_tokens_walked`` is what the paged kernel walks
+        # for these rows: each row's context in whole blocks, by the
+        # kernel's own rule (pad rows are one token long)
         mc = self.model.config
         block = cache.page_size * walk_block_pages(
             cache.page_size, cache.head_dim,
@@ -1314,7 +1448,8 @@ class JittedPagedDecoder:
             cache.k_pages[0].dtype)
         self.last_dispatch = {
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
-            "tokens": sum(ns), "ctx_tokens": sum(before) + sum(ns),
+            "tokens": sum(ns), "tokens_padded": t_b,
+            "ctx_tokens": sum(before) + sum(ns),
             "table_pages": W, "page_size": cache.page_size,
             "kv_tokens_walked": kv_tokens_walked(ctx_arr + ql, block)}
         with monitor.span("engine/dispatch"):

@@ -451,16 +451,23 @@ class TestPreemptResumeTTL:
         plan = faults.FaultPlan([
             # slow chunked prefill for the batch prompt, so it is
             # reliably mid-prefill when interactive traffic arrives
-            {"site": "prefill_chunk", "seq_id": 0, "kind": "delay",
+            {"site": "prefill_chunk", "seq_id": 1, "kind": "delay",
              "delay_s": 0.05},
             # ... and slow interactive decode, so the slot stays busy
             # well past the TTL/aging thresholds
             {"site": "decode_step", "kind": "delay",
-             "delay_s": step_delay, "seq_id": 1}])
+             "delay_s": step_delay, "seq_id": 2}])
+        # the pause is paced by the injected delays alone: the page
+        # table is pinned at its widest and a first request (sequence
+        # 0) builds the chunk and decode programs, so nothing compiles
+        # while the TTL clock runs (three programs used to, which on a
+        # cold compile cache outlasted the TTL by themselves)
         eng = make_engine(model, max_batch=1, prefill_chunk_tokens=4,
-                          preempt_resume_ttl_s=ttl)
+                          preempt_resume_ttl_s=ttl, min_table_pages=16)
         out = {}
         try:
+            eng.submit(rng.integers(0, 64, (4,)),
+                       max_new_tokens=2).result(timeout=120)
             with faults.installed(plan):
                 rb = eng.submit(rng.integers(0, 64, (24,)),
                                 max_new_tokens=4, priority="batch")

@@ -305,3 +305,113 @@ class TestUnifiedStructure:
             got, _, disp = _run(target, prompts, budgets, unified=True)
         _assert_rows_equal(got, ref)
         assert disp["ragged"] == 0 and disp["decode"] > 0
+
+
+def _pow2s(upto):
+    out, v = [], 1
+    while v <= upto:
+        out.append(v)
+        v *= 2
+    return out
+
+
+class TestPackedTokenBound:
+    """ISSUE 32: the engine promises its decoder a bound on a step's
+    tokens and the ragged programs' dense layers are packed to it.  A
+    wrong bound would not raise to the caller — the step would fall
+    back to the legacy composition and, after three, latch the unified
+    step off — so the tests count fallbacks and read every ``dispatch``
+    record."""
+
+    CHUNK, BATCH = 16, 4
+    #: lengths that leave tails of 5, 11, 2, 13, 7 and 9 tokens, so a
+    #: prompt's tail and the next prompt's full chunk share steps
+    SIZES = (21, 43, 50, 29, 39, 25)
+
+    def _serve(self, target, draft=None):
+        from paddle_tpu import monitor
+        from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+        kw = {} if draft is None else dict(draft_model=draft, spec_tokens=2)
+        monitor.start_capture(host_events=False)
+        try:
+            with ContinuousBatchingEngine(
+                    target, total_pages=256, page_size=8,
+                    max_batch=self.BATCH, min_table_pages=16,
+                    prefill_chunk_tokens=self.CHUNK, **kw) as eng:
+                before = monitor.snapshot()
+                bound = eng._decoder.step_tokens
+                reqs = [eng.submit(p, max_new_tokens=6)
+                        for p in _prompts(self.SIZES, seed=32)]
+                outs = [r.result(timeout=300) for r in reqs]
+                latched = eng._unified_off
+                after = monitor.snapshot()
+        finally:
+            monitor.stop_capture()
+        fallbacks = (_counter(after, "engine_unified_fallbacks_total")
+                     - _counter(before, "engine_unified_fallbacks_total"))
+        return outs, bound, fallbacks, latched, \
+            monitor.get_tracer().step_records()
+
+    @pytest.mark.parametrize("spec", [False, True], ids=["plain", "draft"])
+    def test_every_step_is_inside_the_bound(self, target, bad_draft, spec):
+        outs, bound, fallbacks, latched, records = self._serve(
+            target, bad_draft if spec else None)
+        per_row = 3 if spec else 1
+        assert bound == (2 * self.CHUNK - 1) + (self.BATCH - 1) * per_row
+        assert fallbacks == 0 and not latched
+        assert [len(o) for o in outs] == [n + 6 for n in self.SIZES]
+        disp = [r for r in records if r["kind"] == "dispatch"]
+        assert disp
+        for r in disp:
+            assert r["tokens"] <= r["tokens_padded"] \
+                <= r["rows_padded"] * r["span_padded"], r
+            assert r["tokens_padded"] <= -(-bound // 16) * 16, r
+        # the traffic does what the bound is derived from: some step
+        # carries more prefill tokens than one chunk
+        prefill = {}
+        for r in records:
+            if r["kind"] == "prefill_chunk":
+                prefill[r["index"]] = prefill.get(r["index"], 0) + r["tokens"]
+        assert max(prefill.values()) > self.CHUNK
+        # and the pack bites: some step computes fewer positions than
+        # its rectangle holds
+        assert any(r["tokens_padded"] < r["rows_padded"] * r["span_padded"]
+                   for r in disp)
+
+    def test_no_program_is_built_after_a_warm_up_by_bucket(self, target):
+        """The benchmark's warm-up sends one step per (rows bucket, span
+        bucket): ``b - 1`` decoders and one ``s``-token prompt.  The
+        packed width is a function of that key, so a mixed run after it
+        — tails beside chunks, any number of rows — compiles nothing."""
+        from paddle_tpu import monitor
+        from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+        reg = monitor.get_registry()
+        rng = np.random.default_rng(5)
+        with ContinuousBatchingEngine(
+                target, total_pages=256, page_size=8, max_batch=self.BATCH,
+                min_table_pages=16,
+                prefill_chunk_tokens=self.CHUNK) as eng:
+            back = []
+            for b in _pow2s(self.BATCH):
+                back += [eng.submit(rng.integers(0, 64, (1,)),
+                                    max_new_tokens=100)
+                         for _ in range(b - 1 - len(back))]
+                for r in back:
+                    while r.next_token is None and not r.done.is_set():
+                        r.done.wait(0.002)
+                for s in _pow2s(self.CHUNK):
+                    eng.submit(rng.integers(0, 64, (s,)),
+                               max_new_tokens=1).result(timeout=300)
+            prog = eng._decoder._programs[("ragged", "greedy")]
+            built = prog._cache_size()
+            assert built == len(_pow2s(self.BATCH)) * len(_pow2s(self.CHUNK))
+            compiles = reg.get("jit_recompile_count").value()
+            for r in back:
+                r.cancel()
+            reqs = [eng.submit(p, max_new_tokens=6)
+                    for p in _prompts(self.SIZES, seed=33)]
+            for r in reqs:
+                r.result(timeout=300)
+            assert not eng._unified_off
+            assert prog._cache_size() == built
+            assert reg.get("jit_recompile_count").value() == compiles
