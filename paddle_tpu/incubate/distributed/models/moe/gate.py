@@ -277,6 +277,14 @@ def _sigmoid_topk(logits, bias, top_k, renormalize, scaling):
     return idx.astype(jnp.int32), w * scaling
 
 
+@def_op("moe_router_logits_f32")
+def _logits_f32(x, w):
+    """x @ w accumulated AND kept in float32 whatever the operands'
+    dtype: a bfloat16 logit's rounding (2^-9 of its size) is wider than
+    the gap between the k-th and the (k+1)-th of 256 scores."""
+    return jnp.dot(x, w, preferred_element_type=jnp.float32)
+
+
 class SigmoidTopKGate(BaseGate):
     """The DeepSeek-V3 / Kimi router: one sigmoid score an expert, top-k
     over score + ``e_score_correction_bias`` (float32, ``trainable=False``:
@@ -284,13 +292,16 @@ class SigmoidTopKGate(BaseGate):
     gradient), weights renormalised over the chosen and scaled.  No
     capacity and no balance loss: every assignment is kept, so it is
     routed by ``MoELayer``'s held-experts path (``route_no_drop``) and
-    has no dense combine/dispatch form."""
+    has no dense combine/dispatch form.  ``float32_logits``: the logits
+    leave the product in float32 (the scores always are float32)."""
 
     def __init__(self, d_model, num_expert, world_size=1, topk=8,
-                 renormalize=True, routed_scaling_factor=1.0):
+                 renormalize=True, routed_scaling_factor=1.0,
+                 float32_logits=False):
         super().__init__(num_expert, world_size)
         self.d_model = d_model
         self.top_k = topk
+        self.float32_logits = bool(float32_logits)
         self.renormalize = renormalize
         self.routed_scaling_factor = float(routed_scaling_factor)
         self.gate_weight = self.create_parameter(
@@ -305,7 +316,8 @@ class SigmoidTopKGate(BaseGate):
 
     def route_no_drop(self, x):
         """x [T, d_model] -> (expert ids [T, k], weights [T, k])."""
-        logits = x.matmul(self.gate_weight)
+        logits = (_logits_f32(x, self.gate_weight) if self.float32_logits
+                  else x.matmul(self.gate_weight))
         return _sigmoid_topk(logits, self.e_score_correction_bias,
                              self.top_k, self.renormalize,
                              self.routed_scaling_factor)

@@ -26,13 +26,19 @@ expert-parallel rank's share.  The gate still scores the WHOLE expert set
 (``gate.tot_expert``), the layer holds experts ``first .. first + count - 1``
 (``experts`` is a ``SwiGLUExperts`` of ``count``), keeps the assignments that
 fall on them, computes every one of those and returns the partial sum, plus
-the shared expert if it has one.  Nothing can be dropped: every token goes
-through every held expert, weighted 0 where it did not choose it (a share
-holds about as many experts as a token chooses, so the rows any routing may
-ask for, tokens x min(top_k, count), ARE that dense product; a share much
-wider than top_k would want rows sorted by expert instead).  What the other
-ranks' experts would add is NOT here: summing it across ranks is the
-exchange a multi-chip deployment adds around this layer.
+the shared expert if it has one.  Nothing can be dropped, by either of the
+two products the layer chooses between from what it can observe (the count
+it holds against ``top_k``; ``DENSE_SHARE``).  A share of about as many
+experts as a token chooses sends every token through every held expert,
+weighted 0 where it did not choose it: the rows any routing may ask for,
+tokens x min(top_k, count), are that dense product or a fraction of it, and
+its time does not follow the routing.  A wider share (all 256 of a layer
+served whole on one chip) lays the (token, chosen expert) pairs out grouped
+by expert in blocks of 16 rows (``ops/pallas/moe_grouped_ffn.py``): tokens x
+top_k rows plus the blocks' padding, and the weights of the experts that
+were chosen.  Either product leaves the same counts (``ROUTING_FIELDS``).
+What the other ranks' experts would add is NOT here: summing it across
+ranks is the exchange a multi-chip deployment adds around this layer.
 """
 from __future__ import annotations
 
@@ -114,29 +120,77 @@ def _expert_ffn(x, w1, b1, w2, b2, activation):
     return y
 
 
+#: what a share's forward leaves in ``MoELayer.last_routing``, in order, as
+#: float32, from either product: the (token, chosen expert) pairs of the
+#: real tokens; those that fell on a held expert; the rows computed for
+#: them; the most pairs one held expert got; the held experts some token
+#: chose (whose weights the grouped product reads)
+ROUTING_FIELDS = ("slots", "held", "rows", "most", "touched")
+
+#: a share of at most ``DENSE_SHARE x top_k`` experts takes the dense
+#: product, a wider one the grouped.  The two have been measured where the
+#: benchmark's cells stand and nowhere between: 8 held of 256 at top_k 8
+#: (``kimi-linear.train.seq8k``: dense) and 256 of 256 at top_k 8
+#: (``laguna-xs2.serve.agent8``: grouped, 1.41 against 3.16 ms a layer at 8
+#: tokens; PERF.md section 6, PR 33).  2 puts the line just above the first;
+#: where between 16 and 256 experts the products cross is not measured
+DENSE_SHARE = 2
+
+
+def _routing_counts(slots, per_expert, rows):
+    """``ROUTING_FIELDS`` from the pairs a held expert got [count]."""
+    return jnp.stack([jnp.asarray(slots), jnp.sum(per_expert),
+                      jnp.asarray(rows), jnp.max(per_expert),
+                      jnp.sum(per_expert > 0)]).astype(jnp.float32)
+
+
 @def_op("moe_held_experts")
-def _held_experts(x, idx, weight, first, w_gate, w_up, w_down):
+def _held_experts(x, idx, weight, first, w_gate, w_up, w_down,
+                  token_mask=None):
     """Every token through every HELD expert's SwiGLU, each product
     weighted by the token's routing weight for that expert: 0 where the
     token did not choose it.  x [T, M]; idx, weight [T, k] (ids over the
-    whole expert set); stacked weights [count, M, H] / [count, H, M].
-    Returns (y [T, M], counts [slots, held, rows, most held by one
-    expert] as float32).  A token picks an expert at most once, so the
+    whole expert set); stacked weights [count, M, H] / [count, H, M];
+    ``token_mask`` [T] bool or None: a False token has no pair (its row is
+    computed with weight 0).  Returns (y [T, M], counts as
+    ``ROUTING_FIELDS``).  A token picks an expert at most once, so the
     rows a share may have to compute under ANY routing are T x
     min(k, count); with count <= k that is this dense product, whose
     time does not depend on the routing."""
     count = w_gate.shape[0]
     chose = idx[:, :, None] == first + jnp.arange(count)        # [T, k, E]
+    slots = idx.size
+    if token_mask is not None:
+        chose &= token_mask[:, None, None]
+        slots = jnp.sum(token_mask) * idx.shape[1]
     w = jnp.sum(jnp.where(chose, weight[:, :, None], 0.0), axis=1)
     h = (jax.nn.silu(jnp.einsum("tm,emh->teh", x, w_gate))
          * jnp.einsum("tm,emh->teh", x, w_up))
     y = jnp.einsum("teh,ehm->tm", (h * w[:, :, None]).astype(x.dtype),
                    w_down)
-    per_expert = jnp.sum(chose, axis=(0, 1))
-    stats = jnp.stack([jnp.asarray(idx.size), jnp.sum(per_expert),
-                       jnp.asarray(x.shape[0] * count),
-                       jnp.max(per_expert)]).astype(jnp.float32)
-    return y, stats
+    return y, _routing_counts(slots, jnp.sum(chose, axis=(0, 1)),
+                              x.shape[0] * count)
+
+
+@def_op("moe_grouped_experts")
+def _grouped_experts(x, idx, weight, token_mask, first, w_gate, w_up,
+                     w_down):
+    """The (token, chosen expert) pairs that fall on the HELD experts,
+    grouped by expert, each through its expert's SwiGLU and summed back
+    a token with its routing weight (``ops/pallas/moe_grouped_ffn.py``;
+    serving: the Pallas call has no gradient).  ``token_mask`` [T] bool
+    or None: a False token (a pad position of a packed step) has no pair
+    and touches no expert.  Returns (y [T, M], counts as
+    ``ROUTING_FIELDS``)."""
+    from .....ops.pallas.moe_grouped_ffn import grouped_swiglu
+    count = w_gate.shape[0]
+    local = idx - first
+    real = (jnp.ones(idx.shape[:1], bool) if token_mask is None
+            else token_mask)
+    held = (local >= 0) & (local < count) & real[:, None]
+    y, per_expert, rows = grouped_swiglu(x, local, weight, held, w_gate,
+                                         w_up, w_down)
+    return y, _routing_counts(jnp.sum(real) * idx.shape[1], per_expert, rows)
 
 
 class SwiGLUExperts(Layer):
@@ -189,8 +243,10 @@ class MoELayer(Layer):
     docstring): ``experts`` is a ``SwiGLUExperts`` of ``count``, ``gate`` a
     gate with ``route_no_drop`` over the whole set (``SigmoidTopKGate``).
     ``shared_expert``: a Layer every token also goes through, added once.
-    After a forward of a share, ``last_routing`` holds the counts
-    [slots, held, (token, expert) rows computed, most held by one expert].
+    After a forward of a share, ``last_routing`` holds its counts
+    (``ROUTING_FIELDS``; ``routing_counts()`` gives them by name).
+    ``forward(x, token_mask=)``: positions that are no tokens (the pad of a
+    packed serving step) are routed nowhere.
     """
 
     def __init__(self, d_model: int,
@@ -279,23 +335,34 @@ class MoELayer(Layer):
         from .....tensor.manipulation import concat
         return concat(outs, axis=0)                      # [E, C, M]
 
-    def _forward_share(self, tokens):
-        first, _ = self.held_experts
+    def _forward_share(self, tokens, token_mask=None):
+        first, count = self.held_experts
+        ex = self.experts
         with jax.named_scope("moe/router"):
             idx, w = self.gate.route_no_drop(tokens)
         with jax.named_scope("moe/experts"):
-            y, self.last_routing = _held_experts(
-                tokens, idx, w, first, self.experts.gate_proj,
-                self.experts.up_proj, self.experts.down_proj)
+            if count <= DENSE_SHARE * self.gate.top_k:
+                y, self.last_routing = _held_experts(
+                    tokens, idx, w, first, ex.gate_proj, ex.up_proj,
+                    ex.down_proj, token_mask)
+            else:
+                y, self.last_routing = _grouped_experts(
+                    tokens, idx, w, token_mask, first, ex.gate_proj,
+                    ex.up_proj, ex.down_proj)
             if self.shared_expert is not None:
                 y = y + self.shared_expert(tokens)
         return y
 
-    def forward(self, x: Tensor) -> Tensor:
+    def routing_counts(self) -> dict:
+        """The last forward's counts by name (``ROUTING_FIELDS``)."""
+        return dict(zip(ROUTING_FIELDS, self.last_routing._data))
+
+    def forward(self, x: Tensor, token_mask=None) -> Tensor:
         orig_shape = x.shape
         tokens = x.reshape([-1, self.d_model])
         if self.held_experts is not None:
-            return self._forward_share(tokens).reshape(orig_shape)
+            return self._forward_share(tokens, token_mask).reshape(
+                orig_shape)
         use_recompute = self.recompute_interval > 0 and self.training
         if (isinstance(self.gate, NaiveGate)
                 and type(self.gate).forward is NaiveGate.forward):

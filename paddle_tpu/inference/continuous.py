@@ -313,6 +313,29 @@ _kv_quant_pool_bytes_g = monitor.gauge(
 _kv_quant_scale_bytes_g = monitor.gauge(
     "kv_quant_scale_bytes", "resident bytes of the int8 mode's "
     "per-slot scale pools (0 at full precision)")
+# expert layers and sliding-attention layers in the unified step (ISSUE
+# 33): the sums of the ``dispatch`` records' fields of the same names,
+# for a model that has such layers (none is touched for one that has not)
+_STEP_SUMS = {
+    name: monitor.counter(f"serve_{name}_total", text) for name, text in (
+        ("moe_slots", "(real token, chosen expert) pairs of the unified "
+         "steps: tokens x experts a token x expert layers"),
+        ("moe_rows_computed", "rows the grouped expert product computed "
+         "for them: the pairs in whole blocks, an expert at a time"),
+        ("moe_experts_touched", "distinct experts a real token chose, "
+         "summed over the expert layers: whose weights a step reads"),
+        ("moe_expert_layers", "experts a step could have touched: experts "
+         "x expert layers"),
+        ("ctx_tokens_window", "KV positions a sliding-attention layer's "
+         "queries attend"),
+        ("kv_tokens_walked_window", "KV positions a sliding-attention "
+         "layer's paged kernel walks, from the first visible page"),
+        ("kv_tokens_walked_nowindow", "what that walk would be from page "
+         "0"))}
+_kv_window_dead_pages_g = monitor.gauge(
+    "kv_window_dead_pages", "pages the step's rows hold wholly behind "
+    "their next query's sliding window: kept for the full-attention "
+    "layers that share the table, dead to the sliding layers")
 # batched survivor replay (ISSUE 9 satellite): dispatch economics —
 # fewer compiled dispatches per recovery event is the MTTR lever
 _replay_dispatches = monitor.counter(
@@ -2475,6 +2498,11 @@ class ContinuousBatchingEngine:
                 # table) bucket against the real tokens and contexts
                 _tracer.step_record("dispatch", self.steps, t_disp,
                                     now_ns, **self._decoder.last_dispatch)
+            for name, value in self._decoder.last_dispatch.items():
+                if name in _STEP_SUMS:
+                    _STEP_SUMS[name].inc(value)
+                elif name == "kv_window_dead_pages":
+                    _kv_window_dead_pages_g.set(value)
             # ---- chunk rows: the legacy _prefill_chunk bookkeeping
             completed: List[_Request] = []
             for i, (req, _target, k, n, last) in enumerate(chunks):

@@ -30,7 +30,7 @@ from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           _round_up, append_rows,
-                                          dequantize_kv,
+                                          dequantize_kv, kv_tokens_visible,
                                           kv_tokens_walked, paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
@@ -103,6 +103,21 @@ def _tp_plan(model, mesh):
     col, row = P(None, "tensor"), P("tensor", None)
     for i, layer in enumerate(layers):
         attn, mlp = layer.self_attn, layer.mlp
+        # the plan knows one block: q/k/v/o and a dense gate/up/down.
+        # What a layer has beyond it would be left replicated or summed
+        # wrongly, so the plan names it and refuses
+        lacks = [n for n in ("gate_proj", "up_proj", "down_proj")
+                 if not hasattr(mlp, n)]
+        extra = [n for n, sub in attn.named_children()
+                 if n not in ("q_proj", "k_proj", "v_proj", "o_proj")]
+        if lacks or extra:
+            raise ValueError(
+                f"tensor-parallel serving plans a LLaMA-shaped block; "
+                f"layer {i} of {type(model).__name__} has "
+                + (f"no mlp.{'/'.join(lacks)} (an expert block: the plan "
+                   f"has no placement for experts) " if lacks else "")
+                + (f"attention projections it does not place: "
+                   f"{', '.join(extra)}" if extra else ""))
         if attn.num_heads % tp or attn.num_kv_heads % tp:
             raise ValueError(
                 f"layer {i}: num_heads ({attn.num_heads}) and "
@@ -253,6 +268,14 @@ def _packed_of_rows(x, off, tokens):
     return flat[row * span + col]
 
 
+#: what a path with no window says to a sliding-attention layer
+_NO_WINDOW = ("a sliding-attention layer (window=...) reached {}, which "
+              "has no window and would attend the full context: serve "
+              "this model through the ragged unified step "
+              "(ContinuousBatchingEngine(prefill_chunk_tokens=...)), "
+              "whose paged kernels apply it")
+
+
 class _PagedContext:
     """Per-forward attention driver handed down to attention layers.
 
@@ -269,11 +292,18 @@ class _PagedContext:
         self.prefill = prefill
         self.layer_idx = 0
 
-    def attend(self, q: Tensor, k: Tensor, v: Tensor) -> Tensor:
+    def attend(self, q: Tensor, k: Tensor, v: Tensor,
+               window: Optional[int] = None) -> Tensor:
         """q/k/v: (batch, s, heads, head_dim) post-rope.  Writes k/v into
-        the pages, returns the attention output (batch, s, q_heads, d)."""
+        the pages, returns the attention output (batch, s, q_heads, d).
+        ``window``: the calling layer is a sliding-attention layer of
+        that width (decode applies it; the dense prefill has none and
+        refuses)."""
         cache = self.cache
         layer = self.layer_idx
+        if window is not None and self.prefill:
+            raise NotImplementedError(_NO_WINDOW.format(
+                "the eager prefill's dense flash attention"))
         # whole batch in ONE scatter per pool (not per sequence — the
         # per-seq loop copied the full pool batch times per step)
         cache.write_batch(layer, self.seq_ids, k._data, v._data)
@@ -298,7 +328,8 @@ class _PagedContext:
             q._data[:, 0], cache.k_pages[layer], cache.v_pages[layer],
             lens, tab,
             k_scales=(cache.k_scales[layer] if cache.kv_quant else None),
-            v_scales=(cache.v_scales[layer] if cache.kv_quant else None))
+            v_scales=(cache.v_scales[layer] if cache.kv_quant else None),
+            window=window)
         return wrap_array(out[:, None])      # (batch, 1, q_heads, d)
 
 
@@ -331,7 +362,14 @@ class _TracedPagedContext:
     whole rectangle, row-major).  The append takes the packed rows as
     they come; only the paged kernel's call goes to the (rows, ``span``)
     rectangle, each row's span LEFT-aligned in it, and its output comes
-    back packed.  ``lens``, ``q_lens`` and ``tables`` are per ROW."""
+    back packed.  ``lens``, ``q_lens`` and ``tables`` are per ROW.
+
+    What a model may ask of it beyond ``attend``: ``token_mask`` — which
+    positions of the batch axis are tokens (a pad position's write page
+    is out of range); ``count(**named)`` — counters of the model's own,
+    summed by name over whoever calls and handed out of the compiled step
+    beside its tokens: they reach the ``dispatch`` record under their
+    names (``counted``)."""
 
     def __init__(self, k_pages, v_pages, pg, sl, lens=None, tables=None,
                  prefill=False, prefix_lens=None, k_scales=None,
@@ -353,6 +391,27 @@ class _TracedPagedContext:
         self.row_off = row_off          # (rows,) a row's start when packed
         self.span = span                # the rectangle's width (static)
         self.layer_idx = 0
+        self._counts = {}               # name -> the layers' sum
+
+    @property
+    def token_mask(self):
+        """(positions,) bool: False where the position is pad — its
+        (page, slot) write target is the dropped out-of-range page."""
+        return self.pg < self.k_pages[0].shape[1]
+
+    def count(self, **named):
+        for name, value in named.items():
+            self._counts[name] = self._counts.get(name, 0.0) + value
+
+    def counted(self):
+        """(names, outputs): ``()`` twice for a model that counts nothing
+        (no output is added to its programs), else the names and ONE
+        float32 vector of their sums, in the names' order."""
+        names = tuple(sorted(self._counts))
+        if not names:
+            return (), ()
+        return names, (jnp.stack([jnp.asarray(self._counts[n], jnp.float32)
+                                  for n in names]),)
 
     def _scatter(self, layer, ks, vs):
         """One layer's append: ``ks``/``vs`` (kvh, tokens, d) float.
@@ -382,10 +441,19 @@ class _TracedPagedContext:
             return None, None
         return self.k_scales[layer], self.v_scales[layer]
 
-    def attend(self, q, k, v):
+    def attend(self, q, k, v, window=None):
+        """``window``: the calling layer is a sliding-attention layer of
+        that width.  The paged kernels apply it; the prefill modes'
+        dense attention has none and refuses at trace time."""
         layer = self.layer_idx
         b, s = k.shape[0], k.shape[1]
         kvh, d = k.shape[2], k.shape[3]
+        if window is not None and self.prefill:
+            raise NotImplementedError(_NO_WINDOW.format(
+                "the prefix-suffix attention of chunk_prefill / "
+                "prefix_prefill / batch_context_prefill"
+                if self.prefix_lens is not None else
+                "the prefill program's dense flash attention"))
         ks = jnp.swapaxes(k._data.reshape(b * s, kvh, d), 0, 1)
         vs = jnp.swapaxes(v._data.reshape(b * s, kvh, d), 0, 1)
         ks_att, vs_att = self._scatter(layer, ks, vs)
@@ -416,7 +484,8 @@ class _TracedPagedContext:
             rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
             out = paged_attention_ragged(rect, kp, vp, self.lens,
                                          self.q_lens, self.tables,
-                                         k_scales=ksc, v_scales=vsc)
+                                         k_scales=ksc, v_scales=vsc,
+                                         window=window)
             return wrap_array(
                 _packed_of_rows(out, self.row_off, b)[:, None])
         # decode / verify: s tokens per row scatter flat (s == 1 is the
@@ -424,11 +493,11 @@ class _TracedPagedContext:
         if s == 1:
             out = paged_attention(q._data[:, 0], kp, vp, self.lens,
                                   self.tables, k_scales=ksc,
-                                  v_scales=vsc)
+                                  v_scales=vsc, window=window)
             return wrap_array(out[:, None])
         out = paged_attention_multi(q._data, kp, vp, self.lens,
                                     self.tables, k_scales=ksc,
-                                    v_scales=vsc)
+                                    v_scales=vsc, window=window)
         return wrap_array(out)
 
 
@@ -480,6 +549,21 @@ class JittedPagedDecoder:
         self.model = model
         self.params = model.parameters()
         self.max_position = int(model.config.max_position_embeddings)
+        # what each layer's paged call looks like, for the dispatch
+        # record's count of the kernels' walk: (query heads a KV head,
+        # window or None) a layer.  A model whose layers differ says so
+        # (``attention_kinds``); the others have one kind
+        mc = model.config
+        kinds = (model.attention_kinds()
+                 if hasattr(model, "attention_kinds") else
+                 [(mc.num_attention_heads, None)] * mc.num_hidden_layers)
+        self._attn_kinds = {}
+        for heads, window in kinds:
+            kind = (heads // mc.num_key_value_heads, window)
+            self._attn_kinds[kind] = self._attn_kinds.get(kind, 0) + 1
+        # the names of what the model counts in a ragged step
+        # (``_TracedPagedContext.count``), noted when a program is traced
+        self._step_counts = ()
         self.quantize = quantize
         # tensor-parallel serving (ISSUE 20): every compiled program is
         # shard_map'd over the ('tensor',) mesh — weights land as their
@@ -685,7 +769,7 @@ class JittedPagedDecoder:
         in_specs = (list(self._tp_param_specs),
                     *([rep] * self._TP_N_REPLICATED[mode]),
                     pool, pool, pool, pool, rep)
-        n_out = 2 if mode in ("verify", "ragged") else 1
+        n_out = {"verify": 2, "ragged": 3}.get(mode, 1)
         out_specs = (*([rep] * n_out), pool, pool, pool, pool)
         return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
@@ -912,11 +996,15 @@ class JittedPagedDecoder:
                     # decode/chunk rows (q_lens - 1), the bonus
                     # position (accept) for verify rows
                     sel = (q_lens - 1 - nd + accept).astype(jnp.int32)
-                    pools = ctx_pools(ctx)
+                    # what the model counted rides out beside the
+                    # accepts: an empty tuple (no output at all) for a
+                    # model that counts nothing
+                    self._step_counts, counted = ctx.counted()
+                    rest = (counted, *ctx_pools(ctx))
                     if sample == "greedy":
                         ids_out = jnp.take_along_axis(
                             targets, sel[:, None], axis=1)[:, 0]
-                        return ids_out, accept, *pools
+                        return ids_out, accept, *rest
                     first = off if off is not None else \
                         jnp.arange(b, dtype=jnp.int32) * s
                     lg_sel = lg[first + sel]                    # (B, V)
@@ -931,8 +1019,8 @@ class JittedPagedDecoder:
                                 + accept).astype(jnp.int32)
                         ids_out = fused_sample(lg_sel, seeds, ctrs,
                                                temps, flags)
-                        return ids_out, accept, *pools
-                    return lg_sel, accept, *pools  # logits escape hatch
+                        return ids_out, accept, *rest
+                    return lg_sel, accept, *rest  # logits escape hatch
                 finally:
                     self._restore_params(saved)
 
@@ -1441,22 +1529,17 @@ class JittedPagedDecoder:
         # computes.  ``kv_tokens_walked`` is what the paged kernel walks
         # for these rows: each row's context in whole blocks, by the
         # kernel's own rule (pad rows are one token long)
-        mc = self.model.config
-        block = cache.page_size * walk_block_pages(
-            cache.page_size, cache.head_dim,
-            s_b * (mc.num_attention_heads // mc.num_key_value_heads),
-            cache.k_pages[0].dtype)
         self.last_dispatch = {
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
             "tokens": sum(ns), "tokens_padded": t_b,
-            "ctx_tokens": sum(before) + sum(ns),
             "table_pages": W, "page_size": cache.page_size,
-            "kv_tokens_walked": kv_tokens_walked(ctx_arr + ql, block)}
+            **self._walk_counts(cache, ctx_arr + ql, ql, s_b, b)}
         with monitor.span("engine/dispatch"):
             sample, s_args = self._verify_sampling_args(sampling)
             try:
                 _maybe_lose_buffers(cache, seq_ids)
-                out, accept, *pools = self._program("ragged", sample)(
+                out, accept, counted, *pools = self._program(
+                    "ragged", sample)(
                     self._param_arrays(), jnp.asarray(ids),
                     jnp.asarray(ctx_arr), jnp.asarray(ql),
                     jnp.asarray(pg.reshape(-1)),
@@ -1469,7 +1552,46 @@ class JittedPagedDecoder:
                 raise
         with monitor.span("engine/fetch"):
             self._store_pools(cache, *pools)
+            if counted:
+                self.last_dispatch.update(zip(
+                    self._step_counts,
+                    (int(v) for v in np.asarray(counted[0]))))
             return np.asarray(out)[:b], np.asarray(accept)[:b]
+
+    def _walk_counts(self, cache, lens, q_lens, span, rows):
+        """The dispatch record's count of the paged kernels' work for a
+        step whose padded rows hold ``lens`` positions after the write
+        and ``q_lens`` queries, a LAYER's worth each (the mean over the
+        layers where they differ): ``ctx_tokens`` the positions some
+        query attends, ``kv_tokens_walked`` what the kernel walks for
+        them in whole blocks by its own rule.  A model with sliding
+        layers adds one sliding layer's own two counts
+        (``..._window``), what that layer would have walked with no
+        window (``kv_tokens_walked_nowindow``) and the pages its real
+        rows hold wholly behind their next query's window
+        (``kv_window_dead_pages``; a page two rows share counts
+        twice)."""
+        ps, total = cache.page_size, sum(self._attn_kinds.values())
+        out = {"ctx_tokens": 0, "kv_tokens_walked": 0}
+        for (group, window), n in self._attn_kinds.items():
+            block = ps * walk_block_pages(ps, cache.head_dim, span * group,
+                                          cache.k_pages[0].dtype)
+            # the real rows' context; a pad row's one position is walked
+            seen = kv_tokens_visible(lens[:rows], q_lens[:rows], window)
+            walked = kv_tokens_walked(lens, block, window, q_lens, ps)
+            out["ctx_tokens"] += n * seen
+            out["kv_tokens_walked"] += n * walked
+            if window is not None:
+                dead = np.maximum(lens[:rows] + 1 - window, 0) // ps
+                out.update(ctx_tokens_window=seen,
+                           kv_tokens_walked_window=walked,
+                           kv_tokens_walked_nowindow=kv_tokens_walked(
+                               lens, block),
+                           kv_window_dead_pages=int(dead.sum()))
+        for name in ("ctx_tokens", "kv_tokens_walked"):     # a layer's
+            out[name] = (out[name] // total if len(self._attn_kinds) == 1
+                         else out[name] / total)
+        return out
 
     def _build_multi(self):
         """Jitted N-step GREEDY decode: lax.scan over the single-step

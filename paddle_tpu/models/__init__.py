@@ -1,5 +1,6 @@
 """Model zoo: LLaMA (flagship), LLaMA-MoE, Kimi-Linear (KDA + MLA + sigmoid
-MoE), BERT; vision models in paddle_tpu.vision."""
+MoE), Laguna (full + sliding attention, sigmoid MoE), BERT; vision models in
+paddle_tpu.vision."""
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_7b, llama_small,
     shard_llama,
@@ -11,6 +12,9 @@ from .llama_moe import (  # noqa: F401
 from .kimi_linear import (  # noqa: F401
     KimiLinearConfig, KimiLinearForCausalLM, KimiLinearModel,
     KimiDeltaAttention, KimiMLAttention,
+)
+from .laguna import (  # noqa: F401
+    LagunaConfig, LagunaForCausalLM, LagunaModel,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForMaskedLM,

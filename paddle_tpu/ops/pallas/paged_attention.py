@@ -120,19 +120,47 @@ def walk_block_pages(page_size, head_dim, rows, kv_dtype):
     return pages
 
 
-def kv_tokens_walked(lengths, block_tokens):
+def window_first_token(lengths, q_lens, window, page_size):
+    """First position of the page that holds a row's first VISIBLE key
+    under a sliding ``window``: the row's first query stands at position
+    ``length - q_len`` and sees the ``window`` keys that end with itself,
+    so nothing before ``length - q_len + 1 - window`` is visible to any
+    query of the row.  The one rule of the kernels' walk, of their XLA
+    oracles' mask and of the host's count (numpy or traced integers)."""
+    start = lengths - q_lens + 1 - window
+    xp = jnp if isinstance(start, jax.Array) else np
+    return xp.maximum(start, 0) // page_size * page_size
+
+
+def kv_tokens_walked(lengths, block_tokens, window=None, q_lens=1,
+                     page_size=1):
     """KV positions the kernel walks for rows of these ``lengths``: every
     row costs its context rounded up to whole blocks,
     ``ceil(length / block) * block`` — the rule ``_decode_kernel``'s loop
     bound applies per (row, kv head), here on the host for the dispatch
-    record (``kernel.paged_attn.walk_useful``)."""
+    record (``kernel.paged_attn.walk_useful``).  Under a ``window`` the
+    walk starts at the page of the row's first visible key
+    (``window_first_token``) and costs the rest in whole blocks."""
     lengths = np.asarray(lengths, np.int64)
+    if window is not None:
+        lengths = lengths - window_first_token(
+            lengths, np.asarray(q_lens, np.int64), int(window), page_size)
     return int((-(-lengths // block_tokens) * block_tokens).sum())
+
+
+def kv_tokens_visible(lengths, q_lens, window=None):
+    """KV positions some query of each row attends: the row's context, or
+    under a ``window`` its last ``window + q_len - 1`` positions."""
+    lengths = np.asarray(lengths, np.int64)
+    if window is not None:
+        lengths = np.minimum(
+            lengths, int(window) + np.asarray(q_lens, np.int64) - 1)
+    return int(lengths.sum())
 
 
 def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale, page_size, block_pages, n_query=1, group=1,
-                   quantized=False, ragged=False):
+                   quantized=False, ragged=False, window=None):
     """Online-softmax paged attention for ``n_query`` query tokens per
     sequence, one grid step per (row, kv head).  The step WALKS THE ROW'S
     OWN CONTEXT: ``ceil(length / block)`` blocks of ``block_pages`` pages,
@@ -163,7 +191,14 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     ``quantized`` (ISSUE 9): the K/V pages arrive as INT8 with their
     per-slot f32 scale pages copied alongside — dequantization happens
     here in VMEM right before the MXU dots, so full-precision KV never
-    round-trips HBM (the whole point of the int8 storage mode)."""
+    round-trips HBM (the whole point of the int8 storage mode).
+
+    ``window`` (a sliding-attention layer): a query at position ``p``
+    sees keys ``p - window + 1 .. p``.  The walk STARTS at the page that
+    holds the row's first visible key (``window_first_token``) — no copy
+    is issued for a page before it — and the scores are masked on both
+    sides; ``None`` is the causal walk from page 0, the same program as
+    before the window existed."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
          m_scr, l_scr, acc_scr) = rest
@@ -178,6 +213,14 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     length = lens_ref[0, b] if ragged else lens_ref[b]
     # the pages that hold the row's context (never past the table)
     n_pages = jnp.minimum(pl.cdiv(length, page_size), tabs_ref.shape[1])
+    if window is None:
+        page0 = tok0 = 0
+    else:
+        # the walk's pages and columns count from the first visible page
+        qn = lens_ref[1, b] if ragged else n_query
+        tok0 = window_first_token(length, qn, window, page_size)
+        page0 = tok0 // page_size
+        n_pages = n_pages - page0
     n_blocks = pl.cdiv(n_pages, block_pages)
 
     def each_page_copy(blk, slot, act):
@@ -186,7 +229,7 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         first = blk * block_pages
 
         def body(i, carry):
-            page = tabs_ref[b, first + i]
+            page = tabs_ref[b, page0 + first + i]
             act(pltpu.make_async_copy(k_hbm.at[h, page], k_buf.at[slot, i],
                                       sems.at[slot]))
             act(pltpu.make_async_copy(v_hbm.at[h, page], v_buf.at[slot, i],
@@ -241,7 +284,8 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         k = load(k_buf, ks_buf, slot, q.dtype)
         s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
-        cols = blk * block + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        cols = tok0 + blk * block \
+            + lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # row r serves query position r // group of the block; its
         # causal window ends (n_query - 1 - qpos) tokens short of the
         # full length (the later block tokens it must not see)
@@ -255,7 +299,10 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
             limit = jnp.minimum(length, length - qlen + 1 + qpos)
         else:
             limit = length - (n_query - 1 - qpos)
-        s = jnp.where(cols < limit, s, DEFAULT_MASK_VALUE)
+        seen = cols < limit
+        if window is not None:
+            seen &= cols >= limit - window
+        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
 
         m_prev = m_scr[:, :1]
         m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -269,7 +316,8 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         # were never fetched and hold whatever the buffer held — their
         # weights are exact zeros, and so must they be (0 * NaN)
         v = load(v_buf, vs_buf, slot, q.dtype)
-        toks = blk * block + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        toks = tok0 + blk * block \
+            + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
         v = jnp.where(toks < length, v, jnp.zeros_like(v))
         acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
             pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
@@ -284,11 +332,11 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "n_query"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "n_query", "window"))
 def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                    interpret=False, n_query=1, k_scales=None,
-                   v_scales=None, q_lens=None):
+                   v_scales=None, q_lens=None, window=None):
     """``q`` is (batch, q_heads, d) for n_query == 1, else
     (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
     (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
@@ -351,7 +399,7 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                                page_size=page_size,
                                block_pages=block_pages, n_query=n_query,
                                group=group, quantized=quantized,
-                               ragged=ragged)
+                               ragged=ragged, window=window)
     q_spec = pl.BlockSpec((1, 1, rows, lanes),
                           lambda b, h, lens, tabs: (b, h, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -412,8 +460,18 @@ def _gather_dequant(pages, scales, page_tables, batch, kv_heads,
     return out.astype(out_dtype)
 
 
+def _seen(cols, limit, window):
+    """The mask every oracle applies: column ``cols`` is seen by a query
+    whose causal limit is ``limit`` (its own position + 1), and under a
+    ``window`` only the ``window`` columns that end there."""
+    seen = cols < limit
+    if window is not None:
+        seen &= cols >= limit - window
+    return seen
+
+
 def _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                k_scales=None, v_scales=None):
+                k_scales=None, v_scales=None, window=None):
     """Gather + dense masked attention (CPU fallback / correctness ref)."""
     batch, q_heads, d = q.shape
     kv_heads, _tot, page_size, _d = k_pages.shape
@@ -432,13 +490,14 @@ def _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
     s = jnp.einsum("bhd,bhkd->bhk", q, k,
                    preferred_element_type=jnp.float32) * scale
     cols = jnp.arange(max_tokens)[None, None, :]
-    s = jnp.where(cols < lengths[:, None, None], s, DEFAULT_MASK_VALUE)
+    s = jnp.where(_seen(cols, lengths[:, None, None], window), s,
+                  DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhk,bhkd->bhd", p.astype(v.dtype), v).astype(q.dtype)
 
 
 def _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-               k_scales=None, v_scales=None):
+               k_scales=None, v_scales=None, window=None):
     """Gather + dense masked multi-query attention (CPU fallback /
     correctness reference for the ragged verify path)."""
     batch, n_query, q_heads, d = q.shape
@@ -464,14 +523,14 @@ def _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
     qpos = jnp.arange(n_query, dtype=jnp.int32)[None, None, :, None]
     limit = (lengths[:, None, None, None]
              - (n_query - 1 - qpos)).astype(jnp.int32)
-    s = jnp.where(cols < limit, s, DEFAULT_MASK_VALUE)
+    s = jnp.where(_seen(cols, limit, window), s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
-                k_scales=None, v_scales=None):
+                k_scales=None, v_scales=None, window=None):
     """Gather + dense masked attention with PER-ROW query spans (CPU
     fallback / correctness oracle for the ragged unified step).  Same
     einsum structure as ``_multi_xla`` — only the causal limit differs
@@ -504,14 +563,15 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
     kv = lengths[:, None, None, None].astype(jnp.int32)
     ql = q_lens[:, None, None, None].astype(jnp.int32)
     limit = jnp.minimum(kv, kv - ql + 1 + qpos)
-    s = jnp.where(cols < limit, s, DEFAULT_MASK_VALUE)
+    s = jnp.where(_seen(cols, limit, window), s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def paged_attention(q, k_pages, v_pages, lengths, page_tables, scale=None,
-                    interpret=False, k_scales=None, v_scales=None):
+                    interpret=False, k_scales=None, v_scales=None,
+                    window=None):
     """Decode-step attention over a paged KV cache.
 
     q:           (batch, q_heads, head_dim) — ONE new token per sequence
@@ -523,20 +583,27 @@ def paged_attention(q, k_pages, v_pages, lengths, page_tables, scale=None,
                  when the pages store INT8 KV (ISSUE 9): dequant is
                  fused into the kernel (or the gather on the XLA path),
                  so full-precision KV never round-trips HBM.
+    window:      None, or the width of a sliding-attention layer: the
+                 query (at position ``length - 1``) sees the ``window``
+                 keys that end with itself, and the kernel walks from
+                 the page of the first of them.  The same in
+                 ``paged_attention_multi`` and ``paged_attention_ragged``
+                 for every query of a row.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
-                              k_scales=k_scales, v_scales=v_scales)
+                              k_scales=k_scales, v_scales=v_scales,
+                              window=window)
     return _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                       k_scales=k_scales, v_scales=v_scales)
+                       k_scales=k_scales, v_scales=v_scales, window=window)
 
 
 def paged_attention_multi(q, k_pages, v_pages, lengths, page_tables,
                           scale=None, interpret=False, k_scales=None,
-                          v_scales=None):
+                          v_scales=None, window=None):
     """Ragged MULTI-QUERY decode attention: ``n_query`` new tokens per
     sequence in one pass — the speculative-decoding verify step's
     attention ("Ragged Paged Attention" shape: [B, k] queries against
@@ -557,20 +624,20 @@ def paged_attention_multi(q, k_pages, v_pages, lengths, page_tables,
         out = paged_attention(q[:, 0], k_pages, v_pages, lengths,
                               page_tables, scale=scale,
                               interpret=interpret, k_scales=k_scales,
-                              v_scales=v_scales)
+                              v_scales=v_scales, window=window)
         return out[:, None]
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
                               n_query=q.shape[1], k_scales=k_scales,
-                              v_scales=v_scales)
+                              v_scales=v_scales, window=window)
     return _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                      k_scales=k_scales, v_scales=v_scales)
+                      k_scales=k_scales, v_scales=v_scales, window=window)
 
 
 def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
                            page_tables, scale=None, interpret=False,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, window=None):
     """RAGGED paged attention (ISSUE 17): ONE kernel over a batch whose
     rows carry DIFFERENT query-span lengths — decode rows (q_len 1),
     prefill/chunk spans, and speculative verify blocks mix in a single
@@ -605,15 +672,17 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
         out = paged_attention(q[:, 0], k_pages, v_pages, lengths,
                               page_tables, scale=scale,
                               interpret=interpret, k_scales=k_scales,
-                              v_scales=v_scales)
+                              v_scales=v_scales, window=window)
         return out[:, None]
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
                               n_query=q.shape[1], k_scales=k_scales,
-                              v_scales=v_scales, q_lens=q_lens)
+                              v_scales=v_scales, q_lens=q_lens,
+                              window=window)
     return _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables,
-                       scale, k_scales=k_scales, v_scales=v_scales)
+                       scale, k_scales=k_scales, v_scales=v_scales,
+                       window=window)
 
 
 # ------------------------------------------------------------- page cache
@@ -834,7 +903,10 @@ class PagedKVCache:
         return cls(
             num_layers=c.num_hidden_layers,
             kv_heads=c.num_key_value_heads,
-            head_dim=c.hidden_size // c.num_attention_heads,
+            # a config that states its head_dim means it (2,048 / 48
+            # query heads is not 128)
+            head_dim=(getattr(c, "head_dim", None)
+                      or c.hidden_size // c.num_attention_heads),
             total_pages=total_pages, page_size=page_size,
             dtype=model.model.embed_tokens.weight._data.dtype,
             kv_dtype=kv_dtype, mesh=mesh)

@@ -125,7 +125,11 @@ class TestWatchdog:
         mgr._scan_interval = 0.05
         mgr.start()
         tid = mgr.begin("slow_all_reduce", timeout=0.1)
-        time.sleep(0.4)
+        # the scan thread may be starved on a loaded host: wait for its
+        # verdict, not for a duration
+        deadline = time.time() + 60
+        while not hung and time.time() < deadline:
+            time.sleep(0.05)
         mgr.end(tid)
         mgr.stop()
         mgr.set_timeout_handler(None)

@@ -199,7 +199,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
             getattr(part.experts, n).set_value(
                 getattr(whole.experts, n)._data[8 * r:8 * r + 8])
         total = total + part(x)._data - shared
-        slots, held, rows, _ = np.asarray(part.last_routing._data)
+        slots, held, rows, *_ = np.asarray(part.last_routing._data)
         assert slots == 3 * 50 * 4 and rows == 3 * 50 * 8 and 0 < held < slots
     assert rel(total + shared, want) < 1e-5
     # and the uncut layer is the reference's dense sum over its experts
@@ -228,8 +228,8 @@ def test_a_share_computes_every_slot_when_the_router_collapses():
     layer.gate.e_score_correction_bias.set_value(jnp.asarray(bias))
     xt = paddle.to_tensor(x, stop_gradient=False)
     y = layer(xt)
-    slots, held, rows, most = np.asarray(layer.last_routing._data)
-    assert (slots, held, rows, most) == (1024, 1024, 2048, 256)
+    slots, held, rows, most, touched = np.asarray(layer.last_routing._data)
+    assert (slots, held, rows, most, touched) == (1024, 1024, 2048, 256, 4)
     cfg = dict(TINY, num_experts=32, num_experts_per_token=4,
                held_experts=(8, 8))
     w = {"gate.gate_weight": layer.gate.gate_weight._data,

@@ -40,6 +40,7 @@ from paddle_tpu.ops.pallas.fused_norm_rope import (
     fused_rope_pallas,
     rms_norm_pallas,
 )
+from paddle_tpu.ops.pallas import moe_grouped_ffn
 from paddle_tpu.ops.pallas.moe_gating import topk_gating_pallas
 from paddle_tpu.ops.pallas import paged_attention
 from paddle_tpu.ops.pallas.paged_attention import (_decode_pallas,
@@ -129,7 +130,7 @@ def _paged_specs(chip, *, kvh, heads, d, batch, pages, page, table, nq=1,
     return specs
 
 
-def _paged_fn(d, *, nq=1, int8=False, ragged=False):
+def _paged_fn(d, *, nq=1, int8=False, ragged=False, window=None):
     scale = 1.0 / math.sqrt(d)
 
     def fn(q, kp, vp, lens, tabs, *rest):
@@ -139,7 +140,8 @@ def _paged_fn(d, *, nq=1, int8=False, ragged=False):
         if ragged:
             kw["q_lens"] = rest[-1]
         return _decode_pallas(q, kp, vp, lens, tabs, scale,
-                              interpret=False, n_query=nq, **kw)
+                              interpret=False, n_query=nq, window=window,
+                              **kw)
     return fn
 
 
@@ -520,3 +522,39 @@ class TestQuantMatmulLowering:
             functools.partial(w8a8_matmul_pallas, out_dtype=BF16,
                               interpret=False),
             ((m, k), I8), ((m, 1), F32), ((k, n), I8), ((n,), F32))
+
+
+# ------------------------------------- the Laguna serving cell's kernels
+class TestLagunaCellLowering:
+    """`laguna-xs2.serve.agent8`'s own kernels at its shapes: 8 rows, 48
+    or 64 query heads over 8 KV heads of 128, a table pinned at 512 over
+    8,192 pages of 16, a window of 512 in the 64-head layers; 256 experts
+    of 2,048 x 512, 8 a token, at a decode step's 8 positions and a chunk
+    step's 272."""
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("nq", [1, 128], ids=["decode", "span128"])
+    @pytest.mark.parametrize("heads,window", [(48, None), (64, 512)],
+                             ids=["full48", "sliding64"])
+    def test_paged_kernels(self, chip, heads, window, nq, int8):
+        chip.compile(
+            _paged_fn(128, nq=nq, int8=int8, ragged=nq > 1, window=window),
+            *_paged_specs(chip, kvh=8, heads=heads, d=128, batch=8,
+                          pages=8192, page=16, table=512, nq=nq, int8=int8,
+                          ragged=nq > 1))
+
+    @pytest.mark.parametrize("tokens", [8, 272])
+    def test_grouped_experts(self, chip, monkeypatch, tokens):
+        monkeypatch.setattr(moe_grouped_ffn, "_use_pallas", lambda: True)
+        e, m, h, k = 256, 2048, 512, 8
+        text = chip.compile(
+            moe_grouped_ffn.grouped_swiglu, ((tokens, m),),
+            ((tokens, k), I32), ((tokens, k), F32),
+            ((tokens, k), jnp.bool_), ((e, m, h),), ((e, m, h),),
+            ((e, h, m),))
+        calls = [ln for ln in text.splitlines()
+                 if "tpu_custom_call" in ln and "%moe_grouped_ffn" in ln]
+        assert calls, "no custom call named moe_grouped_ffn"
+        # the experts' weights reach the kernel as they are stored
+        assert not re.search(r"bf16\[256,\d+,\d+\][^ ]* (copy|transpose)\(",
+                             text)
