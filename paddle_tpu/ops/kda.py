@@ -29,11 +29,21 @@ summed pair by pair with exp(g_r - g_i), i <= r.  Underflow to zero is
 the right limit.
 
 Log-decays, the triangular inverse and S are float32; the matmul
-operands are the inputs' dtype (bfloat16 in training).  The backward is
-autodiff, bounded in memory by the op itself: the sequence is walked in
-segments of ``segment_chunks`` chunks under ``jax.checkpoint``, so what
-is kept for the backward is the inputs and one state a segment, and a
-segment's intermediates are recomputed when its gradient is taken.
+operands are the inputs' dtype (bfloat16 in training).
+
+Which path runs where is decided by what ``kda_chunk`` can see of its
+input and nothing else.  On a TPU, with dk one 128-lane tile and dv
+whole tiles, it is the two Pallas kernels of ``ops/pallas/kda_chunk.py``
+(a chunk's working set stays in VMEM, the state is carried from chunk to
+chunk in scratch); the backward there is no autodiff but the second
+kernel behind a ``custom_vjp``, which keeps the state that enters each
+chunk and recomputes a chunk's intermediates from it.  Everywhere else
+(the CPU, another head width) it is ``_kda_chunk`` below, plain XLA and
+the kernels' oracle: its backward is autodiff, bounded in memory by the
+op itself: the sequence is walked in segments of ``segment_chunks``
+chunks under ``jax.checkpoint``, so what is kept for the backward is the
+inputs and one state a segment, and a segment's intermediates are
+recomputed when its gradient is taken.
 """
 from __future__ import annotations
 
@@ -42,6 +52,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..framework.dispatch import def_op
+from .pallas import kda_chunk as _pallas
 
 CHUNK = 64
 SUB = 16
@@ -219,7 +230,11 @@ def kda_chunk(q, k, v, a, beta, initial_state=None, segment_chunks=8):
     """Chunkwise KDA.  q, k [B, T, H, dk] (q already scaled), v
     [B, T, H, dv], a [B, T, H, dk] log-decay per channel (<= 0), beta
     [B, T, H], initial_state [B, H, dk, dv] or None.  Returns
-    (o [B, T, H, dv] in v's dtype, final state [B, H, dk, dv] float32)."""
+    (o [B, T, H, dv] in v's dtype, final state [B, H, dk, dv] float32).
+    ``segment_chunks`` is the XLA path's; the kernels keep a state a
+    chunk."""
+    if _pallas.supported(q, v):
+        return _pallas.kda_chunk_pallas(q, k, v, a, beta, initial_state)
     return _kda_chunk(q, k, v, a, beta, initial_state, segment_chunks)
 
 

@@ -558,3 +558,38 @@ class TestLagunaCellLowering:
         # the experts' weights reach the kernel as they are stored
         assert not re.search(r"bf16\[256,\d+,\d+\][^ ]* (copy|transpose)\(",
                              text)
+
+
+# ------------------------------------ the Kimi-Linear cell's KDA kernels
+class TestKdaChunkLowering:
+    """`kimi-linear.train.seq8k`'s chunkwise delta rule at its shape,
+    1 x 8,192 tokens, 32 heads of 128: the forward and the backward
+    kernel behind one ``custom_vjp``, as the traced step calls them."""
+
+    SHAPES = (((1, 8192, 32, 128), BF16),) * 3 + (
+        ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32))
+
+    def test_forward(self, chip):
+        from paddle_tpu.ops.pallas.kda_chunk import kda_chunk_pallas
+        text = chip.compile(lambda *xs: kda_chunk_pallas(*xs), *self.SHAPES)
+        assert "%kda_chunk_fwd" in text and "%kda_chunk_bwd" not in text
+
+    def test_forward_and_backward_stay_under_the_callers_scope(self, chip):
+        from paddle_tpu.ops.pallas.kda_chunk import kda_chunk_pallas
+
+        def loss(*xs):
+            with jax.named_scope("train/model"), jax.named_scope("kda"):
+                o, _ = kda_chunk_pallas(*xs)
+            return jnp.sum(o.astype(F32) ** 2)
+
+        text = chip.compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                            *self.SHAPES)
+        # `train.device.kda` finds an instruction by the scope in its
+        # op_name: both kernels must carry the forward call site's
+        for name in ("kda_chunk_fwd", "kda_chunk_bwd"):
+            calls = [ln for ln in text.splitlines()
+                     if "tpu_custom_call" in ln and f"%{name}" in ln]
+            assert calls, f"no custom call named {name}"
+            for ln in calls:
+                op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
+                assert "train/model" in op_name and "/kda/" in op_name, ln
