@@ -668,6 +668,12 @@ def engine_program_spec(engine, mode: str = "decode", sample=None):
         args = (params, sds((B, S), i32), sds((B,), i32),
                 sds((B,), i32), sds((B * S,), i32), sds((B * S,), i32),
                 sds((B, W), i32), sds((B,), i32), s_args, *pools)
+        if cache.state_pools:
+            # a recurrent model's: its slot pools (donated) and (the
+            # rows' slots, the rows of several tokens, the page count)
+            args += (tuple(sds_of(a) for a in cache.state_pools),
+                     (sds((B,), i32), sds((0 if S == 1 else 2,), i32),
+                      sds((), i32)))
     else:
         if sample == "draw":
             s_args = (sds((B,), jnp.uint32), sds((B,), i32),

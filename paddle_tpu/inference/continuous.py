@@ -331,7 +331,20 @@ _STEP_SUMS = {
         ("kv_tokens_walked_window", "KV positions a sliding-attention "
          "layer's paged kernel walks, from the first visible page"),
         ("kv_tokens_walked_nowindow", "what that walk would be from page "
-         "0"))}
+         "0"),
+        ("state_bytes", "bytes of recurrent state the unified steps' rows "
+         "read and wrote, as the equations count a state (a row that "
+         "carries a token: a layer's state once in, once out)"))}
+# recurrent slots (a model whose layers carry a state of fixed size a
+# sequence): the cache's slot pool beside the page pools
+_slots_taken = monitor.counter(
+    "recurrent_slots_taken_total", "recurrent slots handed to a sequence "
+    "(at admission, and again when a preempted sequence resumes)")
+_slots_zeroed = monitor.counter(
+    "recurrent_slots_zeroed_total", "rows that entered their slot with an "
+    "empty context: the step starts them from zero whatever the slot held")
+_slots_in_use_g = monitor.gauge(
+    "recurrent_slots_in_use", "recurrent slots held by live sequences")
 _kv_window_dead_pages_g = monitor.gauge(
     "kv_window_dead_pages", "pages the step's rows hold wholly behind "
     "their next query's sliding window: kept for the full-attention "
@@ -653,6 +666,22 @@ class ContinuousBatchingEngine:
         self.max_position = int(model.config.max_position_embeddings)
         self.sample_on_device = bool(sample_on_device)
         self.prefix_cache = bool(prefix_cache)
+        # what the model is, read from the model: layers that carry a
+        # recurrent state a sequence (``recurrent_state``) are served by
+        # the ragged unified step alone, a slot a sequence beside the
+        # pages; what cannot hold for such a state refuses here
+        self._recurrent = (hasattr(model, "recurrent_state")
+                           and model.recurrent_state() is not None)
+        if self._recurrent:
+            self._refuse_for_recurrent(
+                draft_model=draft_model, kv_quant=kv_quant, tp=tp,
+                unified_step=unified_step,
+                prefill_chunk_tokens=prefill_chunk_tokens)
+            # a page-aligned prefix has no state to share (a state is of
+            # the whole sequence up to a token, not of a page): the
+            # default is turned off rather than refused, and says so
+            self.prefix_cache = False
+            replay_batch = False    # replay is chunk rows of the ragged step
         self.max_queue = int(max_queue)
         self.default_ttl_s = default_ttl_s
         self.default_queue_timeout_s = default_queue_timeout_s
@@ -718,7 +747,11 @@ class ContinuousBatchingEngine:
             self.mesh = None
         self.cache = PagedKVCache.from_model(
             model, total_pages=total_pages, page_size=page_size,
-            kv_dtype=kv_quant, mesh=self.mesh)
+            kv_dtype=kv_quant, mesh=self.mesh,
+            # a slot a row of the widest step: admitted sequences are at
+            # most ``max_batch``, a preempted one gives its slot back
+            state_slots=self.max_batch)
+        _slots_in_use_g.set(0)
         self.draft_model = draft_model
         self.spec_k = int(spec_tokens)
         from .paged import JittedPagedDecoder
@@ -887,6 +920,35 @@ class ContinuousBatchingEngine:
         self._coloc_registered = True
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
+
+    def _refuse_for_recurrent(self, draft_model, kv_quant, tp, unified_step,
+                              prefill_chunk_tokens) -> None:
+        """What cannot hold for a model whose layers carry a recurrent
+        state, each with its reason."""
+        name = type(self.model).__name__
+        if draft_model is not None:
+            raise ValueError(
+                f"draft_model: {name} carries a recurrent state, and a "
+                "rejected draft block cannot be rolled out of it (a KV "
+                "page is truncated; S has summed the rejected tokens in)")
+        if kv_quant is not None:
+            raise ValueError(
+                f"kv_quant={kv_quant!r}: {name} has no K/V page to "
+                "quantise; its state is float32 slots")
+        if int(tp) > 1:
+            from ..framework.jax_compat import make_tp_mesh
+            from .paged import _tp_plan
+            _tp_plan(self.model, make_tp_mesh(int(tp)))  # names what it lacks
+            raise ValueError(
+                f"tp={tp}: the slot pools of {name} have no placement over "
+                "a tensor mesh")
+        if not unified_step or prefill_chunk_tokens is None:
+            raise ValueError(
+                f"unified_step=False / prefill_chunk_tokens=None: {name} "
+                "carries a recurrent state, which only the ragged unified "
+                "step updates (a slot a row); the legacy prefill, chunk "
+                "and decode programs carry no slot pools.  Pass "
+                "prefill_chunk_tokens and keep unified_step")
 
     # ------------------------------------------------------------- public
     @property
@@ -1729,7 +1791,8 @@ class ContinuousBatchingEngine:
             return -1
         return self._sched.class_of(r).rank
 
-    def _admission_cost_locked(self, req) -> Optional[int]:
+    def _admission_cost_locked(self, req, slots_freed: int = 0
+                               ) -> Optional[int]:
         """Caller holds ``self._cond``.  PURE fit check: the pages this
         request's admission would newly reserve (its DRR cost), or None
         when it does not fit right now.  A prompt whose prefix is
@@ -1744,6 +1807,8 @@ class ContinuousBatchingEngine:
                 - shared_tok // self.cache.page_size + newly_pinned)
         if self._reserved_pages + need > self.cache.total_pages:
             return None
+        if self._recurrent and self.cache.free_slots + slots_freed < 1:
+            return None                 # a slot a sequence, as pages are
         # the draft pool reserves the full worst case too (no prefix
         # sharing there — the draft always prefills whole prompts);
         # both pools must fit or neither is reserved
@@ -1772,6 +1837,8 @@ class ContinuousBatchingEngine:
             req._draft_reserved = True
         req.seq_id = self._next_seq
         self._next_seq += 1
+        if self._recurrent:
+            self._take_slot_locked(req)
         if shared_tok:
             got = self.cache.acquire_prefix(req.seq_id, req.prompt)
             assert got == shared_tok   # nothing ran between probe/acquire
@@ -1792,6 +1859,14 @@ class ContinuousBatchingEngine:
             req.request_id, "admitted", cls=req.priority,
             seq_id=req.seq_id, prefix_tokens=req.prefix_tokens,
             queue_wait_s=round(req.admitted_at - req.submitted_at, 6))
+
+    def _take_slot_locked(self, req) -> None:
+        """Caller holds ``self._cond``.  The sequence's recurrent slot,
+        a slot of every layer's pool; the row that enters it with an
+        empty context starts it from zero."""
+        self.cache.take_slot(req.seq_id)
+        _slots_taken.inc()
+        _slots_in_use_g.set(self.cache.slots_in_use)
 
     def _tpot_parked_locked(self, r) -> bool:
         """Caller holds ``self._cond``.  True while a row parked by the
@@ -1871,6 +1946,18 @@ class ContinuousBatchingEngine:
         else:
             self._active.remove(victim)
             _decode_preempt_total.inc()
+        if self._recurrent:
+            # a paused sequence gives its slot back (slots are a row
+            # each; there is nothing to re-map as pages are): it resumes
+            # by running its tokens so far through chunk rows again into
+            # a zeroed slot, the pending token kept (as a restored
+            # request does)
+            self.cache.release_slot(victim.seq_id)
+            self.cache.truncate(victim.seq_id, 0)
+            _slots_in_use_g.set(self.cache.slots_in_use)
+            if victim.generated:
+                victim.replay_tokens = victim.output_ids
+            victim.prefill_pos = 0
         victim.preempted_at = time.perf_counter()
         self._preempted.append(victim)
         self._sched.note_preempted(victim)
@@ -1893,6 +1980,8 @@ class ContinuousBatchingEngine:
             pre.paused_total += time.perf_counter() - pre.preempted_at
             pre.preempted_at = None
         pre._tpot_parked = False
+        if self._recurrent:
+            self._take_slot_locked(pre)
         if pre.first_token_at is not None \
                 and pre.prefill_pos >= len(pre.prefill_target):
             self._active.append(pre)
@@ -1966,8 +2055,11 @@ class ContinuousBatchingEngine:
                     break
                 victim = self._preemption_victim_locked(qrank)
                 head = self._sched.peek_urgent()
+                # (a paused victim keeps its pages, and gives back the
+                # slot of its recurrent state where it has one)
                 if victim is None or head is None \
-                        or self._admission_cost_locked(head) is None:
+                        or self._admission_cost_locked(
+                            head, slots_freed=1) is None:
                     break
                 self._pause_locked(victim, qrank)
                 pending_rank = qrank
@@ -2200,12 +2292,13 @@ class ContinuousBatchingEngine:
             # re-picking would burn a host RNG draw
             req.next_token = (int(out_row) if sampled
                               else self._pick(req, out_row))
-        req.first_token_at = time.perf_counter()
-        ttft = req.first_token_at - req.submitted_at
-        _ttft_s.observe(ttft)
-        self._sched.note_first_token(req, ttft)
-        _tracer.request_event(req.request_id, "first_token",
-                              ttft_s=round(ttft, 6))
+        if req.first_token_at is None:  # not one resumed into a new slot
+            req.first_token_at = time.perf_counter()
+            ttft = req.first_token_at - req.submitted_at
+            _ttft_s.observe(ttft)
+            self._sched.note_first_token(req, ttft)
+            _tracer.request_event(req.request_id, "first_token",
+                                  ttft_s=round(ttft, 6))
         if self.journal is not None:
             # prefill completion: no tokens appended yet, but the first
             # pending sample is host state a SIGKILL must not lose
@@ -2286,6 +2379,12 @@ class ContinuousBatchingEngine:
         sites themselves (prefill/prefill_chunk/decode_step) are
         pacing, not failure injection: the unified step fires those
         sites itself, so they do NOT divert."""
+        if self._recurrent:
+            # no legacy composition carries slot pools: a fault plan's
+            # rules fire at the unified step's own sites, and a failed
+            # step goes down the retry/bisect ladder over ``ragged_step``
+            # itself (``_isolate_unified``)
+            return False
         if not self.unified_step or self._unified_off:
             return True
         plan = _faults.active()
@@ -2354,7 +2453,7 @@ class ContinuousBatchingEngine:
             if dft is not None and self._spec:
                 self.draft_cache.truncate(r.seq_id, dft)
 
-    def _unified_step(self, plan) -> None:
+    def _unified_step(self, plan, active=None, retried=False) -> None:
         """ONE ragged dispatch for the whole iteration (ISSUE 17): the
         scheduler's rank-ordered chunk plan feeds prefill/chunk row
         spans directly, every active row contributes its decode token
@@ -2372,7 +2471,12 @@ class ContinuousBatchingEngine:
         if a device-side loss zeroed them, and the iteration re-runs
         through the legacy composition — whose retry/bisect machinery
         owns failure isolation; repeated failures latch the unified
-        path off entirely."""
+        path off entirely.  A recurrent model has no legacy composition
+        (nothing else carries its slots): its failed step goes down the
+        same ladder over this method (:meth:`_isolate_unified`), which
+        calls it again with the rows to probe (``active``: the decode
+        rows, all of ``self._active`` if None; ``retried``: the whole
+        step has had its second try)."""
         chunks = []
         for req, n in plan:
             if req.cancelled or req.done.is_set():
@@ -2381,7 +2485,11 @@ class ContinuousBatchingEngine:
             k = req.prefill_pos
             n = min(n, len(target) - k)
             chunks.append((req, target, k, n, k + n == len(target)))
-        active = list(self._active)
+        # a probe's rows: one ejected since (its replay failed during a
+        # sibling's recovery) is never stepped again
+        probe = active is not None
+        active = ([r for r in active if r in self._active] if probe
+                  else list(self._active))
         if not chunks and not active:
             return
         spec = self._spec and any(r.use_draft for r in active)
@@ -2479,13 +2587,17 @@ class ContinuousBatchingEngine:
             self._unified_rollback(chunks, active, lens_before)
             _unified_fallbacks.inc()
             self._unified_failures += 1
-            if self._unified_failures >= 3 and not self._unified_off:
+            if self._unified_failures >= 3 and not self._unified_off \
+                    and not self._recurrent:
                 with self._cond:
                     self._disable_unified_locked()
             # a device-side loss zeroed every survivor's KV: rebuild +
             # replay BEFORE the legacy re-run decodes over zeroed pages
             # (replay-dead requests are quarantined/ejected in here)
             self._after_step_failure(e)
+            if self._recurrent:
+                self._isolate_unified(chunks, active, e, retried)
+                return
             self._run_chunks(plan)
             if self._active:
                 self._decode_step()
@@ -2503,6 +2615,8 @@ class ContinuousBatchingEngine:
                     _STEP_SUMS[name].inc(value)
                 elif name == "kv_window_dead_pages":
                     _kv_window_dead_pages_g.set(value)
+                elif name == "slots_zeroed":
+                    _slots_zeroed.inc(value)
             # ---- chunk rows: the legacy _prefill_chunk bookkeeping
             completed: List[_Request] = []
             for i, (req, _target, k, n, last) in enumerate(chunks):
@@ -2616,6 +2730,10 @@ class ContinuousBatchingEngine:
                     self.steps += 1
                     for r in retired:
                         self._retire_locked(r)
+                    if probe:       # the rows it did not step stay
+                        gone = {id(r) for r in retired}
+                        still = [r for r in self._active
+                                 if id(r) not in gone]
                     self._active = still
                     if not still:
                         self._free_pads_locked()
@@ -2628,6 +2746,41 @@ class ContinuousBatchingEngine:
                 _active_seqs.set(len(still))
             for r in retired:
                 r.done.set()
+
+    def _isolate_unified(self, chunks, active, error, retried) -> None:
+        """The legacy ladder (:meth:`_step_isolated`, :meth:`_bisect_step`)
+        over the ragged step, for a model that has no other path: the
+        failed step (rolled back, survivors replayed) runs whole once
+        more — a transient fault — and then by halves of its rows, each
+        row against its own slot, so healthy halves advance normally and
+        only a row that fails alone is quarantined with the error that
+        killed it."""
+        plan = [(c[0], c[3]) for c in chunks]
+        n = len(plan) + len(active)
+        if not retried:
+            cuts = [(0, n)]
+        elif n > 1:
+            cuts = [(0, (n + 1) // 2), ((n + 1) // 2, n)]
+        else:
+            r = plan[0][0] if plan else active[0]
+            with self._cond:
+                if not r.done.is_set():
+                    r.error = error
+                    for lst in (self._active, self._prefilling):
+                        if r in lst:
+                            lst.remove(r)
+                    _note_quarantine(r)
+                    self._retire_locked(r)
+                if not self._active:
+                    self._free_pads_locked()
+                self._cond.notify_all()
+            r.done.set()
+            return
+        for a, b in cuts:       # the chunk rows first, as the step has them
+            _decode_retries.inc()
+            self._unified_step(
+                plan[a:b], active[max(a - len(plan), 0):max(b - len(plan), 0)],
+                retried=True)
 
     def _pick(self, req, logits_row) -> int:
         from .paged import sample_token
@@ -2669,6 +2822,8 @@ class ContinuousBatchingEngine:
                  - len(self.cache._seq_pages.get(req.seq_id, ())))
         released = self.cache.free(req.seq_id)
         self._reserved_pages -= slack + released
+        if self._recurrent:
+            _slots_in_use_g.set(self.cache.slots_in_use)
         self._release_draft_locked(req)
         req.finished_at = time.perf_counter()
         if req.error is None:
@@ -2729,7 +2884,25 @@ class ContinuousBatchingEngine:
         if upto <= 0 and dlen <= 0:
             return                     # nothing resident yet
         sampling = _null_sampling() if self.sample_on_device else None
-        if upto > 0:
+        if upto > 0 and self._recurrent:
+            # the slot is zeroed by the chunk row that enters it at
+            # context 0; the rest follow through the ragged program the
+            # serving path runs (there is nothing to re-map)
+            tokens = req.output_ids[:upto]
+            self.cache.truncate(sid, 0)
+            seeds, _, temps, flags = _null_sampling()
+            for k in range(0, upto, self.prefill_chunk_tokens):
+                self._step_started_at = time.monotonic()
+                try:
+                    _replay_dispatches.inc()
+                    self._decoder.ragged_step(
+                        self.cache, [sid],
+                        [tokens[k:k + self.prefill_chunk_tokens]], [k],
+                        sampling=((seeds, temps, flags)
+                                  if self.sample_on_device else None))
+                finally:
+                    self._step_started_at = None
+        elif upto > 0:
             tokens = req.output_ids[:upto]
             self.cache.truncate(sid, 0)
             chunk = self.prefill_chunk_tokens or upto

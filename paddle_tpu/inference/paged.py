@@ -276,6 +276,14 @@ _NO_WINDOW = ("a sliding-attention layer (window=...) reached {}, which "
               "whose paged kernels apply it")
 
 
+#: what a path with no slots says to a retention layer
+_NO_SLOTS = ("a retention layer (a recurrent state a sequence) reached {}, "
+             "which carries no slot pools: serve this model through the "
+             "ragged unified step (ContinuousBatchingEngine("
+             "prefill_chunk_tokens=...)), whose rows each update their own "
+             "slot in place")
+
+
 class _PagedContext:
     """Per-forward attention driver handed down to attention layers.
 
@@ -291,6 +299,10 @@ class _PagedContext:
         self.seq_ids = list(seq_ids)
         self.prefill = prefill
         self.layer_idx = 0
+
+    def retain(self, q, k, v, log_g):
+        raise NotImplementedError(_NO_SLOTS.format(
+            "the eager paged context of PagedGenerator"))
 
     def attend(self, q: Tensor, k: Tensor, v: Tensor,
                window: Optional[int] = None) -> Tensor:
@@ -369,13 +381,26 @@ class _TracedPagedContext:
     is out of range); ``count(**named)`` — counters of the model's own,
     summed by name over whoever calls and handed out of the compiled step
     beside its tokens: they reach the ``dispatch`` record under their
-    names (``counted``)."""
+    names (``counted``); ``retain`` — what a retention layer asks in
+    place of ``attend``: the step's rows' recurrent slots (``states``, a
+    pool a retention layer, donated at the jit boundary like the page
+    pools; ``slots`` the rows' slots, ``chunk_rows`` the rows of several
+    tokens) updated in place by the ragged step, each row against its
+    own, a pad row's untouched.  Only the ragged step carries slots."""
 
     def __init__(self, k_pages, v_pages, pg, sl, lens=None, tables=None,
                  prefill=False, prefix_lens=None, k_scales=None,
-                 v_scales=None, q_lens=None, row_off=None, span=None):
+                 v_scales=None, q_lens=None, row_off=None, span=None,
+                 states=(), slots=None, chunk_rows=None, n_pages=None):
         self.k_pages = list(k_pages)
         self.v_pages = list(v_pages)
+        # recurrent slot pools, one a retention layer in the layers'
+        # order, and which one the next ``retain`` takes
+        self.states = list(states)
+        self.slots = slots              # (rows,) traced: a row's slot
+        self.chunk_rows = chunk_rows    # (C,) traced: rows of several tokens
+        self.state_idx = 0
+        self._n_pages = n_pages         # for a model with no page pool
         # int8 KV mode (ISSUE 9): parallel per-slot scale pools carried
         # through the program exactly like the data pools (donated at
         # the jit boundary); empty/None means full-precision storage
@@ -397,7 +422,32 @@ class _TracedPagedContext:
     def token_mask(self):
         """(positions,) bool: False where the position is pad — its
         (page, slot) write target is the dropped out-of-range page."""
-        return self.pg < self.k_pages[0].shape[1]
+        return self.pg < (self.k_pages[0].shape[1] if self.k_pages
+                          else self._n_pages)
+
+    def retain(self, q, k, v, log_g):
+        """One retention layer of the ragged step: ``q`` (tokens, 1,
+        q_heads, d), ``k`` / ``v`` (tokens, 1, kv_heads, d) and ``log_g``
+        (tokens, kv_heads) float32, packed as ``attend`` takes them.
+        Every row's slot of this layer's pool is read, updated and
+        written in place (``ops/power_retention.py::retention_step``);
+        returns (tokens, 1, q_heads, d) float32."""
+        if self.q_lens is None or self.slots is None:
+            raise NotImplementedError(_NO_SLOTS.format(
+                "the prefill / prefix / chunk_prefill programs" if
+                self.prefill else "the decode / verify programs"))
+        from ..ops.power_retention import retention_step
+        i = self.state_idx
+        self.state_idx += 1
+        y, self.states[i] = retention_step(
+            self.states[i], self.slots, self.lens - self.q_lens,
+            self.q_lens, self.row_off, self.chunk_rows, q._data[:, 0],
+            k._data[:, 0], v._data[:, 0], log_g, span=self.span)
+        # the rows that carry a token, a layer: the dispatch record's
+        # ``state_rows`` and ``state_bytes`` are read off this sum
+        scratch = self.states[i].shape[0] - 1
+        self.count(state_row_layers=jnp.sum(self.slots < scratch))
+        return wrap_array(y[:, None])
 
     def count(self, **named):
         for name, value in named.items():
@@ -532,7 +582,9 @@ class JittedPagedDecoder:
     #: per-mode donated arg positions (page pools + scale pools) —
     #: shared between the jit call and the analysis auditor so both
     #: see one contract.  The scale-pool slots hold empty tuples (no
-    #: leaves) for full-precision caches.
+    #: leaves) for full-precision caches.  A model with a recurrent state
+    #: adds its slot pools to the ragged program's (slot 14, behind the
+    #: weight scales: the signature every other caller knows is kept).
     DONATE_ARGNUMS = {"decode": (8, 9, 10, 11), "prefill": (6, 7, 8, 9),
                       "prefix": (8, 9, 10, 11), "verify": (8, 9, 10, 11),
                       "ragged": (9, 10, 11, 12)}
@@ -557,6 +609,15 @@ class JittedPagedDecoder:
         kinds = (model.attention_kinds()
                  if hasattr(model, "attention_kinds") else
                  [(mc.num_attention_heads, None)] * mc.num_hidden_layers)
+        # a model whose layers carry a recurrent state a sequence says so
+        # (``recurrent_state``: layers, a slot's shape, the bytes of it
+        # the equations count): its ragged program takes the slot pools
+        # as one more donated operand and hands them back
+        self._state = (model.recurrent_state()
+                       if hasattr(model, "recurrent_state") else None)
+        if self._state is not None:
+            self.DONATE_ARGNUMS = dict(self.DONATE_ARGNUMS,
+                                       ragged=(9, 10, 11, 12, 14))
         self._attn_kinds = {}
         for heads, window in kinds:
             kind = (heads // mc.num_key_value_heads, window)
@@ -670,12 +731,15 @@ class JittedPagedDecoder:
                 tuple(cache.k_scales), tuple(cache.v_scales))
 
     @staticmethod
-    def _store_pools(cache, k_pages, v_pages, k_scales, v_scales):
+    def _store_pools(cache, k_pages, v_pages, k_scales, v_scales,
+                     states=None):
         cache.k_pages = list(k_pages)
         cache.v_pages = list(v_pages)
         if cache.kv_quant:
             cache.k_scales = list(k_scales)
             cache.v_scales = list(v_scales)
+        if states is not None:
+            cache.state_pools = list(states)
 
     def _swap_params(self, param_arrays, wscales=()):
         saved = [p._data for p in self.params]
@@ -920,7 +984,7 @@ class JittedPagedDecoder:
         elif mode == "ragged":
             def fn(param_arrays, ids, ctx_lens, q_lens, pg, sl, tables,
                    nd, sampling, k_pages, v_pages, k_scales, v_scales,
-                   wscales):
+                   wscales, states=(), recur=()):
                 """Ragged UNIFIED serving step (ISSUE 17): one compiled
                 dispatch processes a batch mixing decode rows
                 (q_len 1), prefill/chunk spans, and speculative verify
@@ -944,11 +1008,19 @@ class JittedPagedDecoder:
                 the rectangle itself, row-major, nothing moved.
                 Accept lengths and the output token's position select
                 ON DEVICE, so the host boundary stays (B,) ids + (B,)
-                accepts whatever the batch mixes."""
+                accepts whatever the batch mixes.
+
+                ``states`` and ``recur`` are a recurrent model's: its
+                slot pools (donated, handed back last) and (the rows'
+                slots (B,), the rows of several tokens (C,), the
+                cache's page count ())."""
                 saved = self._swap_params(param_arrays, wscales)
                 try:
                     b, s = ids.shape
                     t = self.packed_tokens(b, s)
+                    # the page a pad position writes to and loses: past
+                    # the pool's last (a model with no page pool is told)
+                    drop = k_pages[0].shape[1] if k_pages else recur[2]
                     # ids, write targets and rope positions of the
                     # rectangle, taken to the packed axis together
                     cols = jnp.stack(
@@ -963,8 +1035,7 @@ class JittedPagedDecoder:
                             < jnp.sum(q_lens)
                         cols = jnp.where(
                             real[:, None], _packed_of_rows(cols, off, t),
-                            jnp.asarray([0, k_pages[0].shape[1], 0, 0],
-                                        jnp.int32))
+                            jnp.zeros(4, jnp.int32).at[1].set(drop))
                     else:
                         # the packed axis holds the whole rectangle:
                         # every row keeps its place, the host's pads too
@@ -974,7 +1045,9 @@ class JittedPagedDecoder:
                         k_pages, v_pages, cols[:, 1], cols[:, 2],
                         ctx_lens + q_lens, tables, q_lens=q_lens,
                         row_off=off, span=s, k_scales=k_scales,
-                        v_scales=v_scales)
+                        v_scales=v_scales, states=states, n_pages=drop,
+                        **(dict(slots=recur[0], chunk_rows=recur[1])
+                           if recur else {}))
                     with no_grad():
                         hidden = model.model(wrap_array(cols[:, :1]),
                                              cols[:, 3], paged_ctx=ctx)
@@ -1001,6 +1074,8 @@ class JittedPagedDecoder:
                     # model that counts nothing
                     self._step_counts, counted = ctx.counted()
                     rest = (counted, *ctx_pools(ctx))
+                    if self._state is not None:
+                        rest += (tuple(ctx.states),)
                     if sample == "greedy":
                         ids_out = jnp.take_along_axis(
                             targets, sel[:, None], axis=1)[:, 0]
@@ -1455,6 +1530,10 @@ class JittedPagedDecoder:
                 raise ValueError("every row needs at least one token")
             nds = ([0] * b if n_drafts is None
                    else [int(x) for x in n_drafts])
+            if self._state is not None and any(nds):
+                raise ValueError(
+                    "a verify row cannot run against a recurrent state: a "
+                    "rejected draft cannot be rolled out of it")
             before = []
             for sid, k, n, nd in zip(seq_ids, ctxs, ns, nds):
                 if nd and n != nd + 1:
@@ -1501,11 +1580,28 @@ class JittedPagedDecoder:
                 cache.advance([sid], n)
             needed = max(len(cache._seq_pages.get(sid, ()))
                          for sid in seq_ids)
-            W = max(next_pow2(needed), self.min_table_pages)
+            # a model with no K/V layer reads no table: one column, so
+            # no program's shape follows the longest context
+            W = (max(next_pow2(needed), self.min_table_pages)
+                 if cache.num_layers else 1)
             tabs = np.zeros((b_b, W), np.int32)
             for i, sid in enumerate(seq_ids):
-                t = cache._seq_pages[sid]
+                t = cache._seq_pages[sid][:W]
                 tabs[i, :len(t)] = t
+            recur = ()
+            if self._state is not None:
+                # the rows' slots (a pad row: the scratch slot) and the
+                # rows of several tokens, which take the chunk form: a
+                # static few, -1 where there are fewer (none when every
+                # row holds one token)
+                slots = np.full(b_b, cache.scratch_slot, np.int32)
+                slots[:b] = [cache.take_slot(sid) for sid in seq_ids]
+                multi = [i for i, n in enumerate(ns) if n > 1]
+                c_b = 0 if s_b == 1 else max(2, next_pow2(len(multi)))
+                chunk_rows = np.full(c_b, -1, np.int32)
+                chunk_rows[:len(multi)] = multi
+                recur = (slots, chunk_rows,
+                         np.asarray(cache.total_pages, np.int32))
             ctx_arr = np.zeros(b_b, np.int32)
             ctx_arr[:b] = np.asarray([int(k) for k in ctxs], np.int32)
             ql = np.ones(b_b, np.int32)          # pad rows: 1-token span,
@@ -1534,6 +1630,17 @@ class JittedPagedDecoder:
             "tokens": sum(ns), "tokens_padded": t_b,
             "table_pages": W, "page_size": cache.page_size,
             **self._walk_counts(cache, ctx_arr + ql, ql, s_b, b)}
+        if self._state is not None:
+            # the rows of several tokens (the chunk form; the program is
+            # built for ``chunk_rows_padded`` of them) and their tokens,
+            # the slots there are, and the rows that enter their slot
+            # with an empty context: the step that zeroes it
+            self.last_dispatch.update(
+                chunk_rows_padded=len(recur[1]),
+                state_chunk_rows=len(multi),
+                state_chunk_tokens=sum(ns[i] for i in multi),
+                state_slots=cache.state_slots,
+                slots_zeroed=sum(1 for k in ctxs if int(k) == 0))
         with monitor.span("engine/dispatch"):
             sample, s_args = self._verify_sampling_args(sampling)
             try:
@@ -1545,7 +1652,10 @@ class JittedPagedDecoder:
                     jnp.asarray(pg.reshape(-1)),
                     jnp.asarray(sl.reshape(-1)),
                     jnp.asarray(tabs), jnp.asarray(nd_arr), s_args,
-                    *self._pool_args(cache), self._wscale_args())
+                    *self._pool_args(cache), self._wscale_args(),
+                    *((tuple(cache.state_pools),
+                       tuple(jnp.asarray(a) for a in recur))
+                      if recur else ()))
             except BaseException:
                 self._recover_pools(cache)
                 self._rollback_lengths(cache, seq_ids, before)
@@ -1556,6 +1666,13 @@ class JittedPagedDecoder:
                 self.last_dispatch.update(zip(
                     self._step_counts,
                     (int(v) for v in np.asarray(counted[0]))))
+            if self._state is not None:
+                # the rows that carried a token, and the bytes of state
+                # they read and wrote as the equations count them
+                n = self.last_dispatch.pop("state_row_layers", 0)
+                self.last_dispatch["state_rows"] = n // self._state["layers"]
+                self.last_dispatch["state_bytes"] = \
+                    2 * n * self._state["bytes"]
             return np.asarray(out)[:b], np.asarray(accept)[:b]
 
     def _walk_counts(self, cache, lens, q_lens, span, rows):
@@ -1572,6 +1689,8 @@ class JittedPagedDecoder:
         (``kv_window_dead_pages``; a page two rows share counts
         twice)."""
         ps, total = cache.page_size, sum(self._attn_kinds.values())
+        if not total:                   # no K/V layer: nothing is walked
+            return {}
         out = {"ctx_tokens": 0, "kv_tokens_walked": 0}
         for (group, window), n in self._attn_kinds.items():
             block = ps * walk_block_pages(ps, cache.head_dim, span * group,

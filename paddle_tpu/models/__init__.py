@@ -1,5 +1,6 @@
 """Model zoo: LLaMA (flagship), LLaMA-MoE, Kimi-Linear (KDA + MLA + sigmoid
-MoE), Laguna (full + sliding attention, sigmoid MoE), BERT; vision models in
+MoE), Laguna (full + sliding attention, sigmoid MoE), Brumby (power-retention
+layers: a recurrent state in place of a KV cache), BERT; vision models in
 paddle_tpu.vision."""
 from .llama import (  # noqa: F401
     LlamaConfig, LlamaForCausalLM, LlamaModel, llama_7b, llama_small,
@@ -15,6 +16,9 @@ from .kimi_linear import (  # noqa: F401
 )
 from .laguna import (  # noqa: F401
     LagunaConfig, LagunaForCausalLM, LagunaModel,
+)
+from .brumby import (  # noqa: F401
+    BrumbyConfig, BrumbyForCausalLM, BrumbyModel,
 )
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForMaskedLM,

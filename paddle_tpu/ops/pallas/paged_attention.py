@@ -894,14 +894,26 @@ class PagedKVCache:
     def from_model(cls, model, total_pages: int = 256,
                    page_size: int = 16,
                    kv_dtype: Optional[str] = None,
-                   mesh=None) -> "PagedKVCache":
+                   mesh=None, state_slots: int = 0) -> "PagedKVCache":
         """Cache sized for a causal-LM model's config (single wiring
         point shared by PagedGenerator and ContinuousBatchingEngine).
         ``kv_dtype="int8"`` selects the quantized storage mode;
         ``mesh`` shards the pools on the KV-head axis (ISSUE 20)."""
         c = model.config
+        # what the model is, read from the model: the layers that hold
+        # K/V pages (all of them unless it says otherwise) and the
+        # recurrent state a sequence its other layers carry
+        state = (model.recurrent_state()
+                 if hasattr(model, "recurrent_state") else None)
+        kv_layers = (len(model.attention_kinds())
+                     if hasattr(model, "attention_kinds")
+                     else c.num_hidden_layers)
+        if state is not None and mesh is not None:
+            raise ValueError(
+                "a recurrent state has no placement over a tensor mesh: "
+                "the slot pools are not sharded")
         return cls(
-            num_layers=c.num_hidden_layers,
+            num_layers=kv_layers,
             kv_heads=c.num_key_value_heads,
             # a config that states its head_dim means it (2,048 / 48
             # query heads is not 128)
@@ -909,12 +921,16 @@ class PagedKVCache:
                       or c.hidden_size // c.num_attention_heads),
             total_pages=total_pages, page_size=page_size,
             dtype=model.model.embed_tokens.weight._data.dtype,
-            kv_dtype=kv_dtype, mesh=mesh)
+            kv_dtype=kv_dtype, mesh=mesh,
+            state_layers=state["layers"] if state else 0,
+            state_shape=tuple(state["shape"]) if state else (),
+            state_slots=state_slots if state else 0)
 
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int,
                  total_pages: int = 256, page_size: int = 16,
                  dtype=jnp.float32, kv_dtype: Optional[str] = None,
-                 mesh=None):
+                 mesh=None, state_layers: int = 0, state_shape=(),
+                 state_slots: int = 0):
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
@@ -962,6 +978,14 @@ class PagedKVCache:
         else:
             self.k_scales = []
             self.v_scales = []
+        # recurrent slots: float32 whatever the pages' type (a state is
+        # summed over the whole sequence)
+        self.state_layers = int(state_layers)
+        self.state_slots = int(state_slots) if state_layers else 0
+        self.state_shape = tuple(state_shape)
+        self.state_pools = self._state_zeros()
+        self._free_slots: List[int] = list(range(self.state_slots))[::-1]
+        self._seq_slot: Dict[int, int] = {}
         self._free: List[int] = list(range(total_pages))
         self._seq_pages: Dict[int, List[int]] = {}
         self._seq_len: Dict[int, int] = {}
@@ -980,6 +1004,48 @@ class PagedKVCache:
         # across a failed step to tell a host-side fault (KV intact)
         # from a REAL donated-buffer loss (survivors need replay)
         self.generation = 0
+
+    def _state_zeros(self):
+        return [jnp.zeros((self.state_slots + 1,) + self.state_shape,
+                          jnp.float32) for _ in range(self.state_layers)]
+
+    # --------------------------------------------------- recurrent slots
+    @property
+    def scratch_slot(self) -> int:
+        """The slot of a row that is pad: written, never read."""
+        return self.state_slots
+
+    @property
+    def free_slots(self) -> int:
+        return len(self._free_slots)
+
+    @property
+    def slots_in_use(self) -> int:
+        return len(self._seq_slot)
+
+    def take_slot(self, seq_id) -> int:
+        """The sequence's slot, taken from the free ones if it has none.
+        Nothing is cleared here: the row that enters a slot with an
+        empty context starts from zero."""
+        slot = self._seq_slot.get(seq_id)
+        if slot is None:
+            if not self._free_slots:
+                raise RuntimeError(
+                    f"PagedKVCache out of recurrent slots "
+                    f"({self.state_slots}); free() finished sequences")
+            slot = self._seq_slot[seq_id] = self._free_slots.pop()
+        return slot
+
+    def release_slot(self, seq_id) -> None:
+        """Return the sequence's slot (its pages and length stay)."""
+        slot = self._seq_slot.pop(seq_id, None)
+        if slot is not None:
+            self._free_slots.append(slot)
+
+    def slot_of(self, seq_id) -> int:
+        """The sequence's slot; the scratch slot for one that has none
+        (a pad row)."""
+        return self._seq_slot.get(seq_id, self.scratch_slot)
 
     def _zeros(self, shape, dtype):
         """A zeroed pool buffer created IN its placement: sharded over
@@ -1083,6 +1149,7 @@ class PagedKVCache:
         for p in self._seq_pages.pop(seq_id, []):
             released += self._decref_seq(p)
         self._seq_len.pop(seq_id, None)
+        self.release_slot(seq_id)
         return released
 
     def reset_pools(self) -> None:
@@ -1113,6 +1180,9 @@ class PagedKVCache:
                              for _ in range(self.num_layers)]
             self.v_scales = [self._zeros(sshape, jnp.float32)
                              for _ in range(self.num_layers)]
+        # the slots' content is gone with the pages': who holds one
+        # replays into it from an empty context
+        self.state_pools = self._state_zeros()
         while self._prefix_index:
             _, entry = self._prefix_index.popitem(last=False)
             for p in entry.pages:
@@ -1225,13 +1295,21 @@ class PagedKVCache:
         site deletes these; ``_recover_pools`` probes them for
         deadness."""
         return (list(self.k_pages) + list(self.v_pages)
-                + list(self.k_scales) + list(self.v_scales))
+                + list(self.k_scales) + list(self.v_scales)
+                + list(self.state_pools))
 
     @property
     def kv_pool_bytes(self) -> int:
-        """Resident bytes of the KV data pages across all layers."""
+        """Resident bytes of what the cache holds a sequence across all
+        layers: the KV data pages and the recurrent slot pools."""
         return sum(int(a.size) * a.dtype.itemsize
-                   for a in list(self.k_pages) + list(self.v_pages))
+                   for a in list(self.k_pages) + list(self.v_pages)
+                   + list(self.state_pools))
+
+    @property
+    def state_pool_bytes(self) -> int:
+        """Resident bytes of the recurrent slot pools alone."""
+        return sum(int(a.size) * a.dtype.itemsize for a in self.state_pools)
 
     @property
     def kv_scale_bytes(self) -> int:

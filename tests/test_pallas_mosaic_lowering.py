@@ -593,3 +593,50 @@ class TestKdaChunkLowering:
             for ln in calls:
                 op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
                 assert "train/model" in op_name and "/kda/" in op_name, ln
+
+
+# ------------------------------- the Brumby cell's retention state kernels
+class TestRetentionStateLowering:
+    """`brumby-14b.serve.reason16`'s retention layer at its shapes: 16 + 1
+    slots of 8 KV heads x [136, 8320] float32 (580 MB a layer), 40 query
+    heads of 128, a ragged step of 16 rows.  One layer's
+    ``retention_step`` with the pool donated: the one-token kernel alone
+    (span 1), and beside the chunk kernel at (16, 128) packed to 272
+    positions.  The only instructions that produce a pool-sized array are
+    the kernels themselves, which write in place."""
+
+    SLOTS, KVH, HEADS, ROWS = 16, 8, 40, 16
+
+    @pytest.mark.parametrize("span, tokens, chunk_rows",
+                             [(1, 16, 0), (128, 272, 2)],
+                             ids=["decode", "chunk"])
+    def test_a_layer_updates_its_pool_in_place(self, chip, monkeypatch,
+                                               span, tokens, chunk_rows):
+        from paddle_tpu.ops import power_retention as pr
+        monkeypatch.setattr(pr, "_use_pallas", lambda: True)
+        pool = (self.SLOTS + 1,) + pr.state_shape(self.KVH, D7, D7)
+        packed = tokens < self.ROWS * span
+
+        def layer(pool, slots, ctx, q_lens, off, rows, q, k, v, log_g):
+            return pr.retention_step(pool, slots, ctx, q_lens,
+                                     off if packed else None, rows, q, k, v,
+                                     log_g, span=span)
+
+        rows = chip.sds((self.ROWS,), I32)
+        text = jax.jit(layer, donate_argnums=0).lower(
+            chip.sds(pool, F32), rows, rows, rows, rows,
+            chip.sds((chunk_rows,), I32),
+            chip.sds((tokens, self.HEADS, D7)),
+            chip.sds((tokens, self.KVH, D7)),
+            chip.sds((tokens, self.KVH, D7)),
+            chip.sds((tokens, self.KVH), F32)).compile().as_text()
+        assert "%retention_decode" in text
+        assert ("%retention_chunk" in text) == bool(chunk_rows)
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert "(0, {}" in alias.group(1), alias.group(0)
+        ops = TestAppendRowsLowering._pool_sized(text, math.prod(pool),
+                                                 "f32")
+        assert set(ops) <= {"parameter", "get-tuple-element", "bitcast",
+                            "custom-call"}, (
+            f"a pool-sized array is produced by {dict(ops)}: the slots "
+            "are copied inside the step")
