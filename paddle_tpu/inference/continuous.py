@@ -371,6 +371,24 @@ _unified_fallbacks = monitor.counter(
     "ragged dispatch failed and the engine re-ran the step through the "
     "legacy multi-dispatch composition (whose retry/bisect isolation "
     "then owns the failure)")
+# one step in flight (ISSUE 38): how often the loop dispatches a step
+# over an uncommitted one, and why it does not
+_steps_overlapped = monitor.counter(
+    "serve_steps_overlapped_total", "unified steps dispatched while the "
+    "step before them was still uncommitted (its decode rows' tokens fed "
+    "on the device)")
+_overlap_drains = monitor.counter(
+    "serve_overlap_drains_total", "unified steps committed BEFORE the "
+    "next one was dispatched, by what the engine saw: spec, "
+    "host_sampling, unchunked, legacy, fault_plan, replaced, snapshot, "
+    "drain, cancel, deadline, preempt, idle (nothing to dispatch), "
+    "launch_failed", ("reason",))
+_overlap_dropped_rows = monitor.counter(
+    "serve_overlap_dropped_rows_total", "decode rows that rode in a step "
+    "whose request had met its EOS in the step before: computed, dropped "
+    "at the commit, their pages and slot returned there")
+_steps_overlapped.inc(0)
+_overlap_dropped_rows.inc(0)
 
 # closed-loop overload protection (ISSUE 19): the controller's own
 # series — materialized at import so existence gates (chaos_smoke) see
@@ -442,6 +460,46 @@ def retry_after_seconds(queue_depth: int,
     if not queue_depth or not decode_p50_s or decode_p50_s <= 0:
         return 1
     return int(min(30.0, max(1.0, math.ceil(queue_depth * decode_p50_s))))
+
+
+class _LandFirst(Exception):
+    """Internal (ISSUE 38): the schedule pass is about to move a request
+    that has a row in the step in flight.  Raised BEFORE anything is
+    changed; the loop commits that step (counted by ``reason``) and
+    makes the pass again."""
+
+    def __init__(self, reason: str):
+        super().__init__(reason)
+        self.reason = reason
+
+
+class _Step:
+    """One unified step between its dispatch and its commit
+    (``_launch_step`` / ``_commit_step``): the composition a failure
+    unwinds and the ladder re-runs, the decoder's flight, and what the
+    launch already told the scheduler."""
+
+    __slots__ = ("plan", "chunks", "active", "retried", "spec",
+                 "k_spec", "drafts", "lens_before", "sampled", "flight",
+                 "result", "record", "t_ns", "traced", "t0", "index",
+                 "overlapped", "launched", "moved", "chunk_no", "riders",
+                 "deferred", "leaving", "out_index", "dropped")
+
+    def __init__(self, plan, chunks, active, retried, spec, k_spec):
+        self.plan, self.chunks, self.active = plan, chunks, active
+        self.retried = retried
+        self.spec, self.k_spec, self.drafts = spec, k_spec, None
+        self.lens_before, self.sampled = {}, False
+        self.flight = self.result = self.record = None
+        self.t_ns, self.traced, self.t0, self.index = 0, False, 0.0, 0
+        self.overlapped = self.launched = False
+        self.moved = []         # prefills its launch moved to _active
+        self.chunk_no = []      # each chunk row's ordinal in its request
+        self.riders = {}        # id -> request with a row in it
+        self.deferred = set()   # rows whose token was fed on the device
+        self.leaving = set()    # rows fed their last token by count/EOS
+        self.out_index = {}     # id -> the row of ``out`` it continues from
+        self.dropped = {}       # id -> request retired (EOS) a step ago
 
 
 class _Request:
@@ -896,6 +954,11 @@ class ContinuousBatchingEngine:
         # economics, read by the step-ring record (scheduler-thread only)
         self._last_spec = (0, 0)
         self._wedged = threading.Event()
+        # the ONE dispatched and uncommitted unified step, if any, and
+        # the moment the last step's results reached the host (the ring
+        # records' intervals start no earlier): scheduler-thread only
+        self._flight: Optional[_Step] = None
+        self._fetched_ns = 0
         self._stepping = False
         self._snap_waiters = 0
         # stall detection (ISSUE 4): while a compiled step is in flight
@@ -1710,7 +1773,10 @@ class ContinuousBatchingEngine:
                 continue
             keep: List[_Request] = []
             for r in lst:
-                err = r._lifecycle_error(now, queued=False)
+                # (one with a row in the step in flight waits for that
+                # step's commit: ``_riders_hold`` lands it first)
+                err = (None if self._rides(r)
+                       else r._lifecycle_error(now, queued=False))
                 if err is None and lst_name == "_preempted":
                     # resume-TTL (ISSUE 8 satellite): a paused prefill
                     # may hold its page reservation at most
@@ -1728,7 +1794,8 @@ class ContinuousBatchingEngine:
         if self._active:
             still: List[_Request] = []
             for r in self._active:
-                err = r._lifecycle_error(now, queued=False)
+                err = (None if self._rides(r)
+                       else r._lifecycle_error(now, queued=False))
                 if err is None:
                     still.append(r)
                 else:
@@ -1940,7 +2007,10 @@ class ContinuousBatchingEngine:
     def _pause_locked(self, victim, for_rank: int) -> None:
         """Caller holds ``self._cond``.  Move a preemption victim —
         mid-prefill or mid-decode — onto the paused list (seq id,
-        pages and reservation all kept)."""
+        pages and reservation all kept).  One with a row in the step in
+        flight is paused only after that step's commit."""
+        if self._rides(victim):
+            raise _LandFirst("preempt")
         if victim in self._prefilling:
             self._prefilling.remove(victim)
         else:
@@ -2436,22 +2506,109 @@ class ContinuousBatchingEngine:
                 drafts[i] = prop[j, :k]
         return drafts
 
-    def _unified_rollback(self, chunks, active, lens_before) -> None:
-        """Undo the unified composition after a failed (or wedged)
-        ragged dispatch, so the legacy re-run replays the EXACT same
-        step: appended decode tokens pop, every row's cache length
-        returns to its pre-step value (the decoder rolled its own
-        advance back on a host/device error; a wedge's advance stands
-        until this truncate), and speculative rows unwind the draft
-        cache the propose scan advanced."""
-        for req, _target, k, _n, _last in chunks:
-            self.cache.truncate(req.seq_id, k)
-        for r in active:
-            r.generated.pop()
-            tgt, dft = lens_before[r.seq_id]
-            self.cache.truncate(r.seq_id, tgt)
-            if dft is not None and self._spec:
-                self.draft_cache.truncate(r.seq_id, dft)
+    def _unified_rollback(self, step) -> None:
+        """Undo a unified step that failed (or wedged), or that was
+        dispatched over one that did, so that the SAME step can run
+        again: decode tokens appended at its launch pop, every row's
+        cache length returns to its value from before the step (the
+        decoder rolled its own advance back on a host/device error; a
+        wedge's advance stands until this truncate), speculative rows
+        unwind the draft cache the propose scan advanced, and what its
+        launch told the scheduler is taken back: chunk cursors, a
+        finished prefill's place among the decoding rows."""
+        with self._cond:
+            for req, _target, k, _n, _last in step.chunks:
+                self.cache.truncate(req.seq_id, k)
+                if step.launched:
+                    req.prefill_pos = k
+                    req.chunks_done -= 1
+            for req in step.moved:
+                if req in self._active:
+                    self._active.remove(req)
+                    self._prefilling.append(req)
+            for r in step.active:
+                if id(r) in step.dropped:
+                    # retired at its EOS a step ago: only its pages wait
+                    self._release_pages_locked(r)
+                    continue
+                if id(r) not in step.deferred:
+                    r.generated.pop()
+                tgt, dft = step.lens_before[r.seq_id]
+                self.cache.truncate(r.seq_id, tgt)
+                if dft is not None and self._spec:
+                    self.draft_cache.truncate(r.seq_id, dft)
+            step.dropped = {}
+
+    def _step_replaced(self) -> bool:
+        """True when something stands in for the decoder's
+        ``ragged_step`` (a test's wrapper, a subclass): the engine then
+        calls it whole, so whoever wrapped it sees every step, and
+        leaves no step in flight."""
+        from .paged import JittedPagedDecoder
+        dec = self._decoder
+        return ("ragged_step" in vars(dec) or type(dec).ragged_step
+                is not JittedPagedDecoder._RAGGED_STEP)
+
+    def _overlap_hold(self) -> Optional[str]:
+        """THE predicate of the one-step-deep pipeline: why no step may
+        be dispatched while another is uncommitted right now (the reason
+        ``serve_overlap_drains_total`` counts it by), or None.  Each is
+        something the engine sees in itself, never a model's name or a
+        caller's choice: a draft model (accept lengths decide the next
+        tokens and truncate the cache), sampling on the host (it picks
+        from logits), whole-prompt prefill (its own programs run before
+        the step), a legacy iteration or an installed fault plan (their
+        sites are defined against today's order), a stand-in for the
+        decoder's step, a snapshot that waits for a cut between steps,
+        stop and drain.  With a reason, an iteration runs in the old
+        order: schedule, dispatch, fetch, commit."""
+        if self._spec:
+            return "spec"
+        if not self.sample_on_device:
+            return "host_sampling"
+        if self.prefill_chunk_tokens is None:
+            return "unchunked"
+        if self._legacy_iteration():
+            return "legacy"
+        if _faults.active() is not None:
+            return "fault_plan"
+        if self._step_replaced():
+            return "replaced"
+        if self._snap_waiters:
+            return "snapshot"
+        if self._stop or self._draining:
+            return "drain"
+        return None
+
+    def _riders_hold(self) -> Optional[str]:
+        """Why the step in flight must be committed before the schedule
+        pass runs: a request with a row in it was cancelled or is past
+        its deadline, and the pass is about to reap it."""
+        now = time.perf_counter()
+        for r in self._flight.riders.values():
+            err = r._lifecycle_error(now, queued=False)
+            if err is not None:
+                return ("cancel" if isinstance(err, RequestCancelled)
+                        else "deadline")
+        return None
+
+    def _rides(self, req) -> bool:
+        """True while ``req`` has a row in the step in flight: nothing
+        but that step's commit may retire, pause or replay it."""
+        f = self._flight
+        return f is not None and id(req) in f.riders
+
+    def _land(self, reason: str) -> None:
+        """Commit the step in flight BEFORE anything else is dispatched
+        or any of its requests is moved, counted by ``reason``; the
+        loop is in today's order from here until a step is left in
+        flight again."""
+        step, self._flight = self._flight, None
+        _overlap_drains.inc(reason=reason)
+        self._commit_step(step)
+        with self._cond:
+            self._stepping = False      # a snapshot may take its cut
+            self._cond.notify_all()
 
     def _unified_step(self, plan, active=None, retried=False) -> None:
         """ONE ragged dispatch for the whole iteration (ISSUE 17): the
@@ -2466,6 +2623,12 @@ class ContinuousBatchingEngine:
         from ``_decode_step``, speculative accept consumption with
         partial rollback from ``_exec_spec_step``.
 
+        The step is two halves, :meth:`_launch_step` and
+        :meth:`_commit_step`; the loop (:meth:`_pipeline`) dispatches
+        the next step between them where :meth:`_overlap_hold` lets it.
+        This method runs them back to back, with nothing in flight: the
+        probes of :meth:`_isolate_unified`.
+
         On ANY failure the composition unwinds
         (:meth:`_unified_rollback`), pools rebuild + survivors replay
         if a device-side loss zeroed them, and the iteration re-runs
@@ -2477,6 +2640,46 @@ class ContinuousBatchingEngine:
         calls it again with the rows to probe (``active``: the decode
         rows, all of ``self._active`` if None; ``retried``: the whole
         step has had its second try)."""
+        step = self._launch_step(plan, active, retried)
+        if step is not None:
+            self._commit_step(step)
+
+    def _pipeline(self, plan) -> None:
+        """One iteration's device work, at most ONE step deep: dispatch
+        the step the schedule pass planned (its decode rows fed the
+        tokens of the step in flight on the device), THEN fetch and
+        commit the step in flight, and leave the new one in flight for
+        the next iteration — unless :meth:`_overlap_hold` has a reason,
+        and it is committed at once as it always was."""
+        step = self._launch_step(plan)
+        prev = self._flight     # read after: a failed launch landed it
+        self._flight = step
+        if prev is not None:
+            if step is None:
+                _overlap_drains.inc(reason="idle")  # nothing to dispatch
+            self._commit_step(prev)     # a failure unwinds ``step`` too
+        step = self._flight
+        if step is not None:
+            why = self._overlap_hold()
+            if why is not None:
+                self._land(why)
+
+    def _launch_step(self, plan, active=None, retried=False):
+        """The first half of a unified step: compose the rows, dispatch
+        them, and tell the scheduler what does not depend on a token's
+        value — chunk cursors, a finished prefill's move to the decoding
+        rows, who will not continue because the token it is fed is its
+        last by count.  Returns the :class:`_Step` to commit, or None
+        when there was nothing to dispatch or the dispatch failed (and
+        was dealt with: :meth:`_step_failed`, or — over a step in
+        flight — unwound, to be planned again once that one landed).
+
+        A decode row of a request that has a row in the step in flight
+        takes its token from that step's output on the device
+        (``ragged_launch``'s ``feed``); the host learns it at that
+        step's commit and appends it to ``generated`` at THIS step's, so
+        ``generated`` only ever holds ints the host has."""
+        prev = self._flight
         chunks = []
         for req, n in plan:
             if req.cancelled or req.done.is_set():
@@ -2487,32 +2690,50 @@ class ContinuousBatchingEngine:
             chunks.append((req, target, k, n, k + n == len(target)))
         # a probe's rows: one ejected since (its replay failed during a
         # sibling's recovery) is never stepped again
-        probe = active is not None
-        active = ([r for r in active if r in self._active] if probe
-                  else list(self._active))
+        if active is not None:
+            active = [r for r in active if r in self._active]
+        else:
+            active = [r for r in self._active
+                      if prev is None or id(r) not in prev.leaving]
         if not chunks and not active:
-            return
+            return None
         spec = self._spec and any(r.use_draft for r in active)
-        k_spec = self.spec_k if spec else 0
-        lens_before = {
+        step = _Step(plan, chunks, active, retried, spec,
+                     self.spec_k if spec else 0)
+        step.overlapped = prev is not None
+        step.index = self.steps + (1 if prev is not None and prev.active
+                                   else 0)
+        step.lens_before = {
             r.seq_id: (self.cache.length(r.seq_id),
                        (self.draft_cache.length(r.seq_id)
                         if self._spec and r.use_draft else None))
             for r in active}
-        jlens = ({id(r): len(r.generated) for r in active}
-                 if self.journal is not None else None)
-        for r in active:
-            r.generated.append(r.next_token)
+        nchunks = len(chunks)
+        src = [-1] * (nchunks + len(active))
+        for i, r in enumerate(active):
+            at = prev.out_index.get(id(r)) if prev is not None else None
+            if at is None:
+                r.generated.append(r.next_token)
+                fed = len(r.generated)
+                eos = (r.eos_token_id is not None
+                       and r.next_token == r.eos_token_id)
+            else:               # its token is still on the device
+                step.deferred.add(id(r))
+                src[nchunks + i] = at
+                fed = len(r.generated) + (id(r) in prev.deferred) + 1
+                eos = False
+            if eos or fed >= r.max_new_tokens:
+                step.leaving.add(id(r))     # this token is its last
         if active:
             _active_seqs.set(len(active))
             _batch_occupancy.observe(len(active) / self.max_batch)
             _sampling_on_device_g.set(int(self.sample_on_device))
-        drafts = None
-        t_tr = _tracer.now_ns() if _tracer.enabled else 0
+        step.t_ns = _tracer.now_ns()
+        step.traced = _tracer.enabled
         try:
             if spec:
-                drafts = self._propose_drafts(active)
-            nchunks = len(chunks)
+                step.drafts = self._propose_drafts(active)
+            k_spec, drafts = step.k_spec, step.drafts
             with monitor.span("engine/build"):
                 seq_ids, rows, ctxs, nds = [], [], [], []
                 for req, target, k, n, _last in chunks:
@@ -2528,7 +2749,9 @@ class ContinuousBatchingEngine:
                         row[1:] = drafts[i]
                         nds.append(k_spec)
                     else:
-                        row = np.asarray([r.generated[-1]], np.int32)
+                        row = np.asarray(
+                            [0 if id(r) in step.deferred
+                             else r.generated[-1]], np.int32)
                         nds.append(0)
                     rows.append(row)
                     ctxs.append(self.cache.length(r.seq_id))
@@ -2553,64 +2776,150 @@ class ContinuousBatchingEngine:
                     sampling = (seeds, temps, flags)
                 else:
                     sampling = None
-            self._wedged.clear()
-            t0 = self._step_started_at = time.monotonic()
-            try:
-                # only delay-kind pacing rules can be live here
-                # (_legacy_iteration diverts everything else): fire
-                # the legacy sites so throttling plans — per-row
-                # seq_id targeting included — pace the unified step
-                # exactly as they pace the composition it replaces
-                for req, _t, k, _n, _l in chunks:
-                    if not k:
-                        _faults.maybe_fire("prefill",
-                                           seq_ids=[req.seq_id])
-                    _faults.maybe_fire("prefill_chunk",
+                step.sampled = sampling is not None
+            step.t0 = time.monotonic()
+            if prev is None:
+                # only THIS dispatch may flag itself; over a step in
+                # flight the heartbeat keeps that step's start
+                self._wedged.clear()
+                self._step_started_at = step.t0
+            # only delay-kind pacing rules can be live here
+            # (_legacy_iteration diverts everything else): fire
+            # the legacy sites so throttling plans — per-row
+            # seq_id targeting included — pace the unified step
+            # exactly as they pace the composition it replaces
+            for req, _t, k, _n, _l in chunks:
+                if not k:
+                    _faults.maybe_fire("prefill",
                                        seq_ids=[req.seq_id])
-                if active:
-                    _faults.maybe_fire(
-                        "decode_step",
-                        seq_ids=[r.seq_id for r in active])
-                hist = _decode_step_s if active else _prefill_s
-                t_disp = _tracer.now_ns() if t_tr else 0
-                with monitor.span("engine/ragged_step", histogram=hist):
-                    self._count_dispatch("ragged")
-                    out, accept = self._decoder.ragged_step(
-                        self.cache, seq_ids, rows, ctxs,
-                        n_drafts=(nds if spec else None),
-                        sampling=sampling)
-                    self._check_wedged(t0)
-            finally:
-                self._step_started_at = None
-            _last_step_ts.set(time.time())
+                _faults.maybe_fire("prefill_chunk",
+                                   seq_ids=[req.seq_id])
+            if active:
+                _faults.maybe_fire(
+                    "decode_step",
+                    seq_ids=[r.seq_id for r in active])
+            with monitor.span("engine/ragged_step"):
+                self._count_dispatch("ragged")
+                step_args = dict(n_drafts=(nds if spec else None),
+                                 sampling=sampling)
+                if prev is None and self._step_replaced():
+                    step.result = self._decoder.ragged_step(
+                        self.cache, seq_ids, rows, ctxs, **step_args)
+                    step.record = self._decoder.last_dispatch
+                else:
+                    step.flight = self._decoder.ragged_launch(
+                        self.cache, seq_ids, rows, ctxs, **step_args,
+                        feed=((prev.flight, src) if step.deferred
+                              else None))
+                    step.record = step.flight.record
         except BaseException as e:  # noqa: BLE001 — legacy owns isolation
-            self._unified_rollback(chunks, active, lens_before)
-            _unified_fallbacks.inc()
-            self._unified_failures += 1
-            if self._unified_failures >= 3 and not self._unified_off \
-                    and not self._recurrent:
-                with self._cond:
-                    self._disable_unified_locked()
-            # a device-side loss zeroed every survivor's KV: rebuild +
-            # replay BEFORE the legacy re-run decodes over zeroed pages
-            # (replay-dead requests are quarantined/ejected in here)
+            if prev is None:
+                self._step_started_at = None
+                self._step_failed(step, e)
+                return None
+            # over a step in flight nothing can be isolated yet (the
+            # ladder needs every token on the host): take this step
+            # back, land that one, repair what this failure cost the
+            # pools, and let the next iteration plan the step again
+            self._unified_rollback(step)
+            self._land("launch_failed")
             self._after_step_failure(e)
-            if self._recurrent:
-                self._isolate_unified(chunks, active, e, retried)
-                return
-            self._run_chunks(plan)
-            if self._active:
-                self._decode_step()
+            return None
+        # ---- what the next schedule pass must see of this step
+        for req, _target, k, n, _last in chunks:
+            req.prefill_pos = k + n
+            req.chunks_done += 1
+            step.chunk_no.append(req.chunks_done)
+        step.launched = True
+        with self._cond:
+            for i, (req, _t, _k, _n, last) in enumerate(chunks):
+                if not last:
+                    continue
+                if req in self._prefilling:
+                    self._prefilling.remove(req)
+                    self._active.append(req)
+                    step.moved.append(req)
+                if req.next_token is None:  # else: restored, it has one
+                    step.out_index[id(req)] = i
+        for i, r in enumerate(active):
+            if id(r) not in step.leaving:
+                step.out_index[id(r)] = nchunks + i
+        step.riders = {id(r): r for r in
+                       [c[0] for c in chunks] + active}
+        if step.overlapped:
+            _steps_overlapped.inc()
+        return step
+
+    def _step_failed(self, step, error) -> None:
+        """A unified step failed with nothing else in flight: unwind
+        it, repair the pools, and go down today's ladder with ITS
+        composition."""
+        self._unified_rollback(step)
+        _unified_fallbacks.inc()
+        self._unified_failures += 1
+        if self._unified_failures >= 3 and not self._unified_off \
+                and not self._recurrent:
+            with self._cond:
+                self._disable_unified_locked()
+        # a device-side loss zeroed every survivor's KV: rebuild +
+        # replay BEFORE the legacy re-run decodes over zeroed pages
+        # (replay-dead requests are quarantined/ejected in here)
+        self._after_step_failure(error)
+        if self._recurrent:
+            self._isolate_unified(step.chunks, step.active, error,
+                                  step.retried)
             return
+        self._run_chunks(step.plan)
+        if self._active:
+            self._decode_step()
+
+    def _commit_step(self, step) -> bool:
+        """The second half of a unified step: fetch its outputs and do
+        everything that depends on a token's value — ``generated``,
+        ``next_token``, ``first_token_at``, the journal's rows,
+        retirement, ``done``.  ``self._flight`` is the step dispatched
+        over this one, if any: a request that retires here at its EOS
+        with a row in that step keeps its pages until that step lands
+        (:meth:`_retire_locked`), and a failure here unwinds that step
+        first.  Returns False when the step failed (and the ladder has
+        run)."""
+        newer = self._flight
+        try:
+            out, accept = (step.result if step.flight is None else
+                           self._decoder.ragged_fetch(step.flight))
+            fetched_ns = _tracer.now_ns()
+            self._step_started_at = None if newer is None else newer.t0
+            self._check_wedged(step.t0)
+        except BaseException as e:  # noqa: BLE001 — legacy owns isolation
+            self._step_started_at = None
+            if newer is not None:
+                self._flight = None
+                self._decoder.ragged_discard(newer.flight)
+                self._unified_rollback(newer)
+            self._step_failed(step, e)
+            return False
+        _last_step_ts.set(time.time())
+        chunks, active = step.chunks, step.active
+        spec, k_spec, drafts = step.spec, step.k_spec, step.drafts
+        nchunks = len(chunks)
+        # the step's ONE interval, which no neighbour's overlaps: from
+        # the later of its dispatch and the moment the step before it
+        # reached the host, to the moment it did
+        start_ns = max(step.t_ns, self._fetched_ns)
+        self._fetched_ns = fetched_ns
+        (_decode_step_s if active else _prefill_s).observe(
+            (fetched_ns - start_ns) / 1e9)
         with monitor.span("engine/commit"):
             self._unified_failures = 0
-            now_ns = _tracer.now_ns() if _tracer.enabled and t_tr else 0
-            if now_ns:
+            traced = _tracer.enabled and step.traced
+            if traced:
                 # what the decoder says it dispatched: the (rows, span,
                 # table) bucket against the real tokens and contexts
-                _tracer.step_record("dispatch", self.steps, t_disp,
-                                    now_ns, **self._decoder.last_dispatch)
-            for name, value in self._decoder.last_dispatch.items():
+                _tracer.step_record("dispatch", step.index, start_ns,
+                                    fetched_ns,
+                                    overlapped=int(step.overlapped),
+                                    **step.record)
+            for name, value in step.record.items():
                 if name in _STEP_SUMS:
                     _STEP_SUMS[name].inc(value)
                 elif name == "kv_window_dead_pages":
@@ -2618,81 +2927,88 @@ class ContinuousBatchingEngine:
                 elif name == "slots_zeroed":
                     _slots_zeroed.inc(value)
             # ---- chunk rows: the legacy _prefill_chunk bookkeeping
-            completed: List[_Request] = []
             for i, (req, _target, k, n, last) in enumerate(chunks):
-                req.prefill_pos = k + n
-                req.chunks_done += 1
                 self._sched.note_chunk(req)
-                if _tracer.enabled and t_tr:
+                if traced:
                     _tracer.step_record(
-                        "prefill_chunk", self.steps, t_tr, now_ns,
+                        "prefill_chunk", step.index, start_ns, fetched_ns,
                         request=req.request_id, tokens=n, pos=k,
                         cls=req.priority)
                     _tracer.request_event(req.request_id, "prefill_chunk",
                                           tokens=n, pos=k,
-                                          chunk=req.chunks_done)
+                                          chunk=step.chunk_no[i])
                 if last:
-                    completed.append(req)
-                    self._finish_prefill(req, out[i], sampling is not None)
+                    self._finish_prefill(req, out[i], step.sampled)
             # ---- decode/verify rows: the legacy _decode_step retirement
-            still, retired = [], []
+            # (a row whose request met its EOS a step ago is dropped)
+            dropped = step.dropped
+            rows_at = [(nchunks + i, r) for i, r in enumerate(active)
+                       if id(r) not in dropped]
+            live = [r for _, r in rows_at]
+            retired = []
             accepted_emitted = 0
-            if active:
+            if live:
+                jlens = {}
+                for r in live:
+                    jlens[id(r)] = len(r.generated) - (
+                        id(r) not in step.deferred)
+                    if id(r) in step.deferred:
+                        # the token this step fed it: the host has had
+                        # it since the step before was committed
+                        r.generated.append(r.next_token)
                 srows = []
-                d_idx = ([i for i, r in enumerate(active) if r.use_draft]
+                d_idx = ([i for i, r in enumerate(live) if r.use_draft]
                          if spec else [])
-                for i, r in enumerate(active):
+                for i, (at, r) in enumerate(rows_at):
                     if spec:
-                        a = int(accept[nchunks + i])
+                        a = int(accept[at])
                         # page-granular partial rollback, both caches —
                         # the _exec_spec_step contract
-                        new_len = lens_before[r.seq_id][0] + a + 1
+                        new_len = step.lens_before[r.seq_id][0] + a + 1
                         self.cache.truncate(r.seq_id, new_len)
                         if r.use_draft:
                             self.draft_cache.truncate(r.seq_id, new_len)
-                        srows.append(_SpecRow(out[nchunks + i], a,
-                                              drafts[i]))
+                        srows.append(_SpecRow(out[at], a,
+                                              drafts[at - nchunks]))
                     else:
-                        srows.append(out[nchunks + i])
+                        srows.append(out[at])
                 if spec:
-                    self._last_spec = (
-                        k_spec * len(d_idx),
-                        sum(int(accept[nchunks + i]) for i in d_idx))
+                    acc = [int(accept[rows_at[i][0]]) for i in d_idx]
+                    self._last_spec = (k_spec * len(d_idx), sum(acc))
                     if d_idx:
                         _spec_proposed.inc(k_spec * len(d_idx))
                         _spec_accepted.inc(self._last_spec[1])
-                        rejected = 0
-                        for i in d_idx:
-                            _spec_accept_len.observe(
-                                int(accept[nchunks + i]))
-                            rejected += int(accept[nchunks + i]) < k_spec
+                        for a in acc:
+                            _spec_accept_len.observe(a)
+                        rejected = sum(a < k_spec for a in acc)
                         if rejected:
                             _spec_rollback.inc(rejected)
                     _spec_draft_pages.set(self.draft_cache.pinned_pages)
                 else:
                     self._last_spec = (0, 0)
-                if _tracer.enabled and t_tr:
+                if traced:
                     comp: dict = {}
-                    for r in active:
+                    for r in live:
                         comp[r.priority] = comp.get(r.priority, 0) + 1
                     prop, acc = self._last_spec
                     _tracer.step_record(
-                        "decode", self.steps, t_tr, now_ns,
-                        batch=len(active), classes=comp,
+                        "decode", step.index, start_ns, fetched_ns,
+                        batch=len(live), classes=comp,
                         spec_proposed=prop, spec_accepted=acc, poisoned=0,
-                        requests=[r.request_id for r in active])
-                _tokens_total.inc(len(active))
+                        requests=[r.request_id for r in live])
+                _tokens_total.inc(len(live))
                 on_device = self.sample_on_device
-                for r, row in zip(active, srows):
+                still = []
+                for r, row in zip(live, srows):
                     if _tracer.enabled:
                         if isinstance(row, _SpecRow):
                             _tracer.request_event(
                                 r.request_id, "verify_step",
-                                step=self.steps, accept=int(row.accept))
+                                step=step.index, accept=int(row.accept))
                         else:
                             _tracer.request_event(r.request_id,
                                                   "decode_step",
-                                                  step=self.steps)
+                                                  step=step.index)
                     eos_hit = (r.eos_token_id is not None
                                and r.generated[-1] == r.eos_token_id)
                     if eos_hit or len(r.generated) >= r.max_new_tokens:
@@ -2728,24 +3044,27 @@ class ContinuousBatchingEngine:
             with self._cond:
                 if active:
                     self.steps += 1
-                    for r in retired:
-                        self._retire_locked(r)
-                    if probe:       # the rows it did not step stay
-                        gone = {id(r) for r in retired}
-                        still = [r for r in self._active
-                                 if id(r) not in gone]
-                    self._active = still
-                    if not still:
-                        self._free_pads_locked()
-                for r in completed:
-                    if r in self._prefilling:
-                        self._prefilling.remove(r)
-                        self._active.append(r)
+                for r in retired:
+                    self._retire_locked(r)
+                for r in dropped.values():
+                    self._release_pages_locked(r)
+                if retired:
+                    # the rows this step did not carry stay: a probe's
+                    # siblings, the prefills finished by the steps since
+                    gone = {id(r) for r in retired}
+                    self._active = [r for r in self._active
+                                    if id(r) not in gone]
+                if active and not self._active:
+                    self._free_pads_locked()
                 self._cond.notify_all()
+            if dropped:
+                _overlap_dropped_rows.inc(len(dropped))
+                step.dropped = {}
             if active:
-                _active_seqs.set(len(still))
+                _active_seqs.set(len(self._active))
             for r in retired:
                 r.done.set()
+        return True
 
     def _isolate_unified(self, chunks, active, error, retried) -> None:
         """The legacy ladder (:meth:`_step_isolated`, :meth:`_bisect_step`)
@@ -2818,13 +3137,13 @@ class ContinuousBatchingEngine:
         pages it never allocated, plus each held page that stopped being
         pinned (a shared page another live sharer still maps keeps its
         reservation — it transfers to that sharer's accounting)."""
-        slack = (self._pages_for(req)
-                 - len(self.cache._seq_pages.get(req.seq_id, ())))
-        released = self.cache.free(req.seq_id)
-        self._reserved_pages -= slack + released
-        if self._recurrent:
-            _slots_in_use_g.set(self.cache.slots_in_use)
-        self._release_draft_locked(req)
+        if self._rides(req):
+            # it met its EOS with its next row already on the device:
+            # that step writes into these pages and this slot, so they
+            # go back when it lands (``_commit_step`` drops the row)
+            self._flight.dropped[id(req)] = req
+        else:
+            self._release_pages_locked(req)
         req.finished_at = time.perf_counter()
         if req.error is None:
             _gen_latency_s.observe(req.finished_at - req.submitted_at)
@@ -2835,6 +3154,17 @@ class ContinuousBatchingEngine:
             req.request_id, "retire", ok=req.error is None,
             generated=len(req.generated),
             latency_s=round(req.finished_at - req.submitted_at, 6))
+
+    def _release_pages_locked(self, req) -> None:
+        """Caller holds ``self._cond``.  The capacity half of a
+        retirement (see :meth:`_retire_locked`)."""
+        slack = (self._pages_for(req)
+                 - len(self.cache._seq_pages.get(req.seq_id, ())))
+        released = self.cache.free(req.seq_id)
+        self._reserved_pages -= slack + released
+        if self._recurrent:
+            _slots_in_use_g.set(self.cache.slots_in_use)
+        self._release_draft_locked(req)
 
     def _bucket(self, n: int) -> int:
         from .paged import next_pow2
@@ -3610,6 +3940,9 @@ class ContinuousBatchingEngine:
         with self._cond:
             queued = self._sched.pop_all()
             holders = self._active + self._prefilling + self._preempted
+            # a step in flight is abandoned with its rows (they are among
+            # the holders); one it dropped is retired and holds pages only
+            zombies = self._abandon_flight_locked()
             for r in holders + queued:
                 if r.done.is_set():
                     continue
@@ -3625,7 +3958,7 @@ class ContinuousBatchingEngine:
                 # the journal must not resurrect it after a restart
                 self._journal_retire(r)
                 r.done.set()
-            for r in holders:
+            for r in holders + zombies:
                 if r.seq_id is not None:
                     self.cache.free(r.seq_id)
                     if self._spec:
@@ -3641,12 +3974,25 @@ class ContinuousBatchingEngine:
             _queue_depth.set(0)
             self._cond.notify_all()
 
+    def _abandon_flight_locked(self) -> List[_Request]:
+        """Caller holds ``self._cond``.  Forget the step in flight
+        without committing it (hard stop, last-resort failure): the
+        device finishes it on its own and nobody reads it.  Returns the
+        requests it had dropped, which are retired and only wait for
+        their pages to go back."""
+        step, self._flight = self._flight, None
+        self._step_started_at = None
+        if step is None:
+            return []
+        zombies, step.dropped = list(step.dropped.values()), {}
+        return zombies
+
     def _loop(self):
         while True:
             with self._cond:
                 while not self._stop and not len(self._sched) \
                         and not self._active and not self._prefilling \
-                        and not self._preempted:
+                        and not self._preempted and self._flight is None:
                     # brownout is a property of LOAD: an engine with
                     # nothing queued and nothing running is not
                     # browned out, whatever the ladder last latched —
@@ -3657,6 +4003,8 @@ class ContinuousBatchingEngine:
                     with monitor.span("engine/wait"):
                         self._cond.wait(timeout=0.5)
                 if self._stop:
+                    for r in self._abandon_flight_locked():
+                        self._release_pages_locked(r)
                     self._free_pads_locked()
                     stopped = (self._sched.pop_all() + self._prefilling
                                + self._preempted + self._active)
@@ -3670,7 +4018,8 @@ class ContinuousBatchingEngine:
                     return
             # one iteration; the unified step's is one ``engine/step
             # <index>`` span on the profiler's clock, <index> being the
-            # ``index`` its step-ring records carry
+            # ``index`` of the step-ring records of the step it COMMITS
+            # (with a step in flight it dispatches the one after that)
             if self._legacy_iteration():
                 self._iteration(True)
             else:
@@ -3680,34 +4029,55 @@ class ContinuousBatchingEngine:
     def _iteration(self, legacy: bool) -> None:
         """One pass of the scheduler thread: the scheduling pass under
         the lock (``engine/schedule``), the device work outside it, the
-        journal flush (``engine/commit``)."""
+        journal flush (``engine/commit``).  With a step in flight
+        (:meth:`_pipeline`) the pass plans the step AFTER it, from what
+        its launch told the scheduler; it is committed first instead
+        where :meth:`_overlap_hold` says so, where one of its requests
+        is about to be reaped (:meth:`_riders_hold`) or paused
+        (:class:`_LandFirst`)."""
+        reaped: List[_Request] = []
         try:
-            with monitor.span("engine/schedule"), self._cond:
-                reaped = self._reap_locked()
-                # closed-loop overload protection (ISSUE 19): one
-                # controller evaluation per iteration — the ladder
-                # first (its level gates this iteration's sheds),
-                # then the TPOT trigger (its freed slot is visible
-                # to the admission pass below)
-                self._update_brownout_locked()
-                self._tpot_preempt_locked()
-                self._admit_locked()
-                plan = self._plan_chunks_locked()
-                # snapshot barrier (ISSUE 8): a waiting snapshot()
-                # reads its consistent between-steps cut before the
-                # next device batch opens (the wait releases the
-                # lock; nothing below mutates what was planned)
-                while self._snap_waiters and not self._stop:
-                    self._cond.wait(0.1)
-                self._stepping = bool(plan) or bool(self._active)
+            if self._flight is not None:
+                why = (("legacy" if legacy else None)
+                       or self._overlap_hold() or self._riders_hold())
+                if why is not None:
+                    self._land(why)
+            while True:
+                try:
+                    with monitor.span("engine/schedule"), self._cond:
+                        reaped += self._reap_locked()
+                        # closed-loop overload protection (ISSUE 19): one
+                        # controller evaluation per iteration — the ladder
+                        # first (its level gates this iteration's sheds),
+                        # then the TPOT trigger (its freed slot is visible
+                        # to the admission pass below)
+                        self._update_brownout_locked()
+                        self._tpot_preempt_locked()
+                        self._admit_locked()
+                        plan = self._plan_chunks_locked()
+                        # snapshot barrier (ISSUE 8): a waiting snapshot()
+                        # reads its consistent between-steps cut before the
+                        # next device batch opens (the wait releases the
+                        # lock; nothing below mutates what was planned).
+                        # With a step in flight there is no such cut yet:
+                        # the next pass lands it first (``snapshot`` hold)
+                        while self._snap_waiters and not self._stop \
+                                and self._flight is None:
+                            self._cond.wait(0.1)
+                        self._stepping = (bool(plan) or bool(self._active)
+                                          or self._flight is not None)
+                    break
+                except _LandFirst as first:
+                    self._land(first.reason)
         except BaseException as e:  # noqa: BLE001 — scheduler fault
             # a bug in admission/reaping must fail the in-flight
             # requests LOUDLY, never kill this thread silently and
             # leave every waiter blocked on a dead engine
             self._fail_all(e)
             return
-        for r in reaped:
-            r.done.set()
+        finally:
+            for r in reaped:
+                r.done.set()
         # TPOT signal (ISSUE 19): for an active row one iteration
         # is one output token, so the whole iteration's wall time —
         # chunks included — is the per-token latency the budget is
@@ -3737,7 +4107,7 @@ class ContinuousBatchingEngine:
                     # into the ragged dispatch.
                     self._run_chunks(plan)
                     plan = ()
-                self._unified_step(plan)
+                self._pipeline(plan)
         except BaseException as e:  # noqa: BLE001 — fail loudly, not hang
             self._fail_all(e)
         finally:
@@ -3752,7 +4122,10 @@ class ContinuousBatchingEngine:
             # are ignored at replay: their retire precedes them)
             with monitor.span("engine/commit"):
                 self._journal_flush_step()
-            if self._stepping:
+            # the snapshot barrier's flag stays up while a step is in
+            # flight: there is no between-steps cut to read
+            stepping = self._flight is not None
+            if self._stepping != stepping:
                 with self._cond:
-                    self._stepping = False
+                    self._stepping = stepping
                     self._cond.notify_all()

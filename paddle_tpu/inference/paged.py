@@ -551,6 +551,49 @@ class _TracedPagedContext:
         return wrap_array(out)
 
 
+#: rows the feed's index and token vectors are padded to (the rows
+#: bucket where that is more), so that neither of its two programs
+#: follows BOTH steps' shapes: the gather compiles once a rows bucket of
+#: the step fed from, the select once a (rows, span) of the step fed
+FEED_ROWS = 256
+
+
+@jax.jit
+def _feed_tokens(prev_out, src):
+    """(R,) the token each fed row continues from: row ``src`` of the
+    earlier step's ``out`` (B',), still on the device; a row that is not
+    fed (-1) reads row 0 and is not selected."""
+    return jnp.take(prev_out, jnp.maximum(src, 0), axis=0)
+
+
+@jax.jit
+def _feed_ids(ids, tokens, src):
+    """The (B, S) ``ids`` operand of a step with a predecessor in
+    flight: a fed row's first token from ``tokens``, everything else as
+    the host packed it."""
+    b = ids.shape[0]
+    return ids.at[:, 0].set(
+        jnp.where(src[:b] >= 0, tokens[:b], ids[:, 0]))
+
+
+class RaggedFlight:
+    """One ragged step that is dispatched and not fetched
+    (:meth:`JittedPagedDecoder.ragged_launch`): the program's three small
+    outputs, still on the device; what undoes the step if they never
+    arrive (``seq_ids`` at ``before``); and the step's OWN dispatch
+    record, which becomes the decoder's ``last_dispatch`` when it is
+    fetched (a later launch has started another by then)."""
+
+    __slots__ = ("cache", "seq_ids", "before", "out", "accept", "counted",
+                 "record")
+
+    def __init__(self, cache, seq_ids, before, out, accept, counted,
+                 record):
+        self.cache, self.seq_ids, self.before = cache, seq_ids, before
+        self.out, self.accept, self.counted = out, accept, counted
+        self.record = record
+
+
 class JittedPagedDecoder:
     """One-compiled-program decode step: embed + every layer's rope /
     paged write / paged attention / MLP + logits, with the page pools
@@ -695,7 +738,8 @@ class JittedPagedDecoder:
         self._programs = {}              # (mode, sample) -> jitted fn
         self._program_fns = {}           # (mode, sample) -> raw traced fn
         self._jitted_multi = None        # built on first multi_step use
-        self.last_dispatch = None        # ragged_step's last bucket
+        self.last_dispatch = None        # the last FETCHED step's record
+        self._feed_warm = set()          # (rows, span) whose feed compiled
 
     # -------------------------------------------------- compiled programs
     def packed_tokens(self, rows: int, span: int) -> int:
@@ -1121,7 +1165,7 @@ class JittedPagedDecoder:
             self.DONATE_ARGNUMS[mode]
 
     @staticmethod
-    def _recover_pools(cache):
+    def _recover_pools(cache, ran=False):
         """After a failed compiled call, rebuild the page pools ONLY if
         the donated buffers were actually consumed (dispatch reached
         the device/runtime).  A host-side failure before dispatch — a
@@ -1129,14 +1173,28 @@ class JittedPagedDecoder:
         valid, and keeping them preserves every OTHER sequence's cached
         KV and the prefix index: the quarantine machinery (ISSUE 4)
         depends on a poisoned request not zeroing its batchmates'
-        state."""
+        state.
+
+        ``ran``: the call was dispatched and its outputs never arrived
+        (:meth:`ragged_fetch`).  The cache then holds the program's
+        returned pools: they are rebuilt if the execution left an error
+        in them, and always where rows hold a recurrent slot, which the
+        step updated in place and no length rolls back."""
         def dead(a):
             fn = getattr(a, "is_deleted", None)
             try:
                 return bool(fn()) if callable(fn) else False
             except Exception:   # noqa: BLE001 — treat unknown as dead
                 return True
-        if any(dead(a) for a in cache._device_pools()):
+        pools = cache._device_pools()
+        lost = any(dead(a) for a in pools)
+        if ran and not lost:
+            try:
+                jax.block_until_ready(pools)
+                lost = bool(cache.state_pools)
+            except Exception:   # noqa: BLE001 — the execution's own error
+                lost = True
+        if lost:
             cache.reset_pools()
 
     def _rollback_lengths(self, cache, seq_ids, before):
@@ -1522,7 +1580,36 @@ class JittedPagedDecoder:
         selected position's logits rows on the ``sampling=None`` escape
         hatch.  The CALLER rolls verify rows back to their accepted
         length with ``cache.truncate`` (same contract as
-        :meth:`verify`)."""
+        :meth:`verify`).
+
+        This is :meth:`ragged_launch` followed at once by
+        :meth:`ragged_fetch`: a caller that has host work to do while
+        the step runs calls the halves itself."""
+        return self.ragged_fetch(
+            self.ragged_launch(cache, seq_ids, rows, ctxs,
+                               n_drafts=n_drafts, sampling=sampling))
+
+    #: the composition above, for whoever must know that nothing stands in
+    #: for it (the engine leaves a step in flight only then)
+    _RAGGED_STEP = ragged_step
+
+    def ragged_launch(self, cache: PagedKVCache, seq_ids, rows, ctxs,
+                      n_drafts=None, sampling=None, feed=None):
+        """The first half of :meth:`ragged_step`: check, reserve, pack
+        and DISPATCH the step, and come back without waiting for it.
+        The cache is advanced and holds the program's returned pools at
+        once (they are its outputs whether or not it has run: the next
+        call donates them in order); the three small outputs stay on the
+        device in the :class:`RaggedFlight` handed back, which
+        :meth:`ragged_fetch` turns into ``(out, accept)``.
+
+        ``feed`` = (an earlier step's flight, not necessarily fetched;
+        for each row here the index of the row in THAT step's ``out``
+        whose emitted token is this row's first, or -1).  A fed row's
+        first token is taken from the device array (``_feed_ids``: a
+        small program of its own, so the ragged program and its operands
+        are what they are without a feed), whatever ``rows`` holds
+        there; the earlier step must have sampled (``out`` is ids)."""
         with monitor.span("engine/build"):
             b = len(seq_ids)
             ns = [len(r) for r in rows]
@@ -1619,13 +1706,14 @@ class JittedPagedDecoder:
                     np.concatenate([np.asarray(flags, bool),
                                     np.zeros(pad, bool)]))
         # what this dispatch computes against what it was asked for: the
-        # engine writes it into the step ring as the ``dispatch`` record.
+        # engine writes it into the step ring as the ``dispatch`` record
+        # (the flight's own: a later launch starts another).
         # ``rows_padded`` x ``span_padded`` is the paged kernel's query
         # rectangle, ``tokens_padded`` the positions every other layer
         # computes.  ``kv_tokens_walked`` is what the paged kernel walks
         # for these rows: each row's context in whole blocks, by the
         # kernel's own rule (pad rows are one token long)
-        self.last_dispatch = {
+        record = {
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
             "tokens": sum(ns), "tokens_padded": t_b,
             "table_pages": W, "page_size": cache.page_size,
@@ -1635,7 +1723,7 @@ class JittedPagedDecoder:
             # built for ``chunk_rows_padded`` of them) and their tokens,
             # the slots there are, and the rows that enter their slot
             # with an empty context: the step that zeroes it
-            self.last_dispatch.update(
+            record.update(
                 chunk_rows_padded=len(recur[1]),
                 state_chunk_rows=len(multi),
                 state_chunk_tokens=sum(ns[i] for i in multi),
@@ -1645,9 +1733,14 @@ class JittedPagedDecoder:
             sample, s_args = self._verify_sampling_args(sampling)
             try:
                 _maybe_lose_buffers(cache, seq_ids)
+                if sample:
+                    self._warm_feed(b_b, s_b)
+                ids = jnp.asarray(ids)
+                if feed is not None:
+                    ids = self._fed_ids(ids, b, *feed)
                 out, accept, counted, *pools = self._program(
                     "ragged", sample)(
-                    self._param_arrays(), jnp.asarray(ids),
+                    self._param_arrays(), ids,
                     jnp.asarray(ctx_arr), jnp.asarray(ql),
                     jnp.asarray(pg.reshape(-1)),
                     jnp.asarray(sl.reshape(-1)),
@@ -1660,20 +1753,81 @@ class JittedPagedDecoder:
                 self._recover_pools(cache)
                 self._rollback_lengths(cache, seq_ids, before)
                 raise
-        with monitor.span("engine/fetch"):
             self._store_pools(cache, *pools)
-            if counted:
-                self.last_dispatch.update(zip(
-                    self._step_counts,
-                    (int(v) for v in np.asarray(counted[0]))))
+        return RaggedFlight(cache, list(seq_ids), before, out, accept,
+                            counted, record)
+
+    def _warm_feed(self, rows: int, span: int) -> None:
+        """The feed's two programs compile where the ragged program of
+        their shape does: the first launch of a (rows, span) bucket runs
+        the gather on a step of ``rows`` rows' ``out`` and the select on
+        a (rows, span) ``ids``, over zeros and no fed row, so a window
+        that meets no new ragged program meets no new feed either,
+        whichever step feeds which."""
+        if (rows, span) in self._feed_warm:
+            return
+        src = jnp.asarray(np.full(max(FEED_ROWS, rows), -1, np.int32))
+        tokens = _feed_tokens(jnp.asarray(np.zeros(rows, np.int32)), src)
+        _feed_ids(jnp.asarray(np.zeros((rows, span), np.int32)), tokens, src)
+        self._feed_warm.add((rows, span))
+
+    @staticmethod
+    def _fed_ids(ids, b, flight, src):
+        """``ids`` with each fed row's first token taken from
+        ``flight.out`` on the device (``src``: the row's index there, or
+        -1), the rest as the host packed them."""
+        src = np.asarray(src, np.int32)
+        if src.shape != (b,) or int(src.max(initial=-1)) >= len(
+                flight.seq_ids):
+            raise ValueError(
+                f"the feed names rows {src.tolist()} of a step of "
+                f"{len(flight.seq_ids)} rows, for {b} rows")
+        if flight.out.ndim != 1:
+            raise ValueError("a step that handed back logits has no token "
+                             "on the device to feed the next one")
+        padded = np.full(max(FEED_ROWS, ids.shape[0]), -1, np.int32)
+        padded[:b] = src
+        padded = jnp.asarray(padded)
+        return _feed_ids(ids, _feed_tokens(flight.out, padded), padded)
+
+    def ragged_fetch(self, flight: "RaggedFlight"):
+        """The second half of :meth:`ragged_step`: wait for the step's
+        three small outputs, finish its dispatch record (which becomes
+        ``last_dispatch``) and hand back ``(out, accept)`` for its real
+        rows.  If they do not arrive the step is undone as a failed
+        ``ragged_step`` is (:meth:`ragged_discard`) and the error
+        raised; a step launched after this one is the caller's to
+        discard too."""
+        with monitor.span("engine/fetch"):
+            try:
+                out = np.asarray(flight.out)
+                accept = np.asarray(flight.accept)
+                counted = (np.asarray(flight.counted[0])
+                           if flight.counted else ())
+            except BaseException:
+                self.ragged_discard(flight, failed=True)
+                raise
+            record = flight.record
+            record.update(zip(self._step_counts, (int(v) for v in counted)))
             if self._state is not None:
                 # the rows that carried a token, and the bytes of state
                 # they read and wrote as the equations count them
-                n = self.last_dispatch.pop("state_row_layers", 0)
-                self.last_dispatch["state_rows"] = n // self._state["layers"]
-                self.last_dispatch["state_bytes"] = \
-                    2 * n * self._state["bytes"]
-            return np.asarray(out)[:b], np.asarray(accept)[:b]
+                n = record.pop("state_row_layers", 0)
+                record["state_rows"] = n // self._state["layers"]
+                record["state_bytes"] = 2 * n * self._state["bytes"]
+            self.last_dispatch = record
+            b = len(flight.seq_ids)
+            return out[:b], accept[:b]
+
+    def ragged_discard(self, flight: "RaggedFlight", failed=False) -> None:
+        """Undo a launched step that will not be fetched: its rows sit
+        at their lengths from before it again, so the same step can be
+        planned anew.  ``failed``: its outputs did not arrive, so what
+        it left in the pools is not to be read either
+        (:meth:`_recover_pools`)."""
+        if failed:
+            self._recover_pools(flight.cache, ran=True)
+        self._rollback_lengths(flight.cache, flight.seq_ids, flight.before)
 
     def _walk_counts(self, cache, lens, q_lens, span, rows):
         """The dispatch record's count of the paged kernels' work for a

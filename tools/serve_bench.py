@@ -113,6 +113,9 @@ def _hist_delta(before: dict, after: dict, name: str, labels=None):
 def _counter_delta(before: dict, after: dict, name: str,
                    labels=None) -> float:
     def val(snap):
+        if labels is None:      # the metric's total, whatever its series
+            return sum(s["value"] for s in
+                       (snap.get(name) or {}).get("series", ()))
         s = _find_series(snap, name, labels)
         return s["value"] if s else 0.0
     return val(after) - val(before)
