@@ -131,6 +131,7 @@ from typing import List, Optional
 import jax
 import numpy as np
 from .. import monitor
+from ..monitor.gc_hooks import pause_ns as gc_pause_ns
 from ..monitor.trace import get_tracer as _get_tracer
 from ..ops.pallas.paged_attention import PagedKVCache
 from ..testing import faults as _faults
@@ -389,6 +390,55 @@ _overlap_dropped_rows = monitor.counter(
     "at the commit, their pages and slot returned there")
 _steps_overlapped.inc(0)
 _overlap_dropped_rows.inc(0)
+# the host's step, timed where the work happens (ISSUE 39): the loop's
+# phases are ``monitor.span``s that add their seconds to one dict of the
+# scheduler thread's (``into=``), and the counters move ONCE an iteration
+# (:meth:`ContinuousBatchingEngine._flush_host_seconds`), with the
+# profiler off as well as on.  Work and waiting have a counter each, so
+# a sum over a counter's series is one or the other
+_host_work_s = monitor.counter(
+    "engine_host_work_seconds_total", "seconds the scheduler thread "
+    "WORKED on the unified step's iterations, by phase: schedule (reap, "
+    "brownout, preempt, admit, plan), build (row lists, page reservation, "
+    "packing), dispatch (uploads and the program call), commit (what "
+    "depends on a token's value, and the journal flush); over "
+    "serve_steps_launched_total it is what the host costs a step",
+    ("phase",))
+_host_wait_s = monitor.counter(
+    "engine_host_wait_seconds_total", "seconds the scheduler thread "
+    "WAITED, by phase: fetch (for a step's outputs: the device is the "
+    "slower side while this grows) and wait (idle, nothing to run)",
+    ("phase",))
+_host_part_s = monitor.counter(
+    "engine_host_part_seconds_total", "seconds inside named parts of the "
+    "commit and build phases (parts OF engine_host_work_seconds_total, "
+    "not further terms of it): finish_prefill, prefix_register, retire, "
+    "journal; rows, reserve (page reservation, prefix evictions "
+    "included), pack", ("part",))
+_steps_launched = monitor.counter(
+    "serve_steps_launched_total", "unified steps dispatched (a retried "
+    "or probed dispatch that succeeds counts; one that fails does not)")
+_steps_late = monitor.counter(
+    "serve_steps_launched_late_total", "unified steps dispatched when "
+    "the device had nothing left to run: no step was in flight, or the "
+    "one in flight had already finished (its output asked, without "
+    "waiting, just before the program call): the device's idle time as "
+    "the program sees it with no profiler")
+_steps_launched.inc(0)
+_steps_late.inc(0)
+#: span name -> (counter, its label): what an iteration's seconds move
+_HOST_SECONDS = {
+    **{f"engine/{ph}": (_host_work_s, {"phase": ph})
+       for ph in ("schedule", "build", "dispatch", "commit")},
+    **{f"engine/{ph}": (_host_wait_s, {"phase": ph})
+       for ph in ("fetch", "wait")},
+    **{f"engine/{part}": (_host_part_s, {"part": part.split("/")[1]})
+       for part in ("commit/finish_prefill", "commit/prefix_register",
+                    "commit/retire", "commit/journal", "build/rows",
+                    "build/reserve", "build/pack")},
+}
+for _counter, _label in _HOST_SECONDS.values():
+    _counter.inc(0, **_label)
 
 # closed-loop overload protection (ISSUE 19): the controller's own
 # series — materialized at import so existence gates (chaos_smoke) see
@@ -765,6 +815,9 @@ class ContinuousBatchingEngine:
         # every XLA compile the decode loop triggers shows up in
         # jit_recompile_count (steady-state serving should sit at zero)
         monitor.install_compile_hooks()
+        # and of what the collector costs: a full collection stops the
+        # loop for as long as it takes (``host_gc_*``, span ``host/gc``)
+        monitor.install_gc_hooks()
         # quantized serving (ISSUE 9): ``quantize`` runs the compiled
         # programs' Linears int8 (w8 weight-only / w8a8 dynamic);
         # ``kv_quant="int8"`` stores KV pages int8 with per-slot scale
@@ -959,6 +1012,12 @@ class ContinuousBatchingEngine:
         # records' intervals start no earlier): scheduler-thread only
         self._flight: Optional[_Step] = None
         self._fetched_ns = 0
+        # this iteration's seconds by span name (the phases' and their
+        # parts' ``into=``; the decoder's spans add to the same dict) and
+        # the ``dispatch`` records of the steps it committed, which are
+        # written when it ends (:meth:`_flush_host_seconds`)
+        self._host_s = self._decoder.host_seconds
+        self._ring_pending: List[tuple] = []
         self._stepping = False
         self._snap_waiters = 0
         # stall detection (ISSUE 4): while a compiled step is in flight
@@ -2327,52 +2386,56 @@ class ContinuousBatchingEngine:
         path and the unified ragged step: the target is fully resident
         — register its prefix, ingest the draft's copy, latch the first
         sampled token, stamp TTFT, journal the pending sample."""
-        # ---- target fully resident: finish what monolithic prefill did
-        if self.prefix_cache:
-            _prefix_lookups.inc()
-            if req.prefix_tokens:
-                _prefix_hits.inc()
-                _prefix_hit_tokens.inc(req.prefix_tokens)
-            # retain this prompt's page-aligned prefixes for later
-            # sharers (idempotent for the pages it itself shared);
-            # chunk-written pages carry identical KV, so chunked
-            # prompts seed the prefix cache exactly like monolithic ones
-            self.cache.register_prefix(req.seq_id, req.prompt)
-            self._journal_pages(req, "registered", len(req.prompt))
-        if req.use_draft:
-            # the draft ingests the WHOLE target (no prefix sharing in
-            # its pool) so its cache sits at the same length as the
-            # target's — the lockstep invariant every propose/verify
-            # round preserves.  Deferred to prefill COMPLETION under
-            # chunking: a preempted target resumes without ever having
-            # touched the draft pool.  The greedy-tail sampling keeps
-            # the transfer at (1,) ids; the value is discarded.
-            try:
-                self._count_dispatch("draft")
-                self._draft_decoder.prefill(
-                    self.draft_cache, [req.seq_id],
-                    req.prefill_target[None],
-                    bucket=True, sampling=_null_sampling())
-            except BaseException:  # noqa: BLE001 — degrade, don't fail
-                self._downgrade_draft([req])
-        if req.next_token is None:
-            # a restored request keeps its journaled next token (the
-            # replayed final draw equals it by the counter contract);
-            # sampled rows on the host-logits path must ALSO keep it —
-            # re-picking would burn a host RNG draw
-            req.next_token = (int(out_row) if sampled
-                              else self._pick(req, out_row))
-        if req.first_token_at is None:  # not one resumed into a new slot
-            req.first_token_at = time.perf_counter()
-            ttft = req.first_token_at - req.submitted_at
-            _ttft_s.observe(ttft)
-            self._sched.note_first_token(req, ttft)
-            _tracer.request_event(req.request_id, "first_token",
-                                  ttft_s=round(ttft, 6))
-        if self.journal is not None:
-            # prefill completion: no tokens appended yet, but the first
-            # pending sample is host state a SIGKILL must not lose
-            self._jrows.append((req.request_id, (), req.next_token))
+        with monitor.span("engine/commit/finish_prefill",
+                          into=self._host_s):
+            # ---- target fully resident: finish what monolithic prefill did
+            if self.prefix_cache:
+                _prefix_lookups.inc()
+                if req.prefix_tokens:
+                    _prefix_hits.inc()
+                    _prefix_hit_tokens.inc(req.prefix_tokens)
+                # retain this prompt's page-aligned prefixes for later
+                # sharers (idempotent for the pages it itself shared);
+                # chunk-written pages carry identical KV, so chunked
+                # prompts seed the prefix cache exactly like monolithic ones
+                with monitor.span("engine/commit/prefix_register",
+                                  into=self._host_s):
+                    self.cache.register_prefix(req.seq_id, req.prompt)
+                    self._journal_pages(req, "registered", len(req.prompt))
+            if req.use_draft:
+                # the draft ingests the WHOLE target (no prefix sharing in
+                # its pool) so its cache sits at the same length as the
+                # target's — the lockstep invariant every propose/verify
+                # round preserves.  Deferred to prefill COMPLETION under
+                # chunking: a preempted target resumes without ever having
+                # touched the draft pool.  The greedy-tail sampling keeps
+                # the transfer at (1,) ids; the value is discarded.
+                try:
+                    self._count_dispatch("draft")
+                    self._draft_decoder.prefill(
+                        self.draft_cache, [req.seq_id],
+                        req.prefill_target[None],
+                        bucket=True, sampling=_null_sampling())
+                except BaseException:  # noqa: BLE001 — degrade, don't fail
+                    self._downgrade_draft([req])
+            if req.next_token is None:
+                # a restored request keeps its journaled next token (the
+                # replayed final draw equals it by the counter contract);
+                # sampled rows on the host-logits path must ALSO keep it —
+                # re-picking would burn a host RNG draw
+                req.next_token = (int(out_row) if sampled
+                                  else self._pick(req, out_row))
+            if req.first_token_at is None:  # not one resumed into a new slot
+                req.first_token_at = time.perf_counter()
+                ttft = req.first_token_at - req.submitted_at
+                _ttft_s.observe(ttft)
+                self._sched.note_first_token(req, ttft)
+                _tracer.request_event(req.request_id, "first_token",
+                                      ttft_s=round(ttft, 6))
+            if self.journal is not None:
+                # prefill completion: no tokens appended yet, but the first
+                # pending sample is host state a SIGKILL must not lose
+                self._jrows.append((req.request_id, (), req.next_token))
 
     def _run_chunks(self, plan) -> None:
         """Execute one iteration's prefill chunk plan (device work —
@@ -2734,7 +2797,8 @@ class ContinuousBatchingEngine:
             if spec:
                 step.drafts = self._propose_drafts(active)
             k_spec, drafts = step.k_spec, step.drafts
-            with monitor.span("engine/build"):
+            with monitor.span("engine/build", into=self._host_s), \
+                    monitor.span("engine/build/rows", into=self._host_s):
                 seq_ids, rows, ctxs, nds = [], [], [], []
                 for req, target, k, n, _last in chunks:
                     seq_ids.append(req.seq_id)
@@ -2798,20 +2862,19 @@ class ContinuousBatchingEngine:
                 _faults.maybe_fire(
                     "decode_step",
                     seq_ids=[r.seq_id for r in active])
-            with monitor.span("engine/ragged_step"):
-                self._count_dispatch("ragged")
-                step_args = dict(n_drafts=(nds if spec else None),
-                                 sampling=sampling)
-                if prev is None and self._step_replaced():
-                    step.result = self._decoder.ragged_step(
-                        self.cache, seq_ids, rows, ctxs, **step_args)
-                    step.record = self._decoder.last_dispatch
-                else:
-                    step.flight = self._decoder.ragged_launch(
-                        self.cache, seq_ids, rows, ctxs, **step_args,
-                        feed=((prev.flight, src) if step.deferred
-                              else None))
-                    step.record = step.flight.record
+            self._count_dispatch("ragged")
+            step_args = dict(n_drafts=(nds if spec else None),
+                             sampling=sampling)
+            if prev is None and self._step_replaced():
+                step.result = self._decoder.ragged_step(
+                    self.cache, seq_ids, rows, ctxs, **step_args)
+                step.record = self._decoder.last_dispatch
+            else:
+                step.flight = self._decoder.ragged_launch(
+                    self.cache, seq_ids, rows, ctxs, **step_args,
+                    feed=((prev.flight, src) if step.deferred else None),
+                    after=None if prev is None else prev.flight)
+                step.record = step.flight.record
         except BaseException as e:  # noqa: BLE001 — legacy owns isolation
             if prev is None:
                 self._step_started_at = None
@@ -2848,6 +2911,12 @@ class ContinuousBatchingEngine:
                        [c[0] for c in chunks] + active}
         if step.overlapped:
             _steps_overlapped.inc()
+        # ``late``: the device had run dry before this launch (the
+        # decoder asked the step in flight just before the program call;
+        # a stand-in's record may not say: nothing was in flight then)
+        _steps_launched.inc()
+        if step.record.get("late", 1):
+            _steps_late.inc()
         return step
 
     def _step_failed(self, step, error) -> None:
@@ -2909,16 +2978,17 @@ class ContinuousBatchingEngine:
         self._fetched_ns = fetched_ns
         (_decode_step_s if active else _prefill_s).observe(
             (fetched_ns - start_ns) / 1e9)
-        with monitor.span("engine/commit"):
+        with monitor.span("engine/commit", into=self._host_s):
             self._unified_failures = 0
             traced = _tracer.enabled and step.traced
             if traced:
                 # what the decoder says it dispatched: the (rows, span,
-                # table) bucket against the real tokens and contexts
-                _tracer.step_record("dispatch", step.index, start_ns,
-                                    fetched_ns,
-                                    overlapped=int(step.overlapped),
-                                    **step.record)
+                # table) bucket against the real tokens and contexts.
+                # Written when this iteration ends, with what the host
+                # spent in it (:meth:`_flush_host_seconds`)
+                self._ring_pending.append(
+                    (step.index, start_ns, fetched_ns,
+                     dict(step.record, overlapped=int(step.overlapped))))
             for name, value in step.record.items():
                 if name in _STEP_SUMS:
                     _STEP_SUMS[name].inc(value)
@@ -3041,7 +3111,8 @@ class ContinuousBatchingEngine:
                             (r.request_id,
                              list(r.generated[jlens[id(r)]:]),
                              r.next_token))
-            with self._cond:
+            with monitor.span("engine/commit/retire", into=self._host_s), \
+                    self._cond:
                 if active:
                     self.steps += 1
                 for r in retired:
@@ -3987,6 +4058,30 @@ class ContinuousBatchingEngine:
         zombies, step.dropped = list(step.dropped.values()), {}
         return zombies
 
+    def _flush_host_seconds(self, gc_ns: int) -> None:
+        """Scheduler thread, end of one iteration: move the phase
+        counters by what the iteration's spans added to ``_host_s`` (the
+        one place they move: a dozen labelled increments an iteration
+        and none a span), and write the ``dispatch`` records of the
+        steps it committed, each with ``host_work_ns`` — the work phases
+        of THIS iteration, the one whose span is ``engine/step <index>``
+        of the record's index; no waiting — and ``gc_ns``, the collector
+        pauses inside it on any thread.  (``late`` and
+        ``prefix_evicted`` on the record are of the step's own
+        launch, an iteration earlier when it was overlapped.)"""
+        work = 0.0
+        for name, seconds in self._host_s.items():
+            counted, label = _HOST_SECONDS[name]
+            counted.inc(seconds, **label)
+            if counted is _host_work_s:
+                work += seconds
+        self._host_s.clear()
+        for index, start_ns, end_ns, record in self._ring_pending:
+            _tracer.step_record("dispatch", index, start_ns, end_ns,
+                                host_work_ns=int(work * 1e9), gc_ns=gc_ns,
+                                **record)
+        self._ring_pending.clear()
+
     def _loop(self):
         while True:
             with self._cond:
@@ -4000,7 +4095,7 @@ class ContinuousBatchingEngine:
                     # shedding the first arrivals of the next burst
                     if self._brownout:
                         self._set_brownout_locked(0, 0.0)
-                    with monitor.span("engine/wait"):
+                    with monitor.span("engine/wait", into=self._host_s):
                         self._cond.wait(timeout=0.5)
                 if self._stop:
                     for r in self._abandon_flight_locked():
@@ -4036,6 +4131,7 @@ class ContinuousBatchingEngine:
         is about to be reaped (:meth:`_riders_hold`) or paused
         (:class:`_LandFirst`)."""
         reaped: List[_Request] = []
+        gc_ns = gc_pause_ns()
         try:
             if self._flight is not None:
                 why = (("legacy" if legacy else None)
@@ -4044,7 +4140,8 @@ class ContinuousBatchingEngine:
                     self._land(why)
             while True:
                 try:
-                    with monitor.span("engine/schedule"), self._cond:
+                    with monitor.span("engine/schedule",
+                                      into=self._host_s), self._cond:
                         reaped += self._reap_locked()
                         # closed-loop overload protection (ISSUE 19): one
                         # controller evaluation per iteration — the ladder
@@ -4120,8 +4217,11 @@ class ContinuousBatchingEngine:
             # admitted ids + per-row emissions — enqueued ONCE per
             # loop pass (rows for requests _fail_all just retired
             # are ignored at replay: their retire precedes them)
-            with monitor.span("engine/commit"):
+            with monitor.span("engine/commit", into=self._host_s), \
+                    monitor.span("engine/commit/journal",
+                                 into=self._host_s):
                 self._journal_flush_step()
+            self._flush_host_seconds(gc_pause_ns() - gc_ns)
             # the snapshot barrier's flag stays up while a step is in
             # flight: there is no between-steps cut to read
             stepping = self._flight is not None

@@ -739,6 +739,11 @@ class JittedPagedDecoder:
         self._program_fns = {}           # (mode, sample) -> raw traced fn
         self._jitted_multi = None        # built on first multi_step use
         self.last_dispatch = None        # the last FETCHED step's record
+        # seconds by span name, added to by the spans of ``ragged_launch``
+        # and ``ragged_fetch`` (``monitor.span(..., into=)``); whoever
+        # drives the decoder reads and clears it (the engine: once an
+        # iteration, into its phase counters)
+        self.host_seconds: dict = {}
         self._feed_warm = set()          # (rows, span) whose feed compiled
 
     # -------------------------------------------------- compiled programs
@@ -1594,7 +1599,7 @@ class JittedPagedDecoder:
     _RAGGED_STEP = ragged_step
 
     def ragged_launch(self, cache: PagedKVCache, seq_ids, rows, ctxs,
-                      n_drafts=None, sampling=None, feed=None):
+                      n_drafts=None, sampling=None, feed=None, after=None):
         """The first half of :meth:`ragged_step`: check, reserve, pack
         and DISPATCH the step, and come back without waiting for it.
         The cache is advanced and holds the program's returned pools at
@@ -1609,8 +1614,16 @@ class JittedPagedDecoder:
         first token is taken from the device array (``_feed_ids``: a
         small program of its own, so the ragged program and its operands
         are what they are without a feed), whatever ``rows`` holds
-        there; the earlier step must have sampled (``out`` is ids)."""
-        with monitor.span("engine/build"):
+        there; the earlier step must have sampled (``out`` is ids).
+
+        ``after`` = the flight of the step dispatched before this one
+        and not fetched, if there is one.  Just before the program call
+        its output is asked whether it has arrived (``is_ready()``: no
+        wait, no transfer); if it has, or there is none, the device had
+        nothing left to run when this step reached it: the record's
+        ``late`` is 1, else 0."""
+        into = self.host_seconds
+        with monitor.span("engine/build", into=into):
             b = len(seq_ids)
             ns = [len(r) for r in rows]
             if b == 0 or min(ns) < 1:
@@ -1655,56 +1668,61 @@ class JittedPagedDecoder:
                     f"{self.step_tokens})")
             # all-or-nothing page reservation with PER-ROW counts: a
             # mid-batch exhaustion must not strand earlier rows' pages
-            cache.allocate_batch_atomic(seq_ids, ns)
-            ids = np.zeros((b_b, s_b), np.int32)
-            pg = np.full((b_b, s_b), cache.total_pages, np.int32)  # drop
-            sl = np.zeros((b_b, s_b), np.int32)
-            for i, (sid, row, n) in enumerate(zip(seq_ids, rows, ns)):
-                ids[i, :n] = np.asarray(row, np.int32)
-                rpg, rsl = cache.plan_write([sid], n)
-                pg[i, :n] = rpg
-                sl[i, :n] = rsl
-                cache.advance([sid], n)
-            needed = max(len(cache._seq_pages.get(sid, ()))
-                         for sid in seq_ids)
-            # a model with no K/V layer reads no table: one column, so
-            # no program's shape follows the longest context
-            W = (max(next_pow2(needed), self.min_table_pages)
-                 if cache.num_layers else 1)
-            tabs = np.zeros((b_b, W), np.int32)
-            for i, sid in enumerate(seq_ids):
-                t = cache._seq_pages[sid][:W]
-                tabs[i, :len(t)] = t
-            recur = ()
-            if self._state is not None:
-                # the rows' slots (a pad row: the scratch slot) and the
-                # rows of several tokens, which take the chunk form: a
-                # static few, -1 where there are fewer (none when every
-                # row holds one token)
-                slots = np.full(b_b, cache.scratch_slot, np.int32)
-                slots[:b] = [cache.take_slot(sid) for sid in seq_ids]
-                multi = [i for i, n in enumerate(ns) if n > 1]
-                c_b = 0 if s_b == 1 else max(2, next_pow2(len(multi)))
-                chunk_rows = np.full(c_b, -1, np.int32)
-                chunk_rows[:len(multi)] = multi
-                recur = (slots, chunk_rows,
-                         np.asarray(cache.total_pages, np.int32))
-            ctx_arr = np.zeros(b_b, np.int32)
-            ctx_arr[:b] = np.asarray([int(k) for k in ctxs], np.int32)
-            ql = np.ones(b_b, np.int32)          # pad rows: 1-token span,
-            ql[:b] = np.asarray(ns, np.int32)    # ctx 0, dropped scatter
-            nd_arr = np.zeros(b_b, np.int32)
-            nd_arr[:b] = np.asarray(nds, np.int32)
-            if sampling is not None and b_b != b:
-                seeds, temps, flags = sampling
-                pad = b_b - b
-                sampling = (
-                    np.concatenate([np.asarray(seeds, np.uint32),
-                                    np.zeros(pad, np.uint32)]),
-                    np.concatenate([np.asarray(temps, np.float32),
-                                    np.ones(pad, np.float32)]),
-                    np.concatenate([np.asarray(flags, bool),
-                                    np.zeros(pad, bool)]))
+            # (a pool with no free page evicts prefix-index entries here)
+            evicted = cache.prefix_evictions
+            with monitor.span("engine/build/reserve", into=into):
+                cache.allocate_batch_atomic(seq_ids, ns)
+            evicted = cache.prefix_evictions - evicted
+            with monitor.span("engine/build/pack", into=into):
+                ids = np.zeros((b_b, s_b), np.int32)
+                pg = np.full((b_b, s_b), cache.total_pages, np.int32)  # drop
+                sl = np.zeros((b_b, s_b), np.int32)
+                for i, (sid, row, n) in enumerate(zip(seq_ids, rows, ns)):
+                    ids[i, :n] = np.asarray(row, np.int32)
+                    rpg, rsl = cache.plan_write([sid], n)
+                    pg[i, :n] = rpg
+                    sl[i, :n] = rsl
+                    cache.advance([sid], n)
+                needed = max(len(cache._seq_pages.get(sid, ()))
+                             for sid in seq_ids)
+                # a model with no K/V layer reads no table: one column, so
+                # no program's shape follows the longest context
+                W = (max(next_pow2(needed), self.min_table_pages)
+                     if cache.num_layers else 1)
+                tabs = np.zeros((b_b, W), np.int32)
+                for i, sid in enumerate(seq_ids):
+                    t = cache._seq_pages[sid][:W]
+                    tabs[i, :len(t)] = t
+                recur = ()
+                if self._state is not None:
+                    # the rows' slots (a pad row: the scratch slot) and the
+                    # rows of several tokens, which take the chunk form: a
+                    # static few, -1 where there are fewer (none when every
+                    # row holds one token)
+                    slots = np.full(b_b, cache.scratch_slot, np.int32)
+                    slots[:b] = [cache.take_slot(sid) for sid in seq_ids]
+                    multi = [i for i, n in enumerate(ns) if n > 1]
+                    c_b = 0 if s_b == 1 else max(2, next_pow2(len(multi)))
+                    chunk_rows = np.full(c_b, -1, np.int32)
+                    chunk_rows[:len(multi)] = multi
+                    recur = (slots, chunk_rows,
+                             np.asarray(cache.total_pages, np.int32))
+                ctx_arr = np.zeros(b_b, np.int32)
+                ctx_arr[:b] = np.asarray([int(k) for k in ctxs], np.int32)
+                ql = np.ones(b_b, np.int32)          # pad rows: 1-token span,
+                ql[:b] = np.asarray(ns, np.int32)    # ctx 0, dropped scatter
+                nd_arr = np.zeros(b_b, np.int32)
+                nd_arr[:b] = np.asarray(nds, np.int32)
+                if sampling is not None and b_b != b:
+                    seeds, temps, flags = sampling
+                    pad = b_b - b
+                    sampling = (
+                        np.concatenate([np.asarray(seeds, np.uint32),
+                                        np.zeros(pad, np.uint32)]),
+                        np.concatenate([np.asarray(temps, np.float32),
+                                        np.ones(pad, np.float32)]),
+                        np.concatenate([np.asarray(flags, bool),
+                                        np.zeros(pad, bool)]))
         # what this dispatch computes against what it was asked for: the
         # engine writes it into the step ring as the ``dispatch`` record
         # (the flight's own: a later launch starts another).
@@ -1717,6 +1735,7 @@ class JittedPagedDecoder:
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
             "tokens": sum(ns), "tokens_padded": t_b,
             "table_pages": W, "page_size": cache.page_size,
+            "prefix_evicted": evicted,
             **self._walk_counts(cache, ctx_arr + ql, ql, s_b, b)}
         if self._state is not None:
             # the rows of several tokens (the chunk form; the program is
@@ -1729,7 +1748,7 @@ class JittedPagedDecoder:
                 state_chunk_tokens=sum(ns[i] for i in multi),
                 state_slots=cache.state_slots,
                 slots_zeroed=sum(1 for k in ctxs if int(k) == 0))
-        with monitor.span("engine/dispatch"):
+        with monitor.span("engine/dispatch", into=into):
             sample, s_args = self._verify_sampling_args(sampling)
             try:
                 _maybe_lose_buffers(cache, seq_ids)
@@ -1738,8 +1757,8 @@ class JittedPagedDecoder:
                 ids = jnp.asarray(ids)
                 if feed is not None:
                     ids = self._fed_ids(ids, b, *feed)
-                out, accept, counted, *pools = self._program(
-                    "ragged", sample)(
+                program = self._program("ragged", sample)
+                operands = (
                     self._param_arrays(), ids,
                     jnp.asarray(ctx_arr), jnp.asarray(ql),
                     jnp.asarray(pg.reshape(-1)),
@@ -1749,6 +1768,10 @@ class JittedPagedDecoder:
                     *((tuple(cache.state_pools),
                        tuple(jnp.asarray(a) for a in recur))
                       if recur else ()))
+                # asked last, with every operand uploaded: what is left
+                # between the answer and the device is the call itself
+                record["late"] = int(after is None or after.out.is_ready())
+                out, accept, counted, *pools = program(*operands)
             except BaseException:
                 self._recover_pools(cache)
                 self._rollback_lengths(cache, seq_ids, before)
@@ -1798,7 +1821,7 @@ class JittedPagedDecoder:
         ``ragged_step`` is (:meth:`ragged_discard`) and the error
         raised; a step launched after this one is the caller's to
         discard too."""
-        with monitor.span("engine/fetch"):
+        with monitor.span("engine/fetch", into=self.host_seconds):
             try:
                 out = np.asarray(flight.out)
                 accept = np.asarray(flight.accept)

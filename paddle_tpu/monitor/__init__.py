@@ -30,6 +30,7 @@ from .registry import (  # noqa: F401
 )
 from .span import span  # noqa: F401
 from .compile_hooks import install_compile_hooks  # noqa: F401
+from .gc_hooks import install_gc_hooks  # noqa: F401
 from .trace import (  # noqa: F401
     Tracer, get_tracer, start_capture, stop_capture, request_timeline,
     export_chrome_trace, validate_chrome_trace,
@@ -39,6 +40,7 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricRegistry", "get_registry",
     "counter", "gauge", "histogram", "snapshot", "prometheus_text",
     "dump", "dump_on_exit", "span", "install_compile_hooks",
+    "install_gc_hooks",
     "DEFAULT_LATENCY_BUCKETS", "BYTES_BUCKETS",
     "Tracer", "get_tracer", "start_capture", "stop_capture",
     "request_timeline", "export_chrome_trace", "validate_chrome_trace",

@@ -54,11 +54,20 @@ class _Metric:
         self.name = name
         self.help = help
         self.label_names = tuple(label_names)
-        self._lock = threading.Lock()
+        # re-entrant: a collector callback (monitor/gc_hooks.py) moves
+        # its counters from whatever its thread was doing, which may be
+        # reading one of them under this lock
+        self._lock = threading.RLock()
         self._series: Dict[Tuple[str, ...], object] = {}
 
     def _key(self, labels: Dict[str, str]) -> Tuple[str, ...]:
-        return _check_labels(self.label_names, labels)
+        names = self.label_names
+        if len(labels) == len(names):     # the usual case, without sets
+            try:
+                return tuple([str(labels[k]) for k in names])
+            except KeyError:
+                pass
+        return _check_labels(names, labels)
 
     def labeled_series(self) -> List[Tuple[Dict[str, str], object]]:
         with self._lock:

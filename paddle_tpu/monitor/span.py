@@ -1,10 +1,15 @@
 """The one host-span primitive: a block timed once, written to every
 sink.
 
-A ``span`` fans its measurement out to three consumers:
+A ``span`` fans its measurement out to these consumers:
 
   * a Histogram observation (always — metrics are unconditional), on
     ``time.perf_counter``;
+  * ``into``, a plain dict of the caller's: the elapsed seconds are
+    added under the span's name (always).  A loop that opens the same
+    few spans every pass sums them there and moves its counters once a
+    pass (the serving loop's phase seconds), where a labelled counter a
+    span would cost more than the span;
   * a profiler ``HostEvent`` (only while a Profiler or a capture window
     has the recorder in a RECORD state — the push is a no-op
     otherwise), on ``time.perf_counter_ns``;
@@ -53,18 +58,20 @@ class span:
     """``with span("collective/all_reduce", histogram=h, kind="all_reduce"):``
 
     Times the block; observes elapsed seconds into ``histogram`` (with
-    the given labels), records a host event named ``name`` for the
-    profiler timeline and annotates jax's trace with it.  Usable as a
-    decorator.  ``elapsed`` holds the measured seconds after exit.
+    the given labels), adds them to ``into[name]``, records a host event
+    named ``name`` for the profiler timeline and annotates jax's trace
+    with it.  Usable as a decorator.  ``elapsed`` holds the measured
+    seconds after exit.
     """
 
-    __slots__ = ("name", "histogram", "labels", "elapsed",
+    __slots__ = ("name", "histogram", "into", "labels", "elapsed",
                  "_t0", "_start_ns", "_ann")
 
     def __init__(self, name: str, histogram: Optional[Histogram] = None,
-                 **labels):
+                 into: Optional[dict] = None, **labels):
         self.name = name
         self.histogram = histogram
+        self.into = into
         self.labels = labels
         self.elapsed: Optional[float] = None
         self._t0 = None
@@ -88,6 +95,9 @@ class span:
             self._ann = None
         if self.histogram is not None:
             self.histogram.observe(self.elapsed, **self.labels)
+        if self.into is not None:
+            self.into[self.name] = (self.into.get(self.name, 0.0)
+                                    + self.elapsed)
         if self._start_ns is not None:
             rec = get_recorder()
             rec.push(self.name, self._start_ns, rec.now_ns())
@@ -104,7 +114,8 @@ class span:
         and concurrent calls from clobbering each other's timers."""
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
-            inner = span(self.name, self.histogram, **self.labels)
+            inner = span(self.name, self.histogram, self.into,
+                         **self.labels)
             try:
                 with inner:
                     return fn(*args, **kwargs)

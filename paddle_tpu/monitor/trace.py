@@ -28,8 +28,11 @@ Design constraints:
     ``monitor.span`` reaches through a ``TraceAnnotation`` (the device's
     operations are on that one).  What joins the two is not a timestamp
     but the step index: the ring's records carry ``index``, the trace
-    the span ``engine/step <index>``; histograms use
-    ``time.perf_counter``;
+    the span ``engine/step <index>`` (the iteration that COMMITS the
+    step: its ``dispatch`` record carries that iteration's
+    ``host_work_ns`` and is written when it ends, so a window closed in
+    the middle of an iteration has that step's other records and not
+    this one); histograms use ``time.perf_counter``;
   * **stdlib only** — importable before jax, like the rest of
     ``paddle_tpu.monitor``.
 

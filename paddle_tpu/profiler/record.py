@@ -26,7 +26,9 @@ class _PyRecorder:
     with ``time.perf_counter_ns``."""
 
     def __init__(self):
-        self._lock = threading.Lock()
+        # re-entrant: the collector's span (monitor/gc_hooks.py) may end
+        # while its thread is inside ``push``
+        self._lock = threading.RLock()
         self._events: List[HostEvent] = []
         self.enabled = False
 

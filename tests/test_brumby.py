@@ -141,8 +141,10 @@ class TestServedThroughTheEngine:
             outs = [r.result(timeout=600) for r in reqs]
             assert eng.cache.slots_in_use == 0 and eng.cache.free_slots == 4
         finally:
-            monitor.stop_capture()
+            # the engine first: a step's ``dispatch`` record is written
+            # when the iteration that committed it ends
             eng.stop()
+            monitor.stop_capture()
         steps = monitor.get_tracer().step_records()
         seqs = [(p, np.asarray(o[len(p):], np.int32))
                 for p, o in zip(prompts, outs)]

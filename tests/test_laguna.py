@@ -142,8 +142,10 @@ class TestServedThroughTheEngine:
             reqs = [engine.submit(p, max_new_tokens=12) for p in prompts]
             outs = [r.result(timeout=600) for r in reqs]
         finally:
-            monitor.stop_capture()
+            # the engine first: a step's ``dispatch`` record is written
+            # when the iteration that committed it ends
             engine.stop()
+            monitor.stop_capture()
         steps = monitor.get_tracer().step_records()
         seqs = [(p, np.asarray(o[len(p):], np.int32))
                 for p, o in zip(prompts, outs)]
