@@ -206,8 +206,8 @@ def phase_kernels(cfg, seed, *, page_size, decode_batch, table_pages,
     nq = min(chunk_tokens, max_len // 2)
     q_lens = jnp.asarray(rng.integers(1, nq + 1, (decode_batch,))
                          .astype("int32")).at[0].set(nq)
-    # pad queries (j >= q_len) compute discarded garbage on both paths:
-    # compare the real ones
+    # pad queries (j >= q_len) are zeros on both paths: compare the
+    # real ones
     real = np.arange(nq)[None, :] < np.asarray(q_lens)[:, None]
     _check_kernel(
         f"paged ragged b{decode_batch} span<={nq} h{H}/{KVH} d{D}",

@@ -314,9 +314,10 @@ _kv_quant_pool_bytes_g = monitor.gauge(
 _kv_quant_scale_bytes_g = monitor.gauge(
     "kv_quant_scale_bytes", "resident bytes of the int8 mode's "
     "per-slot scale pools (0 at full precision)")
-# expert layers and sliding-attention layers in the unified step (ISSUE
-# 33): the sums of the ``dispatch`` records' fields of the same names,
-# for a model that has such layers (none is touched for one that has not)
+# expert layers, sliding-attention layers and the paged kernel's query
+# tiles in the unified step: the sums of the ``dispatch`` records' fields
+# of the same names, for a model that has such layers (none is touched
+# for one that has not)
 _STEP_SUMS = {
     name: monitor.counter(f"serve_{name}_total", text) for name, text in (
         ("moe_slots", "(real token, chosen expert) pairs of the unified "
@@ -333,6 +334,9 @@ _STEP_SUMS = {
          "layer's paged kernel walks, from the first visible page"),
         ("kv_tokens_walked_nowindow", "what that walk would be from page "
          "0"),
+        ("q_positions_computed", "query positions the paged kernel "
+         "computed for the unified steps' padded rows: a row's own queries "
+         "in whole tiles, a layer's worth"),
         ("state_bytes", "bytes of recurrent state the unified steps' rows "
          "read and wrote, as the equations count a state (a row that "
          "carries a token: a layer's state once in, once out)"))}

@@ -34,6 +34,7 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           kv_tokens_walked, paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
+                                          q_positions_computed,
                                           quantize_kv, walk_block_pages)
 from ..testing import faults as _faults
 
@@ -245,7 +246,8 @@ def next_pow2(n: int) -> int:
 def _rows_of_packed(x, off, span):
     """``x[T, ...]`` packed -> ``[rows, span, ...]``: row ``r`` is the
     ``span`` entries from ``off[r]`` on.  Past the row's own tokens that
-    is its successors' (or pad): garbage the caller masks or discards."""
+    is its successors' (or pad): the paged kernel, which is handed the
+    rows' own lengths, never computes with them."""
     if off is None:
         return x.reshape((-1, span) + x.shape[1:])
     at = off[:, None] + jnp.arange(span, dtype=jnp.int32)[None, :]
@@ -257,7 +259,10 @@ def _packed_of_rows(x, off, tokens):
     """``x[rows, span, ...]`` -> ``[tokens, ...]`` packed: position ``t``
     takes row ``r``'s column ``t - off[r]``, ``r`` the last row that
     starts at or before ``t``.  Past the step's tokens that is the last
-    row's tail: garbage the caller masks or discards."""
+    row's tail: of the paged kernel's output, its dead queries, which
+    the kernel writes as zeros — the dense layers, the router and the
+    head run over the pad positions too, and the caller discards what
+    they make of them."""
     rows, span = x.shape[:2]
     flat = x.reshape((rows * span,) + x.shape[2:])
     if off is None:
@@ -528,8 +533,8 @@ class _TracedPagedContext:
         # blocks mix in one kernel call with per-row traced lengths.
         # The packed tokens (b of them, s == 1) go to the kernel's
         # (rows, span) rectangle for this call alone and come back
-        # packed; the pad queries' finite garbage comes back with them
-        # and is discarded by the program's tail
+        # packed; the pad queries come back as zeros and what the
+        # layers behind make of them is discarded by the program's tail
         if self.q_lens is not None:
             rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
             out = paged_attention_ragged(rect, kp, vp, self.lens,
@@ -1564,8 +1569,9 @@ class JittedPagedDecoder:
         to a power of two.
 
         The (B, S) bucket is what the host hands over and what the
-        paged kernel sees: spans right-pad to S (the kernel clamps pad
-        queries at the row's kv length — finite garbage, discarded) and
+        paged kernel sees: spans right-pad to S (the kernel computes a
+        row's own queries in whole tiles and writes zeros for the pad
+        queries) and
         the batch pads with ctx-0 single-token rows exactly like
         ``batch_context_prefill``.  Everything else in the program runs
         over ``packed_tokens(B, S)`` positions: the rectangle's, or
@@ -1729,8 +1735,10 @@ class JittedPagedDecoder:
         # ``rows_padded`` x ``span_padded`` is the paged kernel's query
         # rectangle, ``tokens_padded`` the positions every other layer
         # computes.  ``kv_tokens_walked`` is what the paged kernel walks
-        # for these rows: each row's context in whole blocks, by the
-        # kernel's own rule (pad rows are one token long)
+        # for these rows, each row's context in whole blocks, and
+        # ``q_positions_computed`` the query positions it computes for
+        # them, each row's own queries in whole tiles: the kernel's own
+        # rules (pad rows are one token long)
         record = {
             "rows": b, "rows_padded": b_b, "span_padded": s_b,
             "tokens": sum(ns), "tokens_padded": t_b,
@@ -1858,7 +1866,9 @@ class JittedPagedDecoder:
         and ``q_lens`` queries, a LAYER's worth each (the mean over the
         layers where they differ): ``ctx_tokens`` the positions some
         query attends, ``kv_tokens_walked`` what the kernel walks for
-        them in whole blocks by its own rule.  A model with sliding
+        them in whole blocks and ``q_positions_computed`` the query
+        positions it computes for them in whole tiles, both by the
+        kernel's own rule.  A model with sliding
         layers adds one sliding layer's own two counts
         (``..._window``), what that layer would have walked with no
         window (``kv_tokens_walked_nowindow``) and the pages its real
@@ -1868,7 +1878,8 @@ class JittedPagedDecoder:
         ps, total = cache.page_size, sum(self._attn_kinds.values())
         if not total:                   # no K/V layer: nothing is walked
             return {}
-        out = {"ctx_tokens": 0, "kv_tokens_walked": 0}
+        means = ("ctx_tokens", "kv_tokens_walked", "q_positions_computed")
+        out = dict.fromkeys(means, 0)
         for (group, window), n in self._attn_kinds.items():
             block = ps * walk_block_pages(ps, cache.head_dim, span * group,
                                           cache.k_pages[0].dtype)
@@ -1877,6 +1888,8 @@ class JittedPagedDecoder:
             walked = kv_tokens_walked(lens, block, window, q_lens, ps)
             out["ctx_tokens"] += n * seen
             out["kv_tokens_walked"] += n * walked
+            out["q_positions_computed"] += n * q_positions_computed(
+                q_lens, span, group, cache.compute_dtype)
             if window is not None:
                 dead = np.maximum(lens[:rows] + 1 - window, 0) // ps
                 out.update(ctx_tokens_window=seen,
@@ -1884,7 +1897,7 @@ class JittedPagedDecoder:
                            kv_tokens_walked_nowindow=kv_tokens_walked(
                                lens, block),
                            kv_window_dead_pages=int(dead.sum()))
-        for name in ("ctx_tokens", "kv_tokens_walked"):     # a layer's
+        for name in means:                                  # a layer's
             out[name] = (out[name] // total if len(self._attn_kinds) == 1
                          else out[name] / total)
         return out

@@ -23,6 +23,12 @@ TPU-native design (see /opt/skills/guides/pallas_guide.md):
     int8 scale pools besides;
   - online softmax in VMEM scratch across blocks; the tail block is
     column-masked (scores) and its unfetched slots zeroed (values);
+  - the ragged kernel computes A ROW'S OWN QUERIES: the (span x group)
+    query block is cut into tiles of whole query positions
+    (``query_tile_rows``: 128 rows, 96 for a group of 6) and scores,
+    softmax and products run for the ``ceil(q_len * group / tile)`` live
+    tiles of each context block, so a one-token row of a 128-wide bucket
+    costs one tile a block and not the bucket; dead queries are zeros;
   - GQA: the q-head group of each kv head computes together (group x
     head_dim MXU tiles);
   - the append (``append_rows``) keeps a pool in the one layout the
@@ -158,9 +164,51 @@ def kv_tokens_visible(lengths, q_lens, window=None):
     return int(lengths.sum())
 
 
+#: rows of one query tile of the ragged kernel: the height at which a
+#: chunk row's products still fill the 128 x 128 MXU
+_QUERY_TILE_ROWS = 128
+
+
+@functools.lru_cache(maxsize=None)      # the host asks at every step
+def query_tile_rows(rows, group, q_dtype):
+    """Rows of one query tile of the ragged kernel, from shapes alone.
+    The kernel's ``rows = n_query * group`` query rows (row =
+    ``s * group + g``) are cut into tiles of whole query positions
+    (multiples of ``group``) and whole sublane tiles of the q dtype (8
+    rows of 32 bits: 16 of bfloat16), the tallest such tile of at most
+    128 rows that divides the bucket: 128 rows for groups 4 and 8 (32 and
+    16 positions), 96 for a group of 6 (16 positions).  A bucket that no
+    such tile divides (a verify bucket of a few tokens) is one tile."""
+    unit = math.lcm(group, 32 // jnp.dtype(q_dtype).itemsize)
+    fits = [t for t in range(unit, min(rows, _QUERY_TILE_ROWS) + 1, unit)
+            if rows % t == 0]
+    return fits[-1] if fits else rows
+
+
+def live_query_tiles(q_lens, group, tile):
+    """Tiles of ``tile`` rows that hold the live queries of rows of
+    these ``q_lens``: a row's queries are its first ``q_len * group``
+    rows.  The one rule of the kernel's trip count and of the host's
+    count (numpy or traced integers)."""
+    return (q_lens * group + tile - 1) // tile
+
+
+def q_positions_computed(q_lens, n_query, group, q_dtype):
+    """Query positions the ragged kernel computes for rows of these
+    ``q_lens`` in an ``n_query`` bucket: every row costs its own queries
+    rounded up to whole tiles, ``live_query_tiles`` tiles of
+    ``tile / group`` positions — ``_decode_kernel``'s trip count a (row,
+    kv head), here on the host for the dispatch record
+    (``kernel.paged_attn.query_useful``).  A bucket of one position is
+    the one-query kernel's: a position a row."""
+    tile = query_tile_rows(n_query * group, group, q_dtype)
+    tiles = live_query_tiles(np.asarray(q_lens, np.int64), group, tile)
+    return int(tiles.sum()) * (tile // group)
+
+
 def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale, page_size, block_pages, n_query=1, group=1,
-                   quantized=False, ragged=False, window=None):
+                   quantized=False, ragged=False, window=None, tile=None):
     """Online-softmax paged attention for ``n_query`` query tokens per
     sequence, one grid step per (row, kv head).  The step WALKS THE ROW'S
     OWN CONTEXT: ``ceil(length / block)`` blocks of ``block_pages`` pages,
@@ -182,11 +230,24 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     ``ragged`` (ISSUE 17): ``lens_ref`` is (2, batch) — kv lengths in
     row 0, PER-ROW query-span lengths in row 1 — and each sequence's
     real queries sit LEFT-aligned in the n_query bucket.  Query ``j``
-    of row ``b`` attends ``cols < kv - qlen + j + 1``; bucket-pad
-    queries (j >= qlen) clamp at the full kv length, computing finite
-    garbage the caller discards.  One grid shape then serves a batch
-    mixing decode rows (qlen 1), prefill/chunk spans, and verify
-    blocks.
+    of row ``b`` attends ``cols < kv - qlen + j + 1``.  One grid shape
+    then serves a batch mixing decode rows (qlen 1), prefill/chunk
+    spans, and verify blocks — and THE QUERY WORK OF A GRID STEP FOLLOWS
+    THE ROW'S OWN ``qlen``: the (n_query * group, d) query block is cut
+    into tiles of ``tile`` rows (whole query positions, whole sublane
+    tiles: ``query_tile_rows``), row = ``s * group + g`` puts the row's
+    live queries in its first ``qlen * group`` rows, and the scratch
+    reset, each context block's scores, mask, online softmax and P.V,
+    and the final division run for the ``ceil(qlen * group / tile)``
+    live tiles only; a block's K and V are loaded once and shared by its
+    tiles.  A one-token row is one tile, a full chunk row all of them
+    (what the whole block computed before the cut); the walk's blocks
+    and their order are the bucket's, so a live query's output does not
+    depend on the tile it falls in.  Dead queries (j >= qlen) are
+    WRITTEN AS ZEROS: whole dead tiles are never computed, and those of
+    the last live tile (which clamp at the full kv length and compute
+    finite values) are zeroed at the end.  The two uniform modes take
+    the whole block as one static tile: the program they always were.
 
     ``quantized`` (ISSUE 9): the K/V pages arrive as INT8 with their
     per-slot f32 scale pages copied alongside — dequantization happens
@@ -263,9 +324,35 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
             x = x.astype(jnp.float32)
         return x.reshape(block, d).astype(dtype)
 
-    m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-    l_scr[:] = jnp.zeros_like(l_scr)
-    acc_scr[:] = jnp.zeros_like(acc_scr)
+    if ragged:
+        # the row's own queries: its first ``qlen * group`` rows are the
+        # live ones (row = s * group + g, left-aligned), in whole tiles
+        qlen = lens_ref[1, b]
+        n_tiles = live_query_tiles(qlen, group, tile)
+
+        def each_tile(act):
+            """``act(rows, row0)`` on every live tile of the query block:
+            ``rows`` indexes the tile in a (rows, ...) ref."""
+            def body(t, carry):
+                row0 = pl.multiple_of(t * tile, tile)
+                act(pl.ds(row0, tile), row0)
+                return carry
+
+            lax.fori_loop(0, n_tiles, body, 0)
+    else:
+        def each_tile(act):             # the whole block, as one
+            act(slice(None), None)
+
+    # a tile's slice of the three (rows, lanes) float32 scratch arrays
+    height = tile if ragged else m_scr.shape[0]
+    narrow, wide = (height, m_scr.shape[1]), (height, acc_scr.shape[1])
+
+    def reset(rows, row0):
+        m_scr[rows] = jnp.full(narrow, -jnp.inf, m_scr.dtype)
+        l_scr[rows] = jnp.zeros(narrow, l_scr.dtype)
+        acc_scr[rows] = jnp.zeros(wide, acc_scr.dtype)
+
+    each_tile(reset)
 
     @pl.when(n_blocks > 0)
     def _first():
@@ -280,56 +367,86 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
         each_page_copy(blk, slot, lambda c: c.wait())
 
-        q = q_ref[0, 0]                         # (n_query*group, d)
-        k = load(k_buf, ks_buf, slot, q.dtype)
-        s = lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        cols = tok0 + blk * block \
-            + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # row r serves query position r // group of the block; its
-        # causal window ends (n_query - 1 - qpos) tokens short of the
-        # full length (the later block tokens it must not see)
-        qpos = lax.broadcasted_iota(jnp.int32, s.shape, 0) // group
-        if ragged:
-            # per-row span: query j's context is kv - qlen + j + 1
-            # tokens; a full row (qlen == n_query) reduces this to the
-            # verify limit below BIT-EXACTLY, so the unified step can
-            # never drift from the legacy modes it replaces
-            qlen = lens_ref[1, b]
-            limit = jnp.minimum(length, length - qlen + 1 + qpos)
-        else:
-            limit = length - (n_query - 1 - qpos)
-        seen = cols < limit
-        if window is not None:
-            seen &= cols >= limit - window
-        s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+        def keys():
+            return load(k_buf, ks_buf, slot, q_ref.dtype)
 
-        m_prev = m_scr[:, :1]
-        m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_next)
-        pexp = jnp.exp(s - m_next)
-        l_scr[:] = jnp.broadcast_to(
-            alpha * l_scr[:, :1] + jnp.sum(pexp, axis=1, keepdims=True),
-            l_scr.shape)
-        # same rounding rule as k above, then the SAME dot the
-        # full-precision path runs on its pages; slots past the length
-        # were never fetched and hold whatever the buffer held — their
-        # weights are exact zeros, and so must they be (0 * NaN)
-        v = load(v_buf, vs_buf, slot, q.dtype)
-        toks = tok0 + blk * block \
-            + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-        v = jnp.where(toks < length, v, jnp.zeros_like(v))
-        acc_scr[:] = acc_scr[:] * alpha + lax.dot_general(
-            pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_next, m_scr.shape)
+        def values():
+            # same rounding rule as the keys, then the SAME dot the
+            # full-precision path runs on its pages; slots past the
+            # length were never fetched and hold whatever the buffer
+            # held — their weights are exact zeros, and so must they be
+            # (0 * NaN)
+            v = load(v_buf, vs_buf, slot, q_ref.dtype)
+            toks = tok0 + blk * block \
+                + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+            return jnp.where(toks < length, v, jnp.zeros_like(v))
+
+        if ragged:
+            # a block's K and V are loaded once and shared by its tiles
+            k_blk, v_blk = keys(), values()
+            keys, values = (lambda: k_blk), (lambda: v_blk)
+
+        def update(rows, row0):
+            q = q_ref[0, 0, rows]               # (tile or rows, d)
+            s = lax.dot_general(q, keys(), (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+            cols = tok0 + blk * block \
+                + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            # row r serves query position r // group of the block; its
+            # causal window ends (n_query - 1 - qpos) tokens short of the
+            # full length (the later block tokens it must not see)
+            qrow = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            if row0 is not None:
+                qrow = qrow + row0
+            qpos = qrow // group
+            if ragged:
+                # per-row span: query j's context is kv - qlen + j + 1
+                # tokens; a full row (qlen == n_query) reduces this to
+                # the verify limit below BIT-EXACTLY, so the unified step
+                # can never drift from the legacy modes it replaces.  The
+                # dead queries of a live tile (j >= qlen) clamp at the
+                # full length: finite, and zeroed at the end
+                limit = jnp.minimum(length, length - qlen + 1 + qpos)
+            else:
+                limit = length - (n_query - 1 - qpos)
+            seen = cols < limit
+            if window is not None:
+                seen &= cols >= limit - window
+            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+
+            m_prev = m_scr[rows, :1]
+            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_next)
+            pexp = jnp.exp(s - m_next)
+            l_scr[rows] = jnp.broadcast_to(
+                alpha * l_scr[rows, :1]
+                + jnp.sum(pexp, axis=1, keepdims=True), narrow)
+            v = values()
+            acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
+                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_scr[rows] = jnp.broadcast_to(m_next, narrow)
+
+        each_tile(update)
         return carry
 
     lax.fori_loop(0, n_blocks, walk, 0)
 
-    l = l_scr[:, :1]
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[0, 0] = (acc_scr[:] / l_safe).astype(o_ref.dtype)
+    if ragged:
+        # the dead tiles: zeros, never what the buffer held
+        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
+
+    def finish(rows, row0):
+        l = l_scr[rows, :1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        out = acc_scr[rows] / l_safe
+        if ragged:
+            # the dead queries of the row's last live tile: zeros too
+            qrow = row0 + lax.broadcasted_iota(jnp.int32, out.shape, 0)
+            out = jnp.where(qrow < qlen * group, out, 0.0)
+        o_ref[0, 0, rows] = out.astype(o_ref.dtype)
+
+    each_tile(finish)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
@@ -341,7 +458,8 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
     (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
     ``q_lens`` (batch,) int32 selects the RAGGED kernel: per-row query
-    spans left-aligned in the n_query bucket (ISSUE 17).
+    spans left-aligned in the n_query bucket (ISSUE 17), computed in
+    tiles of :func:`query_tile_rows` rows, a row's live tiles only.
 
     The grid is (batch, kv_heads); the pools are handed over whole and
     stay in HBM, and each grid step walks its row's context in blocks of
@@ -389,17 +507,19 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
             jnp.broadcast_to(x, x.shape[:-1] + (128,))
             for x in (k_scales, v_scales))
     ragged = q_lens is not None
+    tile = None
     if ragged:
         # both length kinds ride in ONE (2, batch) scalar-prefetch
         # argument — the index maps never read it, so the grid spec is
         # unchanged from the uniform path
         lengths = jnp.stack([jnp.asarray(lengths, jnp.int32),
                              jnp.asarray(q_lens, jnp.int32)])
+        tile = query_tile_rows(rows, group, q.dtype)
     kernel = functools.partial(_decode_kernel, scale=scale,
                                page_size=page_size,
                                block_pages=block_pages, n_query=n_query,
                                group=group, quantized=quantized,
-                               ragged=ragged, window=window)
+                               ragged=ragged, window=window, tile=tile)
     q_spec = pl.BlockSpec((1, 1, rows, lanes),
                           lambda b, h, lens, tabs: (b, h, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -558,7 +678,7 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
     cols = jnp.arange(max_tokens, dtype=jnp.int32)[None, None, None, :]
     # row b's real queries sit LEFT-aligned in the bucket: query j sees
     # cols < kv - qlen + j + 1; bucket pads (j >= qlen) clamp at kv and
-    # compute discarded garbage
+    # are zeroed, as the kernel writes them
     qpos = jnp.arange(n_query, dtype=jnp.int32)[None, None, :, None]
     kv = lengths[:, None, None, None].astype(jnp.int32)
     ql = q_lens[:, None, None, None].astype(jnp.int32)
@@ -566,6 +686,7 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
     s = jnp.where(_seen(cols, limit, window), s, DEFAULT_MASK_VALUE)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
+    out = jnp.where(qpos < ql, out, 0.0)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
@@ -647,8 +768,10 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
 
     q:           (batch, max_q, q_heads, head_dim) — row ``b``'s
                  ``q_lens[b]`` real query tokens sit LEFT-aligned in
-                 the ``max_q`` bucket; pad positions compute finite
-                 garbage the caller discards
+                 the ``max_q`` bucket; pad positions come back as ZEROS
+                 (the kernel computes a row's own queries in whole
+                 tiles, :func:`query_tile_rows`, and writes zeros for
+                 the rest; the XLA path zeroes them after the fact)
     lengths:     (batch,) int32 — valid cached tokens per sequence
                  INCLUDING the row's whole span (already scattered
                  into the pages)

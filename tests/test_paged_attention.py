@@ -11,7 +11,9 @@ import paddle_tpu as paddle
 from paddle_tpu.ops.pallas.paged_attention import (
     PagedKVCache, append_rows, paged_attention, paged_attention_multi,
     paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla,
-    kv_tokens_walked, quantize_kv, walk_block_pages)
+    kv_tokens_walked, live_query_tiles, q_positions_computed,
+    query_tile_rows, quantize_kv, walk_block_pages)
+from paddle_tpu.ops.pallas import paged_attention as paged_attention_mod
 from paddle_tpu.ops.pallas.flash_attention import mha_reference
 from paddle_tpu.ops.pallas.fused_norm_rope import (
     rms_norm_pallas, rms_norm_xla, fused_rope_pallas, fused_rope_xla)
@@ -523,6 +525,154 @@ class TestContextWalk:
         assert kv_tokens_walked([1, 511, 512, 513, 0], 512) == \
             512 + 512 + 512 + 1024
         assert kv_tokens_walked(np.asarray([4096] * 8), 512) == 8 * 4096
+
+
+class TestRaggedQueryTiles:
+    """The ragged kernel computes a row's own queries (ISSUE 40): the
+    (span x group) query block in tiles of whole query positions, a
+    row's live tiles only; dead queries are zeros; every live query
+    equals the whole block computed as ONE tile, which is what the
+    kernel computed before the cut."""
+
+    SPAN = 64
+    # a decode row, a verify row of a few, a ragged tail over a tile's
+    # edge, the full bucket, a row of NO query, the engine's pad row
+    Q_LENS = [1, 3, 37, 64, 0, 1]
+    LENS = [700, 40, 1100, 64, 5, 1]
+
+    @staticmethod
+    def _case(rng, group, kv, d, q_lens, lens, span, kvh=2):
+        """``TestContextWalk``'s ragged case with the model's own dtype
+        for the queries over int8 pages too, as the engine's."""
+        q, *rest = TestContextWalk._case(
+            rng, "ragged", kv, lens, q_heads=group * kvh, kvh=kvh, d=d,
+            span=span, q_lens=q_lens)
+        return (q.astype(jnp.bfloat16), *rest)
+
+    @staticmethod
+    def _one_tile(monkeypatch, q, kp, vp, lens, q_lens, tabs, scale, kw,
+                  window):
+        """The ragged kernel with the whole bucket as ONE tile: every
+        query position of every row against every block, the rule
+        before the cut (a jitted call would keep the tiled program)."""
+        monkeypatch.setattr(paged_attention_mod, "query_tile_rows",
+                            lambda rows, group, dtype: rows)
+        out = paged_attention_mod._decode_pallas.__wrapped__(
+            q, kp, vp, lens, tabs, scale, interpret=True,
+            n_query=q.shape[1], q_lens=q_lens, window=window, **kw)
+        monkeypatch.undo()
+        return out
+
+    @pytest.mark.parametrize("d", [128, 64])
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    @pytest.mark.parametrize("window", [None, 512])
+    @pytest.mark.parametrize("group", [4, 6, 8])
+    def test_live_queries_are_the_whole_block_s_and_dead_ones_zero(
+            self, monkeypatch, group, window, kv, d):
+        rng = np.random.default_rng(40)
+        args = self._case(rng, group, kv, d, self.Q_LENS, self.LENS,
+                          self.SPAN)
+        q, q_lens = args[0], np.asarray(self.Q_LENS)
+        rows = self.SPAN * group
+        tile = query_tile_rows(rows, group, q.dtype)
+        assert tile == (96 if group == 6 else 128) and rows // tile >= 2
+        out = paged_attention_ragged(*args[:6], interpret=True,
+                                     window=window, **args[7])
+        whole = self._one_tile(monkeypatch, *args, window)
+        ref = _ragged_xla(*args[:7], window=window, **args[7])
+        live = np.arange(self.SPAN)[None, :] < q_lens[:, None]
+        out, whole, ref = (np.asarray(x.astype(jnp.float32))
+                           for x in (out, whole, ref))
+        # bit for bit: a row's scores, softmax and products do not
+        # depend on the other rows of its tile
+        np.testing.assert_array_equal(out[live], whole[live])
+        np.testing.assert_allclose(out[live], ref[live], rtol=2e-2,
+                                   atol=2e-2)
+        # dead queries: exact zeros from the kernel and from the oracle
+        assert not out[~live].any() and not ref[~live].any()
+        assert np.isfinite(out).all()
+        # the host's count is the kernel's rule: tiles by hand
+        per = tile // group
+        tiles = [-(-n * group // tile) for n in self.Q_LENS]
+        assert list(live_query_tiles(q_lens, group, tile)) == tiles
+        assert q_positions_computed(q_lens, self.SPAN, group,
+                                    q.dtype) == sum(tiles) * per
+        assert tiles[0] == tiles[1] == tiles[5] == 1 and tiles[4] == 0
+        assert tiles[3] == rows // tile
+
+    @pytest.mark.parametrize("group", [4, 6])
+    def test_full_rows_of_several_tiles_are_the_verify_kernel_s(
+            self, group):
+        """Every row full: the tiled walk is ``paged_attention_multi``'s
+        one static block bit for bit — the uniform kernels are
+        untouched and the ragged one cannot drift from them."""
+        rng = np.random.default_rng(41)
+        lens = [700, 64, 300]
+        args = self._case(rng, group, "bf16", 128, [self.SPAN] * 3, lens,
+                          self.SPAN)
+        out = paged_attention_ragged(*args[:6], interpret=True)
+        multi = paged_attention_multi(*args[:4], args[5], interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(out.astype(jnp.float32)),
+            np.asarray(multi.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("rows,group,dtype,tile", [
+        (512, 4, jnp.bfloat16, 128),    # the Mistral cell: 32 positions
+        (768, 6, jnp.bfloat16, 96),     # Laguna's full layers: 16
+        (1024, 8, jnp.bfloat16, 128),   # Laguna's sliding layers: 16
+        (192, 6, jnp.bfloat16, 96),     # its span-32 bucket
+        (96, 6, jnp.bfloat16, 96),      # span 16: one tile
+        (128, 4, jnp.float32, 128),
+        (384, 3, jnp.float32, 96),      # float32: sublane tiles of 8
+        (24, 6, jnp.bfloat16, 24),      # no whole tile divides: one tile
+        (8, 4, jnp.bfloat16, 8),        # a verify bucket of two tokens
+        (4, 4, jnp.bfloat16, 4),        # the one-query kernel's block
+    ])
+    def test_tile_rule_reads_shapes_only(self, rows, group, dtype, tile):
+        assert query_tile_rows(rows, group, dtype) == tile
+        assert tile % group == 0 and rows % tile == 0
+
+    def test_positions_computed_by_bucket(self):
+        """A chunk step of the two cells: one 128-token row, seven of one
+        token (a pad row is one token long); a decode-only step is the
+        one-query kernel's, a position a row."""
+        ql = [128, 1, 1, 1, 1, 1, 1, 1]
+        assert q_positions_computed(ql, 128, 4, jnp.bfloat16) == 128 + 7 * 32
+        assert q_positions_computed(ql, 128, 6, jnp.bfloat16) == 128 + 7 * 16
+        assert q_positions_computed(ql, 128, 8, jnp.bfloat16) == 128 + 7 * 16
+        assert q_positions_computed([37, 1], 128, 4, jnp.bfloat16) == 64 + 32
+        assert q_positions_computed([1] * 8, 1, 4, jnp.bfloat16) == 8
+
+    def test_one_loop_more_in_each_of_three_places(self):
+        """ONE body: the ragged kernel is the uniform kernel with a loop
+        over the live tiles around the scratch reset, the block update
+        and the final division — no second walk, no second program."""
+        def loops(**kw):
+            q = jnp.zeros((2, 64, 8, 128), jnp.bfloat16)
+            pool = jnp.zeros((2, 8, 16, 128), jnp.bfloat16)
+            text = str(jax.make_jaxpr(
+                lambda *a: paged_attention_mod._decode_pallas.__wrapped__(
+                    *a, 0.1, n_query=64, **kw))(
+                q, pool, pool, jnp.ones((2,), jnp.int32),
+                jnp.zeros((2, 4), jnp.int32)))
+            return text.count("while[") + text.count("scan[")
+
+        assert loops(q_lens=jnp.ones((2,), jnp.int32)) == loops() + 3
+
+    def test_pad_positions_of_the_packed_stream_are_zeros(self):
+        """``_packed_of_rows`` hands the last row's dead columns to the
+        packed stream's pad positions: zeros now, whatever the kernel's
+        scratch held."""
+        from paddle_tpu.inference.paged import _packed_of_rows
+        rng = np.random.default_rng(42)
+        q_lens, lens = [16, 1, 1, 1], [300, 70, 9, 1]
+        args = self._case(rng, 4, "bf16", 128, q_lens, lens, 16, kvh=1)
+        out = paged_attention_ragged(*args[:6], interpret=True)
+        off = jnp.asarray(np.cumsum([0] + q_lens[:-1]), jnp.int32)
+        packed = np.asarray(_packed_of_rows(out, off, 24)
+                            .astype(jnp.float32))
+        assert packed[:sum(q_lens)].any(axis=(1, 2)).all()
+        assert not packed[sum(q_lens):].any()
 
 
 class TestFusedNormRope:

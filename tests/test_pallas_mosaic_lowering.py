@@ -543,6 +543,25 @@ class TestLagunaCellLowering:
                           pages=8192, page=16, table=512, nq=nq, int8=int8,
                           ragged=nq > 1))
 
+    @pytest.mark.parametrize("nq", [16, 32, 64, 128])
+    @pytest.mark.parametrize("heads,window,kvh", [
+        (32, None, 8), (48, None, 8), (64, 512, 8)],
+        ids=["mistral32", "full48", "sliding64"])
+    def test_ragged_query_tiles(self, chip, heads, window, kvh, nq):
+        """The ragged kernel's query tiles at every span the two serving
+        cells' mixes can ask for: 512, 768 and 1,024 rows at span 128 in
+        tiles of 128, 96 and 128 rows, sliced out of the q block, the
+        scratch and the output at a traced row offset."""
+        group = heads // kvh
+        tile = paged_attention.query_tile_rows(nq * group, group, BF16)
+        assert tile == min(nq * group, 96 if group == 6 else 128)
+        text = chip.compile(
+            _paged_fn(128, nq=nq, ragged=True, window=window),
+            *_paged_specs(chip, kvh=kvh, heads=heads, d=128, batch=8,
+                          pages=8192, page=16, table=512, nq=nq,
+                          ragged=True))
+        assert "paged_attention_ragged" in text
+
     @pytest.mark.parametrize("tokens", [8, 272])
     def test_grouped_experts(self, chip, monkeypatch, tokens):
         monkeypatch.setattr(moe_grouped_ffn, "_use_pallas", lambda: True)

@@ -1,0 +1,211 @@
+#!/usr/bin/env python3
+"""One ragged paged-attention call on the chip at the two serving cells'
+shapes: one 128-token chunk row + k one-token rows (k = 3, 5, 7; the rest
+of the 8-row bucket is the engine's pad rows, one token long) at contexts
+400 / 2,000 / 4,000 / 7,000, full and window 512 — the device time of the
+ragged kernel, and of the one-query kernel on the same rows.
+
+    chiprun -- python3 tools/paged_ragged_micro.py --out chiprun_out/micro_change.json
+    python3 tools/paged_ragged_micro.py --repo <a checkout> --out ...   # another tree's kernel
+    python3 tools/paged_ragged_micro.py --table parent.json change.json
+
+Device times are read from a profiler trace (the kernels' own events on the
+device's op line, the mean of ``--iters`` calls); ``host_ms`` beside them is
+the host's clock over the same calls queued back to back (the jitted call
+with its two transposes).  Inputs are made from ``--seed``, so two trees'
+runs see the same arrays: ``live_sha`` is the digest of the live queries'
+output bytes, ``dead_nonzero`` counts dead query positions that are not
+zero.  ``--rehearse`` runs tiny shapes through the interpreter on the CPU
+and reports no time."""
+import argparse
+import hashlib
+import json
+import os
+import sys
+import tempfile
+import time
+
+SHAPES = {
+    # name: q heads, kv heads, window, pages in the pool, table pages
+    "mistral": (32, 8, None, 4096, 256),
+    "laguna-full": (48, 8, None, 8192, 512),
+    "laguna-sliding": (64, 8, 512, 8192, 512),
+}
+ROWS, SPAN, PAGE, D = 8, 128, 16, 128
+CONTEXTS, ONES = (400, 2000, 4000, 7000), (3, 5, 7)
+# an op's event is named by its whole HLO line: match the instruction's
+# own name, not an operand that names it
+RAGGED = r"^%?paged_attention_ragged[.\d]* = "
+ONE_QUERY = r"^%?paged_attention[.\d]* = "
+
+
+def case_inputs(np, jnp, rng, shape, ctx, k, small):
+    heads, kvh, _window, pages, table = SHAPES[shape]
+    if small:
+        pages, table = 256, 32
+    lens = np.ones(ROWS, np.int32)
+    q_lens = np.ones(ROWS, np.int32)
+    lens[0], q_lens[0] = ctx, min(SPAN, ctx)        # the chunk row
+    lens[1:1 + k] = ctx                             # the one-token rows
+    need = -(-lens // PAGE)
+    tabs = np.zeros((ROWS, table), np.int32)
+    perm = rng.permutation(pages)
+    at = 0
+    for i, n in enumerate(need):
+        tabs[i, :n] = perm[at:at + n]
+        at += n
+    q = jnp.asarray(rng.standard_normal((ROWS, SPAN, heads, D)),
+                    jnp.bfloat16)
+    return q, jnp.asarray(lens), jnp.asarray(q_lens), jnp.asarray(tabs)
+
+
+def device_ms(trace, pattern, groups, iters):
+    """Mean duration of the events matching ``pattern`` on the device's op
+    line, in order, ``iters`` a group; nothing where the trace does not
+    hold ``groups x iters`` of them."""
+    from benchmark import xplane
+    ms = xplane.durations_ms(trace, pattern, xplane.OPS_LINE)
+    if len(ms) != groups * iters:
+        names = sorted({name[:80] for p in xplane.device_planes(trace)
+                        for name, _s, _d in xplane.ops(p)})
+        print(f"paged_ragged_micro: {len(ms)} events match {pattern!r}, "
+              f"{groups} x {iters} were run; the line's ops: {names}",
+              file=sys.stderr)
+        return [None] * groups
+    return [sum(ms[g * iters:(g + 1) * iters]) / iters
+            for g in range(groups)]
+
+
+def run(args):
+    if args.repo:
+        sys.path.insert(0, os.path.abspath(args.repo))
+    else:
+        sys.path.insert(0, os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from paddle_tpu.ops.pallas import paged_attention as pa
+
+    if args.tile_rows:
+        pa._QUERY_TILE_ROWS = args.tile_rows
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.rehearse:
+        raise SystemExit("paged_ragged_micro: needs a TPU (or --rehearse)")
+    interpret = args.rehearse
+    contexts = (40, 300) if args.rehearse else CONTEXTS
+    iters = 1 if args.rehearse else args.iters
+    out = {"device": dev.device_kind, "tree": os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.dirname(pa.__file__)))),
+        "iters": iters, "seed": args.seed, "cases": [],
+        "tile_rows": getattr(pa, "_QUERY_TILE_ROWS", None)}
+    for shape, (heads, kvh, window, pages, table) in SHAPES.items():
+        rng = np.random.default_rng(args.seed)
+        if args.rehearse:
+            pages = 256
+        pool = (kvh, pages, PAGE, D)
+        kp = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
+        vp = jnp.asarray(rng.standard_normal(pool), jnp.bfloat16)
+        ragged = jax.jit(lambda q, l, ql, t, kp, vp, w=window:
+                         pa.paged_attention_ragged(
+                             q, kp, vp, l, ql, t, interpret=interpret,
+                             window=w))
+        one = jax.jit(lambda q, l, t, kp, vp, w=window: pa.paged_attention(
+            q, kp, vp, l, t, interpret=interpret, window=w))
+        cases = [(c, k) for c in contexts for k in ONES
+                 if c <= table * PAGE or args.rehearse]
+        made = [case_inputs(np, jnp, rng, shape, c, k, args.rehearse)
+                for c, k in cases]
+        for q, l, ql, t in made[:1]:                # compile both
+            jax.block_until_ready((ragged(q, l, ql, t, kp, vp),
+                                   one(q[:, 0], l, t, kp, vp)))
+        trace_dir = tempfile.mkdtemp(prefix="paged_micro_")
+        if not args.rehearse:
+            jax.profiler.start_trace(trace_dir)
+        rows = []
+        for (c, k), (q, l, ql, t) in zip(cases, made):
+            host = {}
+            for name, fn, a in (
+                    ("ragged", ragged, (q, l, ql, t, kp, vp)),
+                    ("one_query", one, (q[:, 0], l, t, kp, vp))):
+                t0 = time.perf_counter()
+                for _ in range(iters):
+                    y = fn(*a)
+                jax.block_until_ready(y)
+                host[name] = (time.perf_counter() - t0) / iters * 1e3
+                if name == "ragged":
+                    got = np.asarray(y.astype(jnp.float32))
+            live = np.arange(SPAN)[None, :] < np.asarray(ql)[:, None]
+            rows.append({
+                "shape": shape, "context": c, "one_token_rows": k,
+                "ragged_host_ms": host["ragged"],
+                "one_query_host_ms": host["one_query"],
+                "live_sha": hashlib.sha256(
+                    got[live].tobytes()).hexdigest()[:16],
+                "dead_nonzero": int(np.count_nonzero(got[~live])),
+                "nan": bool(np.isnan(got).any())})
+            if args.keep and c == contexts[0] and k == ONES[0]:
+                np.save(os.path.join(args.keep, f"{shape}.npy"), got[live])
+        if not args.rehearse:
+            jax.profiler.stop_trace()
+            from benchmark import xplane
+            trace = xplane.load(trace_dir)
+            for row, r, o in zip(rows,
+                                 device_ms(trace, RAGGED, len(rows), iters),
+                                 device_ms(trace, ONE_QUERY, len(rows),
+                                           iters)):
+                row["ragged_ms"], row["one_query_ms"] = r, o
+        out["cases"] += rows
+        del kp, vp
+    text = json.dumps(out)
+    if args.out:
+        os.makedirs(os.path.dirname(os.path.abspath(args.out)),
+                    exist_ok=True)
+        with open(args.out, "w") as f:
+            f.write(text)
+    print("MICRO", text)
+
+
+def table(parent_file, change_file):
+    parent, change = (json.load(open(f)) for f in (parent_file, change_file))
+    print("| shape | context | one-token rows | parent ms | change ms | "
+          "change / parent | one-query kernel ms | live queries |")
+    print("| --- | --- | --- | --- | --- | --- | --- | --- |")
+    for p, c in zip(parent["cases"], change["cases"]):
+        assert (p["shape"], p["context"], p["one_token_rows"]) == \
+            (c["shape"], c["context"], c["one_token_rows"])
+        same = ("bit for bit" if p["live_sha"] == c["live_sha"]
+                else "DIFFER")
+        key = "ragged_ms" if p.get("ragged_ms") else "ragged_host_ms"
+        one = c.get("one_query_ms") or c["one_query_host_ms"]
+        print(f"| {c['shape']} | {c['context']} | {c['one_token_rows']} | "
+              f"{p[key]:.3f} | {c[key]:.3f} | {c[key] / p[key]:.2f} | "
+              f"{one:.3f} | {same} |")
+    bad = [c for c in change["cases"] if c["dead_nonzero"] or c["nan"]]
+    print(f"\nchange: dead query positions not zero in {len(bad)} of "
+          f"{len(change['cases'])} cases")
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--repo", help="import paddle_tpu from this checkout")
+    ap.add_argument("--out", help="write the JSON here too")
+    ap.add_argument("--keep", help="directory for the first case's live "
+                                   "outputs a shape (.npy)")
+    ap.add_argument("--iters", type=int, default=20)
+    ap.add_argument("--seed", type=int, default=40)
+    ap.add_argument("--tile-rows", type=int,
+                    help="try another height of the query tile (the "
+                         "program has no such option: its rule is "
+                         "query_tile_rows)")
+    ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--table", nargs=2, metavar=("PARENT", "CHANGE"))
+    args = ap.parse_args()
+    if args.table:
+        table(*args.table)
+    else:
+        run(args)
+
+
+if __name__ == "__main__":
+    main()
