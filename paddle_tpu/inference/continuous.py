@@ -133,7 +133,7 @@ import numpy as np
 from .. import monitor
 from ..monitor.gc_hooks import pause_ns as gc_pause_ns
 from ..monitor.trace import get_tracer as _get_tracer
-from ..ops.pallas.paged_attention import PagedKVCache
+from ..ops.pallas.paged_attention import PagedKVCache, paged_layout
 from ..testing import faults as _faults
 from .scheduler import (DEFAULT_CLASS, PriorityClass, QueueFull,
                         WorkloadScheduler)
@@ -339,7 +339,11 @@ _STEP_SUMS = {
          "in whole tiles, a layer's worth"),
         ("state_bytes", "bytes of recurrent state the unified steps' rows "
          "read and wrote, as the equations count a state (a row that "
-         "carries a token: a layer's state once in, once out)"))}
+         "carries a token: a layer's state once in, once out)"),
+        ("kv_tokens_walked_shared", "KV positions the paged kernel walked "
+         "for layers that read ANOTHER layer's pages (they append nothing "
+         "and hold no pool): their part of the steps' walk, a paged "
+         "call's worth"))}
 # recurrent slots (a model whose layers carry a state of fixed size a
 # sequence): the cache's slot pool beside the page pools
 _slots_taken = monitor.counter(
@@ -782,8 +786,8 @@ class ContinuousBatchingEngine:
         # recurrent state a sequence (``recurrent_state``) are served by
         # the ragged unified step alone, a slot a sequence beside the
         # pages; what cannot hold for such a state refuses here
-        self._recurrent = (hasattr(model, "recurrent_state")
-                           and model.recurrent_state() is not None)
+        self.cache_layout = paged_layout(model)
+        self._recurrent = self.cache_layout["state"] is not None
         if self._recurrent:
             self._refuse_for_recurrent(
                 draft_model=draft_model, kv_quant=kv_quant, tp=tp,
@@ -1059,8 +1063,12 @@ class ContinuousBatchingEngine:
                 "page is truncated; S has summed the rejected tokens in)")
         if kv_quant is not None:
             raise ValueError(
-                f"kv_quant={kv_quant!r}: {name} has no K/V page to "
-                "quantise; its state is float32 slots")
+                f"kv_quant={kv_quant!r}: {name} carries a recurrent state "
+                "in float32 slots, which no mode quantises, "
+                + ("and has no K/V page to quantise"
+                   if not self.cache_layout["pools"] else
+                   "and its K/V pages have not been held to a reference "
+                   "in int8 beside them"))
         if int(tp) > 1:
             from ..framework.jax_compat import make_tp_mesh
             from .paged import _tp_plan

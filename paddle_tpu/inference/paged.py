@@ -29,6 +29,7 @@ from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
+                                          paged_layout,
                                           _round_up, append_rows,
                                           dequantize_kv, kv_tokens_visible,
                                           kv_tokens_walked, paged_attention,
@@ -103,7 +104,14 @@ def _tp_plan(model, mesh):
     attn_layers = []
     col, row = P(None, "tensor"), P("tensor", None)
     for i, layer in enumerate(layers):
-        attn, mlp = layer.self_attn, layer.mlp
+        attn, mlp = (getattr(layer, n, None) for n in ("self_attn", "mlp"))
+        if attn is None or mlp is None:
+            raise ValueError(
+                f"tensor-parallel serving plans a LLaMA-shaped block; "
+                f"layer {i} of {type(model).__name__} has no "
+                f"self_attn / mlp pair (its mixer is a "
+                f"{type(getattr(layer, 'mixer', layer)).__name__}: the "
+                "plan has no placement for it)")
         # the plan knows one block: q/k/v/o and a dense gate/up/down.
         # What a layer has beyond it would be left replicated or summed
         # wrongly, so the plan names it and refuses
@@ -281,8 +289,18 @@ _NO_WINDOW = ("a sliding-attention layer (window=...) reached {}, which "
               "whose paged kernels apply it")
 
 
+#: what a path that appends in every call says to a layer that reads
+#: another layer's pages (or scales its scores itself)
+_NO_SHARED = ("a layer that attends another layer's pages (k=None) or "
+              "sets its own score scale reached {}, which appends K/V in "
+              "every call at the head's own scale: serve this model "
+              "through the ragged unified step (ContinuousBatchingEngine("
+              "prefill_chunk_tokens=...))")
+
+
 #: what a path with no slots says to a retention layer
-_NO_SLOTS = ("a retention layer (a recurrent state a sequence) reached {}, "
+_NO_SLOTS = ("a retention layer or a Mamba layer (a recurrent state a "
+             "sequence) reached {}, "
              "which carries no slot pools: serve this model through the "
              "ragged unified step (ContinuousBatchingEngine("
              "prefill_chunk_tokens=...)), whose rows each update their own "
@@ -305,12 +323,14 @@ class _PagedContext:
         self.prefill = prefill
         self.layer_idx = 0
 
-    def retain(self, q, k, v, log_g):
+    def retain(self, *args):
         raise NotImplementedError(_NO_SLOTS.format(
             "the eager paged context of PagedGenerator"))
 
+    conv_rows = scan_rows = retain
+
     def attend(self, q: Tensor, k: Tensor, v: Tensor,
-               window: Optional[int] = None) -> Tensor:
+               window: Optional[int] = None, scale=None) -> Tensor:
         """q/k/v: (batch, s, heads, head_dim) post-rope.  Writes k/v into
         the pages, returns the attention output (batch, s, q_heads, d).
         ``window``: the calling layer is a sliding-attention layer of
@@ -321,6 +341,9 @@ class _PagedContext:
         if window is not None and self.prefill:
             raise NotImplementedError(_NO_WINDOW.format(
                 "the eager prefill's dense flash attention"))
+        if k is None or scale is not None:
+            raise NotImplementedError(_NO_SHARED.format(
+                "the eager paged context of PagedGenerator"))
         # whole batch in ONE scatter per pool (not per sequence — the
         # per-seq loop copied the full pool batch times per step)
         cache.write_batch(layer, self.seq_ids, k._data, v._data)
@@ -391,7 +414,12 @@ class _TracedPagedContext:
     pool a retention layer, donated at the jit boundary like the page
     pools; ``slots`` the rows' slots, ``chunk_rows`` the rows of several
     tokens) updated in place by the ragged step, each row against its
-    own, a pad row's untouched.  Only the ragged step carries slots."""
+    own, a pad row's untouched; ``conv_rows`` / ``scan_rows`` — the same for
+    a Mamba layer, whose slot is two arrays (the convolution's tail and the
+    scan's ``h``: two pools a layer, side by side in ``states``).  Only the
+    ragged step carries slots.  ``attend(q, None, None)`` attends WITHOUT
+    appending, against the pool ``layer_idx`` names: a layer that reads
+    the pages another layer wrote earlier in the same program."""
 
     def __init__(self, k_pages, v_pages, pg, sl, lens=None, tables=None,
                  prefill=False, prefix_lens=None, k_scales=None,
@@ -437,22 +465,59 @@ class _TracedPagedContext:
         Every row's slot of this layer's pool is read, updated and
         written in place (``ops/power_retention.py::retention_step``);
         returns (tokens, 1, q_heads, d) float32."""
+        from ..ops.power_retention import retention_step
+        slots, ctx, q_lens, off = self._recur_rows()
+        i = self.state_idx
+        self.state_idx += 1
+        y, self.states[i] = retention_step(
+            self.states[i], slots, ctx, q_lens, off, self.chunk_rows,
+            q._data[:, 0], k._data[:, 0], v._data[:, 0], log_g,
+            span=self.span)
+        self._count_state_rows(self.states[i])
+        return wrap_array(y[:, None])
+
+    def _count_state_rows(self, pool):
+        """The rows that carry a token, a layer: the dispatch record's
+        ``state_rows`` and ``state_bytes`` are read off this sum."""
+        self.count(state_row_layers=jnp.sum(self.slots < pool.shape[0] - 1))
+
+    def _recur_rows(self):
+        """What a state op is told of the step's rows."""
         if self.q_lens is None or self.slots is None:
             raise NotImplementedError(_NO_SLOTS.format(
                 "the prefill / prefix / chunk_prefill programs" if
                 self.prefill else "the decode / verify programs"))
-        from ..ops.power_retention import retention_step
+        return (self.slots, self.lens - self.q_lens, self.q_lens,
+                self.row_off)
+
+    def conv_rows(self, x, w, b):
+        """A Mamba layer's causal convolution over the step's packed
+        tokens ``x`` (tokens, channels), each row continuing from its
+        slot's tail, which moves on by the row's tokens
+        (``ops/selective_scan.py::conv_step``).  The tail is the SECOND
+        array of the layer's slot; ``scan_rows``, which the layer calls
+        next, takes the first and moves on to the next layer's."""
+        from ..ops.selective_scan import conv_step
+        rows = self._recur_rows()
+        i = self.state_idx + 1
+        y, self.states[i] = conv_step(self.states[i], *rows, x, w, b,
+                                      span=self.span)
+        return y
+
+    def scan_rows(self, u, delta, a, B, C, d):
+        """A Mamba layer's selective scan over the step's packed tokens
+        (``ops/selective_scan.py::scan_step``): every row's ``h`` read,
+        moved on by the row's tokens and written back in place; returns m
+        (tokens, channels) float32."""
+        from ..ops.selective_scan import scan_step
+        slots, ctx, q_lens, off = self._recur_rows()
         i = self.state_idx
-        self.state_idx += 1
-        y, self.states[i] = retention_step(
-            self.states[i], self.slots, self.lens - self.q_lens,
-            self.q_lens, self.row_off, self.chunk_rows, q._data[:, 0],
-            k._data[:, 0], v._data[:, 0], log_g, span=self.span)
-        # the rows that carry a token, a layer: the dispatch record's
-        # ``state_rows`` and ``state_bytes`` are read off this sum
-        scratch = self.states[i].shape[0] - 1
-        self.count(state_row_layers=jnp.sum(self.slots < scratch))
-        return wrap_array(y[:, None])
+        self.state_idx += 2
+        m, self.states[i] = scan_step(
+            self.states[i], slots, ctx, q_lens, off, self.chunk_rows, u,
+            delta, a, B, C, d, span=self.span)
+        self._count_state_rows(self.states[i])
+        return m
 
     def count(self, **named):
         for name, value in named.items():
@@ -496,11 +561,20 @@ class _TracedPagedContext:
             return None, None
         return self.k_scales[layer], self.v_scales[layer]
 
-    def attend(self, q, k, v, window=None):
+    def attend(self, q, k, v, window=None, scale=None):
         """``window``: the calling layer is a sliding-attention layer of
         that width.  The paged kernels apply it; the prefill modes'
-        dense attention has none and refuses at trace time."""
+        dense attention has none and refuses at trace time.  ``k`` /
+        ``v`` None: nothing is appended, the queries attend what pool
+        ``layer_idx`` holds for the rows (ragged step only).  ``scale``:
+        the scores' scale where it is not the page head's width^-1/2."""
         layer = self.layer_idx
+        if (k is None or scale is not None) and self.q_lens is None:
+            raise NotImplementedError(_NO_SHARED.format(
+                "the prefill / prefix / chunk_prefill programs" if
+                self.prefill else "the decode / verify programs"))
+        if k is None:
+            return self._attend_ragged(q, layer, window, scale)
         b, s = k.shape[0], k.shape[1]
         kvh, d = k.shape[2], k.shape[3]
         if window is not None and self.prefill:
@@ -536,13 +610,7 @@ class _TracedPagedContext:
         # packed; the pad queries come back as zeros and what the
         # layers behind make of them is discarded by the program's tail
         if self.q_lens is not None:
-            rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
-            out = paged_attention_ragged(rect, kp, vp, self.lens,
-                                         self.q_lens, self.tables,
-                                         k_scales=ksc, v_scales=vsc,
-                                         window=window)
-            return wrap_array(
-                _packed_of_rows(out, self.row_off, b)[:, None])
+            return self._attend_ragged(q, layer, window, scale)
         # decode / verify: s tokens per row scatter flat (s == 1 is the
         # classic decode step; s > 1 is the speculative verify block)
         if s == 1:
@@ -554,6 +622,19 @@ class _TracedPagedContext:
                                     self.tables, k_scales=ksc,
                                     v_scales=vsc, window=window)
         return wrap_array(out)
+
+    def _attend_ragged(self, q, layer, window, scale):
+        """The ragged step's kernel call against pool ``layer`` as it
+        stands: the packed queries to the (rows, span) rectangle and the
+        output back."""
+        ksc, vsc = self._layer_scales(layer)
+        rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
+        out = paged_attention_ragged(
+            rect, self.k_pages[layer], self.v_pages[layer], self.lens,
+            self.q_lens, self.tables, scale=scale, k_scales=ksc,
+            v_scales=vsc, window=window)
+        return wrap_array(
+            _packed_of_rows(out, self.row_off, q.shape[0])[:, None])
 
 
 #: rows the feed's index and token vectors are padded to (the rows
@@ -649,26 +730,22 @@ class JittedPagedDecoder:
         self.model = model
         self.params = model.parameters()
         self.max_position = int(model.config.max_position_embeddings)
-        # what each layer's paged call looks like, for the dispatch
-        # record's count of the kernels' walk: (query heads a KV head,
-        # window or None) a layer.  A model whose layers differ says so
-        # (``attention_kinds``); the others have one kind
-        mc = model.config
-        kinds = (model.attention_kinds()
-                 if hasattr(model, "attention_kinds") else
-                 [(mc.num_attention_heads, None)] * mc.num_hidden_layers)
-        # a model whose layers carry a recurrent state a sequence says so
-        # (``recurrent_state``: layers, a slot's shape, the bytes of it
-        # the equations count): its ragged program takes the slot pools
-        # as one more donated operand and hands them back
-        self._state = (model.recurrent_state()
-                       if hasattr(model, "recurrent_state") else None)
+        # what each paged call looks like (``paged_layout``), for the
+        # dispatch record's count of the kernels' walk: how many calls
+        # there are of each (query heads a page's KV head, window or None,
+        # walks a pool another call opened).  A model whose layers carry a
+        # recurrent state a sequence says so (``recurrent_state``: layers,
+        # a slot's arrays, the bytes of them the equations count): its
+        # ragged program takes the slot pools as one more donated operand
+        # and hands them back
+        layout = paged_layout(model)
+        self._state = layout["state"]
         if self._state is not None:
             self.DONATE_ARGNUMS = dict(self.DONATE_ARGNUMS,
                                        ragged=(9, 10, 11, 12, 14))
         self._attn_kinds = {}
-        for heads, window in kinds:
-            kind = (heads // mc.num_key_value_heads, window)
+        for heads, window, _, shared in layout["calls"]:
+            kind = (heads // layout["kv_heads"], window, shared)
             self._attn_kinds[kind] = self._attn_kinds.get(kind, 0) + 1
         # the names of what the model counts in a ragged step
         # (``_TracedPagedContext.count``), noted when a program is traced
@@ -1874,13 +1951,17 @@ class JittedPagedDecoder:
         window (``kv_tokens_walked_nowindow``) and the pages its real
         rows hold wholly behind their next query's window
         (``kv_window_dead_pages``; a page two rows share counts
-        twice)."""
+        twice).  A model some of whose calls walk a pool they do not own
+        adds ``kv_tokens_walked_shared``: their part of
+        ``kv_tokens_walked``, in the same unit."""
         ps, total = cache.page_size, sum(self._attn_kinds.values())
         if not total:                   # no K/V layer: nothing is walked
             return {}
-        means = ("ctx_tokens", "kv_tokens_walked", "q_positions_computed")
+        means = ["ctx_tokens", "kv_tokens_walked", "q_positions_computed"]
+        if any(shared for _, _, shared in self._attn_kinds):
+            means.append("kv_tokens_walked_shared")
         out = dict.fromkeys(means, 0)
-        for (group, window), n in self._attn_kinds.items():
+        for (group, window, shared), n in self._attn_kinds.items():
             block = ps * walk_block_pages(ps, cache.head_dim, span * group,
                                           cache.k_pages[0].dtype)
             # the real rows' context; a pad row's one position is walked
@@ -1888,6 +1969,8 @@ class JittedPagedDecoder:
             walked = kv_tokens_walked(lens, block, window, q_lens, ps)
             out["ctx_tokens"] += n * seen
             out["kv_tokens_walked"] += n * walked
+            if shared:
+                out["kv_tokens_walked_shared"] += n * walked
             out["q_positions_computed"] += n * q_positions_computed(
                 q_lens, span, group, cache.compute_dtype)
             if window is not None:

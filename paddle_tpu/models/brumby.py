@@ -208,14 +208,19 @@ class BrumbyForCausalLM(Layer):
 
     # ---- what the paged engine reads of the model
     def attention_kinds(self):
-        """No layer holds K/V pages."""
+        """No layer holds K/V pages: no paged call, no pool (the extended
+        description, (query heads, window, pool) a call, is
+        ``models/laguna.py``'s to say)."""
         return []
 
     def recurrent_state(self) -> Optional[dict]:
         """The state a sequence every layer carries: how many layers, a
         slot's shape as ``ops/power_retention.py`` stores it, and the
         bytes of it the equations count (S in R^{D x d} and z in R^D,
-        float32, D = d (d + 1) / 2) whatever is stored."""
+        float32, D = d (d + 1) / 2) whatever is stored.  (``shape``: a
+        slot of ONE array; a model whose slot is several says ``shapes``,
+        a list, and the cache holds a pool of each a layer:
+        ``models/phi4_flash.py``.)"""
         c = self.config
         return {"layers": c.num_hidden_layers,
                 "shape": pr.state_shape(c.num_key_value_heads, c.head_dim,

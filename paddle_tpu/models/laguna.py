@@ -372,7 +372,13 @@ class LagunaForCausalLM(Layer):
 
     def attention_kinds(self):
         """[(query heads, window or None)] a layer: what each layer's
-        paged call looks like (``JittedPagedDecoder`` counts the kernels'
-        walk from it)."""
+        paged call looks like (``paged_layout`` in
+        ``ops/pallas/paged_attention.py`` reads it: ``PagedKVCache`` opens
+        a page pool an entry and ``JittedPagedDecoder`` counts the
+        kernels' walk from it).  An entry may carry a third member, the
+        index of the POOL the call walks: a call that names a pool an
+        earlier call opened appends nothing and holds no pool of its own
+        (``models/phi4_flash.py``); with two members, as here, every call
+        owns the pool of its own index."""
         return [(layer.self_attn.num_heads, layer.self_attn.window)
                 for layer in self.model.layers]

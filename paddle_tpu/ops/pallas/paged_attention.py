@@ -980,6 +980,48 @@ _append_rows_donated = jax.jit(append_rows, donate_argnums=(0,),
                                static_argnames=("interpret",))
 
 
+def paged_layout(model) -> dict:
+    """What a model's paged calls look like, read from the model (the one
+    place ``PagedKVCache.from_model`` and ``JittedPagedDecoder`` both ask):
+
+    * ``calls``: [(query heads, window or None, pool)] a paged call, in the
+      calls' order, from ``model.attention_kinds()`` where the model has it
+      (an entry of two names the pool of its own index; an entry of three
+      names its pool, and a call on a pool an earlier call opened walks it
+      without appending: ``shared``), else one full-attention call a layer;
+    * ``pools``: how many page pools that makes;
+    * ``kv_heads`` / ``head_dim``: a page's heads as ``attend`` is handed
+      K and V (``model.kv_page_shape()`` where the model lays them out
+      otherwise than its config says);
+    * ``state``: ``model.recurrent_state()`` or None, its slot's arrays
+      under ``shapes`` (a model that says ``shape`` has one)."""
+    c = model.config
+    if hasattr(model, "attention_kinds"):
+        kinds = list(model.attention_kinds())
+    else:
+        kinds = [(c.num_attention_heads, None)] * c.num_hidden_layers
+    calls, opened = [], set()
+    for i, kind in enumerate(kinds):
+        heads, window = kind[0], kind[1]
+        pool = kind[2] if len(kind) > 2 else i
+        calls.append((heads, window, pool, pool in opened))
+        opened.add(pool)
+    if hasattr(model, "kv_page_shape"):
+        kv_heads, head_dim = model.kv_page_shape()
+    else:
+        kv_heads = c.num_key_value_heads
+        # a config that states its head_dim means it (2,048 / 48 query
+        # heads is not 128)
+        head_dim = (getattr(c, "head_dim", None)
+                    or c.hidden_size // c.num_attention_heads)
+    state = (model.recurrent_state()
+             if hasattr(model, "recurrent_state") else None)
+    if state is not None and "shapes" not in state:
+        state = dict(state, shapes=[tuple(state["shape"])])
+    return {"calls": calls, "pools": len(opened), "kv_heads": int(kv_heads),
+            "head_dim": int(head_dim), "state": state}
+
+
 class _PrefixEntry:
     """One cached page-aligned prompt prefix: the pages holding its KV
     plus the token count they cover.  The entry itself holds one index
@@ -998,7 +1040,12 @@ class PagedKVCache:
     bookkeeping (reference: the BlockTable management around
     block_multihead_attention), with REFCOUNTED pages and a prefix index.
 
-    Layout per layer: (kv_heads, total_pages, page_size, head_dim).
+    Layout per pool: (kv_heads, total_pages, page_size, head_dim); a pool
+    a K/V layer, which later layers may walk without one of their own
+    (:func:`paged_layout`).  Beside the pages, for a model whose layers
+    carry a recurrent state: slot pools, ``state_slots`` + 1 slots of each
+    array of a layer's state (``state_pools``, a layer's arrays side by
+    side), a slot a sequence taken and returned with its pages.
 
     Pages carry two kinds of references: sequence refs (a live sequence
     maps the page in its table) and index refs (a cached prompt prefix
@@ -1018,35 +1065,29 @@ class PagedKVCache:
                    page_size: int = 16,
                    kv_dtype: Optional[str] = None,
                    mesh=None, state_slots: int = 0) -> "PagedKVCache":
-        """Cache sized for a causal-LM model's config (single wiring
-        point shared by PagedGenerator and ContinuousBatchingEngine).
+        """Cache sized for a causal-LM model (single wiring point shared
+        by PagedGenerator and ContinuousBatchingEngine), from what the
+        model says of itself (:func:`paged_layout`): ONE page pool for
+        each pool its paged calls name (a layer that reads another
+        layer's pages opens none), pages of the heads ``attend`` is handed,
+        and for a model with a recurrent state ``state_slots`` + 1 slots
+        of every array of its slot, a layer.
         ``kv_dtype="int8"`` selects the quantized storage mode;
         ``mesh`` shards the pools on the KV-head axis (ISSUE 20)."""
-        c = model.config
-        # what the model is, read from the model: the layers that hold
-        # K/V pages (all of them unless it says otherwise) and the
-        # recurrent state a sequence its other layers carry
-        state = (model.recurrent_state()
-                 if hasattr(model, "recurrent_state") else None)
-        kv_layers = (len(model.attention_kinds())
-                     if hasattr(model, "attention_kinds")
-                     else c.num_hidden_layers)
+        layout = paged_layout(model)
+        state = layout["state"]
         if state is not None and mesh is not None:
             raise ValueError(
                 "a recurrent state has no placement over a tensor mesh: "
                 "the slot pools are not sharded")
         return cls(
-            num_layers=kv_layers,
-            kv_heads=c.num_key_value_heads,
-            # a config that states its head_dim means it (2,048 / 48
-            # query heads is not 128)
-            head_dim=(getattr(c, "head_dim", None)
-                      or c.hidden_size // c.num_attention_heads),
+            num_layers=layout["pools"], kv_heads=layout["kv_heads"],
+            head_dim=layout["head_dim"],
             total_pages=total_pages, page_size=page_size,
             dtype=model.model.embed_tokens.weight._data.dtype,
             kv_dtype=kv_dtype, mesh=mesh,
             state_layers=state["layers"] if state else 0,
-            state_shape=tuple(state["shape"]) if state else (),
+            state_shape=[tuple(x) for x in state["shapes"]] if state else (),
             state_slots=state_slots if state else 0)
 
     def __init__(self, num_layers: int, kv_heads: int, head_dim: int,
@@ -1105,7 +1146,12 @@ class PagedKVCache:
         # summed over the whole sequence)
         self.state_layers = int(state_layers)
         self.state_slots = int(state_slots) if state_layers else 0
-        self.state_shape = tuple(state_shape)
+        # a slot's arrays: one shape, or a list of them (a layer then
+        # holds a pool of each, side by side in ``state_pools``)
+        shapes = list(state_shape) if state_layers else []
+        if shapes and not isinstance(shapes[0], (tuple, list)):
+            shapes = [shapes]
+        self.state_shapes = [tuple(x) for x in shapes]
         self.state_pools = self._state_zeros()
         self._free_slots: List[int] = list(range(self.state_slots))[::-1]
         self._seq_slot: Dict[int, int] = {}
@@ -1129,8 +1175,9 @@ class PagedKVCache:
         self.generation = 0
 
     def _state_zeros(self):
-        return [jnp.zeros((self.state_slots + 1,) + self.state_shape,
-                          jnp.float32) for _ in range(self.state_layers)]
+        return [jnp.zeros((self.state_slots + 1,) + shape, jnp.float32)
+                for _ in range(self.state_layers)
+                for shape in self.state_shapes]
 
     # --------------------------------------------------- recurrent slots
     @property
@@ -1423,8 +1470,10 @@ class PagedKVCache:
 
     @property
     def kv_pool_bytes(self) -> int:
-        """Resident bytes of what the cache holds a sequence across all
-        layers: the KV data pages and the recurrent slot pools."""
+        """Resident bytes of what the cache holds a sequence: the KV data
+        pages, a pool once however many layers walk it (a layer that
+        reads another layer's pages has none of its own), and the
+        recurrent slot pools."""
         return sum(int(a.size) * a.dtype.itemsize
                    for a in list(self.k_pages) + list(self.v_pages)
                    + list(self.state_pools))
@@ -1444,8 +1493,9 @@ class PagedKVCache:
     @property
     def kv_pool_bytes_per_chip(self) -> int:
         """Per-chip resident bytes of the KV data pages: the global
-        pool divided by the TP degree (the head-axis sharding's HBM
-        win; equals ``kv_pool_bytes`` for a 1-chip cache)."""
+        pool (a shared pool counted once, as ``kv_pool_bytes`` does)
+        divided by the TP degree (the head-axis sharding's HBM win;
+        equals ``kv_pool_bytes`` for a 1-chip cache)."""
         return self.kv_pool_bytes // max(1, self.tp)
 
     @property

@@ -659,3 +659,60 @@ class TestRetentionStateLowering:
                             "custom-call"}, (
             f"a pool-sized array is produced by {dict(ops)}: the slots "
             "are copied inside the step")
+
+
+# --------------------------- the Phi-4-mini-flash cell's Mamba state kernel
+class TestSelectiveScanLowering:
+    """`phi4-flash.serve.reason32`'s Mamba layer at its shapes: 32 + 1
+    slots of ``h`` [16, 5120] and a tail [3 x 5120] float32, a ragged step
+    of 32 rows.  One layer's ``conv_step`` + ``scan_step`` with both pools
+    donated: the one-token rows alone (span 1), and beside a chunk row at
+    (32, 128) packed to 160 positions.  ``h``'s pool is produced by the
+    kernel alone, which writes in place.  (In a program this small the
+    compiler may stage the whole 10.8 MB pool in VMEM around the kernels
+    and copy it back, ``copy-done``: a placement, which the whole step's
+    program, where nine pools and the weights want that memory, makes for
+    none of its pools in the decode program and one of nine in the chunk
+    program: PERF.md section 6, PR 41.  No scatter, fusion or plain copy
+    of the pool may appear.)"""
+
+    SLOTS, ROWS, N, D, K = 32, 32, 16, 5120, 4
+
+    @pytest.mark.parametrize("span, tokens, chunk_rows",
+                             [(1, 32, 0), (128, 160, 2), (256, 288, 2)],
+                             ids=["decode", "chunk", "chunk256"])
+    def test_a_layer_updates_its_pools_in_place(self, chip, monkeypatch,
+                                                span, tokens, chunk_rows):
+        from paddle_tpu.ops import selective_scan as ss
+        monkeypatch.setattr(ss, "_use_pallas", lambda: True)
+        h_pool = (self.SLOTS + 1, self.N, self.D)
+        packed = tokens < self.ROWS * span
+
+        def layer(h, tail, slots, ctx, q_lens, off, rows, x, w, b, delta, a,
+                  bb, cc, d):
+            off = off if packed else None
+            u, tail = ss.conv_step(tail, slots, ctx, q_lens, off, x, w, b,
+                                   span=span)
+            m, h = ss.scan_step(h, slots, ctx, q_lens, off, rows, u, delta,
+                                a, bb, cc, d, span=span)
+            return m, h, tail
+
+        rows = chip.sds((self.ROWS,), I32)
+        text = jax.jit(layer, donate_argnums=(0, 1)).lower(
+            chip.sds(h_pool, F32),
+            chip.sds((self.SLOTS + 1, (self.K - 1) * self.D), F32),
+            rows, rows, rows, rows, chip.sds((chunk_rows,), I32),
+            chip.sds((tokens, self.D)), chip.sds((self.K, self.D)),
+            chip.sds((self.D,)), chip.sds((tokens, self.D), F32),
+            chip.sds((self.N, self.D), F32), chip.sds((tokens, self.N)),
+            chip.sds((tokens, self.N)), chip.sds((self.D,), F32)
+        ).compile().as_text()
+        assert text.count("tpu_custom_call") >= (2 if chunk_rows else 1)
+        alias = re.search(r"input_output_alias=\{(.*?)\}, entry", text)
+        assert "(0, {}" in alias.group(1), alias.group(0)
+        ops = TestAppendRowsLowering._pool_sized(text, math.prod(h_pool),
+                                                 "f32")
+        assert set(ops) <= {"parameter", "get-tuple-element", "bitcast",
+                            "custom-call", "copy-done"}, (
+            f"a pool-sized array is produced by {dict(ops)}: the slots "
+            "are copied inside the step")

@@ -498,7 +498,8 @@ class TestRecords:
             "moves": "serve.tokens_per_s",
             "workloads": ["mistral7b.serve.closed8",
                           "laguna-xs2.serve.agent8",
-                          "brumby-14b.serve.reason16"]}
+                          "brumby-14b.serve.reason16",
+                          "phi4-flash.serve.reason32"]}
         # appended: behind every metric the benchmark had before it
         names = [m["name"] for m in manifest["per_layer"]]
         assert names.index(entry["name"]) > names.index(
