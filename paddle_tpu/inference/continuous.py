@@ -315,7 +315,7 @@ _kv_quant_scale_bytes_g = monitor.gauge(
     "kv_quant_scale_bytes", "resident bytes of the int8 mode's "
     "per-slot scale pools (0 at full precision)")
 # expert layers, sliding-attention layers and the paged kernel's query
-# tiles in the unified step: the sums of the ``dispatch`` records' fields
+# tiles and page copies in the unified step: the sums of the ``dispatch`` records' fields
 # of the same names, for a model that has such layers (none is touched
 # for one that has not)
 _STEP_SUMS = {
@@ -337,6 +337,12 @@ _STEP_SUMS = {
         ("q_positions_computed", "query positions the paged kernel "
          "computed for the unified steps' padded rows: a row's own queries "
          "in whole tiles, a layer's worth"),
+        ("page_copies", "page-copy descriptors the paged kernel's walks "
+         "issued for the unified steps' padded rows: one a page for each "
+         "group of kv heads a grid step owns and each pool, a layer's "
+         "worth"),
+        ("head_page_reads", "(page, kv head, pool) reads those descriptors "
+         "served: over page_copies it is the heads a copy carries"),
         ("state_bytes", "bytes of recurrent state the unified steps' rows "
          "read and wrote, as the equations count a state (a row that "
          "carries a token: a layer's state once in, once out)"),

@@ -32,11 +32,13 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           paged_layout,
                                           _round_up, append_rows,
                                           dequantize_kv, kv_tokens_visible,
-                                          kv_tokens_walked, paged_attention,
+                                          kv_pages_copied, kv_tokens_walked,
+                                          paged_attention,
                                           paged_attention_multi,
                                           paged_attention_ragged,
                                           q_positions_computed,
-                                          quantize_kv, walk_block_pages)
+                                          quantize_kv, walk_block_pages,
+                                          walk_head_group)
 from ..testing import faults as _faults
 
 
@@ -1821,7 +1823,7 @@ class JittedPagedDecoder:
             "tokens": sum(ns), "tokens_padded": t_b,
             "table_pages": W, "page_size": cache.page_size,
             "prefix_evicted": evicted,
-            **self._walk_counts(cache, ctx_arr + ql, ql, s_b, b)}
+            **self._walk_counts(cache, ctx_arr + ql, ql, s_b, b, W)}
         if self._state is not None:
             # the rows of several tokens (the chunk form; the program is
             # built for ``chunk_rows_padded`` of them) and their tokens,
@@ -1937,15 +1939,18 @@ class JittedPagedDecoder:
             self._recover_pools(flight.cache, ran=True)
         self._rollback_lengths(flight.cache, flight.seq_ids, flight.before)
 
-    def _walk_counts(self, cache, lens, q_lens, span, rows):
+    def _walk_counts(self, cache, lens, q_lens, span, rows, table_pages):
         """The dispatch record's count of the paged kernels' work for a
         step whose padded rows hold ``lens`` positions after the write
         and ``q_lens`` queries, a LAYER's worth each (the mean over the
         layers where they differ): ``ctx_tokens`` the positions some
         query attends, ``kv_tokens_walked`` what the kernel walks for
         them in whole blocks and ``q_positions_computed`` the query
-        positions it computes for them in whole tiles, both by the
-        kernel's own rule.  A model with sliding
+        positions it computes for them in whole tiles, ``page_copies``
+        the copy descriptors its walks issue (one a page for each group of
+        kv heads a grid step owns and each pool) and ``head_page_reads``
+        the (page, head, pool) reads they serve, all by the kernel's own
+        rule (a chip's own heads under ``tp``).  A model with sliding
         layers adds one sliding layer's own two counts
         (``..._window``), what that layer would have walked with no
         window (``kv_tokens_walked_nowindow``) and the pages its real
@@ -1957,13 +1962,21 @@ class JittedPagedDecoder:
         ps, total = cache.page_size, sum(self._attn_kinds.values())
         if not total:                   # no K/V layer: nothing is walked
             return {}
-        means = ["ctx_tokens", "kv_tokens_walked", "q_positions_computed"]
+        means = ["ctx_tokens", "kv_tokens_walked", "q_positions_computed",
+                 "page_copies", "head_page_reads"]
+        kv_dtype = cache.k_pages[0].dtype
+        heads, pools = cache.kv_heads // cache.tp, 4 if cache.kv_quant else 2
         if any(shared for _, _, shared in self._attn_kinds):
             means.append("kv_tokens_walked_shared")
         out = dict.fromkeys(means, 0)
         for (group, window, shared), n in self._attn_kinds.items():
             block = ps * walk_block_pages(ps, cache.head_dim, span * group,
-                                          cache.k_pages[0].dtype)
+                                          kv_dtype)
+            steps = heads // walk_head_group(
+                heads, ps, cache.head_dim, span * group, kv_dtype,
+                cache.compute_dtype)
+            pages = pools * kv_pages_copied(lens, ps, table_pages, window,
+                                            q_lens)
             # the real rows' context; a pad row's one position is walked
             seen = kv_tokens_visible(lens[:rows], q_lens[:rows], window)
             walked = kv_tokens_walked(lens, block, window, q_lens, ps)
@@ -1973,6 +1986,8 @@ class JittedPagedDecoder:
                 out["kv_tokens_walked_shared"] += n * walked
             out["q_positions_computed"] += n * q_positions_computed(
                 q_lens, span, group, cache.compute_dtype)
+            out["page_copies"] += n * steps * pages
+            out["head_page_reads"] += n * heads * pages
             if window is not None:
                 dead = np.maximum(lens[:rows] + 1 - window, 0) // ps
                 out.update(ctx_tokens_window=seen,

@@ -7,18 +7,22 @@ in fixed-size pages, a per-sequence block table maps logical positions to
 pages, decode attends one query token against the paged cache.
 
 TPU-native design (see /opt/skills/guides/pallas_guide.md):
-  - the decode kernel is a Pallas grid (batch, kv_heads): one grid step
-    per (row, kv head), which WALKS THE ROW'S OWN CONTEXT in blocks of
-    several pages (``walk_block_pages``: 128-512 tokens, from the shapes
-    and a VMEM budget) — ``ceil(length / block)`` blocks whatever the
-    page table's width, so a table pinned wide for a compile-free window
+  - the decode kernel is a Pallas grid (batch, kv_heads // hb): a grid
+    step owns a row and a GROUP of ``hb`` of its kv heads
+    (``walk_head_group``: from the shapes and a VMEM budget, all the heads
+    where they fit) and WALKS THE ROW'S OWN CONTEXT in blocks of several
+    pages (``walk_block_pages``: 128-512 tokens, from the shapes and a
+    VMEM budget) — ``ceil(length / block)`` blocks whatever the page
+    table's width, so a table pinned wide for a compile-free window
     costs what a tight one costs;
   - the pools stay in HBM (``memory_space=pl.ANY``); the lengths and the
     page table ride in as SCALAR-PREFETCH arguments, and the kernel
-    starts one asynchronous copy a page (``pltpu.make_async_copy``) into
-    a VMEM buffer two blocks deep: the next block's pages are in flight
-    while this block's scores and products are computed.  Nothing is
-    fetched past a row's length — the same discipline as jax's
+    starts one asynchronous copy a page and a pool FOR ALL THE STEP'S
+    HEADS (``pltpu.make_async_copy`` of ``pool.at[heads, page]``: the
+    pool's head axis strided, as the append stages a page) into a VMEM
+    buffer two blocks deep: the next block's pages are in flight while
+    this block's scores and products are computed, a head at a time.
+    Nothing is fetched past a row's length — the same discipline as jax's
     production paged_attention kernel, with per-row query spans and the
     int8 scale pools besides;
   - online softmax in VMEM scratch across blocks; the tail block is
@@ -89,34 +93,48 @@ def dequantize_kv(q, scale, dtype):
 
 # ------------------------------------------------------------------ kernel
 #: what one block of the walk may take of VMEM: the float32 score block
-#: (rows x block tokens), and the double K and V buffers with their scale
-#: buffers.  Both sized for the v5e's 16 MB of scoped VMEM with room for
-#: the score block's temporaries (mask, exponentials, their bf16 copy).
+#: (rows x block tokens), and ONE kv head's double K and V buffers with
+#: their scale buffers.  Both sized for the v5e's 16 MB of scoped VMEM with
+#: room for the score block's temporaries (mask, exponentials, their bf16
+#: copy).
 _SCORE_BLOCK_BYTES = 1 << 20
 _KV_BUFFER_BYTES = 2 << 20
 _MAX_BLOCK_TOKENS = 512
+#: what the kv heads of one grid step may hold of VMEM together
+#: (``walk_head_group``), and what a call asks the compiler for: that and
+#: the room one head's block always had (the v5e has 128 MiB of VMEM)
+_HEAD_GROUP_BYTES = 32 << 20
+_VMEM_LIMIT_BYTES = _HEAD_GROUP_BYTES + (16 << 20)
 
 
 def _round_up(x, m):
     return -(-x // m) * m
 
 
-def walk_block_pages(page_size, head_dim, rows, kv_dtype):
-    """Pages one block of the kernel's walk holds, from shapes alone: as
-    many as keep the score block (``rows`` x tokens, float32) and the
-    double-buffered K and V pages (with the int8 mode's scale pages) inside
-    their VMEM budgets, at most 512 tokens, at least one page.  ``rows`` is
-    ``n_query * group``.  Whole multiples of 128 tokens where that many
-    fit, so the score block's lane axis is unpadded.  The table's width is
-    NOT an input: a row's blocks are cut the same whatever table carries
-    them, which is what makes a pinned table free and its results
-    bit-identical to a tight one's."""
+def _page_vmem_bytes(page_size, head_dim, kv_dtype):
+    """VMEM one page of one kv head takes in a walk buffer: whole tiles of
+    its dtype (8 sublanes of 32 bits, so 8 / 16 / 32 rows by itemsize), and
+    in the int8 mode its (page_size, 1) float32 scales over a lane tile."""
     item = jnp.dtype(kv_dtype).itemsize
     lanes = _round_up(head_dim, 128)
-    # VMEM tiles: 8 sublanes of 32 bits, so 8 / 16 / 32 rows by itemsize
     page_bytes = _round_up(page_size, 32 // item) * lanes * item
-    if item == 1:                       # + the (page_size, 1) f32 scales
+    if item == 1:
         page_bytes += _round_up(page_size, 8) * 128 * 4
+    return page_bytes
+
+
+def walk_block_pages(page_size, head_dim, rows, kv_dtype):
+    """Pages one block of the kernel's walk holds, from shapes alone: as
+    many as keep the score block (``rows`` x tokens, float32) and ONE kv
+    head's double-buffered K and V pages (with the int8 mode's scale pages)
+    inside their VMEM budgets, at most 512 tokens, at least one page.
+    ``rows`` is ``n_query * group``.  Whole multiples of 128 tokens where
+    that many fit, so the score block's lane axis is unpadded.  The table's
+    width is NOT an input: a row's blocks are cut the same whatever table
+    carries them, which is what makes a pinned table free and its results
+    bit-identical to a tight one's.  Nor are the heads a grid step owns
+    (``walk_head_group``): the buffers grow with them, the block does not."""
+    page_bytes = _page_vmem_bytes(page_size, head_dim, kv_dtype)
     by_kv = _KV_BUFFER_BYTES // (4 * page_bytes)     # K, V x two slots
     by_score = _SCORE_BLOCK_BYTES // (4 * _round_up(rows, 8) * page_size)
     pages = max(1, min(_MAX_BLOCK_TOKENS // page_size, by_kv, by_score))
@@ -124,6 +142,31 @@ def walk_block_pages(page_size, head_dim, rows, kv_dtype):
     if pages >= per_128:
         pages -= pages % per_128
     return pages
+
+
+@functools.lru_cache(maxsize=None)      # the host asks at every step
+def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype):
+    """KV heads one grid step of the kernel owns, from shapes alone: the
+    largest divisor of the call's ``kv_heads`` whose heads together keep
+    their q and out blocks (two pipeline buffers each), their softmax
+    scratch (m, l, acc in float32) and their double K and V buffers of
+    ``walk_block_pages`` pages inside ``_HEAD_GROUP_BYTES``.  A page copy
+    then serves the whole group: ONE descriptor a (row, group, page, pool)
+    where a grid step of one head issued one a head.  All the heads for
+    the one-query and verify kernels (a few KB a head beside the buffers)
+    and for the ragged kernel's 512 / 768 / 1,024-row buckets at 8 heads
+    (1.75 / 2.1 / 2.75 MB a head); 1 is the grid of one head a step.  The
+    walk of a (row, head) does not depend on the group it rides in."""
+    lanes = _round_up(head_dim, 128)
+    q_item = jnp.dtype(q_dtype).itemsize
+    block_pages = walk_block_pages(page_size, head_dim, rows, kv_dtype)
+    per_head = (
+        4 * block_pages * _page_vmem_bytes(page_size, head_dim, kv_dtype)
+        + 4 * _round_up(rows, 32 // q_item) * lanes * q_item   # q, out
+        + _round_up(rows, 8) * (128 + 128 + lanes) * 4)        # m, l, acc
+    return max(g for g in range(1, kv_heads + 1)
+               if kv_heads % g == 0
+               and (g == 1 or g * per_head <= _HEAD_GROUP_BYTES))
 
 
 def window_first_token(lengths, q_lens, window, page_size):
@@ -152,6 +195,25 @@ def kv_tokens_walked(lengths, block_tokens, window=None, q_lens=1,
         lengths = lengths - window_first_token(
             lengths, np.asarray(q_lens, np.int64), int(window), page_size)
     return int((-(-lengths // block_tokens) * block_tokens).sum())
+
+
+def kv_pages_copied(lengths, page_size, table_pages, window=None, q_lens=1):
+    """Pages the kernel copies for rows of these ``lengths``, a (kv head,
+    pool): the pages that hold a row's context, never past the table and
+    under a ``window`` from the page of the row's first visible key —
+    ``_decode_kernel``'s ``n_pages``, here on the host for the dispatch
+    record.  Nothing is copied past a row's length, so this is NOT the
+    walk in whole blocks (``kv_tokens_walked``).  A call issues one
+    descriptor a page for each GROUP of ``walk_head_group`` heads and each
+    pool (K, V and in the int8 mode their scales), and serves one
+    (page, head, pool) read a head (``kernel.paged_attn.copy_share``)."""
+    lengths = np.asarray(lengths, np.int64)
+    pages = np.minimum(-(-lengths // page_size), table_pages)
+    if window is not None:
+        pages = pages - window_first_token(
+            lengths, np.asarray(q_lens, np.int64), int(window),
+            page_size) // page_size
+    return int(pages.sum())
 
 
 def kv_tokens_visible(lengths, q_lens, window=None):
@@ -210,15 +272,19 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale, page_size, block_pages, n_query=1, group=1,
                    quantized=False, ragged=False, window=None, tile=None):
     """Online-softmax paged attention for ``n_query`` query tokens per
-    sequence, one grid step per (row, kv head).  The step WALKS THE ROW'S
-    OWN CONTEXT: ``ceil(length / block)`` blocks of ``block_pages`` pages,
-    whatever the table's width.  The pools stay in HBM; the kernel reads
-    the page indices from the scalar-prefetched table and starts one
-    asynchronous copy a page into a VMEM buffer, two buffers deep, so the
-    next block's pages are in flight while this block's scores and
-    products are computed.  Only pages that hold context are fetched:
-    nothing is issued past the row's length, the tail block's unfetched
-    slots are masked by column (scores) and zeroed (values).
+    sequence, one grid step per (row, group of ``hb`` kv heads: the head
+    axis of the blocks and buffers it is handed).  The step WALKS THE
+    ROW'S OWN CONTEXT: ``ceil(length / block)`` blocks of ``block_pages``
+    pages, whatever the table's width.  The pools stay in HBM; the kernel
+    reads the page indices from the scalar-prefetched table and starts ONE
+    asynchronous copy a page and a pool for all its heads into a VMEM
+    buffer, two buffers deep, so the next block's pages are in flight
+    while this block's scores and products are computed — a head at a
+    time over the same block: a (row, head)'s blocks, their order and
+    every operation on them are those of a step that owns the one head.
+    Only pages that hold context are fetched: nothing is issued past the
+    row's length, the tail block's unfetched slots are masked by column
+    (scores) and zeroed (values).
 
     ``n_query == 1`` is the classic decode step; n_query > 1 is the
     RAGGED MULTI-QUERY verify path (speculative decoding): the
@@ -266,8 +332,13 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     else:
         o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
         ks_buf = vs_buf = None
+    # what a page is copied from and to; its scale block travels with it
+    pools = [(k_hbm, k_buf), (v_hbm, v_buf)]
+    if quantized:
+        pools += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
     b = pl.program_id(0)
-    h = pl.program_id(1)
+    hb = k_buf.shape[2]                 # the step's kv heads
+    heads = pl.ds(pl.program_id(1) * hb, hb)
     block = block_pages * page_size
     d = k_buf.shape[-1]
 
@@ -286,30 +357,24 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def each_page_copy(blk, slot, act):
         """``act`` on every copy of block ``blk``'s pages into buffer
-        ``slot`` — the same descriptors to start and to wait on."""
+        ``slot`` — the same descriptors to start and to wait on.  A
+        descriptor carries a page of ALL the step's heads (the pool's
+        head axis is strided, as in the append's staged page)."""
         first = blk * block_pages
 
         def body(i, carry):
             page = tabs_ref[b, page0 + first + i]
-            act(pltpu.make_async_copy(k_hbm.at[h, page], k_buf.at[slot, i],
-                                      sems.at[slot]))
-            act(pltpu.make_async_copy(v_hbm.at[h, page], v_buf.at[slot, i],
-                                      sems.at[slot]))
-            if quantized:
-                # a page's scale block travels with its page
-                act(pltpu.make_async_copy(ks_hbm.at[h, page],
-                                          ks_buf.at[slot, i],
-                                          sems.at[slot]))
-                act(pltpu.make_async_copy(vs_hbm.at[h, page],
-                                          vs_buf.at[slot, i],
-                                          sems.at[slot]))
+            for hbm, buf in pools:
+                act(pltpu.make_async_copy(hbm.at[heads, page],
+                                          buf.at[slot, i], sems.at[slot]))
             return carry
 
         lax.fori_loop(0, jnp.minimum(block_pages, n_pages - first), body, 0)
 
-    def load(buf, s_buf, slot, dtype):
-        """Buffer ``slot`` as (block tokens, d) in the compute dtype."""
-        x = buf[slot]                           # (block_pages, page, d)
+    def load(buf, s_buf, slot, j, dtype):
+        """Head ``j``'s part of buffer ``slot`` as (block tokens, d) in
+        the compute dtype."""
+        x = buf[slot, :, j]                     # (block_pages, page, d)
         if quantized:
             # per-slot dequant in VMEM: int8 page * (page_size, 1)
             # scale, ROUNDED through the compute dtype — the same
@@ -317,12 +382,20 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
             # bf16 model's decode sees bit-identical K/V to what
             # prefill's fake-quant round-trip and the XLA gathers
             # produced (the exactness invariant)
-            x = x.astype(jnp.float32) * s_buf[slot][:, :, :1]
+            x = x.astype(jnp.float32) * s_buf[slot, :, j][:, :, :1]
         elif page_size % (32 // x.dtype.itemsize):
             # a page that is not whole tiles of its dtype folds into
             # the token axis as float32, whose 8-row tile it does fill
             x = x.astype(jnp.float32)
         return x.reshape(block, d).astype(dtype)
+
+    def each_head(act):
+        """``act(j)`` on every kv head of the step, one after another."""
+        def body(j, carry):
+            act(j)
+            return carry
+
+        lax.fori_loop(0, hb, body, 0)
 
     if ragged:
         # the row's own queries: its first ``qlen * group`` rows are the
@@ -343,20 +416,50 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         def each_tile(act):             # the whole block, as one
             act(slice(None), None)
 
-    # a tile's slice of the three (rows, lanes) float32 scratch arrays
-    height = tile if ragged else m_scr.shape[0]
-    narrow, wide = (height, m_scr.shape[1]), (height, acc_scr.shape[1])
+    # a tile's slice of a head's three (rows, lanes) float32 scratch arrays
+    height = tile if ragged else m_scr.shape[1]
+    narrow, wide = (height, m_scr.shape[2]), (height, acc_scr.shape[2])
 
-    def reset(rows, row0):
-        m_scr[rows] = jnp.full(narrow, -jnp.inf, m_scr.dtype)
-        l_scr[rows] = jnp.zeros(narrow, l_scr.dtype)
-        acc_scr[rows] = jnp.zeros(wide, acc_scr.dtype)
+    def reset(j):
+        def tile_reset(rows, row0):
+            m_scr[j, rows] = jnp.full(narrow, -jnp.inf, m_scr.dtype)
+            l_scr[j, rows] = jnp.zeros(narrow, l_scr.dtype)
+            acc_scr[j, rows] = jnp.zeros(wide, acc_scr.dtype)
 
-    each_tile(reset)
+        each_tile(tile_reset)
+
+    each_head(reset)
 
     @pl.when(n_blocks > 0)
     def _first():
         each_page_copy(0, 0, lambda c: c.start())
+
+    def seen_by(shape, row0, blk):
+        """Which of block ``blk``'s columns the queries of a ``shape``
+        (rows, block) score tile that starts at row ``row0`` see: the
+        same for every head."""
+        cols = tok0 + blk * block + lax.broadcasted_iota(jnp.int32, shape, 1)
+        # row r serves query position r // group of the block; its
+        # causal window ends (n_query - 1 - qpos) tokens short of the
+        # full length (the later block tokens it must not see)
+        qrow = lax.broadcasted_iota(jnp.int32, shape, 0)
+        if row0 is not None:
+            qrow = qrow + row0
+        qpos = qrow // group
+        if ragged:
+            # per-row span: query j's context is kv - qlen + j + 1
+            # tokens; a full row (qlen == n_query) reduces this to
+            # the verify limit below BIT-EXACTLY, so the unified step
+            # can never drift from the legacy modes it replaces.  The
+            # dead queries of a live tile (j >= qlen) clamp at the
+            # full length: finite, and zeroed at the end
+            limit = jnp.minimum(length, length - qlen + 1 + qpos)
+        else:
+            limit = length - (n_query - 1 - qpos)
+        seen = cols < limit
+        if window is not None:
+            seen &= cols >= limit - window
+        return seen
 
     def walk(blk, carry):
         slot = blk % 2
@@ -367,107 +470,89 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
         each_page_copy(blk, slot, lambda c: c.wait())
 
-        def keys():
-            return load(k_buf, ks_buf, slot, q_ref.dtype)
+        # slots past the length were never fetched and hold whatever the
+        # buffer held — their weights are exact zeros, and so must the
+        # values be (0 * NaN)
+        toks = tok0 + blk * block \
+            + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+        fetched = toks < length
+        # one tile: the heads share its mask
+        whole = None if ragged else seen_by((m_scr.shape[1], block), None,
+                                            blk)
 
-        def values():
-            # same rounding rule as the keys, then the SAME dot the
-            # full-precision path runs on its pages; slots past the
-            # length were never fetched and hold whatever the buffer
-            # held — their weights are exact zeros, and so must they be
-            # (0 * NaN)
-            v = load(v_buf, vs_buf, slot, q_ref.dtype)
-            toks = tok0 + blk * block \
-                + lax.broadcasted_iota(jnp.int32, (block, 1), 0)
-            return jnp.where(toks < length, v, jnp.zeros_like(v))
+        def head(j):
+            def keys():
+                return load(k_buf, ks_buf, slot, j, q_ref.dtype)
 
-        if ragged:
-            # a block's K and V are loaded once and shared by its tiles
-            k_blk, v_blk = keys(), values()
-            keys, values = (lambda: k_blk), (lambda: v_blk)
+            def values():
+                # same rounding rule as the keys, then the SAME dot the
+                # full-precision path runs on its pages
+                v = load(v_buf, vs_buf, slot, j, q_ref.dtype)
+                return jnp.where(fetched, v, jnp.zeros_like(v))
 
-        def update(rows, row0):
-            q = q_ref[0, 0, rows]               # (tile or rows, d)
-            s = lax.dot_general(q, keys(), (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-            cols = tok0 + blk * block \
-                + lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            # row r serves query position r // group of the block; its
-            # causal window ends (n_query - 1 - qpos) tokens short of the
-            # full length (the later block tokens it must not see)
-            qrow = lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            if row0 is not None:
-                qrow = qrow + row0
-            qpos = qrow // group
             if ragged:
-                # per-row span: query j's context is kv - qlen + j + 1
-                # tokens; a full row (qlen == n_query) reduces this to
-                # the verify limit below BIT-EXACTLY, so the unified step
-                # can never drift from the legacy modes it replaces.  The
-                # dead queries of a live tile (j >= qlen) clamp at the
-                # full length: finite, and zeroed at the end
-                limit = jnp.minimum(length, length - qlen + 1 + qpos)
-            else:
-                limit = length - (n_query - 1 - qpos)
-            seen = cols < limit
-            if window is not None:
-                seen &= cols >= limit - window
-            s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
+                # a head's K and V of the block are loaded once and
+                # shared by its tiles
+                k_blk, v_blk = keys(), values()
+                keys, values = (lambda: k_blk), (lambda: v_blk)
 
-            m_prev = m_scr[rows, :1]
-            m_next = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_next)
-            pexp = jnp.exp(s - m_next)
-            l_scr[rows] = jnp.broadcast_to(
-                alpha * l_scr[rows, :1]
-                + jnp.sum(pexp, axis=1, keepdims=True), narrow)
-            v = values()
-            acc_scr[rows] = acc_scr[rows] * alpha + lax.dot_general(
-                pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_scr[rows] = jnp.broadcast_to(m_next, narrow)
+            def update(rows, row0):
+                q = q_ref[0, j, rows]               # (tile or rows, d)
+                s = lax.dot_general(q, keys(), (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32) \
+                    * scale
+                seen = seen_by(s.shape, row0, blk) if ragged else whole
+                s = jnp.where(seen, s, DEFAULT_MASK_VALUE)
 
-        each_tile(update)
+                m_prev = m_scr[j, rows, :1]
+                m_next = jnp.maximum(m_prev,
+                                     jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_next)
+                pexp = jnp.exp(s - m_next)
+                l_scr[j, rows] = jnp.broadcast_to(
+                    alpha * l_scr[j, rows, :1]
+                    + jnp.sum(pexp, axis=1, keepdims=True), narrow)
+                v = values()
+                acc_scr[j, rows] = acc_scr[j, rows] * alpha \
+                    + lax.dot_general(
+                        pexp.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_scr[j, rows] = jnp.broadcast_to(m_next, narrow)
+
+            each_tile(update)
+
+        each_head(head)
         return carry
 
     lax.fori_loop(0, n_blocks, walk, 0)
 
     if ragged:
         # the dead tiles: zeros, never what the buffer held
-        o_ref[0, 0] = jnp.zeros(o_ref.shape[2:], o_ref.dtype)
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
 
-    def finish(rows, row0):
-        l = l_scr[rows, :1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_scr[rows] / l_safe
-        if ragged:
-            # the dead queries of the row's last live tile: zeros too
-            qrow = row0 + lax.broadcasted_iota(jnp.int32, out.shape, 0)
-            out = jnp.where(qrow < qlen * group, out, 0.0)
-        o_ref[0, 0, rows] = out.astype(o_ref.dtype)
+    def finish(j):
+        def tile_finish(rows, row0):
+            l = l_scr[j, rows, :1]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            out = acc_scr[j, rows] / l_safe
+            if ragged:
+                # the dead queries of the row's last live tile: zeros too
+                qrow = row0 + lax.broadcasted_iota(jnp.int32, out.shape, 0)
+                out = jnp.where(qrow < qlen * group, out, 0.0)
+            o_ref[0, j, rows] = out.astype(o_ref.dtype)
 
-    each_tile(finish)
+        each_tile(tile_finish)
+
+    each_head(finish)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret",
-                                             "n_query", "window"))
-def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
-                   interpret=False, n_query=1, k_scales=None,
-                   v_scales=None, q_lens=None, window=None):
-    """``q`` is (batch, q_heads, d) for n_query == 1, else
-    (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
-    (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
-    ``q_lens`` (batch,) int32 selects the RAGGED kernel: per-row query
-    spans left-aligned in the n_query bucket (ISSUE 17), computed in
-    tiles of :func:`query_tile_rows` rows, a row's live tiles only.
-
-    The grid is (batch, kv_heads); the pools are handed over whole and
-    stay in HBM, and each grid step walks its row's context in blocks of
-    :func:`walk_block_pages` pages (see ``_decode_kernel``).
-
-    Jitted, so that a program's layers, which all call it at the same
-    shapes, share ONE traced and lowered kernel: a serving engine builds
-    a program a (rows, span) bucket, each of them every layer deep."""
+def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
+                 interpret=False, n_query=1, k_scales=None, v_scales=None,
+                 q_lens=None, window=None, head_group=None):
+    """The ``pallas_call`` behind :func:`_decode_pallas` (its arguments).
+    ``head_group``: the kv heads a grid step owns where a TEST wants
+    another count than the shapes give (``walk_head_group``); no caller of
+    the program passes it."""
     if n_query == 1:
         batch, q_heads, d = q.shape
     else:
@@ -476,6 +561,8 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     group = q_heads // kv_heads
     rows = n_query * group
     block_pages = walk_block_pages(page_size, d, rows, k_pages.dtype)
+    hb = head_group or walk_head_group(kv_heads, page_size, d, rows,
+                                       k_pages.dtype, q.dtype)
 
     # (batch, q_heads, d) -> (batch, kv_heads, group, d): the kv-head
     # group rides as its own FULL axis so the q block's trailing dims
@@ -520,30 +607,31 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                                block_pages=block_pages, n_query=n_query,
                                group=group, quantized=quantized,
                                ragged=ragged, window=window, tile=tile)
-    q_spec = pl.BlockSpec((1, 1, rows, lanes),
-                          lambda b, h, lens, tabs: (b, h, 0, 0))
+    q_spec = pl.BlockSpec((1, hb, rows, lanes),
+                          lambda b, g, lens, tabs: (b, g, 0, 0))
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     in_specs = [q_spec, hbm, hbm]
     inputs = [lengths, page_tables, q4, k_pages, v_pages]
-    # two buffers a pool: one block computing, the next in flight
-    page_buf = pltpu.VMEM((2, block_pages, page_size, lanes),
+    # two buffers a pool: one block computing, the next in flight; a
+    # page of the step's ``hb`` heads lands side by side
+    page_buf = pltpu.VMEM((2, block_pages, hb, page_size, lanes),
                           k_pages.dtype)
     scratch = [page_buf, page_buf]
     if quantized:
         in_specs += [hbm, hbm]
         inputs += [k_scales, v_scales]
-        scale_buf = pltpu.VMEM((2, block_pages, page_size, 128),
+        scale_buf = pltpu.VMEM((2, block_pages, hb, page_size, 128),
                                jnp.float32)
         scratch += [scale_buf, scale_buf]
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),          # one a buffer slot
-        pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((rows, 128), jnp.float32),
-        pltpu.VMEM((rows, lanes), jnp.float32),
+        pltpu.VMEM((hb, rows, 128), jnp.float32),
+        pltpu.VMEM((hb, rows, 128), jnp.float32),
+        pltpu.VMEM((hb, rows, lanes), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # lengths, page_tables
-        grid=(batch, kv_heads),
+        grid=(batch, kv_heads // hb),
         in_specs=in_specs,
         out_specs=q_spec,
         scratch_shapes=scratch,
@@ -555,13 +643,41 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
         out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, lanes),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("parallel", "parallel"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
     )(*inputs)[..., :d]
     if n_query == 1:
         return out.reshape(batch, q_heads, d)
     return out.reshape(batch, kv_heads, n_query, group, d) \
         .transpose(0, 2, 1, 3, 4).reshape(batch, n_query, q_heads, d)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret",
+                                             "n_query", "window"))
+def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
+                   interpret=False, n_query=1, k_scales=None,
+                   v_scales=None, q_lens=None, window=None):
+    """``q`` is (batch, q_heads, d) for n_query == 1, else
+    (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
+    (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
+    ``q_lens`` (batch,) int32 selects the RAGGED kernel: per-row query
+    spans left-aligned in the n_query bucket (ISSUE 17), computed in
+    tiles of :func:`query_tile_rows` rows, a row's live tiles only.
+
+    The grid is (batch, kv_heads // hb): a grid step owns a row and
+    ``hb`` of its kv heads (:func:`walk_head_group`: all of them where
+    they fit).  The pools are handed over whole and stay in HBM, and each
+    grid step walks its row's context in blocks of
+    :func:`walk_block_pages` pages (see ``_decode_kernel``).
+
+    Jitted, so that a program's layers, which all call it at the same
+    shapes, share ONE traced and lowered kernel: a serving engine builds
+    a program a (rows, span) bucket, each of them every layer deep."""
+    return _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
+                        interpret=interpret, n_query=n_query,
+                        k_scales=k_scales, v_scales=v_scales,
+                        q_lens=q_lens, window=window)
 
 
 def _gather_dequant(pages, scales, page_tables, batch, kv_heads,

@@ -11,8 +11,9 @@ import paddle_tpu as paddle
 from paddle_tpu.ops.pallas.paged_attention import (
     PagedKVCache, append_rows, paged_attention, paged_attention_multi,
     paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla,
-    kv_tokens_walked, live_query_tiles, q_positions_computed,
-    query_tile_rows, quantize_kv, walk_block_pages)
+    kv_pages_copied, kv_tokens_walked, live_query_tiles,
+    q_positions_computed, query_tile_rows, quantize_kv, walk_block_pages,
+    walk_head_group)
 from paddle_tpu.ops.pallas import paged_attention as paged_attention_mod
 from paddle_tpu.ops.pallas.flash_attention import mha_reference
 from paddle_tpu.ops.pallas.fused_norm_rope import (
@@ -673,6 +674,183 @@ class TestRaggedQueryTiles:
                             .astype(jnp.float32))
         assert packed[:sum(q_lens)].any(axis=(1, 2)).all()
         assert not packed[sum(q_lens):].any()
+
+
+def head_group_call(mode, q, kp, vp, lens, q_lens, tabs, scale, kw,
+                    window=None, head_group=None):
+    """The kernel behind the three entries with the kv heads a grid step
+    owns FORCED (``None``: what the shapes give): ``_decode_call``, which
+    the jitted ``_decode_pallas`` wraps and no program calls itself."""
+    ragged = {"q_lens": q_lens} if mode == "ragged" else {}
+    if mode == "decode":
+        q = q[:, 0]
+    return paged_attention_mod._decode_call(
+        q, kp, vp, lens, tabs, scale, interpret=True,
+        n_query=1 if mode == "decode" else q.shape[1], window=window,
+        head_group=head_group, **ragged, **kw)
+
+
+def head_group_check(mode, args, window, hb):
+    """A grouped call against its XLA oracle, and BIT FOR BIT against the
+    same call a head a grid step (the program before the groups): a
+    (row, head)'s blocks, their order and every operation on them do not
+    depend on the group it rides in."""
+    q, kp = args[0], args[1]
+    rows = (1 if mode == "decode" else q.shape[1]) * q.shape[2] // kp.shape[0]
+    assert walk_head_group(kp.shape[0], kp.shape[2], q.shape[3], rows,
+                           kp.dtype, q.dtype) == hb
+    kw = dict(args[7], window=window)
+    out = head_group_call(mode, *args, window=window)
+    one = head_group_call(mode, *args, window=window, head_group=1)
+    ref = _walk_call(mode, *args[:7], kw, oracle=True)
+    np.testing.assert_array_equal(np.asarray(out.astype(jnp.float32)),
+                                  np.asarray(one.astype(jnp.float32)))
+    tol = 2e-4 if q.dtype == jnp.float32 else 2e-2
+    held = np.asarray(args[3]) > 0      # an empty row attends nothing
+    np.testing.assert_allclose(
+        _real_queries(mode, out[held], args[4][held]),
+        _real_queries(mode, ref[held], args[4][held]), rtol=tol, atol=tol)
+    assert np.isfinite(np.asarray(out.astype(jnp.float32))).all()
+    return out
+
+
+# lengths of 0, one page, a whole block and not a whole block (blocks
+# of 512 and 256 tokens), the engine's pad row among them
+HEAD_GROUP_LENS = [0, 16, 512, 700, 1, 300]
+
+
+class TestHeadGroups:
+    """A grid step owns a row and a GROUP of its kv heads (ISSUE 42): a
+    page is copied once for the group, scores, softmax and products run
+    a head at a time over that block.  ``walk_head_group`` reads the
+    call's shapes alone; every output is what one head a step gave."""
+
+    # name: mode, kv heads, group, head_dim, storage, span, hb
+    CASES = {
+        "phi4_flash_one_query": ("decode", 10, 4, 128, "bf16", 1, 10),
+        "mistral_ragged": ("ragged", 8, 4, 128, "bf16", 128, 8),
+        "laguna_full_ragged": ("ragged", 8, 6, 128, "bf16", 128, 8),
+        "verify_bucket": ("multi", 8, 4, 128, "bf16", 4, 8),
+        "int8_pages_and_scale_pools": ("ragged", 4, 4, 128, "int8", 32, 4),
+        "int8_one_query": ("decode", 4, 4, 128, "int8", 1, 4),
+        "head_dim_64": ("ragged", 4, 3, 64, "bf16", 16, 4),
+        "a_tp_shard_s_two_heads": ("ragged", 2, 4, 128, "bf16", 128, 2),
+        "float32_one_query": ("decode", 6, 2, 128, "f32", 1, 6),
+    }
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_grouped_is_one_head_a_step_bit_for_bit(self, name):
+        mode, kvh, group, d, kv, span, hb = self.CASES[name]
+        rng = np.random.default_rng(42)
+        lens = HEAD_GROUP_LENS
+        if name == "phi4_flash_one_query":      # the cell's 32 rows
+            lens = lens * 5 + [1300, 47]
+        q_lens = None
+        if mode == "ragged":                    # a chunk row, decode rows
+            q_lens = [min(n, L, span) for n, L in zip(
+                [0, 1, span, 37, 1, 1], lens)]
+        if mode == "multi":
+            lens = [max(L, span) for L in lens]
+        args = TestContextWalk._case(
+            rng, mode, kv, lens, q_heads=kvh * group, kvh=kvh, d=d,
+            span=span, q_lens=q_lens, table=96)
+        if kv == "int8":            # the model's dtype over int8 pages
+            args = (args[0].astype(jnp.bfloat16), *args[1:])
+        out = head_group_check(mode, args, None, hb)
+        if mode == "ragged":        # dead queries: zeros
+            dead = np.arange(span)[None, :] >= np.asarray(q_lens)[:, None]
+            assert not np.asarray(out.astype(jnp.float32))[dead].any()
+
+    @pytest.mark.parametrize("kvh", [3, 7])
+    def test_heads_no_divisor_of_which_fits_but_one(self, monkeypatch, kvh):
+        """A prime count of heads that do not fit together: one head a
+        grid step, the program before the groups, and the same bits as
+        the heads together."""
+        rng = np.random.default_rng(43)
+        args = TestContextWalk._case(
+            rng, "ragged", "bf16", HEAD_GROUP_LENS, q_heads=kvh * 4,
+            kvh=kvh, span=32, q_lens=[0, 1, 32, 5, 1, 1])
+        rule = walk_head_group.__wrapped__
+        shapes = (kvh, 16, 128, 128, jnp.bfloat16, jnp.bfloat16)
+        assert rule(*shapes) == kvh
+        together = head_group_call("ragged", *args)
+        # room for two heads' buffers and blocks, not for three
+        monkeypatch.setattr(paged_attention_mod, "_HEAD_GROUP_BYTES",
+                            1 << 21)
+        assert rule(*shapes) == 1
+        monkeypatch.setattr(paged_attention_mod, "walk_head_group", rule)
+        alone = head_group_call("ragged", *args)
+        np.testing.assert_array_equal(
+            np.asarray(together.astype(jnp.float32)),
+            np.asarray(alone.astype(jnp.float32)))
+
+    @pytest.mark.parametrize("kvh,rows,kv,hb,pages", [
+        (10, 4, jnp.bfloat16, 10, 32),      # Phi-4-flash, a decode step
+        (10, 512, jnp.bfloat16, 10, 32),    # its chunk step: 17.5 MB
+        (8, 4, jnp.bfloat16, 8, 32),        # Mistral and Laguna, decode
+        (8, 512, jnp.bfloat16, 8, 32),      # Mistral's chunk step
+        (8, 768, jnp.bfloat16, 8, 16),      # Laguna's full layers
+        (8, 1024, jnp.bfloat16, 8, 16),     # Laguna's sliding layers
+        (8, 512, jnp.int8, 8, 32),
+        (2, 512, jnp.bfloat16, 2, 32),      # a shard of tp = 4
+        (32, 512, jnp.bfloat16, 16, 32),    # 1.75 MB a head: 16 of 32
+        (8, 2048, jnp.bfloat16, 4, 8),      # 5.1 MB a head: half
+        (7, 2048, jnp.bfloat16, 1, 8),      # and no half of seven
+        (8, 1 << 16, jnp.bfloat16, 1, 1),   # nothing fits: a head a step
+    ])
+    def test_group_rule_reads_shapes_only(self, kvh, rows, kv, hb, pages):
+        got = walk_head_group(kvh, 16, 128, rows, kv, jnp.bfloat16)
+        assert got == hb and kvh % got == 0
+        # the block of a (row, head) is what it was: the buffers grow
+        # with the group, the block does not
+        assert walk_block_pages(16, 128, rows, kv) == pages
+        per_head = (4 * pages * paged_attention_mod._page_vmem_bytes(
+            16, 128, kv) + 4 * rows * 128 * 2 + rows * 384 * 4)
+        budget = paged_attention_mod._HEAD_GROUP_BYTES
+        assert got == 1 or got * per_head <= budget
+        bigger = [g for g in range(got + 1, kvh + 1) if kvh % g == 0]
+        assert all(g * per_head > budget for g in bigger)
+
+    @pytest.mark.parametrize("window", [None, 64])
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_host_count_is_the_kernel_s_own(self, monkeypatch, kv, window):
+        """``kv_pages_copied`` x pools x grid steps a row against the
+        descriptors an interpreted kernel STARTS, counted where it starts
+        them; one a (page, head, pool) a head a step."""
+        started = []
+        make = paged_attention_mod.pltpu.make_async_copy
+
+        class Counted:
+            def __init__(self, copy):
+                self.copy = copy
+
+            def start(self):
+                jax.debug.callback(lambda: started.append(1))
+                self.copy.start()
+
+            def wait(self):
+                self.copy.wait()
+
+        monkeypatch.setattr(paged_attention_mod.pltpu, "make_async_copy",
+                            lambda *a: Counted(make(*a)))
+        rng = np.random.default_rng(44)
+        lens, q_lens = [0, 16, 100, 700, 1], [0, 1, 8, 3, 1]
+        args = TestContextWalk._case(
+            rng, "ragged", kv, lens, q_heads=16, kvh=4, span=8,
+            q_lens=q_lens)
+        args = (*args[:5], args[5][:, :40], *args[6:])  # cuts the 700 short
+        pools = 4 if kv == "int8" else 2
+        pages = kv_pages_copied(lens, 16, 40, window, q_lens)
+        by_hand = [min(-(-L // 16), 40) - (
+            max(L - n + 1 - window, 0) // 16 if window else 0)
+            for L, n in zip(lens, q_lens)]
+        assert pages == sum(by_hand)
+        for hb in (4, 2, 1):
+            started.clear()
+            jax.block_until_ready(head_group_call(
+                "ragged", *args, window=window, head_group=hb))
+            jax.effects_barrier()
+            assert len(started) == pools * pages * (4 // hb)
 
 
 class TestFusedNormRope:

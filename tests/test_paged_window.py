@@ -125,6 +125,39 @@ class TestWindowedWalk:
                                       np.asarray(b.astype(jnp.float32)))
 
 
+class TestWindowedHeadGroups:
+    """The window under a grid step that owns a group of kv heads
+    (ISSUE 42): the walk starts at the row's first visible page for the
+    whole group, and every output is one head a step's, bit for bit."""
+
+    # name: mode, kv heads, group, storage, span, rows
+    CASES = {
+        "phi4_flash_one_query": ("decode", 10, 4, "bf16", 1, 32),
+        "laguna_sliding_ragged": ("ragged", 8, 8, "bf16", 128, 6),
+        "verify_bucket": ("multi", 8, 4, "bf16", 4, 6),
+        "int8_ragged": ("ragged", 4, 4, "int8", 32, 6),
+    }
+
+    @pytest.mark.parametrize("name", sorted(CASES))
+    def test_grouped_is_one_head_a_step_bit_for_bit(self, name):
+        mode, kvh, group, kv, span, rows = self.CASES[name]
+        rng = np.random.default_rng(42)
+        # shorter than the window, around it, blocks past it, empty
+        lens = ([0, 16, 511, 512, 513, 1300] * 6)[:rows]
+        lens[-1] = 1029
+        q_lens = None
+        if mode == "ragged":
+            q_lens = [min(n, L, span) for n, L in zip(
+                [0, 1, span, 37, 1, 1], lens)]
+        if mode == "multi":
+            lens = [max(L, span) for L in lens]
+        args = case(rng, mode, kv, lens, q_heads=kvh * group, kvh=kvh,
+                    span=span, q_lens=q_lens, table=96)
+        if kv == "int8":
+            args = (args[0].astype(jnp.bfloat16), *args[1:])
+        tpa.head_group_check(mode, args, 512, kvh)
+
+
 class TestWindowedCount:
     def test_first_token_is_the_page_of_the_first_visible_key(self):
         # a decode row of 1,000 tokens under a window of 512 sees 488..999

@@ -236,6 +236,43 @@ class TestPagedAttentionLowering:
         assert "all-gather" not in text
 
 
+class TestHeadGroupLowering:
+    """A grid step owns a row and a group of its kv heads (ISSUE 42): the
+    page copy with the pool's head axis strided, the (hb, rows, lanes)
+    blocks and scratch and a head index read from a loop counter, at the
+    shapes `phi4-flash.serve.reason32` hands the kernels (32 rows, ten
+    pair-heads of 128 lanes at group 4, a table of 256 over 6,144 pages)
+    and with the group forced to a part of the heads and to one."""
+
+    @pytest.mark.parametrize("nq", [1, 128], ids=["decode", "span128"])
+    @pytest.mark.parametrize("window", [None, 512], ids=["full", "sliding"])
+    def test_phi4_flash_cell(self, chip, window, nq):
+        assert paged_attention.walk_head_group(
+            10, 16, 128, nq * 4, BF16, BF16) == 10
+        text = chip.compile(
+            _paged_fn(128, nq=nq, ragged=nq > 1, window=window),
+            *_paged_specs(chip, kvh=10, heads=40, d=128, batch=32,
+                          pages=6144, page=16, table=256, nq=nq,
+                          ragged=nq > 1))
+        # the q block of a grid step: all ten heads
+        assert f"bf16[32,10,{nq * 4},128]" in text
+
+    @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+    @pytest.mark.parametrize("hb", [1, 2, 8])
+    def test_a_part_of_the_heads(self, chip, hb, int8):
+        def fn(q, kp, vp, lens, tabs, *rest):
+            kw = {"q_lens": rest[-1]}
+            if int8:
+                kw.update(k_scales=rest[0], v_scales=rest[1])
+            return paged_attention._decode_call(
+                q, kp, vp, lens, tabs, 0.1, n_query=128, head_group=hb,
+                **kw)
+
+        chip.compile(fn, *_paged_specs(
+            chip, kvh=8, heads=H7, d=D7, batch=8, pages=4096, page=16,
+            table=256, nq=128, int8=int8, ragged=True))
+
+
 # ------------------------------------------------------- the KV append
 class TestAppendRowsLowering:
     """One layer of the serving step at the benchmark cell's shapes

@@ -225,6 +225,52 @@ class TestEngineStepUnderTrace:
             assert r["kv_tokens_walked"] >= r["ctx_tokens"]
             assert r["kv_tokens_walked"] % block == 0
 
+    def test_dispatch_record_counts_the_page_copies(self, run):
+        """``page_copies`` and ``head_page_reads`` are the paged kernel's
+        own copy rule applied to the dispatch's padded rows: a descriptor
+        a page, a pool and a GROUP of kv heads (both of tiny_model's two
+        ride together), and ``kernel.paged_attn.copy_share`` reads their
+        ratio from the ring."""
+        import json
+        import sys
+        from paddle_tpu.ops.pallas.paged_attention import (
+            kv_pages_copied, walk_head_group)
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        sys.path.insert(0, os.path.join(root, "benchmark"))
+        try:
+            from readers import ring_ratio
+        finally:
+            sys.path.remove(os.path.join(root, "benchmark"))
+        _events, records, _ = run
+        disp = [r for r in records if r["kind"] == "dispatch"]
+        for r, rows in zip(disp, self.row_lengths):
+            assert walk_head_group(2, r["page_size"], 8,
+                                   r["span_padded"] * 2, np.float32,
+                                   np.float32) == 2
+            padded = rows + [1] * (r["rows_padded"] - r["rows"])
+            pages = kv_pages_copied(padded, r["page_size"],
+                                    r["table_pages"])
+            assert pages == sum(-(-n // r["page_size"]) for n in padded)
+            assert r["page_copies"] == 2 * pages            # K and V
+            assert r["head_page_reads"] == 2 * 2 * pages    # of two heads
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               "kernel.paged_attn.copy_share.json")) as f:
+            spec = json.load(f)
+        assert spec["reader"] == "ring_ratio"
+        assert ring_ratio.read(spec["args"], {"steps": records}) == 50.0
+        # a program without the fields (the parent): nothing to read
+        bare = [{k: v for k, v in r.items() if k != "page_copies"}
+                for r in records]
+        assert ring_ratio.read(spec["args"], {"steps": bare}) is None
+        with open(os.path.join(root, "BENCHMARK.json")) as f:
+            (entry,) = [m for m in json.load(f)["per_layer"]
+                        if m["name"] == "kernel.paged_attn.copy_share"]
+        assert entry["better"] == "lower" and entry["workloads"] == [
+            "mistral7b.serve.closed8", "laguna-xs2.serve.agent8",
+            "phi4-flash.serve.reason32"]
+        assert (entry["unit"], entry["layer"], entry["moves"]) == (
+            spec["unit"], spec["layer"], spec["moves"])
+
     def test_dispatch_interval_lies_inside_the_steps_other_records(self, run):
         _events, records, _ = run
         by_index = {}
