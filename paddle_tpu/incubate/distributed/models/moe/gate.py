@@ -327,3 +327,81 @@ class SigmoidTopKGate(BaseGate):
             "SigmoidTopKGate keeps every assignment and has no "
             "[tokens, experts, capacity] form: use it in a MoELayer "
             "built with held_experts=(first, count)")
+
+
+@def_op("moe_mlp_router_top1")
+def _mlp_router_top1(r, norm_w, w1, b1, w2, b2, w3, bias, eps):
+    """The router's float32 path from its 256-wide state on: RMSNorm, a
+    three-layer GELU MLP, softmax; the expert chosen is the argmax of
+    probability + ``bias`` (the bias steers the choice only) and its
+    weight the UNBIASED probability, not renormalised.  Every product at
+    precision "highest": a float32 operand's default product on the TPU
+    is one bfloat16 pass.  Returns (ids [T, 1] int32, weights [T, 1])."""
+    hi = jax.lax.Precision.HIGHEST
+    u = r * jax.lax.rsqrt(jnp.mean(r * r, axis=-1, keepdims=True) + eps) \
+        * norm_w
+    h = jax.nn.gelu(jnp.dot(u, w1, precision=hi) + b1, approximate=False)
+    h = jax.nn.gelu(jnp.dot(h, w2, precision=hi) + b2, approximate=False)
+    p = jax.nn.softmax(jnp.dot(h, w3, precision=hi), axis=-1)
+    idx = jnp.argmax(p + bias, axis=-1)[:, None].astype(jnp.int32)
+    return idx, jnp.take_along_axis(p, idx, axis=-1)
+
+
+class DepthAveragedMLPGate(BaseGate):
+    """The ZAYA router: more than one matrix, and a state handed from one
+    layer's router to the next.  ``r = W_d x + b_d`` (d_model ->
+    ``hidden``, accumulated and kept in float32); with the previous
+    layer's state ``r_prev``: ``r += gamma * r_prev`` (depth averaging;
+    ``r`` is handed on as it stands HERE, before the norm); then
+    ``softmax(W_3 gelu(W_2 gelu(W_1 RMSNorm(r) + b_1) + b_2))`` over the
+    experts, top-1 over probability + ``balancing_bias`` (float32,
+    ``trainable=False``: moved against the load outside the gradient, a
+    parameter and not a buffer for ``SigmoidTopKGate``'s reason), the
+    weight the unbiased probability.  Everything behind ``W_d`` is float32
+    whatever the model's dtype.  No capacity: routed by ``MoELayer``'s
+    held-experts path, which hands the state in and out
+    (``carries_state``).  ``first`` (the stack's first layer) has no
+    ``gamma``: there is no state before it."""
+
+    carries_state = True
+    top_k = 1
+
+    def __init__(self, d_model, num_expert, hidden, first=False, eps=1e-5,
+                 weight_attr=None):
+        super().__init__(num_expert, 1)
+        self.d_model, self.hidden, self.eps = d_model, hidden, float(eps)
+        attr = weight_attr if weight_attr is not None else XavierNormal()
+        f32 = dict(dtype="float32")
+        self.down_weight = self.create_parameter([d_model, hidden], attr=attr)
+        self.down_bias = self.create_parameter([hidden], is_bias=True, **f32)
+        self.gamma = None if first else self.create_parameter(
+            [hidden], default_initializer=Constant(1.0), **f32)
+        self.norm_weight = self.create_parameter(
+            [hidden], default_initializer=Constant(1.0), **f32)
+        self.w1 = self.create_parameter([hidden, hidden], attr=attr, **f32)
+        self.b1 = self.create_parameter([hidden], is_bias=True, **f32)
+        self.w2 = self.create_parameter([hidden, hidden], attr=attr, **f32)
+        self.b2 = self.create_parameter([hidden], is_bias=True, **f32)
+        self.w3 = self.create_parameter([hidden, self.tot_expert], attr=attr,
+                                        **f32)
+        self.balancing_bias = self.create_parameter(
+            [self.tot_expert], dtype="float32",
+            attr=ParamAttr(initializer=Constant(0.0), trainable=False))
+
+    def route_no_drop(self, x, state=None):
+        """x [T, d_model], ``state`` [T, hidden] float32 the previous
+        layer's or None -> (expert ids [T, 1], weights [T, 1], this
+        layer's state [T, hidden])."""
+        r = _logits_f32(x, self.down_weight) + self.down_bias
+        if self.gamma is not None and state is not None:
+            r = r + self.gamma * state
+        idx, w = _mlp_router_top1(r, self.norm_weight, self.w1, self.b1,
+                                  self.w2, self.b2, self.w3,
+                                  self.balancing_bias, self.eps)
+        return idx, w, r
+
+    def forward(self, x):
+        raise NotImplementedError(
+            "DepthAveragedMLPGate keeps every assignment and has no "
+            "[tokens, experts, capacity] form: use it in a MoELayer "
+            "built with held_experts=(first, count)")

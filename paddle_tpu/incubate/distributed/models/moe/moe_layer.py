@@ -36,7 +36,8 @@ its time does not follow the routing.  A wider share (all 256 of a layer
 served whole on one chip) lays the (token, chosen expert) pairs out grouped
 by expert in blocks of 16 rows (``ops/pallas/moe_grouped_ffn.py``): tokens x
 top_k rows plus the blocks' padding, and the weights of the experts that
-were chosen.  Either product leaves the same counts (``ROUTING_FIELDS``).
+were chosen (16 experts of 2,048 x 2,048 at top-1 take it too: the kernel
+holds a tile of an expert's width at a time).  Either product leaves the same counts (``ROUTING_FIELDS``).
 What the other ranks' experts would add is NOT here: summing it across
 ranks is the exchange a multi-chip deployment adds around this layer.
 """
@@ -246,7 +247,11 @@ class MoELayer(Layer):
     After a forward of a share, ``last_routing`` holds its counts
     (``ROUTING_FIELDS``; ``routing_counts()`` gives them by name).
     ``forward(x, token_mask=)``: positions that are no tokens (the pad of a
-    packed serving step) are routed nowhere.
+    packed serving step) are routed nowhere.  ``forward(x, router_state=)``:
+    for a gate whose router reads the previous layer's router
+    (``DepthAveragedMLPGate``: ``carries_state``), that layer's state
+    [tokens, hidden] or None for the first; the layer then returns
+    ``(y, its own state)``, to be handed to the next.
     """
 
     def __init__(self, d_model: int,
@@ -306,7 +311,8 @@ class MoELayer(Layer):
             assert isinstance(self.experts, SwiGLUExperts) \
                 and hasattr(gate, "route_no_drop"), (
                     "a share of the experts is a SwiGLUExperts routed by a "
-                    "gate with route_no_drop (SigmoidTopKGate)")
+                    "gate with route_no_drop (SigmoidTopKGate, "
+                    "DepthAveragedMLPGate)")
             assert count == self.num_expert and first >= 0 \
                 and first + count <= gate.tot_expert, (
                     f"held_experts=({first}, {count}) must name the "
@@ -335,11 +341,15 @@ class MoELayer(Layer):
         from .....tensor.manipulation import concat
         return concat(outs, axis=0)                      # [E, C, M]
 
-    def _forward_share(self, tokens, token_mask=None):
+    def _forward_share(self, tokens, token_mask=None, router_state=None):
         first, count = self.held_experts
         ex = self.experts
+        state = None
         with jax.named_scope("moe/router"):
-            idx, w = self.gate.route_no_drop(tokens)
+            if getattr(self.gate, "carries_state", False):
+                idx, w, state = self.gate.route_no_drop(tokens, router_state)
+            else:
+                idx, w = self.gate.route_no_drop(tokens)
         with jax.named_scope("moe/experts"):
             if count <= DENSE_SHARE * self.gate.top_k:
                 y, self.last_routing = _held_experts(
@@ -351,18 +361,22 @@ class MoELayer(Layer):
                     ex.up_proj, ex.down_proj)
             if self.shared_expert is not None:
                 y = y + self.shared_expert(tokens)
-        return y
+        return y, state
 
     def routing_counts(self) -> dict:
         """The last forward's counts by name (``ROUTING_FIELDS``)."""
         return dict(zip(ROUTING_FIELDS, self.last_routing._data))
 
-    def forward(self, x: Tensor, token_mask=None) -> Tensor:
+    def forward(self, x: Tensor, token_mask=None, router_state=None):
         orig_shape = x.shape
         tokens = x.reshape([-1, self.d_model])
         if self.held_experts is not None:
-            return self._forward_share(tokens, token_mask).reshape(
-                orig_shape)
+            y, state = self._forward_share(tokens, token_mask, router_state)
+            y = y.reshape(orig_shape)
+            # a gate that carries a state from layer to layer hands this
+            # layer's out beside the output, for the caller to hand the
+            # next layer's: it lives in the caller's program, never here
+            return (y, state) if state is not None else y
         use_recompute = self.recompute_interval > 0 and self.training
         if (isinstance(self.gate, NaiveGate)
                 and type(self.gate).forward is NaiveGate.forward):

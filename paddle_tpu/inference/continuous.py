@@ -328,6 +328,9 @@ _STEP_SUMS = {
          "summed over the expert layers: whose weights a step reads"),
         ("moe_expert_layers", "experts a step could have touched: experts "
          "x expert layers"),
+        ("moe_max_expert_pairs", "pairs a layer's FULLEST expert got, "
+         "summed over the expert layers: over moe_slots it is the share of "
+         "a layer's tokens the expert most chosen holds"),
         ("ctx_tokens_window", "KV positions a sliding-attention layer's "
          "queries attend"),
         ("kv_tokens_walked_window", "KV positions a sliding-attention "

@@ -301,8 +301,8 @@ _NO_SHARED = ("a layer that attends another layer's pages (k=None) or "
 
 
 #: what a path with no slots says to a retention layer
-_NO_SLOTS = ("a retention layer or a Mamba layer (a recurrent state a "
-             "sequence) reached {}, "
+_NO_SLOTS = ("a retention layer, a Mamba layer or a convolutional-"
+             "attention layer (a recurrent state a sequence) reached {}, "
              "which carries no slot pools: serve this model through the "
              "ragged unified step (ContinuousBatchingEngine("
              "prefill_chunk_tokens=...)), whose rows each update their own "
@@ -329,7 +329,7 @@ class _PagedContext:
         raise NotImplementedError(_NO_SLOTS.format(
             "the eager paged context of PagedGenerator"))
 
-    conv_rows = scan_rows = retain
+    conv_rows = scan_rows = shift_rows = retain
 
     def attend(self, q: Tensor, k: Tensor, v: Tensor,
                window: Optional[int] = None, scale=None) -> Tensor:
@@ -418,8 +418,11 @@ class _TracedPagedContext:
     tokens) updated in place by the ragged step, each row against its
     own, a pad row's untouched; ``conv_rows`` / ``scan_rows`` — the same for
     a Mamba layer, whose slot is two arrays (the convolution's tail and the
-    scan's ``h``: two pools a layer, side by side in ``states``).  Only the
-    ragged step carries slots.  ``attend(q, None, None)`` attends WITHOUT
+    scan's ``h``: two pools a layer, side by side in ``states``);
+    ``shift_rows`` — for a layer whose slot is one-token tails (a token's
+    predecessor: ``models/zaya.py``, where the layer ALSO calls ``attend``
+    on a page pool of its own: a slot layer need not be a layer without
+    pages).  Only the ragged step carries slots.  ``attend(q, None, None)`` attends WITHOUT
     appending, against the pool ``layer_idx`` names: a layer that reads
     the pages another layer wrote earlier in the same program."""
 
@@ -492,13 +495,35 @@ class _TracedPagedContext:
         return (self.slots, self.lens - self.q_lens, self.q_lens,
                 self.row_off)
 
+    def shift_rows(self, x, part=0, parts=1):
+        """Every packed token's predecessor in its own sequence, ``x``
+        (tokens, channels) -> x_{t-1} float32: a row's first token reads
+        array ``part`` of the layer's slot (zeros at context 0 whatever
+        the slot held), and that array moves on to the row's last token
+        (``ops/selective_scan.py::shift_step``).  A slot is whatever
+        arrays ``recurrent_state()["shapes"]`` lists, in the order the
+        layer names them by ``part``; the call on the LAST of its
+        ``parts`` moves on to the next layer's slot and counts the
+        layer's rows."""
+        from ..ops.selective_scan import shift_step
+        rows = self._recur_rows()       # refuses where there are no slots
+        i = self.state_idx + part
+        y, self.states[i] = shift_step(self.states[i], *rows, x,
+                                       span=self.span)
+        if part == parts - 1:
+            self.state_idx += parts
+            self._count_state_rows(self.states[i])
+        return y
+
     def conv_rows(self, x, w, b):
         """A Mamba layer's causal convolution over the step's packed
         tokens ``x`` (tokens, channels), each row continuing from its
         slot's tail, which moves on by the row's tokens
-        (``ops/selective_scan.py::conv_step``).  The tail is the SECOND
-        array of the layer's slot; ``scan_rows``, which the layer calls
-        next, takes the first and moves on to the next layer's."""
+        (``ops/selective_scan.py::conv_step``).  A Mamba layer's slot is
+        two arrays: the tail is the SECOND; ``scan_rows``, which the
+        layer calls next, takes the first and moves on to the next
+        layer's.  (A slot need not be Mamba's: ``shift_rows`` serves a
+        layer whose slot is tails alone, each named by its index.)"""
         from ..ops.selective_scan import conv_step
         rows = self._recur_rows()
         i = self.state_idx + 1

@@ -20,6 +20,9 @@ from .laguna import (  # noqa: F401
 from .brumby import (  # noqa: F401
     BrumbyConfig, BrumbyForCausalLM, BrumbyModel,
 )
+from .zaya import (  # noqa: F401
+    ZayaConfig, ZayaForCausalLM, ZayaModel,
+)
 from .bert import (  # noqa: F401
     BertConfig, BertModel, BertForSequenceClassification, BertForMaskedLM,
     bert_base, bert_tiny,

@@ -14,7 +14,9 @@ product: on the TPU the Pallas kernel ``moe_grouped_ffn`` — a grid step a
 block, the block's expert read from a scalar-prefetched table by the
 weight BlockSpecs' index maps (a run of blocks of one expert fetches its
 weights once; the blocks past the last in use point at the last expert
-in use and fetch nothing), its compute skipped past the blocks in use;
+in use and fetch nothing), its compute skipped past the blocks in use,
+an expert wider than ``TILE_WIDTH`` a tile of its width at a time
+(``_ffn_pallas``);
 elsewhere ``lax.ragged_dot`` over the same layout, the kernel's oracle.
 
 Dispatch and combine are 0/1 matrices over (rows, tokens) multiplied on
@@ -37,6 +39,10 @@ from .flash_attention import _use_pallas
 #: rows a block holds: the bf16 tile's sublanes, so a block is whole
 #: tiles in the dtype the serving step computes in
 BLOCK_ROWS = 16
+#: columns of an expert's width a grid step holds in VMEM
+TILE_WIDTH = 512
+#: the most a several-tile call's float32 accumulator may take of VMEM
+ACC_BYTES = 16 * 1024 * 1024
 
 
 def plan_blocks(pairs: int, experts: int) -> int:
@@ -77,11 +83,18 @@ def plan(idx, held, experts: int):
 
 
 def _ffn_kernel(be_ref, nb_ref, x_ref, wrow_ref, wg_ref, wu_ref, wd_ref,
-                o_ref):
-    """One block of ``BLOCK_ROWS`` rows through its expert's SwiGLU, each
-    row scaled by its routing weight before the down projection."""
+                o_ref, *acc, tiles):
+    """One block of ``BLOCK_ROWS`` rows through ONE TILE of its expert's
+    SwiGLU: the tile's columns of the gate and up products, each row
+    scaled by its routing weight, times the tile's rows of the down
+    projection.  An expert of one tile is done there; one of several
+    sums the tiles' products in ``acc`` (float32, every block's rows: the
+    grid walks all blocks a tile, so a block comes back once a tile) and
+    writes the sum so far each time, the last being the whole."""
     del be_ref                          # read by the weights' index maps
-    in_use = pl.program_id(0) < nb_ref[0]
+    b = pl.program_id(0 if tiles == 1 else 1)
+    in_use = b < nb_ref[0]
+    first_tile = pl.program_id(0) == 0      # (read outside the branches)
 
     @pl.when(in_use)
     def _():
@@ -89,9 +102,14 @@ def _ffn_kernel(be_ref, nb_ref, x_ref, wrow_ref, wg_ref, wu_ref, wd_ref,
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         h = jax.nn.silu(g) * u * wrow_ref[:, :1]
-        o_ref[...] = jnp.dot(h.astype(x.dtype), wd_ref[0],
-                             preferred_element_type=jnp.float32
-                             ).astype(o_ref.dtype)
+        y = jnp.dot(h.astype(x.dtype), wd_ref[0],
+                    preferred_element_type=jnp.float32)
+        if tiles > 1:
+            rows = pl.ds(pl.multiple_of(b * BLOCK_ROWS, BLOCK_ROWS),
+                         BLOCK_ROWS)
+            y = jnp.where(first_tile, y, acc[0][rows, :] + y)
+            acc[0][rows, :] = y
+        o_ref[...] = y.astype(o_ref.dtype)
 
     @pl.when(jnp.logical_not(in_use))
     def _():
@@ -103,33 +121,61 @@ def _ffn_pallas(x_rows, w_rows, block_expert, n_blocks, w_gate, w_up,
                 w_down, interpret=False):
     """``_ffn_kernel`` over the block layout.  Jitted like the paged
     kernels: a program's expert layers share one traced and lowered
-    body."""
+    body.
+
+    An expert's three matrices are held in VMEM a TILE of ``TILE_WIDTH``
+    of its width at a time, two buffers each: at 2,048 x 512 (Laguna) the
+    expert is one tile, 3 x 2.1 MB x 2 = 12.6 MB, and the grid is a step a
+    block as it always was; at 2,048 x 2,048 (ZAYA) whole matrices would
+    be 3 x 8.4 MB x 2 = 50.3 MB, over ``vmem_limit_bytes``, so the grid
+    gains a LEADING axis over the four tiles (the same 12.6 MB of weight
+    buffers) and a float32 accumulator of every block's rows (432 rows x
+    2,048 x 4 B = 3.5 MB at 192 tokens).  Tiles outermost: within a tile
+    a run of blocks of one expert still names one weight block, so each
+    tile of each touched expert is fetched once a run, as each expert was.
+    A width that is no multiple of the tile is one tile."""
     rows, m = x_rows.shape
     _, _, hidden = w_gate.shape
     nb = rows // BLOCK_ROWS
+    tile = TILE_WIDTH if hidden % TILE_WIDTH == 0 else hidden
+    tiles = hidden // tile
+    if tiles > 1 and rows * m * 4 > ACC_BYTES:
+        raise NotImplementedError(
+            f"{rows} rows of {m} would hold {rows * m * 4} bytes of float32 "
+            f"partial sums in VMEM (limit {ACC_BYTES}): an expert wider "
+            f"than a tile of {TILE_WIDTH} is served a step's tokens, not a "
+            "training batch")
     wrow = jnp.broadcast_to(w_rows.astype(jnp.float32)[:, None], (rows, 128))
 
-    def of_expert(b, be, n):
-        return (be[b], 0, 0)
+    # the grid's ids, then the two prefetched tables; one tile: (b,)
+    def of_block(*a):
+        return (a[-3], 0)
 
-    def of_block(b, be, n):
-        return (b, 0)
+    def of_expert_cols(*a):
+        return (a[-2][a[-3]], 0, a[0] if tiles > 1 else 0)
+
+    def of_expert_rows(*a):
+        return (a[-2][a[-3]], a[0] if tiles > 1 else 0, 0)
 
     return pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_ffn_kernel, tiles=tiles),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,          # block_expert, n_blocks
-            grid=(nb,),
+            grid=(nb,) if tiles == 1 else (tiles, nb),
             in_specs=[pl.BlockSpec((BLOCK_ROWS, m), of_block),
                       pl.BlockSpec((BLOCK_ROWS, 128), of_block),
-                      pl.BlockSpec((1, m, hidden), of_expert),
-                      pl.BlockSpec((1, m, hidden), of_expert),
-                      pl.BlockSpec((1, hidden, m), of_expert)],
-            out_specs=pl.BlockSpec((BLOCK_ROWS, m), of_block)),
+                      pl.BlockSpec((1, m, tile), of_expert_cols),
+                      pl.BlockSpec((1, m, tile), of_expert_cols),
+                      pl.BlockSpec((1, tile, m), of_expert_rows)],
+            out_specs=pl.BlockSpec((BLOCK_ROWS, m), of_block),
+            scratch_shapes=([] if tiles == 1 else
+                            [pltpu.VMEM((rows, m), jnp.float32)])),
         out_shape=jax.ShapeDtypeStruct((rows, m), x_rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            # three weight blocks, two buffers each: 12 MB at 2,048 x 512
+            dimension_semantics=(("arbitrary",) if tiles == 1
+                                 else ("arbitrary", "arbitrary")),
+            # three weight tiles, two buffers each, 12.6 MB at 2,048 x 512
+            # whatever the expert's width; the accumulator beside them
             vmem_limit_bytes=48 * 1024 * 1024),
         name="moe_grouped_ffn", interpret=interpret,
     )(block_expert, n_blocks.reshape(1), x_rows, wrow, w_gate, w_up, w_down)

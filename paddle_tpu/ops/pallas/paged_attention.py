@@ -1110,7 +1110,14 @@ def paged_layout(model) -> dict:
       K and V (``model.kv_page_shape()`` where the model lays them out
       otherwise than its config says);
     * ``state``: ``model.recurrent_state()`` or None, its slot's arrays
-      under ``shapes`` (a model that says ``shape`` has one)."""
+      under ``shapes`` (a model that says ``shape`` has one).  A slot is
+      whatever arrays the model lists, ``layers`` times over, in the order
+      its layers name them to the paged context: a retention layer's one
+      state, a Mamba layer's ``h`` and convolution tail, a convolutional-
+      attention layer's one-token tails (``models/zaya.py``).  A layer
+      with a slot may ALSO own a page pool: ``calls`` and ``state`` are
+      counted apart, and a sequence takes and returns a slot of every
+      state layer with its pages."""
     c = model.config
     if hasattr(model, "attention_kinds"):
         kinds = list(model.attention_kinds())
@@ -1161,7 +1168,8 @@ class PagedKVCache:
     (:func:`paged_layout`).  Beside the pages, for a model whose layers
     carry a recurrent state: slot pools, ``state_slots`` + 1 slots of each
     array of a layer's state (``state_pools``, a layer's arrays side by
-    side), a slot a sequence taken and returned with its pages.
+    side), a slot a sequence taken and returned with its pages; a layer
+    may hold both (a slot beside its own pages: ``models/zaya.py``).
 
     Pages carry two kinds of references: sequence refs (a live sequence
     maps the page in its table) and index refs (a cached prompt prefix

@@ -17,6 +17,8 @@ layer's inputs x [T, D], ``w`` [K, D]:
 * ``scan_recurrence`` / ``conv_recurrence``: the recurrences token by
   token over one whole sequence (the oracle, and the model's forward
   without a cache);
+* ``shift_step``: ``conv_step`` at width 2 with taps (1, 0): a token's
+  predecessor, for a layer whose slot is nothing but such tails;
 * ``scan_step`` / ``conv_step``: one layer of a RAGGED serving step
   against the pools of slots.  A sequence's state a layer is two arrays, a
   slot of each pool: ``h`` [N, D] and the convolution's tail, the last
@@ -131,6 +133,21 @@ def conv_step(pool, slots, ctx_lens, q_lens, row_off, x, w, b, span):
                   old[r, jnp.clip(q_lens + i, 0, k - 2)])
         for i in range(k - 1)], axis=1)
     return y, pool.at[slots].set(new.reshape(rows, -1))
+
+
+def shift_step(pool, slots, ctx_lens, q_lens, row_off, x, span):
+    """Every token's PREDECESSOR in its sequence: ``conv_step`` at K = 2
+    with taps (1, 0) and no bias.  ``x`` [T, D] packed as there, ``pool``
+    [slots + 1, D] each sequence's last token's ``x``: a row's first token
+    reads its slot (zeros at context 0, whatever the slot held), and the
+    slot moves on to the row's last token.  What a layer that mixes a
+    token with the one before it keeps a sequence beside its pages
+    (``models/zaya.py``: the convolutions' inputs and the shifted value).
+    Returns (x_{t-1} [T, D] float32, the pool)."""
+    width = x.shape[1]
+    taps = jnp.stack([jnp.ones(width, F32), jnp.zeros(width, F32)])
+    return conv_step(pool, slots, ctx_lens, q_lens, row_off, x, taps,
+                     jnp.zeros(width, F32), span=span)
 
 
 def _scan_kernel(slot_ref, fresh_ref, len_ref, u_ref, dt_ref, b_ref, c_ref,

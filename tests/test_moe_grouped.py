@@ -97,6 +97,60 @@ class TestGroupedProduct:
             interpret=interpret)
         assert rel(got[:real], want) < 1e-5
 
+    @pytest.mark.parametrize("case", ["even", "nobody_chose_3", "one_row"])
+    @pytest.mark.parametrize("tiles", [1, 2, 4])
+    def test_tiled_kernel_against_its_oracle(self, monkeypatch, tiles, case):
+        """An expert wider than a VMEM tile (ISSUE 44: 2,048 x 2,048 is
+        four tiles of 512; here the tile is 32 and the width 32, 64 and
+        128): the kernel, interpreted, a tile of the width at a time with
+        its float32 partial sums, against ``_ffn_xla`` over the SAME block
+        layout, top-1 as ZAYA routes; an expert nobody chose holds no
+        block and a block of ONE real row computes its 15 pads as zeros.
+        Float32: the tiles' sums differ from one product in order only."""
+        monkeypatch.setattr(G, "TILE_WIDTH", 32)
+        rng = np.random.default_rng(44)
+        t, hidden = 40, 32 * tiles
+        f = lambda *s: jnp.asarray(rng.standard_normal(s) * 0.1, jnp.float32)
+        wg, wu, wd = f(E, M, hidden), f(E, M, hidden), f(E, hidden, M)
+        x = jnp.asarray(rng.standard_normal((t, M)), jnp.float32)
+        idx = rng.integers(0, E, (t, 1))
+        if case == "nobody_chose_3":
+            idx[idx == 3] = 4
+        elif case == "one_row":
+            idx[idx == 6] = 5
+            idx[7] = 6                         # expert 6: one pair alone
+        idx = jnp.asarray(idx, jnp.int32)
+        w = jnp.asarray(rng.uniform(0.1, 1.0, (t, 1)), jnp.float32)
+        held = jnp.ones((t, 1), bool)
+        dest, be, nb, counts = G.plan(idx, held, E)
+        rows = be.shape[0] * G.BLOCK_ROWS
+        at = np.asarray(dest)[None, :, 0] == np.arange(rows)[:, None]
+        x_rows = jnp.asarray(at, jnp.float32) @ x
+        w_rows = jnp.asarray(at, jnp.float32) @ w[:, 0]
+        want = G._ffn_xla(x_rows, w_rows, be, nb, wg, wu, wd)
+        got = G._ffn_pallas(x_rows, w_rows, be, nb, wg, wu, wd,
+                            interpret=True)
+        assert rel(got, want) < 1e-5
+        live = int(nb) * G.BLOCK_ROWS
+        assert not np.asarray(got[live:]).any()    # blocks not in use
+        assert not np.asarray(got)[~at.any(axis=1)].any()   # a block's pads
+        if case == "nobody_chose_3":
+            assert counts[3] == 0 and 3 not in np.asarray(be[:int(nb)])
+        if case == "one_row":
+            assert counts[6] == 1
+        every, _ = _held_experts.raw_fn(x, idx, w, 0, wg, wu, wd)
+        y, *_ = G.grouped_swiglu(x, idx, w, held, wg, wu, wd, interpret=True)
+        assert rel(y, every) < 1e-5
+
+    def test_tiled_kernel_refuses_a_training_batch(self, monkeypatch):
+        monkeypatch.setattr(G, "TILE_WIDTH", 32)
+        monkeypatch.setattr(G, "ACC_BYTES", 1024)
+        z = jnp.zeros
+        with pytest.raises(NotImplementedError, match="partial sums"):
+            G._ffn_pallas(z((32, M)), z(32), z(2, jnp.int32),
+                          jnp.asarray(2, jnp.int32), z((E, M, 64)),
+                          z((E, M, 64)), z((E, 64, M)), interpret=True)
+
     def test_two_halves_of_the_experts_add_up(self):
         """The grouped product of a share (first > 0): a pair that falls
         on an expert held elsewhere gets no row here."""

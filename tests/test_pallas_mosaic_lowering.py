@@ -599,10 +599,17 @@ class TestLagunaCellLowering:
                           ragged=True))
         assert "paged_attention_ragged" in text
 
-    @pytest.mark.parametrize("tokens", [8, 272])
-    def test_grouped_experts(self, chip, monkeypatch, tokens):
+    @pytest.mark.parametrize("tokens,e,h,k", [
+        (8, 256, 512, 8), (272, 256, 512, 8),
+        (64, 16, 2048, 1), (192, 16, 2048, 1)],
+        ids=["laguna8", "laguna272", "zaya64", "zaya192"])
+    def test_grouped_experts(self, chip, monkeypatch, tokens, e, h, k):
+        """Laguna's 256 experts of 2,048 x 512 (one tile: a step a block)
+        and ZAYA's 16 of 2,048 x 2,048 (four tiles of 512 and a float32
+        accumulator: whole, 50.3 MB of weight buffers would not fit
+        VMEM), at a decode step's positions and a chunk step's."""
         monkeypatch.setattr(moe_grouped_ffn, "_use_pallas", lambda: True)
-        e, m, h, k = 256, 2048, 512, 8
+        m = 2048
         text = chip.compile(
             moe_grouped_ffn.grouped_swiglu, ((tokens, m),),
             ((tokens, k), I32), ((tokens, k), F32),
@@ -612,8 +619,8 @@ class TestLagunaCellLowering:
                  if "tpu_custom_call" in ln and "%moe_grouped_ffn" in ln]
         assert calls, "no custom call named moe_grouped_ffn"
         # the experts' weights reach the kernel as they are stored
-        assert not re.search(r"bf16\[256,\d+,\d+\][^ ]* (copy|transpose)\(",
-                             text)
+        assert not re.search(
+            rf"bf16\[{e},\d+,\d+\][^ ]* (copy|transpose)\(", text)
 
 
 # ------------------------------------ the Kimi-Linear cell's KDA kernels

@@ -265,7 +265,8 @@ class TestEngineStepUnderTrace:
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             (entry,) = [m for m in json.load(f)["per_layer"]
                         if m["name"] == "kernel.paged_attn.copy_share"]
-        assert entry["better"] == "lower" and entry["workloads"] == [
+        # a cell with K/V pages that a later PR adds joins the list's END
+        assert entry["better"] == "lower" and entry["workloads"][:3] == [
             "mistral7b.serve.closed8", "laguna-xs2.serve.agent8",
             "phi4-flash.serve.reason32"]
         assert (entry["unit"], entry["layer"], entry["moves"]) == (
