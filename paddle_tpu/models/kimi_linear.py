@@ -12,7 +12,11 @@ keeps its key names.  Pre-norm residual blocks, a block assembled from
   scaled by dk^-1/2); a log-decay per CHANNEL a = -exp(A_log[h]) *
   softplus(W_f_b W_f_a x + dt_bias); beta = sigmoid(W_b x); the gated
   delta rule (``ops/kda.py``, chunkwise); y = W_o [RMSNorm_head(o) *
-  sigmoid(W_g_b W_g_a x)].  No positional encoding.
+  sigmoid(W_g_b W_g_a x)].  No positional encoding.  Between the
+  projections and W_o every stream is the [B, T, H * d] rows a
+  projection writes, the layout the TPU kernels read: the per-head
+  reductions are taken under ``_head_view``, never under a [B, T, H, d]
+  view, which a TPU would have to copy the stream to make.
 * MLA mixer (``full_attn_layers``; ``q_lora_rank`` null, ``mla_use_nope``
   true): q = W_q x (heads x (nope + rope)); c = W_kva x; c_kv =
   RMSNorm(c[:kv_lora_rank]); k_pe = c[kv_lora_rank:], shared by the
@@ -48,7 +52,7 @@ from ..nn.initializer import Constant, Normal, Uniform
 from ..nn.layer.common import Embedding, Linear
 from ..nn.layer.layers import Layer, LayerList
 from ..nn.layer.norm import RMSNorm
-from ..ops.kda import kda_chunk
+from ..ops.kda import kda_chunk_rows
 from ..ops.pallas.flash_attention import flash_attention_bshd
 
 F32 = jnp.float32
@@ -144,6 +148,24 @@ class KimiLinearConfig:
 
 
 # ------------------------------------------------------------------- ops
+# A [B, T, H * d] stream is laid out on a TPU in tiles of 8 tokens x 128
+# lanes: with d = 128 a tile is 8 tokens of ONE head.  A [B, T, H, d] view
+# is tiled 8 heads x 128 lanes, so XLA re-lays the whole stream out to make
+# it and again to leave it; a view that keeps 8 tokens together is the same
+# bytes in the same order, and XLA reduces over a head's lanes inside the
+# tile (PERF.md section 6, PR 46).
+_TILE_TOKENS = 8
+
+
+def _head_view(x, heads):
+    """x [B, T, H * d] -> [B, T / 8, 8, H, d], the view of a row array
+    under which a reduction over d costs no re-layout ([B, T, 1, H, d]
+    where T is no multiple of 8)."""
+    b, t, wide = x.shape
+    g = _TILE_TOKENS if t % _TILE_TOKENS == 0 else 1
+    return x.reshape(b, t // g, g, heads, wide // heads)
+
+
 @def_op("short_conv_silu")
 def _short_conv_silu(x, w, tail=None):
     """SiLU of a depthwise causal convolution over time.  x [B, T, C];
@@ -162,33 +184,33 @@ def _short_conv_silu(x, w, tail=None):
 
 @def_op("kda_gates")
 def _kda_gates(q, k, f, a_log, dt_bias, b_logits, heads):
-    """From the convolved streams to the delta rule's operands: q, k
-    [B, T, H*dk] -> L2-normalised per head [B, T, H, dk] (q times
-    dk^-1/2); the log-decay a = -exp(A_log[h]) softplus(f + dt_bias)
-    [B, T, H, dk] float32; beta = sigmoid(b_logits) [B, T, H] float32."""
-    b, t = q.shape[:2]
+    """From the convolved streams to the delta rule's operands, every one
+    in the rows it came in: q, k [B, T, H * dk] L2-normalised per head (q
+    times dk^-1/2); the log-decay a = -exp(A_log[h]) softplus(f + dt_bias)
+    [B, T, H * dk] float32; beta = sigmoid(b_logits) [B, T, H] float32."""
     dk = q.shape[-1] // heads
 
     def unit(x):
-        x = x.reshape(b, t, heads, dk).astype(F32)
-        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+        v = _head_view(x.astype(F32), heads)
+        v = v * jax.lax.rsqrt(jnp.sum(v * v, -1, keepdims=True) + 1e-6)
+        return v.reshape(x.shape)
 
-    a = -jnp.exp(a_log.astype(F32))[:, None] * jax.nn.softplus(
-        (f.astype(F32) + dt_bias.astype(F32)).reshape(b, t, heads, dk))
+    # A_log [H] -> a value a channel [H * dk]: no head axis on the stream
+    a = jnp.repeat(-jnp.exp(a_log.astype(F32)), dk) * jax.nn.softplus(
+        f.astype(F32) + dt_bias.astype(F32))
     return ((unit(q) * dk ** -0.5).astype(q.dtype), unit(k).astype(k.dtype),
             a, jax.nn.sigmoid(b_logits.astype(F32)))
 
 
 @def_op("gated_head_rms_norm")
 def _gated_head_rms_norm(o, gate, weight, eps):
-    """RMSNorm over each head's width, times sigmoid(gate).  o [B, T, H,
-    dv], gate [B, T, H*dv], weight [dv] -> [B, T, H*dv]."""
-    b, t, h, dv = o.shape
-    x = o.astype(F32)
-    x = x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps)
-    x = x * weight.astype(F32) * jax.nn.sigmoid(
-        gate.astype(F32).reshape(b, t, h, dv))
-    return x.reshape(b, t, h * dv).astype(o.dtype)
+    """RMSNorm over each head's width, times sigmoid(gate).  o, gate
+    [B, T, H * dv], weight [dv] -> [B, T, H * dv]."""
+    v = _head_view(o.astype(F32), o.shape[-1] // weight.shape[-1])
+    v = v * jax.lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + eps)
+    x = (v * weight.astype(F32)).reshape(o.shape) * jax.nn.sigmoid(
+        gate.astype(F32))
+    return x.astype(o.dtype)
 
 
 @def_op("mla_attention")
@@ -255,18 +277,28 @@ class KimiDeltaAttention(Layer):
         self.o_proj = lin(self.heads * self.dv, h)
 
     def forward(self, x, state=None):
+        # every stream stays in the rows its projection wrote,
+        # [B, T, H * d], from here to o_proj (``ops/kda.py``); the scopes
+        # name the mixer's parts in a device trace (train/model/kda/...)
         tails, s0 = state if state is not None else ((None,) * 3, None)
-        q, q_tail = self.q_conv1d(self.q_proj(x), tails[0])
-        k, k_tail = self.k_conv1d(self.k_proj(x), tails[1])
-        v, v_tail = self.v_conv1d(self.v_proj(x), tails[2])
-        q, k, a, beta = _kda_gates(
-            q, k, self.f_b_proj(self.f_a_proj(x)), self.A_log, self.dt_bias,
-            self.b_proj(x), self.heads)
-        b, t = x.shape[0], x.shape[1]
-        o, s = kda_chunk(q, k, v.reshape([b, t, self.heads, self.dv]), a,
-                         beta, s0)
-        y = self.o_proj(_gated_head_rms_norm(
-            o, self.g_b_proj(self.g_a_proj(x)), self.o_norm.weight, self.eps))
+        with jax.named_scope("proj"):
+            q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
+            f = self.f_b_proj(self.f_a_proj(x))
+            gate = self.g_b_proj(self.g_a_proj(x))
+            b_logits = self.b_proj(x)
+        with jax.named_scope("conv"):
+            q, q_tail = self.q_conv1d(q, tails[0])
+            k, k_tail = self.k_conv1d(k, tails[1])
+            v, v_tail = self.v_conv1d(v, tails[2])
+        with jax.named_scope("gates"):
+            q, k, a, beta = _kda_gates(q, k, f, self.A_log, self.dt_bias,
+                                       b_logits, self.heads)
+        with jax.named_scope("chunk"):
+            o, s = kda_chunk_rows(q, k, v, a, beta, s0)
+        with jax.named_scope("norm"):
+            o = _gated_head_rms_norm(o, gate, self.o_norm.weight, self.eps)
+        with jax.named_scope("out"):
+            y = self.o_proj(o)
         if state is not None:
             return y, ((q_tail, k_tail, v_tail), s)
         return y

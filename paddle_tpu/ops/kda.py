@@ -31,8 +31,8 @@ the right limit.
 Log-decays, the triangular inverse and S are float32; the matmul
 operands are the inputs' dtype (bfloat16 in training).
 
-Which path runs where is decided by what ``kda_chunk`` can see of its
-input and nothing else.  On a TPU, with dk one 128-lane tile and dv
+Which path runs where is decided by what ``_kda_chunk_rows`` can see of
+its input and nothing else.  On a TPU, with dk one 128-lane tile and dv
 whole tiles, it is the two Pallas kernels of ``ops/pallas/kda_chunk.py``
 (a chunk's working set stays in VMEM, the state is carried from chunk to
 chunk in scratch); the backward there is no autodiff but the second
@@ -44,6 +44,18 @@ op itself: the sequence is walked in segments of ``segment_chunks``
 chunks under ``jax.checkpoint``, so what is kept for the backward is the
 inputs and one state a segment, and a segment's intermediates are
 recomputed when its gradient is taken.
+
+Which layout each path takes.  The kernels read and write a stream as
+the ROWS a projection leaves it in, [B, T, H * d] (on a TPU a tile of
+such an array is 8 tokens of one head; a [B, T, H, d] array is tiled 8
+heads x 128 lanes, and going from one to the other copies the stream:
+PERF.md section 6, PR 46).  ``kda_chunk_rows`` is the op in that layout:
+``models/kimi_linear.py`` makes every stream in rows and takes rows back,
+so on the kernel path no [B, T, H, d] view of a stream exists.
+``_kda_chunk`` (and ``kda_recurrent``) work on [B, T, H, d]; where they
+run, ``kda_chunk_rows`` hands them that view of its rows, which costs
+nothing there.  ``kda_chunk`` keeps the [B, T, H, d] signature for every
+other caller and is the same op behind the opposite view.
 """
 from __future__ import annotations
 
@@ -225,6 +237,31 @@ def _kda_chunk(q, k, v, a, beta, initial_state=None, segment_chunks=8):
     return o[:, :t], state
 
 
+def _kda_chunk_rows(q, k, v, a, beta, initial_state=None, segment_chunks=8):
+    """The one place the path is chosen (the module docstring).  Streams
+    in rows, [B, T, H * d]; beta [B, T, H] says how many heads."""
+    h = beta.shape[-1]
+    if _pallas.supported(q.shape[-1] // h, v.shape[-1] // h):
+        return _pallas.kda_chunk_pallas(q, k, v, a, beta, initial_state)
+
+    def heads(x):
+        return x.reshape(x.shape[:2] + (h, -1))
+
+    o, state = _kda_chunk(heads(q), heads(k), heads(v), heads(a), beta,
+                          initial_state, segment_chunks)
+    return o.reshape(o.shape[:2] + (-1,)), state
+
+
+@def_op("kda_chunk_rows")
+def kda_chunk_rows(q, k, v, a, beta, initial_state=None, segment_chunks=8):
+    """``kda_chunk`` over streams in the rows a projection writes: q, k, a
+    [B, T, H * dk], v [B, T, H * dv], beta [B, T, H].  Returns (o
+    [B, T, H * dv] in v's dtype, final state [B, H, dk, dv] float32).  The
+    kernels take and return rows, so on their path no stream is re-laid
+    out; the XLA path sees the [B, T, H, d] view."""
+    return _kda_chunk_rows(q, k, v, a, beta, initial_state, segment_chunks)
+
+
 @def_op("kda_chunk")
 def kda_chunk(q, k, v, a, beta, initial_state=None, segment_chunks=8):
     """Chunkwise KDA.  q, k [B, T, H, dk] (q already scaled), v
@@ -233,9 +270,12 @@ def kda_chunk(q, k, v, a, beta, initial_state=None, segment_chunks=8):
     (o [B, T, H, dv] in v's dtype, final state [B, H, dk, dv] float32).
     ``segment_chunks`` is the XLA path's; the kernels keep a state a
     chunk."""
-    if _pallas.supported(q, v):
-        return _pallas.kda_chunk_pallas(q, k, v, a, beta, initial_state)
-    return _kda_chunk(q, k, v, a, beta, initial_state, segment_chunks)
+    def rows(x):
+        return x.reshape(x.shape[:2] + (-1,))
+
+    o, state = _kda_chunk_rows(rows(q), rows(k), rows(v), rows(a), beta,
+                               initial_state, segment_chunks)
+    return o.reshape(v.shape), state
 
 
 def _kda_recurrent(q, k, v, a, beta, initial_state=None):

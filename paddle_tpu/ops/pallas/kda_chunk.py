@@ -7,7 +7,11 @@ exp(-g), T = (I + A)^-1 by forward substitution in float32, matmul
 operands in the inputs' dtype with float32 accumulation); this file is
 where they run on a TPU.  HBM sees the op's inputs, its output and, for
 the backward, the state that enters each chunk; ``col``, ``pair``, the
-diagonal sums and the blocks of the inverse never leave the chip.
+diagonal sums and the blocks of the inverse never leave the chip.  A
+stream is read and written as the rows a projection leaves it in,
+[B, T, H * d]: a grid step's block is 64 tokens of ``hb`` heads' lanes,
+and ``kda_chunk_pallas`` takes and returns such rows, so nothing around
+the kernels re-lays a stream out.
 
 * ``kda_chunk_fwd``: grid (batch, head group, chunk), the chunk axis
   sequential; the state of each head of the group is carried from chunk
@@ -520,30 +524,31 @@ _kda.defvjp(_kda_fwd, _kda_bwd)
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def kda_chunk_pallas(q, k, v, a, beta, initial_state=None, interpret=False):
-    """``ops/kda.py::kda_chunk``'s arguments and results; dk 128, dv
-    whole 128-lane tiles.  Differentiable in all six arguments.  T is padded
-    to whole chunks: zero keys, values and betas and a zero log-decay
-    leave the state as it is."""
-    b, t, h, dk = q.shape
+    """``ops/kda.py::kda_chunk_rows``'s arguments and results, in the
+    kernels' own layout: q, k, a [B, T, H * dk], v [B, T, H * dv] as a
+    projection writes them, beta [B, T, H], initial_state [B, H, dk, dv]
+    or None; returns (o [B, T, H * dv], final state [B, H, dk, dv]
+    float32).  No stream is reshaped on the way in or out.  dk 128, dv
+    whole 128-lane tiles.  Differentiable in all six arguments.  T is
+    padded to whole chunks: zero keys, values and betas and a zero
+    log-decay leave the state as it is."""
+    b, t, h = beta.shape
+    dk, dv = q.shape[-1] // h, v.shape[-1] // h
     pad = -t % CHUNK
 
-    def stream(x):
-        x = x.reshape(b, t, -1)
+    def whole(x):
         return jnp.pad(x, ((0, 0), (0, pad), (0, 0))) if pad else x
 
-    beta = jnp.swapaxes(beta.astype(F32), 1, 2)               # [B, H, T]
-    if pad:
-        beta = jnp.pad(beta, ((0, 0), (0, 0), (0, pad)))
-    s0 = (jnp.zeros((b, h, v.shape[-1], dk), F32) if initial_state is None
+    beta = jnp.swapaxes(whole(beta.astype(F32)), 1, 2)        # [B, H, T]
+    s0 = (jnp.zeros((b, h, dv, dk), F32) if initial_state is None
           else jnp.swapaxes(initial_state.astype(F32), -1, -2))
-    o, s_end = _kda(stream(q), stream(k), stream(v), stream(a.astype(F32)),
+    o, s_end = _kda(whole(q), whole(k), whole(v), whole(a.astype(F32)),
                     beta[..., None], s0, interpret)
-    return o[:, :t].reshape(b, t, h, -1), jnp.swapaxes(s_end, -1, -2)
+    return o[:, :t], jnp.swapaxes(s_end, -1, -2)
 
 
-def supported(q, v):
+def supported(dk, dv):
     """What the kernels can take, from what the caller can see: a TPU,
     keys one 128-lane tile wide (the published head width; the TPU's
     compiler aborts on this kernel at two) and values of whole tiles."""
-    return (jax.default_backend() == "tpu"
-            and q.shape[-1] == 128 and v.shape[-1] % 128 == 0)
+    return jax.default_backend() == "tpu" and dk == 128 and dv % 128 == 0

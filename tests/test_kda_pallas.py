@@ -1,10 +1,12 @@
 """The Pallas kernels of the chunkwise delta rule (``ops/pallas/
 kda_chunk.py``) in interpret mode on the CPU, against both of their
 oracles: ``kda._kda_chunk`` (the XLA path they replace on a TPU) and
-``kda._kda_recurrent`` (the recurrence token by token).  Output, final
-state and the gradients of all six arguments; and the route of
-``kda.kda_chunk``, which is decided by backend and shape alone.  The
-compile for a described chip: tests/test_pallas_mosaic_lowering.py."""
+``kda._kda_recurrent`` (the recurrence token by token).  The wrapper
+takes and returns ROWS ([B, T, H * d]); the oracles see the [B, T, H, d]
+view of the same arrays.  Output, final state and the gradients of all
+six arguments; and the route of ``kda.kda_chunk`` and
+``kda.kda_chunk_rows``, which is decided by backend and shape alone.
+The compile for a described chip: tests/test_pallas_mosaic_lowering.py."""
 import functools
 
 import jax
@@ -14,11 +16,21 @@ import pytest
 
 from test_kimi_linear import exact_float32, rel  # noqa: F401
 from test_kimi_linear_ops import kda_inputs
+from test_kda_rows import rows
 from paddle_tpu.ops import kda
 from paddle_tpu.ops.pallas import kda_chunk as kc
 
 WIDE = dict(b=1, h=2, dk=128, dv=128)       # whole lane tiles
-kernel = functools.partial(kc.kda_chunk_pallas, interpret=True)
+
+
+def kernel(q, k, v, a, beta, s0=None, call=None):
+    """The rows-in / rows-out wrapper, interpreted, behind the oracles'
+    [B, T, H, d] signature: the reshapes are the test's, so a gradient
+    comes back in the oracle's shape."""
+    call = call or functools.partial(kc.kda_chunk_pallas, interpret=True)
+    o, s = call(rows(q), rows(k), rows(v), rows(a), beta, s0)
+    assert o.shape == v.shape[:2] + (v.shape[2] * v.shape[3],)
+    return o.reshape(v.shape), s
 
 
 T = 100      # a whole chunk and 36 tokens of a second; one length, so the
@@ -100,35 +112,58 @@ def test_kernels_without_an_initial_state_and_heads_sharing_a_step(
     for heads in (1, 3):
         # the constant is read when the op is traced: trace it anew
         monkeypatch.setattr(kc, "HEADS_PER_STEP", heads)
-        got = jax.jit(functools.partial(kc.kda_chunk_pallas.__wrapped__,
-                                        interpret=True))(q, k, v, a, beta)
+        got = jax.jit(functools.partial(
+            kernel, call=functools.partial(kc.kda_chunk_pallas.__wrapped__,
+                                           interpret=True)))(q, k, v, a, beta)
         assert rel(got[0], want[0]) < 2e-5 and rel(got[1], want[1]) < 2e-5
 
 
 def test_the_route_is_decided_by_backend_and_shape(monkeypatch):
     wide = kda_inputs(1, 70, 0.5, **WIDE)
     narrow = kda_inputs(1, 70, 0.5, b=1, h=2, dk=32, dv=16)
-    # here, on the CPU: the XLA path, bit for bit
-    assert not kc.supported(wide[0], wide[2])
+    interpreted = functools.partial(kc.kda_chunk_pallas, interpret=True)
+
+    def in_rows(args):
+        return tuple(rows(x) for x in args[:4]) + tuple(args[4:])
+
+    def both_ops(args):
+        """(kda_chunk on [B, T, H, d], kda_chunk_rows on the rows)."""
+        return (kda.kda_chunk.raw_fn(*args),
+                kda.kda_chunk_rows.raw_fn(*in_rows(args)))
+
+    # here, on the CPU: the XLA path, bit for bit, under either view
+    assert not kc.supported(128, 128)
     calls = []
     monkeypatch.setattr(kc, "kda_chunk_pallas",
-                        lambda *xs: calls.append(xs) or kernel(*xs))
+                        lambda *xs: calls.append(xs) or interpreted(*xs))
     for args in (wide, narrow):
-        got, want = kda.kda_chunk.raw_fn(*args), kda._kda_chunk(*args)
-        assert all(np.array_equal(x, y) for x, y in zip(got, want))
+        want = kda._kda_chunk(*args)
+        four, flat = both_ops(args)
+        assert all(np.array_equal(x, y) for x, y in zip(four, want))
+        assert np.array_equal(flat[0], rows(want[0]))
+        assert np.array_equal(flat[1], want[1])
     assert not calls
     # on a TPU: the kernels for whole lane tiles, the XLA path otherwise
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert kc.supported(wide[0], wide[2])
-    assert not kc.supported(narrow[0], narrow[2])
-    assert not kc.supported(wide[0], narrow[2])
-    assert not kc.supported(jnp.zeros((1, 8, 2, 256)), wide[2])
-    got, want = kda.kda_chunk.raw_fn(*narrow), kda._kda_chunk(*narrow)
-    assert not calls and all(np.array_equal(x, y) for x, y in zip(got, want))
-    # both routes: the same shapes and dtypes
-    got, want = kda.kda_chunk.raw_fn(*in_bf16(wide)), kda._kda_chunk(
-        *in_bf16(wide))
-    assert len(calls) == 1
-    for x, y in zip(got, want):
+    assert kc.supported(128, 128) and kc.supported(128, 256)
+    assert not kc.supported(32, 16)
+    assert not kc.supported(128, 16)
+    assert not kc.supported(256, 128)
+    want = kda._kda_chunk(*narrow)
+    for got in both_ops(narrow):
+        assert np.array_equal(got[0].reshape(want[0].shape), want[0])
+        assert np.array_equal(got[1], want[1])
+    assert not calls
+    # both routes: the same shapes and dtypes, and the kernels were
+    # handed rows under either view
+    want = kda._kda_chunk(*in_bf16(wide))
+    four, flat = both_ops(in_bf16(wide))
+    assert len(calls) == 2
+    for xs in calls:
+        assert [x.ndim for x in xs[:5]] == [3, 3, 3, 3, 3]
+        assert xs[0].shape == (1, 70, 256) and xs[4].shape == (1, 70, 2)
+    for x, y in zip(four, want):
         assert x.shape == y.shape and x.dtype == y.dtype
-    assert got[0].dtype == jnp.bfloat16 and got[1].dtype == jnp.float32
+    assert flat[0].shape == (1, 70, 256) and flat[1].shape == want[1].shape
+    assert np.array_equal(flat[0].reshape(want[0].shape), four[0])
+    assert four[0].dtype == jnp.bfloat16 and four[1].dtype == jnp.float32

@@ -626,11 +626,14 @@ class TestLagunaCellLowering:
 # ------------------------------------ the Kimi-Linear cell's KDA kernels
 class TestKdaChunkLowering:
     """`kimi-linear.train.seq8k`'s chunkwise delta rule at its shape,
-    1 x 8,192 tokens, 32 heads of 128: the forward and the backward
-    kernel behind one ``custom_vjp``, as the traced step calls them."""
+    1 x 8,192 tokens, 32 heads of 128, every stream in the rows the
+    kernels read ([B, T, H * d]: PR 46): the forward and the backward
+    kernel behind one ``custom_vjp``, as the traced step calls them; and
+    the mixer's XLA half around them, which must reach the kernels
+    without re-laying a stream out."""
 
-    SHAPES = (((1, 8192, 32, 128), BF16),) * 3 + (
-        ((1, 8192, 32, 128), F32), ((1, 8192, 32), F32))
+    ROWS = (1, 8192, 32 * 128)
+    SHAPES = ((ROWS, BF16),) * 3 + ((ROWS, F32), ((1, 8192, 32), F32))
 
     def test_forward(self, chip):
         from paddle_tpu.ops.pallas.kda_chunk import kda_chunk_pallas
@@ -656,6 +659,40 @@ class TestKdaChunkLowering:
             for ln in calls:
                 op_name = re.search(r'op_name="([^"]*)"', ln).group(1)
                 assert "train/model" in op_name and "/kda/" in op_name, ln
+
+    def test_the_mixers_streams_reach_the_kernels_as_they_are_made(
+            self, chip, monkeypatch):
+        """Gates, kernels and the gated head norm, forward and backward:
+        the compiled program holds no instruction of its own that only
+        moves a stream (a `copy`, `transpose`, `reshape` or `broadcast`
+        of 8,192 x 4,096 elements, which is what the [B, T, H, d] views
+        cost: nine `copy f32[1024,8,32,128]` a layer before PR 46) — a
+        per-head reduction is a fusion over the rows' own tiles."""
+        from paddle_tpu.models import kimi_linear as KL
+        from paddle_tpu.ops import kda
+        from paddle_tpu.ops.pallas import kda_chunk as kc
+        monkeypatch.setattr(kc, "supported", lambda dk, dv: True)
+
+        def loss(q, k, v, f, gate, b_logits, a_log, dt_bias, weight):
+            q, k, a, beta = KL._kda_gates.raw_fn(q, k, f, a_log, dt_bias,
+                                                 b_logits, 32)
+            o, _ = kda.kda_chunk_rows.raw_fn(q, k, v, a, beta)
+            y = KL._gated_head_rms_norm.raw_fn(o, gate, weight, 1e-5)
+            return jnp.sum(y.astype(F32) ** 2)
+
+        text = chip.compile(
+            jax.grad(loss, argnums=tuple(range(9))),
+            *((self.ROWS, BF16),) * 5, ((1, 8192, 32), BF16),
+            ((32,), F32), ((4096,), F32), ((128,), BF16))
+        assert "%kda_chunk_fwd" in text and "%kda_chunk_bwd" in text
+        entry = text[text.index("ENTRY"):]
+        mover = re.compile(r" = (?:bf16|f32)\[([\d,]*)\]\S* "
+                           r"(?:copy|transpose|reshape|broadcast)\(")
+        moved = [ln.strip()[:120] for ln in entry.splitlines()
+                 if (m := mover.search(ln)) and math.prod(
+                     int(n) for n in m.group(1).split(",") if n)
+                 >= 8192 * 4096]
+        assert not moved, moved
 
 
 # ------------------------------- the Brumby cell's retention state kernels

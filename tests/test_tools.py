@@ -345,3 +345,71 @@ class TestExternalOracle:
                 mods.add(node.module.split(".")[0])
         assert mods <= {"jax", "numpy"}, (
             f"oracle must stay framework-free, imports: {mods}")
+
+
+class TestScopeOpTable:
+    """tools/scope_op_table.py: a device plane's operations by (scope,
+    operation), own times, a step."""
+
+    def _load(self):
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "scope_op_table", os.path.join(REPO, "tools",
+                                           "scope_op_table.py"))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    def test_scope_paths_and_the_table(self, tmp_path, capsys):
+        tool = self._load()
+        bench = os.path.join(REPO, "benchmark")
+        sys.path.insert(0, bench)
+        try:
+            import xplane
+            from readers.xplane_scope_share import own_times
+        finally:
+            sys.path.remove(bench)
+        assert tool.full_scope(
+            "jit(pure_step)/transpose(jvp(train/model))/kda/gates/mul") \
+            == "train/model/kda/gates/mul"
+        assert tool.full_scope("jit(pure_step)/dot_general") == ""
+        # a recomputed mixer: backward and recomputed forward under the
+        # forward's path
+        for inner in ("checkpoint/", "checkpoint/rematted_computation/"):
+            assert tool.full_scope(
+                "jit(pure_step)/transpose(jvp(train/model))/kda/"
+                f"jvp(train/model)/kda/{inner}conv/mul") \
+                == "train/model/kda/conv/mul"
+        events = [
+            # two steps; a while over its body, a fusion of each scope
+            ("%while.1 = (f32[8]) while(%t)", 0, 100),
+            ("%fusion.3 = f32[8,4]{1,0} fusion(%a)", 10, 30),
+            ("%copy.7 = f32[8,4]{1,0} copy(%b)", 120, 40),
+            ("%fusion.4 = f32[8,4]{1,0} fusion(%a)", 200, 50),
+        ]
+        # two whole steps and half of a third: 2.5 steps
+        modules = [("jit_pure_step(123)", 0, 100), ("jit_other(1)", 0, 1),
+                   ("jit_pure_step(123)", 100, 100),
+                   ("jit_pure_step(123)", 200, 50)]
+        scopes = {"fusion.3": "train/model/kda/gates/mul/more",
+                  "fusion.4": "train/model/kda/gates/add",
+                  "while.1": "train/model/moe/experts/while"}
+        table = tool.scope_table(events, modules, scopes, own_times,
+                                 xplane.short_name)
+        assert table["steps"] == 2.5
+        rows = {(s, n): (ms, c) for s, n, ms, c in table["rows"]}
+        # ns -> ms a step; the two fusions fall together by short name,
+        # the while keeps its own 70 ns, the copy has no scope
+        assert rows[("train/model/kda/gates", "fusion f32[8,4]")] == (
+            pytest.approx(80 / 1e6 / 2.5), pytest.approx(0.8))
+        assert rows[("train/model/moe/experts", "while f32[8]")][0] == \
+            pytest.approx(70 / 1e6 / 2.5)
+        assert rows[("", "copy f32[8,4]")] == (pytest.approx(40 / 1e6 / 2.5),
+                                               pytest.approx(0.4))
+        assert table["rows"][0][1] == "fusion f32[8,4]"      # longest first
+        assert tool.by_scope(table, "train/model/kda") == {
+            "train/model/kda/gates": pytest.approx(80 / 1e6 / 2.5)}
+        path = tmp_path / "t.json"
+        path.write_text(json.dumps(table))
+        assert tool.main(["--table", str(path), str(path)]) == 0
+        assert "train/model/kda/gates" in capsys.readouterr().out
