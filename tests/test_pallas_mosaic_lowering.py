@@ -419,6 +419,30 @@ class TestFlashAttentionLowering:
                                             192 ** -0.5, interpret=False)
         chip.compile(bwd, qk, qk, v, v, ((1, 32, 8192), F32), v)
 
+    @pytest.mark.parametrize("qk,kv,v,tiles", [
+        ((1, 32, 4096, 128), (1, 8, 4096, 128), (1, 8, 4096, 128), 10),
+        ((1, 32, 8192, 192), (1, 32, 8192, 192), (1, 32, 8192, 128), 36),
+    ], ids=["mistral7b.train.seq4k", "kimi-linear.train.seq8k"])
+    def test_the_training_cells_at_the_swept_blocks(self, chip, qk, kv, v,
+                                                    tiles):
+        """Both cells' calls with no block named: the sweep's 1,024 x
+        1,024 lowers for the chip, forward and both backward kernels, and
+        a head's schedule is the lower triangle's tiles."""
+        from paddle_tpu import monitor
+        visited = monitor.counter("flash_attn_tiles_visited_total")
+        scale, out = qk[-1] ** -0.5, qk[:3] + v[-1:]
+        before = visited.value()
+        chip.compile(functools.partial(flash_attention_forward, causal=True,
+                                       scale=scale, interpret=False),
+                     (qk,), (kv,), (v,))
+        assert visited.value() - before == 32 * tiles
+
+        def bwd(q, k, v, out, lse, do):
+            return flash_attention_backward(q, k, v, out, lse, do, True,
+                                            scale, interpret=False)
+        chip.compile(bwd, (qk,), (kv,), (v,), (out,), (qk[:3], F32), (out,))
+        assert visited.value() - before == 3 * 32 * tiles
+
     def test_compiled_program_carries_the_kernel_payload(self, chip):
         fn = functools.partial(flash_attention_forward, causal=True,
                                interpret=False)

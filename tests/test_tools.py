@@ -413,3 +413,59 @@ class TestScopeOpTable:
         path.write_text(json.dumps(table))
         assert tool.main(["--table", str(path), str(path)]) == 0
         assert "train/model/kda/gates" in capsys.readouterr().out
+
+
+class TestFlashAttnMicro:
+    """``tools/flash_attn_micro.py``'s reductions (its runs need the chip;
+    ``--rehearse`` drives the kernels through the interpreter)."""
+
+    @pytest.fixture()
+    def tool(self):
+        sys.path.insert(0, os.path.join(REPO, "tools"))
+        try:
+            import flash_attn_micro
+        finally:
+            sys.path.pop(0)
+        return flash_attn_micro
+
+    @staticmethod
+    def _file(tmp_path, name, rel_rms, fwd_ms):
+        gap = {"max_abs": 0.01, "rel_rms": rel_rms, "nan": False}
+        data = {
+            "check": [{"shape": "mistral-4k", "tiles_a_head": 30.0,
+                       **{p: gap for p in ("out", "dq", "dk", "dv")}}],
+            "time": [{"shape": "mistral-4k", "fwd_ms": fwd_ms,
+                      "bwd_dkv_ms": 2.0, "bwd_dq_ms": None}],
+            "sweep": [{"shape": "mistral-4k", "block_q": bq, "block_kv": bkv,
+                       "fwd_ms": 1.0 + (bq != 1024) + (bkv != 1024),
+                       "bwd_dkv_ms": 2.0, "bwd_dq_ms": 1.5}
+                      for bq in (512, 1024) for bkv in (512, 1024)]
+            + [{"shape": "mistral-4k", "block_q": 256, "block_kv": 2048,
+                "refused": "vmem"}]}
+        path = tmp_path / name
+        path.write_text(json.dumps(data))
+        return str(path)
+
+    @pytest.mark.parametrize("rel_rms,sound", [(2.0e-3, True),
+                                               (2.5e-3, False)])
+    def test_table_holds_the_change_to_the_parents_gaps(
+            self, tool, tmp_path, capsys, rel_rms, sound):
+        parent = self._file(tmp_path, "p.json", 2.0e-3, 3.0)
+        change = self._file(tmp_path, "c.json", rel_rms, 1.5)
+        assert tool.table(parent, change) is sound
+        out = capsys.readouterr().out
+        assert "| mistral-4k | fwd | 3.000 | 1.500 | 0.50 |" in out
+        assert "| mistral-4k | bwd_dq | — | — | — |" in out
+        assert ("yes" if sound else "NO") in out
+        assert "fwd: best 1024 x 1024 at 1.000" in out
+
+    def test_device_ms_means_a_group_of_calls(self, tool, monkeypatch):
+        from benchmark import xplane
+        events = {tool.KERNELS["fwd"]: [1.0, 3.0, 5.0, 7.0],
+                  tool.KERNELS["bwd_dkv"]: [2.0, 2.0, 4.0],   # one is lost
+                  tool.KERNELS["bwd_dq"]: []}
+        monkeypatch.setattr(xplane, "durations_ms",
+                            lambda trace, pattern, line: events[pattern])
+        assert tool.device_ms(None, 2, 2) == {
+            "fwd": [2.0, 6.0], "bwd_dkv": [None, None],
+            "bwd_dq": [None, None]}
