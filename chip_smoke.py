@@ -448,9 +448,6 @@ def phase_serve(cfg, seed, *, param_dtype, total_pages, page_size,
         min_table_pages=table_pages)
     engine = server._engine
     try:
-        if not engine.unified_step:
-            raise AssertionError("the engine default is no longer the "
-                                 "unified ragged step")
         # the step's worst shape (every slot a full chunk), compiled
         # before anything runs: the kernel is in it and it fits
         fn, donate, args, meta = engine_program_spec(engine, "ragged",

@@ -137,10 +137,6 @@ LOCK_CLASSES: Dict[str, Tuple[str, frozenset]] = {
         # the loop thread and snapshotting threads hand off through
         # these under _cond
         "_stepping", "_snap_waiters",
-        # unified ragged step (ISSUE 17): the repeated-failure latch
-        # that routes iterations back to the legacy composition —
-        # flipped only via _disable_unified_locked
-        "_unified_off",
         # overload protection (ISSUE 19): the brownout ladder rung —
         # written by _set_brownout_locked on the scheduler thread,
         # read by submit()'s shed decision and retry_after_hint under

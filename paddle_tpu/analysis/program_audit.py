@@ -567,12 +567,9 @@ def engine_program_spec(engine, mode: str = "decode", sample=None):
     import jax.numpy as jnp
     from ..inference.paged import next_pow2
 
-    if mode not in ("decode", "verify", "chunk", "ragged"):
+    if mode not in ("decode", "chunk", "ragged"):
         raise ValueError(f"engine programs are mode='decode', "
-                         f"'verify', 'chunk' or 'ragged', got {mode!r}")
-    if mode == "verify" and not getattr(engine, "_spec", False):
-        raise ValueError("mode='verify' needs an engine built with a "
-                         "draft_model")
+                         f"'chunk' or 'ragged', got {mode!r}")
     decoder = engine._decoder
     cache = engine.cache
     if sample is None:
@@ -643,21 +640,11 @@ def engine_program_spec(engine, mode: str = "decode", sample=None):
         args = (params, sds((B, S), i32), sds((B,), i32),
                 sds((B * S,), i32), sds((B * S,), i32),
                 sds((B, W), i32), sds((B,), i32), s_args, *pools)
-    elif mode == "verify":
-        S = engine.spec_k + 1
-        if sample == "draw":
-            s_args = (sds((B,), jnp.uint32), sds((B,), jnp.float32),
-                      sds((B,), jnp.bool_))
-        else:
-            s_args = ()
-        args = (params, sds((B, S), i32), sds((B,), i32),
-                sds((B * S,), i32), sds((B * S,), i32), sds((B,), i32),
-                sds((B, W), i32), s_args, *pools)
     elif mode == "ragged":
         # ONE program for the whole mixed step: per-row ctx lengths,
         # span lengths and draft counts all ride traced — fn signature
         # (params, ids, ctx_lens, q_lens, pg, sl, ptabs, nd, sampling,
-        # pools, wscales), the _verify_sampling_args 3-tuple (the draw
+        # pools, wscales), the _ragged_sampling_args 3-tuple (the draw
         # counter is computed in-program from ctx + span + accept)
         S = S_ragged
         if sample == "draw":
@@ -696,20 +683,21 @@ def engine_program_spec(engine, mode: str = "decode", sample=None):
 def audit_engine(engine, mode: str = "decode", sample=None,
                  per_row_budget: int = 64, publish: bool = True,
                  **limits) -> ProgramAudit:
-    """Audit a ContinuousBatchingEngine's compiled decode or
-    speculative-verify program without running it: rebuilds the exact
-    traced function + donation contract ``JittedPagedDecoder`` jits and
-    traces it on abstract inputs shaped like a full decode batch
+    """Audit one of a ContinuousBatchingEngine's compiled programs
+    without running it: rebuilds the exact traced function + donation
+    contract ``JittedPagedDecoder`` jits and traces it on abstract
+    inputs shaped like a full decode batch
     (:func:`engine_program_spec` is the shared rebuild).
 
     With the engine's default ``sample_on_device=True`` the program's
     only non-donated outputs are the ``(batch,)`` int32 ids (decode) —
-    plus the ``(batch,)`` int32 accept counts for ``mode="verify"`` —
-    so the audit must report zero host-transfer findings (PR 2's
-    invariant, extended to the speculative hot path).  The verify audit
-    also proves no ``[B, k]``-shaped draft block was baked in as a
-    constant (the block rides as a traced argument) and that BOTH page
-    pools stay donated.  A QUANTIZED engine (ISSUE 9: ``quantize``
+    plus the ``(batch,)`` int32 accept counts and the counted walk for
+    ``mode="ragged"`` — so the audit must report zero host-transfer
+    findings (PR 2's invariant).  On a speculating engine the ragged
+    audit spans the verify block (``spec_k + 1`` tokens a row) and also
+    proves no ``[B, k]``-shaped draft block was baked in as a constant
+    (the block rides as a traced argument) and that BOTH page pools
+    stay donated.  A QUANTIZED engine (ISSUE 9: ``quantize``
     and/or ``kv_quant``) is certified further: donation intact on the
     int8 page AND scale pools, int8->accumulator casts exempt from the
     dtype-creep rule, and no scale baked in as a const

@@ -29,24 +29,29 @@ Resilience layer (ISSUE 4):
   * graceful drain — ``drain()`` stops new submissions, finishes
     everything already submitted, then reclaims the pool and stops the
     scheduler (``stop()`` stays the hard kill);
-  * failure isolation — a failing prefill errors only its request; a
-    failing decode step is retried once and then BISECTED (solo replay
-    at size 1) to eject exactly the poisoned sequence(s) while the rest
-    of the batch keeps decoding;
+  * failure isolation — a failing step (chunk rows, decode rows and
+    verify rows are one step) is retried whole once and then BISECTED
+    by its rows (solo replay at size 1) to eject exactly the poisoned
+    sequence(s) while the rest of the batch keeps going
+    (:meth:`ContinuousBatchingEngine._isolate_unified`); a failing
+    whole-prompt prefill of an unchunked engine errors only its
+    request;
   * stall detection — an engine heartbeat registered with the comm
     watchdog (``step_timeout_s``) fires the same timeout machinery as
     a hung collective when a device step wedges;
   * deterministic fault injection — the ``paddle_tpu.testing.faults``
-    sites ``prefill`` / ``decode_step`` / ``page_alloc`` are consulted
-    at near-zero cost when no plan is installed.
+    sites ``prefill`` / ``prefill_chunk`` / ``decode_step`` /
+    ``engine_wedge`` / ``page_alloc`` are consulted at near-zero cost
+    when no plan is installed.
 
 Speculative decoding (ISSUE 6):
 
   * pass ``draft_model`` and the engine decodes speculatively: the
     draft proposes ``spec_tokens`` greedy tokens per active sequence in
     ONE compiled scan over its OWN PagedKVCache (pages allocated/freed
-    in lockstep with the target's), then the target scores the whole
-    ``[B, k+1]`` block in ONE compiled verify dispatch — accept lengths
+    in lockstep with the target's), then the target scores each row's
+    ``k+1``-token block as a verify row of the step's ONE ragged
+    dispatch — accept lengths
     and the bonus token are computed on device, so the host boundary
     stays ``(batch,)`` ids + ``(batch,)`` accept counts;
   * greedy speculative decoding is EXACT (bit-identical tokens to
@@ -145,25 +150,6 @@ __all__ = [
 ]
 
 _PAD_SEQ = "__pad__"
-
-
-# fault-injection sites whose quarantine semantics are defined against
-# the LEGACY per-mode dispatch granularity (one poisoned chunk fails one
-# request, a decode fault bisects the batch, ...): an iteration running
-# under a plan that targets any of them diverts to the legacy
-# composition so chaos plans keep their documented blast radius
-_ENGINE_FAULT_SITES = frozenset((
-    "prefill", "prefill_chunk", "decode_step", "engine_wedge",
-    "buffer_loss", "page_alloc"))
-# ... EXCEPT pure pacing: a delay-kind rule on a dispatch site injects
-# no failure — the unified step fires these sites itself (same sleep,
-# same seq_id targeting), so benches that throttle decode to build
-# batch occupancy warm the SAME programs the measured window runs.
-# Delay rules on engine_wedge/buffer_loss/page_alloc still divert:
-# those delays are semantic triggers (watchdog wedges, donated-buffer
-# loss windows), defined against the legacy machinery.
-_PACING_FAULT_SITES = frozenset(("prefill", "prefill_chunk",
-                                 "decode_step"))
 
 
 def _null_sampling(n: int = 1):
@@ -372,23 +358,21 @@ _kv_window_dead_pages_g = monitor.gauge(
 _replay_dispatches = monitor.counter(
     "replay_dispatches_total", "compiled dispatches issued by survivor-"
     "KV replay (batched replay amortizes many survivors per dispatch)")
-# ragged unified step (ISSUE 17): dispatch economics.  The legacy step
-# composition issues one compiled dispatch per program mode per
-# iteration (prefill, chunk, decode, draft propose, verify); the
-# unified step folds prefill/chunk/decode/verify rows into ONE "ragged"
-# dispatch, so a mixed iteration's serving cost is quoted straight off
-# this counter's mode split (serve_bench's mixed-batch lane gates on it)
+# ragged unified step (ISSUE 17): dispatch economics.  An iteration's
+# chunk, decode and verify rows are ONE "ragged" dispatch, so a mixed
+# iteration's serving cost is quoted straight off this counter's mode
+# split (serve_bench's mixed-batch lane gates on it)
 _dispatches_total = monitor.counter(
     "engine_dispatches_total", "compiled program dispatches issued by "
     "the serving loop, per program mode — 'ragged' is the unified "
-    "single-dispatch step; 'prefill'/'chunk'/'decode'/'verify' are the "
-    "legacy composition; 'draft' is the draft model's own propose/"
-    "ingest dispatches (a second model: never foldable)", ("mode",))
+    "single-dispatch step; 'prefill'/'chunk' are the whole-prompt "
+    "prefill of an engine without prefill_chunk_tokens; 'draft' is the "
+    "draft model's own propose/ingest dispatches (a second model: never "
+    "foldable)", ("mode",))
 _unified_fallbacks = monitor.counter(
-    "engine_unified_fallbacks_total", "iterations where the unified "
-    "ragged dispatch failed and the engine re-ran the step through the "
-    "legacy multi-dispatch composition (whose retry/bisect isolation "
-    "then owns the failure)")
+    "engine_unified_fallbacks_total", "unified steps that failed and "
+    "went down the isolation ladder: rolled back, retried whole, then "
+    "by halves of their rows")
 # one step in flight (ISSUE 38): how often the loop dispatches a step
 # over an uncommitted one, and why it does not
 _steps_overlapped = monitor.counter(
@@ -398,7 +382,7 @@ _steps_overlapped = monitor.counter(
 _overlap_drains = monitor.counter(
     "serve_overlap_drains_total", "unified steps committed BEFORE the "
     "next one was dispatched, by what the engine saw: spec, "
-    "host_sampling, unchunked, legacy, fault_plan, replaced, snapshot, "
+    "host_sampling, unchunked, fault_plan, replaced, snapshot, "
     "drain, cancel, deadline, preempt, idle (nothing to dispatch), "
     "launch_failed", ("reason",))
 _overlap_dropped_rows = monitor.counter(
@@ -546,14 +530,14 @@ class _Step:
     unwinds and the ladder re-runs, the decoder's flight, and what the
     launch already told the scheduler."""
 
-    __slots__ = ("plan", "chunks", "active", "retried", "spec",
+    __slots__ = ("chunks", "active", "retried", "spec",
                  "k_spec", "drafts", "lens_before", "sampled", "flight",
                  "result", "record", "t_ns", "traced", "t0", "index",
                  "overlapped", "launched", "moved", "chunk_no", "riders",
                  "deferred", "leaving", "out_index", "dropped")
 
-    def __init__(self, plan, chunks, active, retried, spec, k_spec):
-        self.plan, self.chunks, self.active = plan, chunks, active
+    def __init__(self, chunks, active, retried, spec, k_spec):
+        self.chunks, self.active = chunks, active
         self.retried = retried
         self.spec, self.k_spec, self.drafts = spec, k_spec, None
         self.lens_before, self.sampled = {}, False
@@ -779,7 +763,6 @@ class ContinuousBatchingEngine:
                  replay_batch: Optional[bool] = None,
                  result_cache_size: int = 256,
                  journal=None,
-                 unified_step: bool = True,
                  brownout_thresholds=None,
                  brownout_patience: int = 3,
                  decode_preempt: bool = True,
@@ -800,7 +783,6 @@ class ContinuousBatchingEngine:
         if self._recurrent:
             self._refuse_for_recurrent(
                 draft_model=draft_model, kv_quant=kv_quant, tp=tp,
-                unified_step=unified_step,
                 prefill_chunk_tokens=prefill_chunk_tokens)
             # a page-aligned prefix has no state to share (a state is of
             # the whole sequence up to a token, not of a page): the
@@ -917,15 +899,16 @@ class ContinuousBatchingEngine:
             self._draft_decoder = None
             self.draft_cache = None
             self._draft_max_position = 0
-        # one scratch sequence backs every padding row of every bucket;
-        # its page(s) stay allocated WHILE sequences are active
-        # (the old allocate/truncate/free per padded step churned the
-        # free list under the pool lock) and are released whenever the
-        # engine goes idle, so an idle engine still reports a fully
-        # reclaimed pool; admission arithmetic always reserves the pad
-        # headroom either way.  A speculative pad row rewrites
-        # spec_tokens + 1 slots per verify step, so its headroom grows
-        # with k.
+        # one scratch sequence backs every padding row of the draft's
+        # propose scan (the ragged step's pad rows write to a dropped
+        # page and hold nothing); its page(s) stay allocated WHILE
+        # sequences are active (an allocate/truncate/free per padded
+        # step would churn the free list under the pool lock) and are
+        # released whenever the engine goes idle, so an idle engine
+        # still reports a fully reclaimed pool; admission arithmetic
+        # always reserves the pad headroom, on both pools.  A
+        # speculative pad row rewrites spec_tokens + 1 slots per step,
+        # so its headroom grows with k.
         pad_tokens = (self.spec_k + 1) if draft_model is not None else 1
         self._pad_pages = max(1, -(-pad_tokens // int(page_size)))
         self._reserved_pages = self._pad_pages
@@ -960,21 +943,11 @@ class ContinuousBatchingEngine:
         self.journal = journal
         self._jadm: List[str] = []
         self._jrows: List[tuple] = []
-        # ragged unified step (ISSUE 17): fold each iteration's
-        # prefill/chunk/decode/verify rows into ONE compiled dispatch.
-        # `unified_step=False` is the legacy multi-dispatch escape
-        # hatch (per-mode fault-injection plans also divert an
-        # iteration to it — the chaos sites fire per legacy dispatch,
-        # and their quarantine semantics are defined against that
-        # granularity).  `_unified_off` latches the unified path off
-        # after repeated dispatch failures (lock-guarded: readers are
-        # the scheduler thread, writers hold _cond); `_unified_failures`
-        # and `_disp_n`/`_disp_ragged` (this iteration's dispatch count
-        # and mode for the journal's step record) are scheduler-thread
-        # only, like _jadm/_jrows.
-        self.unified_step = bool(unified_step)
-        self._unified_off = False
-        self._unified_failures = 0
+        # ragged unified step (ISSUE 17): each iteration's chunk, decode
+        # and verify rows are ONE compiled dispatch.  `_disp_n` /
+        # `_disp_ragged` (this iteration's dispatch count and mode for
+        # the journal's step record) are scheduler-thread only, like
+        # _jadm/_jrows.
         self._disp_n = 0
         self._disp_ragged = False
         # closed-loop overload protection (ISSUE 19).  The brownout
@@ -1060,7 +1033,7 @@ class ContinuousBatchingEngine:
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
 
-    def _refuse_for_recurrent(self, draft_model, kv_quant, tp, unified_step,
+    def _refuse_for_recurrent(self, draft_model, kv_quant, tp,
                               prefill_chunk_tokens) -> None:
         """What cannot hold for a model whose layers carry a recurrent
         state, each with its reason."""
@@ -1085,13 +1058,12 @@ class ContinuousBatchingEngine:
             raise ValueError(
                 f"tp={tp}: the slot pools of {name} have no placement over "
                 "a tensor mesh")
-        if not unified_step or prefill_chunk_tokens is None:
+        if prefill_chunk_tokens is None:
             raise ValueError(
-                f"unified_step=False / prefill_chunk_tokens=None: {name} "
-                "carries a recurrent state, which only the ragged unified "
-                "step updates (a slot a row); the legacy prefill, chunk "
-                "and decode programs carry no slot pools.  Pass "
-                "prefill_chunk_tokens and keep unified_step")
+                f"prefill_chunk_tokens=None: {name} carries a recurrent "
+                "state, which only the ragged unified step updates (a slot "
+                "a row); the whole-prompt prefill programs carry no slot "
+                "pools.  Pass prefill_chunk_tokens")
 
     # ------------------------------------------------------------- public
     @property
@@ -1532,7 +1504,7 @@ class ContinuousBatchingEngine:
         if self.journal is not None and (self._jadm or self._jrows):
             self.journal.append_step(
                 self._jadm, self._jrows, dispatches=self._disp_n,
-                mode=(("ragged" if self._disp_ragged else "legacy")
+                mode=(("ragged" if self._disp_ragged else "prefill")
                       if self._disp_n else None))
         self._jadm = []
         self._jrows = []
@@ -1819,9 +1791,8 @@ class ContinuousBatchingEngine:
 
     def _free_pads_locked(self) -> None:
         """Caller holds ``self._cond`` (or the engine is single-threaded
-        at the call site).  Release the pad scratch page(s) on every
-        pool so an idle engine reports fully reclaimed capacity."""
-        self.cache.free(_PAD_SEQ)
+        at the call site).  Release the draft pool's pad scratch page(s)
+        so an idle engine reports fully reclaimed capacity."""
         if self._spec:
             self.draft_cache.free(_PAD_SEQ)
             _spec_draft_pages.set(self.draft_cache.pinned_pages)
@@ -2403,8 +2374,8 @@ class ContinuousBatchingEngine:
         return True
 
     def _finish_prefill(self, req, out_row, sampled: bool) -> None:
-        """Prefill-completion side effects, shared by the legacy chunk
-        path and the unified ragged step: the target is fully resident
+        """Prefill-completion side effects, shared by whole-prompt
+        prefill and the unified ragged step: the target is fully resident
         — register its prefix, ingest the draft's copy, latch the first
         sampled token, stamp TTFT, journal the pending sample."""
         with monitor.span("engine/commit/finish_prefill",
@@ -2522,46 +2493,15 @@ class ContinuousBatchingEngine:
             r.done.set()
 
     # ------------------------------------------- unified ragged step
-    def _legacy_iteration(self) -> bool:
-        """True when THIS iteration must run the legacy multi-dispatch
-        composition: the ``unified_step=False`` escape hatch, the
-        repeated-failure latch, or an installed fault plan targeting
-        the legacy dispatch sites (chaos plans' quarantine semantics
-        are defined against per-mode dispatch granularity — one
-        poisoned chunk fails one request — which a single fused
-        dispatch would widen).  Delay-kind rules on the dispatch
-        sites themselves (prefill/prefill_chunk/decode_step) are
-        pacing, not failure injection: the unified step fires those
-        sites itself, so they do NOT divert."""
-        if self._recurrent:
-            # no legacy composition carries slot pools: a fault plan's
-            # rules fire at the unified step's own sites, and a failed
-            # step goes down the retry/bisect ladder over ``ragged_step``
-            # itself (``_isolate_unified``)
-            return False
-        if not self.unified_step or self._unified_off:
-            return True
-        plan = _faults.active()
-        return plan is not None and any(
-            r.site in _ENGINE_FAULT_SITES
-            and not (r.kind == "delay"
-                     and r.site in _PACING_FAULT_SITES)
-            for r in plan.rules)
-
-    def _disable_unified_locked(self) -> None:
-        """Caller holds ``self._cond``.  Latch the unified path off
-        after repeated ragged-dispatch failures: the legacy
-        composition — whose retry/bisect isolation just absorbed those
-        failures row by row — serves from here on."""
-        self._unified_off = True
-
     def _propose_drafts(self, reqs):
-        """Draft-model propose for the unified step — the legacy
-        ``_exec_spec_step`` propose block: ONE compiled scan dispatch
-        for the opted-in rows.  A draft failure downgrades them to
+        """The draft model proposes ``spec_k`` greedy tokens for each
+        opted-in row in ONE compiled scan dispatch (plus one write-only
+        step, so its cache covers the last proposal).  Greedy, so a
+        rolled-back step proposes the same drafts again.  A draft
+        failure never fails the step: those rows are downgraded to
         plain decode (their drafts stay ``-1``, which never matches:
         they ride the verify rows with unmatched slots and advance
-        exactly one token, exactly as the legacy path degrades)."""
+        exactly one token)."""
         k = self.spec_k
         drafts = np.full((len(reqs), k), -1, np.int32)
         d_idx = [i for i, r in enumerate(reqs) if r.use_draft]
@@ -2641,19 +2581,17 @@ class ContinuousBatchingEngine:
         caller's choice: a draft model (accept lengths decide the next
         tokens and truncate the cache), sampling on the host (it picks
         from logits), whole-prompt prefill (its own programs run before
-        the step), a legacy iteration or an installed fault plan (their
-        sites are defined against today's order), a stand-in for the
-        decoder's step, a snapshot that waits for a cut between steps,
-        stop and drain.  With a reason, an iteration runs in the old
-        order: schedule, dispatch, fetch, commit."""
+        the step), an installed fault plan (a failed step is isolated
+        with every token on the host), a stand-in for the decoder's
+        step, a snapshot that waits for a cut between steps, stop and
+        drain.  With a reason, an iteration runs schedule, dispatch,
+        fetch, commit, and leaves nothing in flight."""
         if self._spec:
             return "spec"
         if not self.sample_on_device:
             return "host_sampling"
         if self.prefill_chunk_tokens is None:
             return "unchunked"
-        if self._legacy_iteration():
-            return "legacy"
         if _faults.active() is not None:
             return "fault_plan"
         if self._step_replaced():
@@ -2697,15 +2635,10 @@ class ContinuousBatchingEngine:
     def _unified_step(self, plan, active=None, retried=False) -> None:
         """ONE ragged dispatch for the whole iteration (ISSUE 17): the
         scheduler's rank-ordered chunk plan feeds prefill/chunk row
-        spans directly, every active row contributes its decode token
-        — or, under speculation, a (k+1)-token verify row of freshly
-        proposed drafts — and the single compiled ``ragged_step`` call
-        replaces the legacy decode-vs-chunk dispatch alternation.
-        Post-processing replays the legacy paths' side effects
-        exactly: chunk bookkeeping and prefill completion
-        (:meth:`_finish_prefill`), retirement/journal/steps accounting
-        from ``_decode_step``, speculative accept consumption with
-        partial rollback from ``_exec_spec_step``.
+        spans directly and every active row contributes its decode
+        token — or, under speculation, a (k+1)-token verify row of
+        freshly proposed drafts — to a single compiled ``ragged_step``
+        call.
 
         The step is two halves, :meth:`_launch_step` and
         :meth:`_commit_step`; the loop (:meth:`_pipeline`) dispatches
@@ -2713,15 +2646,11 @@ class ContinuousBatchingEngine:
         This method runs them back to back, with nothing in flight: the
         probes of :meth:`_isolate_unified`.
 
-        On ANY failure the composition unwinds
-        (:meth:`_unified_rollback`), pools rebuild + survivors replay
-        if a device-side loss zeroed them, and the iteration re-runs
-        through the legacy composition — whose retry/bisect machinery
-        owns failure isolation; repeated failures latch the unified
-        path off entirely.  A recurrent model has no legacy composition
-        (nothing else carries its slots): its failed step goes down the
-        same ladder over this method (:meth:`_isolate_unified`), which
-        calls it again with the rows to probe (``active``: the decode
+        On ANY failure the step unwinds (:meth:`_unified_rollback`),
+        the pools are rebuilt and the survivors replayed if a
+        device-side loss zeroed them, and the step goes down the ladder
+        (:meth:`_step_failed`, :meth:`_isolate_unified`), which calls
+        this method again with the rows to probe (``active``: the decode
         rows, all of ``self._active`` if None; ``retried``: the whole
         step has had its second try)."""
         step = self._launch_step(plan, active, retried)
@@ -2782,7 +2711,7 @@ class ContinuousBatchingEngine:
         if not chunks and not active:
             return None
         spec = self._spec and any(r.use_draft for r in active)
-        step = _Step(plan, chunks, active, retried, spec,
+        step = _Step(chunks, active, retried, spec,
                      self.spec_k if spec else 0)
         step.overlapped = prev is not None
         step.index = self.steps + (1 if prev is not None and prev.active
@@ -2868,11 +2797,10 @@ class ContinuousBatchingEngine:
                 # flight the heartbeat keeps that step's start
                 self._wedged.clear()
                 self._step_started_at = step.t0
-            # only delay-kind pacing rules can be live here
-            # (_legacy_iteration diverts everything else): fire
-            # the legacy sites so throttling plans — per-row
-            # seq_id targeting included — pace the unified step
-            # exactly as they pace the composition it replaces
+            # the engine's fault sites, each with the seq_ids of the
+            # rows it speaks of: a rule of any kind fires here, for
+            # every model, and an error goes down the ladder
+            # (:meth:`_step_failed`) like a failed program call
             for req, _t, k, _n, _l in chunks:
                 if not k:
                     _faults.maybe_fire("prefill",
@@ -2880,9 +2808,9 @@ class ContinuousBatchingEngine:
                 _faults.maybe_fire("prefill_chunk",
                                    seq_ids=[req.seq_id])
             if active:
-                _faults.maybe_fire(
-                    "decode_step",
-                    seq_ids=[r.seq_id for r in active])
+                decoding = [r.seq_id for r in active]
+                _faults.maybe_fire("decode_step", seq_ids=decoding)
+                _faults.maybe_fire("engine_wedge", seq_ids=decoding)
             self._count_dispatch("ragged")
             step_args = dict(n_drafts=(nds if spec else None),
                              sampling=sampling)
@@ -2896,7 +2824,7 @@ class ContinuousBatchingEngine:
                     feed=((prev.flight, src) if step.deferred else None),
                     after=None if prev is None else prev.flight)
                 step.record = step.flight.record
-        except BaseException as e:  # noqa: BLE001 — legacy owns isolation
+        except BaseException as e:  # noqa: BLE001 — the ladder isolates
             if prev is None:
                 self._step_started_at = None
                 self._step_failed(step, e)
@@ -2942,26 +2870,15 @@ class ContinuousBatchingEngine:
 
     def _step_failed(self, step, error) -> None:
         """A unified step failed with nothing else in flight: unwind
-        it, repair the pools, and go down today's ladder with ITS
-        composition."""
+        it, repair the pools, and go down the ladder with ITS rows."""
         self._unified_rollback(step)
         _unified_fallbacks.inc()
-        self._unified_failures += 1
-        if self._unified_failures >= 3 and not self._unified_off \
-                and not self._recurrent:
-            with self._cond:
-                self._disable_unified_locked()
         # a device-side loss zeroed every survivor's KV: rebuild +
-        # replay BEFORE the legacy re-run decodes over zeroed pages
+        # replay BEFORE the retry decodes over zeroed pages
         # (replay-dead requests are quarantined/ejected in here)
         self._after_step_failure(error)
-        if self._recurrent:
-            self._isolate_unified(step.chunks, step.active, error,
-                                  step.retried)
-            return
-        self._run_chunks(step.plan)
-        if self._active:
-            self._decode_step()
+        self._isolate_unified(step.chunks, step.active, error,
+                              step.retried)
 
     def _commit_step(self, step) -> bool:
         """The second half of a unified step: fetch its outputs and do
@@ -2980,7 +2897,7 @@ class ContinuousBatchingEngine:
             fetched_ns = _tracer.now_ns()
             self._step_started_at = None if newer is None else newer.t0
             self._check_wedged(step.t0)
-        except BaseException as e:  # noqa: BLE001 — legacy owns isolation
+        except BaseException as e:  # noqa: BLE001 — the ladder isolates
             self._step_started_at = None
             if newer is not None:
                 self._flight = None
@@ -3000,7 +2917,6 @@ class ContinuousBatchingEngine:
         (_decode_step_s if active else _prefill_s).observe(
             (fetched_ns - start_ns) / 1e9)
         with monitor.span("engine/commit", into=self._host_s):
-            self._unified_failures = 0
             traced = _tracer.enabled and step.traced
             if traced:
                 # what the decoder says it dispatched: the (rows, span,
@@ -3017,7 +2933,8 @@ class ContinuousBatchingEngine:
                     _kv_window_dead_pages_g.set(value)
                 elif name == "slots_zeroed":
                     _slots_zeroed.inc(value)
-            # ---- chunk rows: the legacy _prefill_chunk bookkeeping
+            # ---- chunk rows: the scheduler's count, the timeline, and
+            # what a finished prefill owes (:meth:`_finish_prefill`)
             for i, (req, _target, k, n, last) in enumerate(chunks):
                 self._sched.note_chunk(req)
                 if traced:
@@ -3030,8 +2947,8 @@ class ContinuousBatchingEngine:
                                           chunk=step.chunk_no[i])
                 if last:
                     self._finish_prefill(req, out[i], step.sampled)
-            # ---- decode/verify rows: the legacy _decode_step retirement
-            # (a row whose request met its EOS a step ago is dropped)
+            # ---- decode/verify rows: tokens, retirement, the journal's
+            # rows (a row whose request met its EOS a step ago is dropped)
             dropped = step.dropped
             rows_at = [(nchunks + i, r) for i, r in enumerate(active)
                        if id(r) not in dropped]
@@ -3053,8 +2970,10 @@ class ContinuousBatchingEngine:
                 for i, (at, r) in enumerate(rows_at):
                     if spec:
                         a = int(accept[at])
-                        # page-granular partial rollback, both caches —
-                        # the _exec_spec_step contract
+                        # page-granular partial rollback: the rejected
+                        # positions' lengths unwind on BOTH caches; their
+                        # pages stay mapped (inside the admission
+                        # reservation) and later steps rewrite the slots
                         new_len = step.lens_before[r.seq_id][0] + a + 1
                         self.cache.truncate(r.seq_id, new_len)
                         if r.use_draft:
@@ -3159,13 +3078,16 @@ class ContinuousBatchingEngine:
         return True
 
     def _isolate_unified(self, chunks, active, error, retried) -> None:
-        """The legacy ladder (:meth:`_step_isolated`, :meth:`_bisect_step`)
-        over the ragged step, for a model that has no other path: the
-        failed step (rolled back, survivors replayed) runs whole once
-        more — a transient fault — and then by halves of its rows, each
-        row against its own slot, so healthy halves advance normally and
-        only a row that fails alone is quarantined with the error that
-        killed it."""
+        """THE failure ladder, for every model: the failed step (rolled
+        back, survivors replayed) runs whole once more — a transient
+        fault, the common case after a preemption blip — and then by
+        halves of its rows, chunk rows and decode rows alike, so healthy
+        halves advance normally and only a row that fails alone is
+        quarantined with the error that killed it.  O(k log n) extra
+        dispatches for k poisoned rows in a step of n.  A probe is a
+        step like any other (:meth:`_unified_step`): derived from
+        request and cache state, so it draws the same samples and
+        proposes the same drafts as the step it replays."""
         plan = [(c[0], c[3]) for c in chunks]
         n = len(plan) + len(active)
         if not retried:
@@ -3516,8 +3438,7 @@ class ContinuousBatchingEngine:
                 break
         return failed
 
-    def _after_step_failure(self, error=None, exclude=(),
-                            in_step: bool = False) -> List[_Request]:
+    def _after_step_failure(self, error=None, exclude=()) -> None:
         """Recovery hook run after ANY failed (or wedged) step/chunk
         was rolled back: a wedge rebuilds the pools outright
         (consumer 2 — the watchdog-driven restart); then, if the pools
@@ -3527,17 +3448,15 @@ class ContinuousBatchingEngine:
         zeroed pages and quarantine stays per-request for device-side
         failures too.
 
-        Requests whose own replay failed are quarantined: with
-        ``in_step`` the ones in the active batch are RETURNED (the
-        step caller must drop them from its retry and treat them as
-        poisoned — they carry an un-executed token to pop); everything
-        else is retired here."""
+        Requests whose own replay failed are quarantined and retired
+        here (the failed step was rolled back first, so none of them
+        carries an un-executed token)."""
         if isinstance(error, _EngineWedged):
             self.cache.reset_pools()
             if self._spec:
                 self.draft_cache.reset_pools()
         if not self._pools_rebuilt():
-            return []
+            return
         _rebuilds_total.inc()
         t_tr = _tracer.now_ns() if _tracer.enabled else 0
         with monitor.span("engine/recovery", histogram=_recovery_s):
@@ -3548,26 +3467,20 @@ class ContinuousBatchingEngine:
                 wedged=isinstance(error, _EngineWedged),
                 replay_failed=len(failed))
         if not failed:
-            return []
-        caller_owned = ([r for r in failed if r in self._active]
-                        if in_step else [])
-        eject = [r for r in failed if r not in caller_owned]
-        if eject:
-            with self._cond:
-                for r in eject:
-                    for lst_name in ("_active", "_prefilling",
-                                     "_preempted"):
-                        lst = getattr(self, lst_name)
-                        if r in lst:
-                            lst.remove(r)
-                    # quarantine BEFORE retire: terminal timeline event
-                    # stays 'retire' at every ejection site
-                    _note_quarantine(r)
-                    self._retire_locked(r)
-                self._cond.notify_all()
-            for r in eject:
-                r.done.set()
-        return caller_owned
+            return
+        with self._cond:
+            for r in failed:
+                for lst_name in ("_active", "_prefilling", "_preempted"):
+                    lst = getattr(self, lst_name)
+                    if r in lst:
+                        lst.remove(r)
+                # quarantine BEFORE retire: terminal timeline event
+                # stays 'retire' at every ejection site
+                _note_quarantine(r)
+                self._retire_locked(r)
+            self._cond.notify_all()
+        for r in failed:
+            r.done.set()
 
     def _check_wedged(self, started_at: Optional[float] = None) -> None:
         """Consume the watchdog's wedge flag: raised as a step failure
@@ -3593,436 +3506,6 @@ class ContinuousBatchingEngine:
             "decode step exceeded the watchdog heartbeat timeout; "
             "treating its results as suspect")
 
-    # ------------------------------------------------- decode + isolation
-    def _spec_sampling_for(self, reqs, n: int):
-        """(seeds, temps, flags) arrays for the verify program's fused
-        bonus-token tail, padded to ``n`` rows — ``_sampling_for``
-        minus the host-side counters: the draw position is
-        pos + accept + 1, computed on device, so plain and speculative
-        draws replay identically by construction."""
-        seeds, _, temps, flags = self._sampling_for(
-            reqs, np.zeros(n, np.int32))
-        return seeds, temps, flags
-
-    def _exec_spec_step(self, reqs) -> List[_SpecRow]:
-        """One SPECULATIVE decode step for ``reqs``: the draft proposes
-        ``spec_k`` greedy tokens per opted-in row in ONE compiled scan
-        dispatch (plus one write-only step so its cache covers the last
-        proposal), then the target verifies the whole ``[B, k+1]``
-        block in ONE compiled dispatch — per-row accept lengths and the
-        bonus token computed on device.  Rows that opted out (or whose
-        draft just failed) ride along with unmatched draft slots: they
-        advance exactly one token, exactly as a plain step would.
-
-        Replays identically after a rollback (greedy draft + the same
-        threefry counters), which the retry/bisect recovery depends on.
-        Partial rollback happens HERE: both caches truncate to each
-        row's verified length pos + accept + 1 before returning."""
-        k = self.spec_k
-        B = self._bucket(len(reqs))
-        npad = B - len(reqs)
-        drafts = np.full((len(reqs), k), -1, np.int32)  # -1 never matches
-        d_idx = [i for i, r in enumerate(reqs) if r.use_draft]
-        # a flag raised against an EARLIER dispatch (one that errored
-        # before its own _check_wedged, or a slow replay) must not
-        # condemn this fresh step to a needless rebuild
-        self._wedged.clear()
-        t0 = self._step_started_at = time.monotonic()
-        try:
-            _faults.maybe_fire("decode_step",
-                               seq_ids=[r.seq_id for r in reqs])
-            _faults.maybe_fire("engine_wedge",
-                               seq_ids=[r.seq_id for r in reqs])
-            with monitor.span("engine/decode_step",
-                              histogram=_decode_step_s):
-                if d_idx:
-                    Bd = self._bucket(len(d_idx))
-                    d_seqs = [reqs[i].seq_id for i in d_idx]
-                    d_tok = np.array(
-                        [reqs[i].generated[-1] for i in d_idx], np.int32)
-                    d_pos = np.array(
-                        [self.draft_cache.length(s) for s in d_seqs],
-                        np.int32)
-                    if Bd > len(d_idx):
-                        self.draft_cache.truncate(_PAD_SEQ, 0)
-                        pad_n = Bd - len(d_idx)
-                        d_seqs += [_PAD_SEQ] * pad_n
-                        d_tok = np.concatenate(
-                            [d_tok, np.zeros(pad_n, np.int32)])
-                        d_pos = np.concatenate(
-                            [d_pos, np.zeros(pad_n, np.int32)])
-                    try:
-                        self._count_dispatch("draft")
-                        prop = self._draft_decoder.multi_step(
-                            self.draft_cache, d_seqs, d_tok, d_pos, k + 1)
-                    except BaseException:  # noqa: BLE001 — degrade
-                        # a draft failure must never fail the batch:
-                        # those rows decode plain from here on (their
-                        # draft cache cannot rejoin lockstep)
-                        self._downgrade_draft([reqs[i] for i in d_idx])
-                        d_idx = []
-                    else:
-                        for j, i in enumerate(d_idx):
-                            drafts[i] = prop[j, :k]
-                block = np.zeros((B, k + 1), np.int32)
-                pos = np.zeros(B, np.int32)
-                seq_ids = []
-                for i, r in enumerate(reqs):
-                    block[i, 0] = r.generated[-1]
-                    block[i, 1:] = drafts[i]
-                    pos[i] = self.cache.length(r.seq_id)
-                    seq_ids.append(r.seq_id)
-                if npad:
-                    self.cache.truncate(_PAD_SEQ, 0)
-                    seq_ids.extend([_PAD_SEQ] * npad)
-                sampling = (self._spec_sampling_for(reqs, B)
-                            if self.sample_on_device else None)
-                self._count_dispatch("verify")
-                out, accept = self._decoder.verify(
-                    self.cache, seq_ids, block, pos, sampling=sampling)
-                self._check_wedged(t0)
-        finally:
-            self._step_started_at = None
-        _last_step_ts.set(time.time())
-        rows: List[_SpecRow] = []
-        for i, r in enumerate(reqs):
-            a = int(accept[i])
-            new_len = int(pos[i]) + a + 1
-            # page-granular partial rollback: rejected positions'
-            # lengths unwind on BOTH caches; their pages stay mapped
-            # (inside the admission reservation) and their slots are
-            # simply rewritten by later steps
-            self.cache.truncate(r.seq_id, new_len)
-            if r.use_draft:
-                self.draft_cache.truncate(r.seq_id, new_len)
-            rows.append(_SpecRow(out[i], a, drafts[i]))
-        self._last_spec = (k * len(d_idx),
-                           sum(int(accept[i]) for i in d_idx))
-        if d_idx:
-            _spec_proposed.inc(k * len(d_idx))
-            _spec_accepted.inc(sum(int(accept[i]) for i in d_idx))
-            rejected = 0
-            for i in d_idx:
-                _spec_accept_len.observe(int(accept[i]))
-                rejected += int(accept[i]) < k
-            if rejected:
-                _spec_rollback.inc(rejected)
-        _spec_draft_pages.set(self.draft_cache.pinned_pages)
-        return rows
-
-    def _exec_step(self, reqs) -> List[np.ndarray]:
-        """Run ONE compiled decode step for ``reqs`` (all of, or a
-        bisected subset of, the active batch), padded to a bucket.
-        Resets ``_last_spec`` — a plain step proposes nothing.
-        Tokens, positions and sampling counters are derived from
-        request/cache state — a rolled-back step therefore replays
-        IDENTICALLY (same threefry counters → same draws), which the
-        retry/bisect recovery depends on.  Returns one output row per
-        request (sampled token id, or the logits row).  With a draft
-        model and at least one opted-in row the step runs SPECULATIVELY
-        (one propose scan + one verify dispatch, multiple tokens per
-        row) and the rows are :class:`_SpecRow`."""
-        if self._spec and any(r.use_draft for r in reqs):
-            return self._exec_spec_step(reqs)
-        self._last_spec = (0, 0)
-        B = self._bucket(len(reqs))
-        npad = B - len(reqs)
-        # the new token enters the sequence now: its rope position
-        # (== current length) is read before the write
-        tokens = np.zeros((B, 1), np.int32)
-        pos = np.zeros(B, np.int32)
-        seq_ids = []
-        for i, r in enumerate(reqs):
-            tokens[i, 0] = r.generated[-1]
-            pos[i] = self.cache.length(r.seq_id)
-            seq_ids.append(r.seq_id)       # decoder.step allocates pages
-        # pad rows: a scratch sequence rewrites its slot 0 every step;
-        # its page PERSISTS across steps (no allocate/free churn) and is
-        # released only when the engine drains
-        if npad:
-            # truncate FIRST: the pad length advanced once per pad row
-            # last step, and allocating against that stale length could
-            # demand a second page once max_batch > page_size — the
-            # scratch sequence must only ever hold its one headroom page
-            self.cache.truncate(_PAD_SEQ, 0)
-            self.cache.allocate(_PAD_SEQ, 1)   # no-op while already held
-            seq_ids.extend([_PAD_SEQ] * npad)
-        sampling = (self._sampling_for(reqs, pos + 1)
-                    if self.sample_on_device else None)
-        # ONE compiled program per step attempt for the whole subset
-        # (per-row positions, pools donated through the step); with
-        # on-device sampling the result is (B,) token ids — the only
-        # per-step device->host transfer.  A wedge flag raised against
-        # an earlier dispatch is stale here — drop it
-        self._wedged.clear()
-        t0 = self._step_started_at = time.monotonic()
-        try:
-            _faults.maybe_fire("decode_step", seq_ids=seq_ids[:len(reqs)])
-            _faults.maybe_fire("engine_wedge",
-                               seq_ids=seq_ids[:len(reqs)])
-            with monitor.span("engine/decode_step",
-                              histogram=_decode_step_s):
-                self._count_dispatch("decode")
-                out_np = self._decoder.step(self.cache, seq_ids, tokens,
-                                            pos, sampling=sampling)
-                self._check_wedged(t0)
-        finally:
-            self._step_started_at = None
-        _last_step_ts.set(time.time())
-        return [out_np[i] for i in range(len(reqs))]
-
-    def _rollback_step(self, reqs, lens_before) -> None:
-        """Restore pre-step cache lengths after a failed attempt (the
-        decoder also rolls back its own advance; this covers faults
-        fired before the decoder ran).  Pages stay mapped — they are
-        inside the admission reservation and the replay rewrites their
-        slots.  Speculative steps unwind the DRAFT cache too (the
-        propose scan may have advanced it before the verify failed)."""
-        for r in reqs:
-            tgt, dft = lens_before[r.seq_id]
-            self.cache.truncate(r.seq_id, tgt)
-            if dft is not None and self._spec:
-                self.draft_cache.truncate(r.seq_id, dft)
-
-    def _step_isolated(self, reqs, lens_before):
-        """(survivors, rows, poisoned) for one logical decode step:
-        try the whole batch; on failure retry once (transient faults —
-        the common TPU case after a preemption blip), then bisect to
-        isolate the poisoned sequence(s) instead of erroring everyone
-        (the old ``_fail_all`` blast radius)."""
-        try:
-            return reqs, self._exec_step(reqs), []
-        except BaseException as e:  # noqa: BLE001 — classified below
-            self._rollback_step(reqs, lens_before)
-            # ISSUE 8: a REAL donated-buffer loss (or a watchdog-
-            # flagged wedge) zeroed every sequence's KV — replay the
-            # survivors so the retry below replays the step EXACTLY
-            # instead of decoding over zeroed pages.  A row whose OWN
-            # replay failed is dropped from the retry and quarantined.
-            live, poisoned = self._split_replay_dead(
-                reqs, self._after_step_failure(e, in_step=True))
-            _decode_retries.inc()
-            if not live:
-                return [], [], poisoned
-            try:
-                return live, self._exec_step(live), poisoned
-            except BaseException as e2:  # noqa: BLE001
-                self._rollback_step(live, lens_before)
-                live, dead2 = self._split_replay_dead(
-                    live, self._after_step_failure(e2, in_step=True))
-                poisoned += dead2
-                if not live:
-                    return [], [], poisoned
-                s, o, p = self._bisect_step(live, lens_before, e2)
-                return s, o, p + poisoned
-
-    @staticmethod
-    def _split_replay_dead(reqs, dead):
-        """(live, quarantined) partition of ``reqs`` around the
-        replay-failure set ``dead`` — each dead row counts as a
-        quarantine (its error was set by the failed replay)."""
-        if not dead:
-            return list(reqs), []
-        dead_ids = {id(r) for r in dead}
-        live, out = [], []
-        for r in reqs:
-            if id(r) in dead_ids:
-                _note_quarantine(r)
-                out.append(r)
-            else:
-                live.append(r)
-        return live, out
-
-    def _bisect_step(self, reqs, lens_before, error):
-        """Deterministic fault isolation: halve the failing batch and
-        replay each half (solo replay at size 1).  Healthy halves
-        advance their token normally; a size-1 failure quarantines that
-        request with the error that killed it.  O(k·log n) extra step
-        attempts for k poisoned sequences in a batch of n."""
-        if len(reqs) == 1:
-            r = reqs[0]
-            r.error = error
-            _note_quarantine(r)
-            return [], [], [r]
-        mid = (len(reqs) + 1) // 2
-        survivors, rows, poisoned = [], [], []
-        for half in (reqs[:mid], reqs[mid:]):
-            # a row whose KV replay failed during a SIBLING subset's
-            # recovery carries its error already — never step it again
-            # (the _decode_step sweep retires it)
-            half = [r for r in half if r.error is None]
-            if not half:
-                continue
-            try:
-                _decode_retries.inc()
-                half_rows = self._exec_step(half)
-            except BaseException as e:  # noqa: BLE001
-                self._rollback_step(half, lens_before)
-                # a device-side failure in THIS half also zeroed the
-                # other half's (possibly already-advanced) KV: replay
-                # everyone to their current lengths before probing on
-                live, dead = self._split_replay_dead(
-                    half, self._after_step_failure(e, in_step=True))
-                poisoned.extend(dead)
-                if live:
-                    s, o, p = self._bisect_step(live, lens_before, e)
-                    survivors.extend(s)
-                    rows.extend(o)
-                    poisoned.extend(p)
-            else:
-                survivors.extend(half)
-                rows.extend(half_rows)
-        return survivors, rows, poisoned
-
-    def _decode_step(self):
-        """One token for every active sequence, padded to a bucket;
-        failures are isolated per sequence (retry, then bisect) rather
-        than erroring the whole batch."""
-        active = self._active
-        lens_before = {
-            r.seq_id: (self.cache.length(r.seq_id),
-                       (self.draft_cache.length(r.seq_id)
-                        if self._spec and r.use_draft else None))
-            for r in active}
-        jlens = ({id(r): len(r.generated) for r in active}
-                 if self.journal is not None else None)
-        for r in active:
-            r.generated.append(r.next_token)
-        _active_seqs.set(len(active))
-        _batch_occupancy.observe(len(active) / self.max_batch)
-        # the gauge is process-global (last constructor wins), so the
-        # engine doing the decoding re-asserts its mode every step —
-        # a live server's /metrics stays truthful even after another
-        # engine (bench baseline, parity test) was built in-process
-        _sampling_on_device_g.set(int(self.sample_on_device))
-        on_device = self.sample_on_device
-        t_tr = _tracer.now_ns() if _tracer.enabled else 0
-        survivors, rows, poisoned = self._step_isolated(active, lens_before)
-        if _tracer.enabled and t_tr:
-            # the engine-step ring (ISSUE 10): batch composition per
-            # class + spec economics + the dispatch wall time (retries
-            # and bisection probes included — that IS this step's cost;
-            # t_tr == 0 = window opened mid-dispatch, skip the slice)
-            comp: dict = {}
-            for r in active:
-                comp[r.priority] = comp.get(r.priority, 0) + 1
-            prop, acc = self._last_spec
-            _tracer.step_record(
-                "decode", self.steps, t_tr, _tracer.now_ns(),
-                batch=len(active), classes=comp, spec_proposed=prop,
-                spec_accepted=acc, poisoned=len(poisoned),
-                requests=[r.request_id for r in active])
-        # ISSUE 8 replay-failure sweep: a row whose KV replay failed
-        # during recovery carries its error.  The failing subset's own
-        # dead rows are already in `poisoned`; one that died OUTSIDE
-        # that scope — its bisect half had already succeeded, or was
-        # still pending — must be ejected HERE, never left decoding
-        # over a half-reconstructed cache.  Executed-token rows retire
-        # without the pop; un-stepped rows join the poisoned path.
-        dead_done: List[_Request] = []
-        if any(r.error is not None for r in survivors):
-            pairs = list(zip(survivors, rows))
-            survivors, rows = [], []
-            for r, row in pairs:
-                if r.error is not None:
-                    _note_quarantine(r)
-                    dead_done.append(r)
-                else:
-                    survivors.append(r)
-                    rows.append(row)
-        accounted = ({id(r) for r in survivors}
-                     | {id(r) for r in poisoned}
-                     | {id(r) for r in dead_done})
-        for r in active:
-            if id(r) not in accounted and not r.done.is_set() \
-                    and r.error is not None:
-                _note_quarantine(r)
-                poisoned.append(r)
-        _tokens_total.inc(len(survivors))
-
-        # request-local state (r.*) is scheduler-thread-owned: decide
-        # retirements and sample next tokens OUTSIDE the lock, then take
-        # the lock for the shared-state transition (pages/reservations/
-        # active list) — the discipline tpu_lint TPL004 enforces
-        still, retired = [], []
-        accepted_emitted = 0
-        for r, row in zip(survivors, rows):
-            if _tracer.enabled:
-                if isinstance(row, _SpecRow):
-                    _tracer.request_event(
-                        r.request_id, "verify_step", step=self.steps,
-                        accept=int(row.accept))
-                else:
-                    _tracer.request_event(r.request_id, "decode_step",
-                                          step=self.steps)
-            eos_hit = (r.eos_token_id is not None
-                       and r.generated[-1] == r.eos_token_id)
-            if eos_hit or len(r.generated) >= r.max_new_tokens:
-                retired.append(r)
-                continue
-            if isinstance(row, _SpecRow):
-                # consume the accepted draft tokens SEQUENTIALLY, with
-                # the same eos/budget checks the plain path applies one
-                # step at a time — so speculative output is, token for
-                # token, what target-only greedy would have emitted
-                done = False
-                for t in row.drafts[:row.accept]:
-                    r.generated.append(int(t))
-                    accepted_emitted += 1
-                    if (r.eos_token_id is not None
-                            and int(t) == r.eos_token_id) \
-                            or len(r.generated) >= r.max_new_tokens:
-                        done = True
-                        break
-                if done:
-                    retired.append(r)
-                    continue
-                out_row = row.out
-            else:
-                out_row = row
-            r.next_token = (int(out_row) if on_device
-                            else self._pick(r, out_row))
-            still.append(r)
-        if accepted_emitted:
-            _tokens_total.inc(accepted_emitted)
-        if self.journal is not None:
-            # one journal row per CONTINUING request: the tokens this
-            # step committed plus the new pending sample.  Retiring
-            # rows need no emission — their retire record (below, via
-            # _retire_locked) drops them from the live set, and a
-            # crash before that record replays their last step
-            # bit-identically anyway.
-            for r in still:
-                self._jrows.append(
-                    (r.request_id, list(r.generated[jlens[id(r)]:]),
-                     r.next_token))
-        for r in poisoned:
-            # the token recorded for this step never executed
-            r.generated.pop()
-        with self._cond:
-            self.steps += 1
-            for r in retired:
-                self._retire_locked(r)
-            for r in poisoned:
-                self._retire_locked(r)
-            for r in dead_done:
-                self._retire_locked(r)
-            self._active = still
-            if not still:
-                # idle: the scratch page goes back too, so a drained
-                # engine reports a fully reclaimed pool — released
-                # BEFORE waking the retired requests' waiters, who may
-                # assert exactly that
-                self._free_pads_locked()
-            self._cond.notify_all()        # drain() waits on this
-        _active_seqs.set(len(still))
-        for r in retired:
-            r.done.set()
-        for r in poisoned:
-            r.done.set()
-        for r in dead_done:
-            r.done.set()
-
     def _fail_all(self, exc):
         """LAST-RESORT scheduler-fault handler (isolation failed or the
         fault was outside any step): error out every in-flight request
@@ -4040,7 +3523,7 @@ class ContinuousBatchingEngine:
                     continue
                 if r.finished_at is not None:
                     # retired successfully earlier THIS step (its
-                    # done.set() is deferred to the end of _decode_step):
+                    # done.set() is deferred to the end of the commit):
                     # deliver the completed generation, don't error it
                     r.done.set()
                     continue
@@ -4132,17 +3615,14 @@ class ContinuousBatchingEngine:
                         self._cache_result_locked(r)
                         r.done.set()
                     return
-            # one iteration; the unified step's is one ``engine/step
-            # <index>`` span on the profiler's clock, <index> being the
-            # ``index`` of the step-ring records of the step it COMMITS
-            # (with a step in flight it dispatches the one after that)
-            if self._legacy_iteration():
-                self._iteration(True)
-            else:
-                with monitor.span(f"engine/step {self.steps}"):
-                    self._iteration(False)
+            # one iteration is one ``engine/step <index>`` span on the
+            # profiler's clock, <index> being the ``index`` of the
+            # step-ring records of the step it COMMITS (with a step in
+            # flight it dispatches the one after that)
+            with monitor.span(f"engine/step {self.steps}"):
+                self._iteration()
 
-    def _iteration(self, legacy: bool) -> None:
+    def _iteration(self) -> None:
         """One pass of the scheduler thread: the scheduling pass under
         the lock (``engine/schedule``), the device work outside it, the
         journal flush (``engine/commit``).  With a step in flight
@@ -4155,8 +3635,7 @@ class ContinuousBatchingEngine:
         gc_ns = gc_pause_ns()
         try:
             if self._flight is not None:
-                why = (("legacy" if legacy else None)
-                       or self._overlap_hold() or self._riders_hold())
+                why = self._overlap_hold() or self._riders_hold()
                 if why is not None:
                     self._land(why)
             while True:
@@ -4203,29 +3682,19 @@ class ContinuousBatchingEngine:
         had_active = bool(self._active)
         t_iter = time.perf_counter()
         try:
-            if legacy:
-                # legacy composition: at most ~a chunk budget of
-                # prefill dispatches, then ONE decode step for
-                # everything active (ISSUE 7 interleaving);
-                # per-chunk failures quarantine only their own
-                # request (ISSUE 4 discipline carried over)
+            # unified ragged step (ISSUE 17): the chunk plan's
+            # spans + every active row in ONE compiled dispatch
+            if self.prefill_chunk_tokens is None and plan:
+                # unchunked: full-prompt spans would give the
+                # ragged program an unbounded (rows, max-span)
+                # bucket space — every novel prompt length a
+                # recompile.  Whole-prompt prefill stays on the
+                # length-bucketed programs and only the active
+                # rows (span 1 or k+1: bounded) go into the
+                # ragged dispatch.
                 self._run_chunks(plan)     # device work: outside lock
-                if self._active:
-                    self._decode_step()
-            else:
-                # unified ragged step (ISSUE 17): the chunk plan's
-                # spans + every active row in ONE compiled dispatch
-                if self.prefill_chunk_tokens is None and plan:
-                    # unchunked: full-prompt spans would give the
-                    # ragged program an unbounded (rows, max-span)
-                    # bucket space — every novel prompt length a
-                    # recompile.  Keep whole-prompt prefill on the
-                    # legacy length-bucketed program and fold only
-                    # the active rows (span 1 or k+1: bounded)
-                    # into the ragged dispatch.
-                    self._run_chunks(plan)
-                    plan = ()
-                self._pipeline(plan)
+                plan = ()
+            self._pipeline(plan)
         except BaseException as e:  # noqa: BLE001 — fail loudly, not hang
             self._fail_all(e)
         finally:

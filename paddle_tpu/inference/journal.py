@@ -533,8 +533,10 @@ class RequestJournal:
 
         ``dispatches``/``mode`` (ISSUE 17) describe HOW the iteration
         executed: the number of compiled dispatches it issued and
-        ``"ragged"`` (the unified single-dispatch step) vs ``"legacy"``
-        (the multi-dispatch composition).  Optional keys — replay
+        ``"ragged"`` (the unified single-dispatch step) vs ``"prefill"``
+        (an unchunked engine's whole-prompt prefill programs and nothing
+        else; journals from before PR 48 say ``"legacy"`` for any
+        iteration without a ragged dispatch).  Optional keys — replay
         ignores them (see :class:`_LiveSet`), so journals written
         before the unified step restore unchanged, and journals written
         after it replay on older readers."""

@@ -34,7 +34,6 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           dequantize_kv, kv_tokens_visible,
                                           kv_pages_copied, kv_tokens_walked,
                                           paged_attention,
-                                          paged_attention_multi,
                                           paged_attention_ragged,
                                           q_positions_computed,
                                           quantize_kv, walk_block_pages,
@@ -491,7 +490,7 @@ class _TracedPagedContext:
         if self.q_lens is None or self.slots is None:
             raise NotImplementedError(_NO_SLOTS.format(
                 "the prefill / prefix / chunk_prefill programs" if
-                self.prefill else "the decode / verify programs"))
+                self.prefill else "the decode programs"))
         return (self.slots, self.lens - self.q_lens, self.q_lens,
                 self.row_off)
 
@@ -599,7 +598,7 @@ class _TracedPagedContext:
         if (k is None or scale is not None) and self.q_lens is None:
             raise NotImplementedError(_NO_SHARED.format(
                 "the prefill / prefix / chunk_prefill programs" if
-                self.prefill else "the decode / verify programs"))
+                self.prefill else "the decode programs"))
         if k is None:
             return self._attend_ragged(q, layer, window, scale)
         b, s = k.shape[0], k.shape[1]
@@ -638,17 +637,11 @@ class _TracedPagedContext:
         # layers behind make of them is discarded by the program's tail
         if self.q_lens is not None:
             return self._attend_ragged(q, layer, window, scale)
-        # decode / verify: s tokens per row scatter flat (s == 1 is the
-        # classic decode step; s > 1 is the speculative verify block)
-        if s == 1:
-            out = paged_attention(q._data[:, 0], kp, vp, self.lens,
-                                  self.tables, k_scales=ksc,
-                                  v_scales=vsc, window=window)
-            return wrap_array(out[:, None])
-        out = paged_attention_multi(q._data, kp, vp, self.lens,
-                                    self.tables, k_scales=ksc,
-                                    v_scales=vsc, window=window)
-        return wrap_array(out)
+        # decode (``step``, the scan of ``multi_step``): one token a row
+        out = paged_attention(q._data[:, 0], kp, vp, self.lens,
+                              self.tables, k_scales=ksc,
+                              v_scales=vsc, window=window)
+        return wrap_array(out[:, None])
 
     def _attend_ragged(self, q, layer, window, scale):
         """The ragged step's kernel call against pool ``layer`` as it
@@ -742,8 +735,7 @@ class JittedPagedDecoder:
     #: adds its slot pools to the ragged program's (slot 14, behind the
     #: weight scales: the signature every other caller knows is kept).
     DONATE_ARGNUMS = {"decode": (8, 9, 10, 11), "prefill": (6, 7, 8, 9),
-                      "prefix": (8, 9, 10, 11), "verify": (8, 9, 10, 11),
-                      "ragged": (9, 10, 11, 12)}
+                      "prefix": (8, 9, 10, 11), "ragged": (9, 10, 11, 12)}
 
     def __init__(self, model, min_table_pages: int = 1,
                  quantize: Optional[str] = None, mesh=None,
@@ -832,7 +824,7 @@ class JittedPagedDecoder:
             self._quant_idx = {}
         # page-table width floor: with the default 1 the table width is
         # next_pow2(longest sequence's pages), which recompiles the
-        # decode/verify/chunk programs every time the running batch
+        # decode/chunk/ragged programs every time the running batch
         # crosses a width bucket; pinning it at the pool's worst case
         # (ceil(max_position / page_size) rounded up) trades a bounded
         # amount of gather work for a FIXED program signature — the
@@ -970,7 +962,7 @@ class JittedPagedDecoder:
     #: (everything host-shaped rides replicated; pools shard on the
     #: kv-head axis; the param list gets its per-param spec list)
     _TP_N_REPLICATED = {"decode": 7, "prefill": 5, "prefix": 7,
-                        "verify": 7, "ragged": 8}
+                        "ragged": 8}
 
     def _mesh_wrap(self, mode, fn):
         """shard_map a program body over the tensor mesh (identity on
@@ -991,7 +983,7 @@ class JittedPagedDecoder:
         in_specs = (list(self._tp_param_specs),
                     *([rep] * self._TP_N_REPLICATED[mode]),
                     pool, pool, pool, pool, rep)
-        n_out = {"verify": 2, "ragged": 3}.get(mode, 1)
+        n_out = 3 if mode == "ragged" else 1
         out_specs = (*([rep] * n_out), pool, pool, pool, pool)
         return shard_map(fn, mesh=self.mesh, in_specs=in_specs,
                          out_specs=out_specs, check_vma=False)
@@ -1086,56 +1078,6 @@ class JittedPagedDecoder:
                                              paged_ctx=ctx)
                         logits = last_logits(hidden, last_idx)
                     return (tail(logits, sampling), *ctx_pools(ctx))
-                finally:
-                    self._restore_params(saved)
-
-        elif mode == "verify":
-            def fn(param_arrays, block, pos, pg, sl, lens, tables,
-                   sampling, k_pages, v_pages, k_scales, v_scales,
-                   wscales):
-                """Speculative-decoding verify: ONE compiled dispatch
-                scores the whole (B, S) block — S = 1 fed token + k
-                draft proposals — against paged KV + the in-flight
-                block suffix (ragged multi-query attention), computes
-                per-row ACCEPT LENGTHS on device, and fuses the bonus
-                token's sampling, so the host boundary stays (batch,)
-                ids + (batch,) accept counts whatever k is."""
-                saved = self._swap_params(param_arrays, wscales)
-                try:
-                    ctx = _TracedPagedContext(k_pages, v_pages, pg, sl,
-                                              lens, tables,
-                                              k_scales=k_scales,
-                                              v_scales=v_scales)
-                    with no_grad():
-                        hidden = model.model(wrap_array(block), pos,
-                                             paged_ctx=ctx)
-                        logits = model._logits_of(hidden)
-                    lg = logits._data.astype(jnp.float32)   # (B, S, V)
-                    # targets[b, s] = the target's own next token after
-                    # block[b, :s+1] — the greedy-exactness oracle
-                    targets = jnp.argmax(lg, axis=-1).astype(jnp.int32)
-                    match = (block[:, 1:] == targets[:, :-1]) \
-                        .astype(jnp.int32)
-                    accept = jnp.sum(jnp.cumprod(match, axis=1),
-                                     axis=1).astype(jnp.int32)  # (B,)
-                    pools = ctx_pools(ctx)
-                    if sample == "greedy":
-                        ids = jnp.take_along_axis(
-                            targets, accept[:, None], axis=1)[:, 0]
-                        return ids, accept, *pools
-                    bonus = jnp.take_along_axis(
-                        lg, accept[:, None, None], axis=1)[:, 0]
-                    if sample == "draw":
-                        seeds, temps, flags = sampling
-                        # the bonus token's absolute position — sampled
-                        # rows ride with accept == 0 (host feeds them
-                        # unmatched draft slots), so this replays the
-                        # plain decode path's (seed, position) draw
-                        ctrs = pos + accept + 1
-                        ids = fused_sample(bonus, seeds, ctrs, temps,
-                                           flags)
-                        return ids, accept, *pools
-                    return bonus, accept, *pools   # logits escape hatch
                 finally:
                     self._restore_params(saved)
 
@@ -1247,7 +1189,7 @@ class JittedPagedDecoder:
                         # ctx + q_len for decode/chunk rows, the
                         # bonus position ctx + accept + 1 for verify
                         # rows: the SAME (seed, position) threefry
-                        # draw every legacy mode replays
+                        # draw the decode and prefill programs make
                         ctrs = (ctx_lens + q_lens - nd
                                 + accept).astype(jnp.int32)
                         ids_out = fused_sample(lg_sel, seeds, ctrs,
@@ -1591,10 +1533,11 @@ class JittedPagedDecoder:
         return out[:b]
 
     @staticmethod
-    def _verify_sampling_args(sampling):
-        """Verify-tail variant of ``_sampling_args``: no host-side
-        counters — the bonus draw's position is ``pos + accept + 1``,
-        computed IN-PROGRAM from the device-side accept length."""
+    def _ragged_sampling_args(sampling):
+        """The ragged program's variant of ``_sampling_args``: no
+        host-side counters — a row's draw position is ``ctx + span -
+        drafts + accept``, computed IN-PROGRAM from the device-side
+        accept length."""
         if sampling is None:
             return False, ()
         seeds, temps, flags = sampling
@@ -1603,61 +1546,6 @@ class JittedPagedDecoder:
         return "draw", (jnp.asarray(np.asarray(seeds, np.uint32)),
                         jnp.asarray(np.asarray(temps, np.float32)),
                         jnp.asarray(np.asarray(flags, bool)))
-
-    def verify(self, cache: PagedKVCache, seq_ids, block_np,
-               positions_np, sampling=None):
-        """Speculative verify: score a (batch, S) token block — each
-        row's last fed token followed by S-1 draft proposals — in ONE
-        compiled multi-token step over the paged cache, replacing S-1
-        bandwidth-bound decode dispatches with one compute-dense pass.
-
-        block_np (batch, S) int32; positions_np (batch,) int32 — each
-        row's current length (the block's first rope position).  All S
-        positions' KV are written and the lengths advance by S; the
-        CALLER rolls back to the verified length with
-        ``cache.truncate(sid, pos + accept + 1)`` (the page-granular
-        partial rollback — pages stay mapped inside the admission
-        reservation, rejected slots are simply rewritten later).
-
-        Returns ``(out, accept)``: ``accept`` (batch,) int32 counts the
-        leading draft tokens the target reproduced; ``out`` is the
-        bonus token ids (batch,) int32 under fused sampling, or the
-        bonus position's logits row (batch, vocab) f32 on the
-        ``sampling=None`` escape hatch.  With ``sampling=(seeds,
-        temps, flags)`` sampled rows draw at position pos+accept+1 with
-        the same (seed, position) threefry key the plain decode path
-        uses."""
-        b, s = block_np.shape
-        if int(positions_np.max()) + s > self.max_position:
-            raise ValueError(
-                f"verify through position {int(positions_np.max()) + s} "
-                f"exceeds max_position_embeddings ({self.max_position})")
-        before = [cache.length(sid) for sid in seq_ids]
-        # all-or-nothing: mid-batch exhaustion must not strand rows
-        cache.allocate_batch_atomic(seq_ids, s)
-        pg, sl = cache.plan_write(seq_ids, s)
-        cache.advance(seq_ids, s)
-        needed = max(len(cache._seq_pages.get(sid, ()))
-                     for sid in seq_ids)
-        tabs, lens = cache.page_table(
-            seq_ids, max_pages=max(next_pow2(needed),
-                                   self.min_table_pages))
-        sample, s_args = self._verify_sampling_args(sampling)
-        try:
-            _maybe_lose_buffers(cache, seq_ids)
-            out, accept, *pools = self._program(
-                "verify", sample)(
-                self._param_arrays(),
-                jnp.asarray(block_np.astype(np.int32)),
-                jnp.asarray(positions_np.astype(np.int32)),
-                jnp.asarray(pg), jnp.asarray(sl), lens, tabs, s_args,
-                *self._pool_args(cache), self._wscale_args())
-        except BaseException:
-            self._recover_pools(cache)
-            self._rollback_lengths(cache, seq_ids, before)
-            raise
-        self._store_pools(cache, *pools)
-        return np.asarray(out), np.asarray(accept)
 
     def ragged_step(self, cache: PagedKVCache, seq_ids, rows, ctxs,
                     n_drafts=None, sampling=None):
@@ -1694,8 +1582,9 @@ class JittedPagedDecoder:
         int32 under ``sampling=(seeds, temps, flags)`` / greedy, or the
         selected position's logits rows on the ``sampling=None`` escape
         hatch.  The CALLER rolls verify rows back to their accepted
-        length with ``cache.truncate`` (same contract as
-        :meth:`verify`).
+        length with ``cache.truncate(sid, ctx + accept + 1)`` (pages
+        stay mapped inside the admission reservation, rejected slots
+        are rewritten later).
 
         This is :meth:`ragged_launch` followed at once by
         :meth:`ragged_fetch`: a caller that has host work to do while
@@ -1861,7 +1750,7 @@ class JittedPagedDecoder:
                 state_slots=cache.state_slots,
                 slots_zeroed=sum(1 for k in ctxs if int(k) == 0))
         with monitor.span("engine/dispatch", into=into):
-            sample, s_args = self._verify_sampling_args(sampling)
+            sample, s_args = self._ragged_sampling_args(sampling)
             try:
                 _maybe_lose_buffers(cache, seq_ids)
                 if sample:
@@ -2118,7 +2007,7 @@ class JittedPagedDecoder:
                 jnp.asarray(pos_steps), tabs,
                 *self._pool_args(cache), self._wscale_args())
         except BaseException:
-            # same contract as step()/verify(): rebuild the donated
+            # same contract as step(): rebuild the donated
             # pools only if they were actually consumed, and roll the
             # lengths back so the exact chunk can be replayed — a
             # host-side fault must not zero batchmates' KV (the engine's

@@ -107,30 +107,25 @@ class TestServeBench:
         # the timings themselves are printed, and decide nothing
         assert summary["chat_ttft_p50_flood_chunked_s"] is not None
         assert summary["chat_ttft_p50_flood_fifo_s"] is not None
-        # ISSUE 17 CI satellite: the mixed-batch dispatch pair — the
-        # unified window is single-program (ragged-mode only, one
-        # dispatch per iteration), the legacy baseline is the
-        # multi-dispatch composition, and the collapse shows as
-        # strictly fewer target-model dispatches on the SAME workload
+        # ISSUE 17 CI satellite: the mixed-batch lane — the chunked
+        # window is single-program (ragged-mode only, one dispatch per
+        # iteration, no step down the failure ladder)
         mixed = {x["lane"]: x for x in lines
                  if x.get("lane", "").startswith("mixed-batch-")}
-        assert set(mixed) == {"mixed-batch-unified", "mixed-batch-legacy"}
-        uni, leg = mixed["mixed-batch-unified"], mixed["mixed-batch-legacy"]
+        assert set(mixed) == {"mixed-batch-unified"}
+        uni = mixed["mixed-batch-unified"]
         assert uni["dispatches"]["ragged"] > 0
-        assert all(uni["dispatches"][m] == 0
-                   for m in ("prefill", "chunk", "decode", "verify"))
-        assert leg["dispatches"]["ragged"] == 0
-        assert leg["dispatches"]["decode"] > 0
-        assert 0 < uni["dispatches_target_model"] \
-            < leg["dispatches_target_model"]
+        assert all(n == 0 for m, n in uni["dispatches"].items()
+                   if m != "ragged")
+        assert uni["dispatches_target_model"] \
+            == uni["dispatches"]["ragged"]
         assert uni["unified_fallbacks"] == 0
-        # same workload, same work: every request runs to budget, so
-        # the token totals agree exactly (steps may batch differently
-        # under thread timing)
-        assert uni["generated_tokens"] == leg["generated_tokens"] > 0
-        assert uni["steps"] > 0 and leg["steps"] > 0
-        assert uni["tokens_per_s"] > 0 and leg["tokens_per_s"] > 0
-        assert uni["jit_recompiles"] == leg["jit_recompiles"] == 0
+        # every request runs to budget: 4 floods and 2 rags of 6 tokens,
+        # 4 chats of 8
+        assert uni["generated_tokens"] == 4 * 6 + 2 * 6 + 4 * 8
+        assert uni["steps"] > 0
+        assert uni["tokens_per_s"] > 0
+        assert uni["jit_recompiles"] == 0
         assert uni["audit_error_findings"] == 0
         assert summary["dispatches_unified"] == \
             uni["dispatches_target_model"]

@@ -301,8 +301,7 @@ class TestPreemptResumeAndReplay:
         """A fault that follows one sequence fails every step it is a row
         of: the ladder over the ragged step (whole once more, then by
         halves) ends at that row alone, the other 15 are served as if it
-        had never been there, and the unified step is not latched off
-        (there is no other)."""
+        had never been there."""
         rng = np.random.default_rng(8)
         prompts = [rng.integers(0, 96, n).astype(np.int32)
                    for n in rng.integers(5, 31, 16)]
@@ -317,7 +316,6 @@ class TestPreemptResumeAndReplay:
                 assert reqs[5].seq_id == 5      # admitted in order
                 outs = [r.result(timeout=300)
                         for i, r in enumerate(reqs) if i != 5]
-                assert not eng._unified_off
                 assert eng.cache.slots_in_use == 0
             finally:
                 eng.stop()
@@ -356,7 +354,6 @@ class TestWhatCannotHoldRefuses:
         (dict(draft_model="model"), "rolled out of it"),
         (dict(kv_quant="int8"), "no K/V page to quantise"),
         (dict(tp=2), "g_proj"),
-        (dict(unified_step=False), "only the ragged unified step"),
         (dict(prefill_chunk_tokens=None), "only the ragged unified step"),
     ])
     def test_at_construction(self, model, kw, reason):

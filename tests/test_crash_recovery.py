@@ -196,8 +196,8 @@ class TestSurvivorReplay:
         with make_engine(model, **ekw) as eng:
             reqs = submit_and_ripen(eng, prompts, 10)
             # nth=2 skips the draft propose scan (match 1) and lands
-            # on the TARGET verify dispatch — both pools then replay
-            # in lockstep
+            # on the TARGET's ragged dispatch of the verify rows — both
+            # pools then replay in lockstep
             install_at_step_boundary(eng, faults.FaultPlan(
                 [{"site": "buffer_loss", "nth": 2}]))
             outs = [r.result(timeout=120) for r in reqs]
@@ -927,8 +927,10 @@ class TestJournalRecovery:
         the dead on restart."""
         rng = np.random.default_rng(46)
         j = self._journal(tmp_path)
+        # the fault follows the second admission (seq 1): whichever way
+        # its prompt reaches the device, it is the one that goes
         plan = faults.FaultPlan(
-            [{"site": "prefill", "nth": 2}])
+            [{"site": "prefill", "seq_id": 1}])
         with faults.installed(plan):
             with make_engine(model, journal=j) as eng:
                 ok = eng.submit(rng.integers(0, 64, (5,)),

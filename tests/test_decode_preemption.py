@@ -173,13 +173,14 @@ class TestDecodePreemptBitExact:
                                        prefill_chunk_tokens=8)
         np.testing.assert_array_equal(got, want)
 
-    def test_legacy_split_step_path(self, model):
-        """The pre-unification prefill/decode split path preempts and
-        resumes mid-decode identically."""
+    def test_host_sampling_path(self, model):
+        """With sampling on the host (logits rows cross, nothing is in
+        flight) a victim pauses mid-decode and resumes identically."""
         rng = np.random.default_rng(26)
         p = rng.integers(0, 64, (24,)).astype("int32")
         want = reference(model, p, 8)
-        got = self._decode_preempt_run(model, p, 8, unified_step=False)
+        got = self._decode_preempt_run(model, p, 8,
+                                       sample_on_device=False)
         np.testing.assert_array_equal(got, want)
 
     def test_decode_preempt_off_preserves_run_to_completion(self, model):

@@ -285,16 +285,37 @@ class TestDrain:
         assert eng.cache.free_pages == 64             # pool reclaimed
 
 
+def dispatch_counts():
+    """engine_dispatches_total by mode, every label the counter has
+    ever had (a mode that is gone must stay at zero)."""
+    m = monitor.get_registry().get("engine_dispatches_total")
+    return {mode: (0.0 if m is None else m.value(mode=mode))
+            for mode in ("ragged", "prefill", "chunk", "decode", "verify")}
+
+
+def moved(before, after):
+    return {m for m in after if after[m] != before[m]}
+
+
 class TestQuarantine:
-    def test_poisoned_prefill_errors_only_that_request(self, model):
+    @pytest.mark.parametrize("chunk", [None, 8],
+                             ids=["whole_prompt", "chunked"])
+    def test_poisoned_prefill_errors_only_that_request(self, model, chunk):
+        """A prefill fault that FOLLOWS a request (seq 1 = the second
+        admission) quarantines exactly it, whichever way its prompt
+        reaches the device: whole, on the prefill program (first
+        failure quarantines), or a chunk row of the ragged step (the
+        step is retried whole, then by halves, and the row that fails
+        alone goes)."""
         rng = np.random.default_rng(7)
         prompts = [rng.integers(0, 64, (5,)).astype("int32")
                    for _ in range(3)]
         expects = [reference(model, p, 6) for p in prompts]
         before_q = counter_value("quarantined_requests_total")
-        plan = faults.FaultPlan([{"site": "prefill", "nth": 2}])
+        before_d = dispatch_counts()
+        plan = faults.FaultPlan([{"site": "prefill", "seq_id": 1}])
         with faults.installed(plan):
-            with make_engine(model) as eng:
+            with make_engine(model, prefill_chunk_tokens=chunk) as eng:
                 reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
                 with pytest.raises(faults.FaultError):
                     reqs[1].result(timeout=120)
@@ -305,6 +326,80 @@ class TestQuarantine:
                          msg="pool reclaim")
                 assert eng._reserved_pages == 1
         assert counter_value("quarantined_requests_total") == before_q + 1
+        assert moved(before_d, dispatch_counts()) == (
+            {"ragged"} if chunk else {"ragged", "prefill"})
+
+    def test_one_shot_prefill_fault_recovers_and_quarantines_nobody(
+            self, model):
+        """A chunk row's transient fault (the 2nd admission's first
+        chunk, once) fails its step; the step is retried whole on the
+        ragged program and everyone finishes with the reference's
+        tokens."""
+        rng = np.random.default_rng(14)
+        prompts = [rng.integers(0, 64, (n,)).astype("int32")
+                   for n in (5, 20, 9)]
+        expects = [reference(model, p, 6) for p in prompts]
+        before_q = counter_value("quarantined_requests_total")
+        before_r = counter_value("decode_retries_total")
+        before_f = counter_value("engine_unified_fallbacks_total")
+        before_d = dispatch_counts()
+        plan = faults.FaultPlan([{"site": "prefill", "nth": 2}])
+        with faults.installed(plan):
+            with make_engine(model, prefill_chunk_tokens=8) as eng:
+                reqs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+                for r, want in zip(reqs, expects):
+                    np.testing.assert_array_equal(
+                        r.result(timeout=120), want)
+                wait_for(lambda: eng.cache.free_pages == 64,
+                         msg="pool reclaim")
+                assert eng._reserved_pages == 1
+        assert [f[0] for f in plan.fired] == ["prefill"]
+        assert counter_value("quarantined_requests_total") == before_q
+        assert counter_value("decode_retries_total") == before_r + 1
+        assert counter_value("engine_unified_fallbacks_total") \
+            == before_f + 1
+        assert moved(before_d, dispatch_counts()) == {"ragged"}
+
+    def test_sticky_chunk_row_is_quarantined_alone_beside_decode_rows(
+            self, model):
+        """A fault that follows ONE chunk row of a step that also holds
+        decode rows: the ladder bisects the step's rows, chunk and
+        decode alike; the chunk row fails alone and goes with the error
+        that killed it, the decode rows' tokens are exact."""
+        rng = np.random.default_rng(15)
+        short = [rng.integers(0, 64, (n,)).astype("int32")
+                 for n in (5, 7)]
+        long = rng.integers(0, 64, (30,)).astype("int32")
+        expects = [reference(model, p, 12) for p in short]
+        before_q = counter_value("quarantined_requests_total")
+        before_r = counter_value("decode_retries_total")
+        before_d = dispatch_counts()
+        # seqs 0 and 1 decode; seq 2 is the long prompt, poisoned for
+        # good.  The delay holds the decoders in flight while it is
+        # admitted
+        plan = faults.FaultPlan([
+            {"site": "decode_step", "kind": "delay", "delay_s": 0.01},
+            {"site": "prefill_chunk", "seq_id": 2}])
+        with faults.installed(plan):
+            with make_engine(model, prefill_chunk_tokens=8) as eng:
+                reqs = [eng.submit(p, max_new_tokens=12) for p in short]
+                wait_for(lambda: all(len(r.generated) >= 1 for r in reqs),
+                         msg="decode rows in flight")
+                assert not any(r.done.is_set() for r in reqs)
+                bad = eng.submit(long, max_new_tokens=4)
+                with pytest.raises(faults.FaultError):
+                    bad.result(timeout=120)
+                for r, want in zip(reqs, expects):
+                    np.testing.assert_array_equal(
+                        r.result(timeout=120), want)
+                wait_for(lambda: eng.cache.free_pages == 64,
+                         msg="pool reclaim")
+                assert eng._reserved_pages == 1
+        assert counter_value("quarantined_requests_total") == before_q + 1
+        # it failed BESIDE decode rows: a chunk row alone is one retry,
+        # with one decode row it is the whole retry and two halves
+        assert counter_value("decode_retries_total") >= before_r + 3
+        assert moved(before_d, dispatch_counts()) == {"ragged"}
 
     def test_decode_bisection_ejects_poisoned_sharer(self, model):
         """A sticky mid-decode fault on one prefix-cache sharer: the

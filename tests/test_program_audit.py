@@ -155,7 +155,8 @@ class TestEngineDecodeAudit:
 
 
 class TestEngineVerifyAudit:
-    """ISSUE 6 CI satellite: the speculative verify program is certified
+    """ISSUE 6 CI satellite: the ragged program of a SPECULATING engine
+    (every row a verify block of spec_k + 1 tokens) is certified
     transfer-free (ids + accept counts only), donation-intact on BOTH
     page pools, and free of baked [B, k]-shaped host constants — the
     draft block must ride as a traced argument, never a const."""
@@ -168,12 +169,12 @@ class TestEngineVerifyAudit:
 
     def test_verify_is_transfer_free_and_bakes_no_block(self):
         with self._spec_engine() as eng:
-            audit = analysis.audit_engine(eng, mode="verify")
+            audit = analysis.audit_engine(eng, mode="ragged")
             assert audit.host_transfer_findings == [], audit.report()
             # no [B, k]-shaped (or any other) host constant baked in
             assert not audit.by_rule("const-capture"), audit.report()
             # the fused-draw variant keeps the same contract
-            draw = analysis.audit_engine(eng, mode="verify",
+            draw = analysis.audit_engine(eng, mode="ragged",
                                          sample="draw")
             assert draw.host_transfer_findings == [], draw.report()
             assert not draw.by_rule("const-capture"), draw.report()
@@ -181,16 +182,25 @@ class TestEngineVerifyAudit:
     def test_verify_keeps_both_pools_donated(self):
         with self._spec_engine() as eng:
             pool_bytes = int(np.prod(eng.cache.k_pages[0].shape)) * 4
-            audit = analysis.audit_engine(eng, mode="verify",
+            audit = analysis.audit_engine(eng, mode="ragged",
                                           donation_bytes=pool_bytes)
             assert not audit.by_rule("missed-donation"), audit.report()
             assert not audit.by_rule("output-transfer"), audit.report()
 
-    def test_verify_mode_requires_draft_engine(self):
+    def test_the_ragged_audit_spans_the_verify_block(self):
+        """The audited shape is the widest row the engine composes: the
+        verify block of a speculating engine, one token without a draft;
+        no program of its own carries a verify block any more."""
+        from paddle_tpu.analysis.program_audit import engine_program_spec
         from paddle_tpu.inference.continuous import ContinuousBatchingEngine
+        with self._spec_engine() as eng:
+            _, _, args, meta = engine_program_spec(eng, "ragged")
+            assert args[1].shape == (meta["batch"], 4)   # pow2(3 + 1)
         with ContinuousBatchingEngine(_tiny_model(), total_pages=32,
                                       page_size=8) as eng:
-            with pytest.raises(ValueError, match="draft_model"):
+            _, _, args, meta = engine_program_spec(eng, "ragged")
+            assert args[1].shape == (meta["batch"], 1)
+            with pytest.raises(ValueError, match="'chunk' or 'ragged'"):
                 analysis.audit_engine(eng, mode="verify")
 
 

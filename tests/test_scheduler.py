@@ -374,17 +374,23 @@ class TestPreemptResume:
 
 class TestChunkFaultIsolation:
     def test_poisoned_chunk_quarantines_only_its_request(self, model):
-        """A fault on the 3rd chunk of the batch request errors only
-        it: pages from its earlier chunks are reclaimed, its batchmate
-        (another tenant) finishes bit-exact, and the engine keeps
-        serving."""
+        """A fault that follows the batch request from its 3rd chunk
+        on errors only it: the step is retried whole, then by halves,
+        the chunk row fails alone; pages from its earlier chunks are
+        reclaimed, its batchmate (another tenant) finishes bit-exact,
+        and the engine keeps serving."""
         rng = np.random.default_rng(8)
         long_p = rng.integers(0, 64, (40,)).astype("int32")
         mate_p = rng.integers(0, 64, (6,)).astype("int32")
         want_mate = reference(model, mate_p, 6)
         before_q = counter_value("quarantined_requests_total")
+        # sticky FROM the third chunk: every rule counts seq 0's chunk
+        # dispatches, so nth = 3, 4, 5, ... fire on the third chunk, on
+        # its whole retry and on every probe of the ladder after it (a
+        # one-shot fault would be absorbed by the retry)
         plan = faults.FaultPlan([
-            {"site": "prefill_chunk", "seq_id": 0, "nth": 3}])
+            {"site": "prefill_chunk", "seq_id": 0, "nth": n}
+            for n in range(3, 12)])
         with faults.installed(plan):
             with make_engine(model, max_batch=2,
                              prefill_chunk_tokens=8) as eng:

@@ -165,9 +165,8 @@ class TestSpecScheduling:
 
     def test_verify_is_one_dispatch_per_step(self, target, clone_draft):
         """No per-proposed-token host loop: exactly ONE verify-bearing
-        dispatch per engine decode step — a ``decoder.verify`` call on
-        the legacy composition, a ``ragged_step`` call carrying draft
-        rows on the unified step (ISSUE 17)."""
+        dispatch per engine decode step — a ``ragged_step`` call that
+        carries draft rows (ISSUE 17)."""
         from paddle_tpu.inference.continuous import ContinuousBatchingEngine
 
         calls = []
@@ -175,12 +174,7 @@ class TestSpecScheduling:
                                       max_batch=2,
                                       draft_model=clone_draft,
                                       spec_tokens=3) as eng:
-            orig_v = eng._decoder.verify
             orig_r = eng._decoder.ragged_step
-
-            def counting_verify(*a, **kw):
-                calls.append(1)
-                return orig_v(*a, **kw)
 
             def counting_ragged(*a, **kw):
                 nds = kw.get("n_drafts")
@@ -188,7 +182,6 @@ class TestSpecScheduling:
                     calls.append(1)
                 return orig_r(*a, **kw)
 
-            eng._decoder.verify = counting_verify
             eng._decoder.ragged_step = counting_ragged
             eng.submit(_prompts([5], seed=2)[0],
                        max_new_tokens=12).result(timeout=300)

@@ -5,8 +5,8 @@ The acceptance core is BIT-EXACT greedy parity: the same prompt set
 through a 1-chip engine and a TP=2 engine (virtual CPU devices — the
 conftest splits the host into 8) must produce identical tokens on the
 host-logits escape hatch, across every serving composition the engine
-dispatches — the unified ragged step, the legacy decode/prefill
-programs, chunked prefill, prefix-cache hits, and speculative verify.
+dispatches — the unified ragged step, its fused sampling tail,
+chunked prefill, prefix-cache hits, and speculative verify.
 Column-parallel projections are exact by construction; the one f32
 ``psum`` per block closes each row-parallel projection with the same
 summands on every chip, so greedy argmax never diverges.
@@ -172,8 +172,10 @@ class TestEngineParity:
     def test_unified_ragged_step(self):
         assert_parity(_prompts((5, 9, 13, 20)))
 
-    def test_legacy_programs(self):
-        assert_parity(_prompts((5, 9, 3)), unified_step=False)
+    def test_fused_sampling_tail(self):
+        # the programs that end in the on-device sampler (ids cross the
+        # host boundary, not logits), sharded against one chip
+        assert_parity(_prompts((5, 9, 3)), sample_on_device=True)
 
     def test_chunked_prefill(self):
         # 40-token prompts chunk at 8 through the prefix program
@@ -210,6 +212,38 @@ class TestEngineParity:
         # same-seed draft accepts ~everything: the verify program is
         # the hot path, and its sharded twin must match token-for-token
         assert_parity(_prompts((6, 11, 4)), draft=True)
+
+    def test_failure_ladder_over_a_mesh(self):
+        """The ladder over the SHARDED ragged step: one transient loss
+        of the donated (sharded) pools — rebuilt sharded, survivors
+        replayed — and a decode fault that follows seq 1.  Exactly that
+        request goes; the others' tokens are the 1-chip engine's."""
+        from paddle_tpu.testing import faults
+        prompts = _prompts((5, 9, 13))
+        want = greedy_run(prompts, prefill_chunk_tokens=8)
+        # buffer_loss fires once a program call: 3 is a step that holds
+        # every row (the three prompts are one chunk step or two)
+        plan = faults.FaultPlan([
+            {"site": "buffer_loss", "nth": 3},
+            {"site": "decode_step", "seq_id": 1}])
+        try:
+            with faults.installed(plan), ContinuousBatchingEngine(
+                    tiny_model(), total_pages=128, page_size=8,
+                    max_batch=4, sample_on_device=False, tp=2,
+                    prefill_chunk_tokens=8) as eng:
+                reqs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+                with pytest.raises(faults.FaultError):
+                    reqs[1].result(timeout=600)
+                got = {i: np.asarray(reqs[i].result(timeout=600))
+                       for i in (0, 2)}
+                sharding = eng.cache.k_pages[0].sharding
+                assert dict(sharding.mesh.shape) == {"tensor": 2}
+                assert eng.cache.free_pages == eng.cache.total_pages
+        finally:
+            faults.clear()
+        assert {f[0] for f in plan.fired} == {"buffer_loss", "decode_step"}
+        for i in (0, 2):
+            assert np.array_equal(got[i], want[i])
 
     def test_int8_collectives_within_tolerance(self):
         # quantized all-reduces are NOT bit-exact (absmax-int8 round

@@ -255,7 +255,8 @@ class TestRequestIdContinuity:
             assert [r.request_id for r in reqs] == ["solo"]
 
     def test_error_results_are_cached(self, model):
-        plan = faults.FaultPlan([{"site": "prefill", "nth": 1}])
+        # the fault follows the request (seq 0)
+        plan = faults.FaultPlan([{"site": "prefill", "seq_id": 0}])
         with faults.installed(plan):
             with ContinuousBatchingEngine(model, total_pages=32,
                                           page_size=8) as eng:

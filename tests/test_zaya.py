@@ -574,7 +574,6 @@ class TestWhatCannotHoldRefuses:
         (dict(draft_model="model"), "rolled out of it"),
         (dict(kv_quant="int8"), "not been held to a reference in int8"),
         (dict(tp=2), "an expert block: the plan has no placement"),
-        (dict(unified_step=False), "only the ragged unified step"),
         (dict(prefill_chunk_tokens=None), "only the ragged unified step"),
     ])
     def test_at_construction(self, model, kw, reason):

@@ -65,13 +65,11 @@ against a 1-replica fleet: the autoscaler spawns a replica under
 pressure, the scaled fleet serves a compile-free window, and calm
 drains it back to the floor with zero failed requests.
 
-Mixed-batch dispatch lane (ISSUE 17): the scenario matrix also runs
-the flood workload through the legacy multi-dispatch composition
-(``unified_step=False``) and prints a ``mixed-batch-unified`` /
-``mixed-batch-legacy`` JSON line pair quoting tokens/s, per-class
-TTFT/TPOT and the ``engine_dispatches_total`` mode split, gating that
-the unified window is single-program (ragged-mode dispatches only,
-strictly fewer than the legacy baseline, zero fallbacks).
+Mixed-batch dispatch lane (ISSUE 17): the scenario matrix prints a
+``mixed-batch-unified`` JSON line for the flood workload quoting
+tokens/s, per-class TTFT/TPOT and the ``engine_dispatches_total`` mode
+split, gating that the window is single-program (ragged-mode dispatches
+only, zero fallbacks).
 """
 from __future__ import annotations
 
@@ -527,8 +525,7 @@ def _build_tiny_model(vocab=64, hidden=32):
 
 
 def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
-                      flood_n=4, rag_n=2, chat_n=6, seed=0,
-                      unified=True) -> dict:
+                      flood_n=4, rag_n=2, chat_n=6, seed=0) -> dict:
     """One scenario-matrix serving run: ``flood_n`` long-prompt
     (96-token, 8x chunk) offline-batch requests, ``rag_n`` shared-
     system-prefix RAG requests, and ``chat_n`` short interactive
@@ -548,12 +545,8 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
     chunk/prefix program shape the (position-derived, never
     timing-derived) chunk plan can produce.
 
-    ``unified=False`` flips the engine to the legacy multi-dispatch
-    composition (one prefill/chunk/decode/verify program per phase) —
-    the mixed-batch baseline the unified ragged step is measured
-    against.  Both variants quote the ``engine_dispatches_total`` mode
-    split, steps, tokens/s and wall time over the measured window, so
-    the 5->1 dispatch collapse reads straight off the JSON lines."""
+    Every lane quotes the ``engine_dispatches_total`` mode split,
+    steps, tokens/s and wall time over the measured window."""
     import time
 
     import numpy as np
@@ -572,8 +565,7 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
     with ContinuousBatchingEngine(
             model, total_pages=192, page_size=8, max_batch=4,
             prefill_chunk_tokens=chunk_tokens,
-            min_table_pages=16, max_queue=64,
-            unified_step=unified) as eng:
+            min_table_pages=16, max_queue=64) as eng:
         n_sub = [0]
 
         def submit(prompt, max_new, priority, tenant):
@@ -632,12 +624,11 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
         if rag_n:
             rag_req().result(timeout=600)
         wave()
-        if unified:
-            # the unified step buckets (rows, max span) JOINTLY, so
-            # admission timing can realize a bucket combo the first
-            # warm wave missed; a second pass keeps the measured
-            # window compile-free
-            wave()
+        # the unified step buckets (rows, max span) JOINTLY, so
+        # admission timing can realize a bucket combo the first
+        # warm wave missed; a second pass keeps the measured
+        # window compile-free
+        wave()
 
         before = monitor.snapshot()
         steps0 = eng.steps
@@ -648,11 +639,9 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
         after = monitor.snapshot()
         audit_errors = None
         if chunk_tokens:
-            # audit the program that actually served the window: the
-            # unified ragged step, or the legacy chunk program
-            audit = analysis.audit_engine(
-                eng, mode="ragged" if unified else "chunk",
-                publish=False)
+            # audit the program that served the window
+            audit = analysis.audit_engine(eng, mode="ragged",
+                                          publish=False)
             audit_errors = sum(1 for f in audit.findings
                                if f.severity == "error")
 
@@ -679,8 +668,7 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
     dispatches = {
         m: int(_counter_delta(before, after, "engine_dispatches_total",
                               {"mode": m}))
-        for m in ("ragged", "prefill", "chunk", "decode", "verify",
-                  "draft")}
+        for m in ("ragged", "prefill", "chunk", "draft")}
     dispatches_target = sum(v for m, v in dispatches.items()
                             if m != "draft")
     per_class = {}
@@ -715,7 +703,6 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
         "lane": "scenario-matrix",
         "chunk_tokens": chunk_tokens,
         "classes": bool(use_classes),
-        "unified": bool(unified),
         "flood": flood_n, "rag": rag_n, "chat": chat_n,
         "chat_ttft_p50_s": _p50(chat_ttfts),
         "chat_ttft_mean_s": (sum(chat_ttfts) / len(chat_ttfts)
@@ -739,25 +726,20 @@ def run_scenario_lane(model=None, chunk_tokens=16, use_classes=True,
 
 
 def run_scenario_matrix(argv) -> int:
-    """The ``--scenario-matrix`` lane: four runs of the same mixed
+    """The ``--scenario-matrix`` lane: three runs of the same mixed
     workload — (1) chunked+classes without the flood (the chat-class
-    no-flood TTFT baseline), (2) chunked+classes with the flood under
-    the unified ragged step (one JSON line per class), (3) the same
-    flood through the legacy multi-dispatch composition
-    (``unified_step=False`` — the mixed-batch dispatch baseline),
-    (4) unchunked FIFO with the flood (the stall the scheduler exists
-    to prevent).  Gates: under the flood every chat request has its
-    first token before the first flood request is done; in the FIFO
-    baseline none has (it demonstrably stalled); zero recompiles in
-    every measured window; the serving program audited transfer-free;
-    batch-class preemption actually exercised; and the dispatch
-    collapse itself — the unified window
-    issues ONLY ragged-mode dispatches (zero prefill/chunk/decode/
-    verify programs), strictly fewer target-model dispatches than the
-    legacy window on the same workload, and zero unified->legacy
-    fallbacks.  Every timing (chat TTFT by lane, tokens/s unified vs
-    legacy) is quoted in the summary JSON and decides nothing: a CPU
-    that other processes share measures the neighbours."""
+    no-flood TTFT baseline), (2) chunked+classes with the flood (one
+    JSON line per class), (3) unchunked FIFO with the flood (the stall
+    the scheduler exists to prevent).  Gates: under the flood every
+    chat request has its first token before the first flood request is
+    done; in the FIFO baseline none has (it demonstrably stalled); zero
+    recompiles in every measured window; the serving program audited
+    transfer-free; batch-class preemption actually exercised; and the
+    chunked window is single-program — ONLY ragged-mode dispatches
+    (zero prefill/chunk programs) and zero steps down the failure
+    ladder.  Every timing (chat TTFT by lane, tokens/s) is quoted in
+    the summary JSON and decides nothing: a CPU that other processes
+    share measures the neighbours."""
     chunk = _int_arg(argv, "chunk-tokens", 16)
     flood_n = _int_arg(argv, "flood", 4)
     rag_n = _int_arg(argv, "rag", 2)
@@ -768,38 +750,31 @@ def run_scenario_matrix(argv) -> int:
                               rag_n=rag_n, chat_n=chat_n)
     mixed = run_scenario_lane(model, chunk_tokens=chunk, flood_n=flood_n,
                               rag_n=rag_n, chat_n=chat_n)
-    legacy = run_scenario_lane(model, chunk_tokens=chunk, flood_n=flood_n,
-                               rag_n=rag_n, chat_n=chat_n, unified=False)
-    # the FIFO stall baseline models the HISTORICAL engine (no
-    # scheduler, no chunking, multi-dispatch composition) — running it
-    # legacy also keeps its unchunked full-prompt rows out of the
-    # unified bucket space
+    # the FIFO stall baseline: no scheduler classes, no chunking
+    # (whole-prompt prefill on its own programs, decode rows ragged)
     fifo = run_scenario_lane(model, chunk_tokens=None, use_classes=False,
-                             flood_n=flood_n, rag_n=rag_n, chat_n=chat_n,
-                             unified=False)
+                             flood_n=flood_n, rag_n=rag_n, chat_n=chat_n)
     for c in SCENARIO_CLASSES:
         if c in mixed["per_class"]:
             print(json.dumps(mixed["per_class"][c], sort_keys=True))
-    for lane, tag in ((mixed, "unified"), (legacy, "legacy")):
-        print(json.dumps({
-            "lane": f"mixed-batch-{tag}",
-            "unified": lane["unified"],
-            "tokens_per_s": lane["tokens_per_s"],
-            "generated_tokens": lane["generated_tokens"],
-            "wall_s": lane["wall_s"],
-            "steps": lane["steps"],
-            "dispatches": lane["dispatches"],
-            "dispatches_target_model": lane["dispatches_target_model"],
-            "dispatches_per_step": lane["dispatches_per_step"],
-            "unified_fallbacks": lane["unified_fallbacks"],
-            "chat_ttft_p50_s": lane["chat_ttft_p50_s"],
-            "chat_ttft_mean_s": lane["chat_ttft_mean_s"],
-            "chat_tpot_mean_s": (lane["per_class"]
-                                 .get("interactive", {})
-                                 .get("tpot_mean_s")),
-            "jit_recompiles": lane["jit_recompiles"],
-            "audit_error_findings": lane["audit_error_findings"],
-        }, sort_keys=True))
+    print(json.dumps({
+        "lane": "mixed-batch-unified",
+        "tokens_per_s": mixed["tokens_per_s"],
+        "generated_tokens": mixed["generated_tokens"],
+        "wall_s": mixed["wall_s"],
+        "steps": mixed["steps"],
+        "dispatches": mixed["dispatches"],
+        "dispatches_target_model": mixed["dispatches_target_model"],
+        "dispatches_per_step": mixed["dispatches_per_step"],
+        "unified_fallbacks": mixed["unified_fallbacks"],
+        "chat_ttft_p50_s": mixed["chat_ttft_p50_s"],
+        "chat_ttft_mean_s": mixed["chat_ttft_mean_s"],
+        "chat_tpot_mean_s": (mixed["per_class"]
+                             .get("interactive", {})
+                             .get("tpot_mean_s")),
+        "jit_recompiles": mixed["jit_recompiles"],
+        "audit_error_findings": mixed["audit_error_findings"],
+    }, sort_keys=True))
     preemptions = (mixed["per_class"]["batch"]["preemptions"]
                    + mixed["per_class"]["batch"]["chunk_deferrals"])
     summary = {
@@ -820,13 +795,9 @@ def run_scenario_matrix(argv) -> int:
         "audit_error_findings": mixed["audit_error_findings"],
         "jit_recompiles": (alone["jit_recompiles"]
                            + mixed["jit_recompiles"]
-                           + legacy["jit_recompiles"]
                            + fifo["jit_recompiles"]),
         "tokens_per_s_unified": mixed["tokens_per_s"],
-        "tokens_per_s_legacy": legacy["tokens_per_s"],
-        "chat_ttft_p50_legacy_s": legacy["chat_ttft_p50_s"],
         "dispatches_unified": mixed["dispatches_target_model"],
-        "dispatches_legacy": legacy["dispatches_target_model"],
         "unified_fallbacks": mixed["unified_fallbacks"],
     }
     print(json.dumps(summary, sort_keys=True))
@@ -867,32 +838,19 @@ def run_scenario_matrix(argv) -> int:
               "prefill — the priority machinery did not engage",
               file=sys.stderr)
         ok = False
-    # dispatch-collapse gates (ISSUE 17): structural, not wall-clock —
-    # CPU CI cannot gate tokens/s, but it CAN prove the unified window
+    # single-program gates (ISSUE 17): structural, not wall-clock —
+    # CPU CI cannot gate tokens/s, but it CAN prove the chunked window
     # served every phase through the one ragged program
     md = mixed["dispatches"]
-    legacy_modes = {m: md[m] for m in ("prefill", "chunk", "decode",
-                                       "verify") if md[m]}
-    if legacy_modes or md["ragged"] <= 0:
+    other_modes = {m: md[m] for m in ("prefill", "chunk") if md[m]}
+    if other_modes or md["ragged"] <= 0:
         print("FAIL: the unified window was not single-program — "
-              f"ragged={md['ragged']}, legacy-mode dispatches="
-              f"{legacy_modes}", file=sys.stderr)
-        ok = False
-    if legacy["dispatches"]["ragged"] != 0:
-        print("FAIL: the unified_step=False baseline issued "
-              f"{legacy['dispatches']['ragged']} ragged dispatch(es) "
-              "— it is not a multi-dispatch baseline", file=sys.stderr)
-        ok = False
-    if not (0 < mixed["dispatches_target_model"]
-            < legacy["dispatches_target_model"]):
-        print("FAIL: unified step did not reduce dispatches — "
-              f"{mixed['dispatches_target_model']} unified vs "
-              f"{legacy['dispatches_target_model']} legacy on the "
-              "same workload", file=sys.stderr)
+              f"ragged={md['ragged']}, other dispatches="
+              f"{other_modes}", file=sys.stderr)
         ok = False
     if mixed["unified_fallbacks"] != 0:
-        print(f"FAIL: {mixed['unified_fallbacks']} unified-step "
-              "fallback(s) to the legacy composition inside the "
+        print(f"FAIL: {mixed['unified_fallbacks']} unified step(s) "
+              "failed and went down the isolation ladder inside the "
               "measured window", file=sys.stderr)
         ok = False
     return 0 if ok else 1
