@@ -129,12 +129,24 @@ def _expert_ffn(x, w1, b1, w2, b2, activation):
 ROUTING_FIELDS = ("slots", "held", "rows", "most", "touched")
 
 #: a share of at most ``DENSE_SHARE x top_k`` experts takes the dense
-#: product, a wider one the grouped.  The two have been measured where the
-#: benchmark's cells stand and nowhere between: 8 held of 256 at top_k 8
-#: (``kimi-linear.train.seq8k``: dense) and 256 of 256 at top_k 8
+#: product, a wider one the grouped.  Measured at 8 held of 256 at top_k 8
+#: (``kimi-linear.train.seq8k``: dense), at 256 of 256 at top_k 8
 #: (``laguna-xs2.serve.agent8``: grouped, 1.41 against 3.16 ms a layer at 8
-#: tokens; PERF.md section 6, PR 33).  2 puts the line just above the first;
-#: where between 16 and 256 experts the products cross is not measured
+#: tokens; PERF.md section 6, PR 33), and ON the line, 16 held of 256 at
+#: top_k 8, experts of 4,096 x 2,048 (``mimo-v2-flash.serve.mixed32``; ms a
+#: layer from the host's clock around 30 queued calls, dense against
+#: grouped, ``benchmark/tests/chip_limits_mimo.py --experts``, PERF.md
+#: section 6, PR 49): 160 tokens (5 pairs an expert, all 16 touched) 1.12
+#: against 1.26; 288 tokens (9 pairs: the step that cell runs 97.6 % of the
+#: time) 1.39 against 1.51; 32 tokens (1 pair an expert, 13 of 16 touched)
+#: 1.17 against 0.99.  Both products are bound by the experts' bytes (805 MB
+#: at 819 GB/s is 0.98 ms); once every held expert is touched the grouped one
+#: only adds its layout, so the line stays where it was and a share ON it
+#: takes the dense product at every step.  The grouped one's 0.18 ms at 32
+#: tokens is not taken: no cell stands on that side (PERF.md section 7); its
+#: readings at 160 and 288 came through a grid with the blocks outermost that
+#: is not in the tree (``_ffn_pallas`` refuses a layout whose partial sums
+#: pass ``ACC_BYTES``, as this one's do from 98 tokens)
 DENSE_SHARE = 2
 
 

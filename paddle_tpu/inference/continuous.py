@@ -338,7 +338,17 @@ _STEP_SUMS = {
         ("kv_tokens_walked_shared", "KV positions the paged kernel walked "
          "for layers that read ANOTHER layer's pages (they append nothing "
          "and hold no pool): their part of the steps' walk, a paged "
-         "call's worth"))}
+         "call's worth"),
+        ("kv_pinned_bytes", "bytes the pages the unified steps' real rows "
+         "map hold in every page pool, each pool at its own page shape"),
+        ("kv_dead_bytes", "the part of kv_pinned_bytes that lies in the "
+         "sliding layers' own pools wholly behind the rows' next query's "
+         "window: what a page table a pool kind would free"))}
+_kv_bytes_copied = monitor.counter(
+    "serve_kv_bytes_copied_total", "bytes of K and V pages the paged "
+    "kernels' walks copied for the unified steps' padded rows, summed "
+    "over all the calls of a kind (full, sliding) and every kv head, each "
+    "pool at its own page shape", label_names=("kind",))
 # recurrent slots (a model whose layers carry a state of fixed size a
 # sequence): the cache's slot pool beside the page pools
 _slots_taken = monitor.counter(
@@ -789,6 +799,18 @@ class ContinuousBatchingEngine:
             # default is turned off rather than refused, and says so
             self.prefix_cache = False
             replay_batch = False    # replay is chunk rows of the ragged step
+        if self.cache_layout["kv_heads"] is None:
+            # pools of unequal shape (KV heads a pool, K wider than V): the
+            # ragged unified step's kernels alone take them; the prefix
+            # cache shares PAGES, which are pages in every pool alike
+            self._refuse_for_unlike_pools(
+                draft_model=draft_model, kv_quant=kv_quant, tp=tp,
+                prefill_chunk_tokens=prefill_chunk_tokens)
+            replay_batch = False    # as above: chunk rows of the ragged step
+        # survivors of a pool loss are replayed through the ragged program
+        # where no other program can write the model's state or pages
+        self._replay_ragged = (self._recurrent
+                               or self.cache_layout["kv_heads"] is None)
         self.max_queue = int(max_queue)
         self.default_ttl_s = default_ttl_s
         self.default_queue_timeout_s = default_queue_timeout_s
@@ -1064,6 +1086,38 @@ class ContinuousBatchingEngine:
                 "state, which only the ragged unified step updates (a slot "
                 "a row); the whole-prompt prefill programs carry no slot "
                 "pools.  Pass prefill_chunk_tokens")
+
+    def _refuse_for_unlike_pools(self, draft_model, kv_quant, tp,
+                                 prefill_chunk_tokens) -> None:
+        """What cannot hold for a model whose page pools differ in shape
+        (KV heads a pool, K heads wider than V's), each with its reason."""
+        name = type(self.model).__name__
+        shapes = sorted(set(self.cache_layout["pool_shapes"]))
+        what = (f"{name}'s page pools differ in shape ({shapes} as (kv "
+                "heads, K width, V width))")
+        if draft_model is not None:
+            raise ValueError(
+                f"draft_model: {what}, and the draft-and-verify path has "
+                "not been held to a reference over such pools (a draft "
+                "would need them too)")
+        if kv_quant is not None:
+            raise ValueError(
+                f"kv_quant={kv_quant!r}: {what}, and the int8 scale pools "
+                "have not been held to a reference beside pools of unequal "
+                "heads or a K wider than its V")
+        if int(tp) > 1:
+            from ..framework.jax_compat import make_tp_mesh
+            from .paged import _tp_plan
+            _tp_plan(self.model, make_tp_mesh(int(tp)))  # names what it lacks
+            raise ValueError(
+                f"tp={tp}: {what}, and the head-axis sharding of the pools "
+                "has no plan for pools of unequal heads")
+        if prefill_chunk_tokens is None:
+            raise ValueError(
+                f"prefill_chunk_tokens=None: {what}; only the ragged "
+                "unified step's paged kernels take K and V of unequal "
+                "width (the whole-prompt prefill programs' dense attention "
+                "takes one width and no sink).  Pass prefill_chunk_tokens")
 
     # ------------------------------------------------------------- public
     @property
@@ -2929,6 +2983,9 @@ class ContinuousBatchingEngine:
             for name, value in step.record.items():
                 if name in _STEP_SUMS:
                     _STEP_SUMS[name].inc(value)
+                elif name.startswith("kv_bytes_copied_"):
+                    _kv_bytes_copied.inc(
+                        value, kind=name[len("kv_bytes_copied_"):])
                 elif name == "kv_window_dead_pages":
                     _kv_window_dead_pages_g.set(value)
                 elif name == "slots_zeroed":
@@ -3228,8 +3285,8 @@ class ContinuousBatchingEngine:
         if upto <= 0 and dlen <= 0:
             return                     # nothing resident yet
         sampling = _null_sampling() if self.sample_on_device else None
-        if upto > 0 and self._recurrent:
-            # the slot is zeroed by the chunk row that enters it at
+        if upto > 0 and self._replay_ragged:
+            # a slot is zeroed by the chunk row that enters it at
             # context 0; the rest follow through the ragged program the
             # serving path runs (there is nothing to re-map)
             tokens = req.output_ids[:upto]

@@ -33,6 +33,7 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           _round_up, append_rows,
                                           dequantize_kv, kv_tokens_visible,
                                           kv_pages_copied, kv_tokens_walked,
+                                          packed_k_rows, packed_queries,
                                           paged_attention,
                                           paged_attention_ragged,
                                           q_positions_computed,
@@ -299,6 +300,16 @@ _NO_SHARED = ("a layer that attends another layer's pages (k=None) or "
               "prefill_chunk_tokens=...))")
 
 
+#: what a path whose attention is one width and one plain softmax says to
+#: a layer whose K heads are wider than its V heads or that hands a sink
+_NO_SPLIT = ("a layer whose K heads are wider than its V heads, or whose "
+             "softmax holds a learned sink (sinks=...), reached {}, whose "
+             "attention takes K and V of one width and no sink: serve "
+             "this model through the ragged unified step "
+             "(ContinuousBatchingEngine(prefill_chunk_tokens=...)), "
+             "whose paged kernels take both")
+
+
 #: what a path with no slots says to a retention layer
 _NO_SLOTS = ("a retention layer, a Mamba layer or a convolutional-"
              "attention layer (a recurrent state a sequence) reached {}, "
@@ -306,6 +317,14 @@ _NO_SLOTS = ("a retention layer, a Mamba layer or a convolutional-"
              "ragged unified step (ContinuousBatchingEngine("
              "prefill_chunk_tokens=...)), whose rows each update their own "
              "slot in place")
+
+
+def _sink_array(sinks):
+    """``attend``'s ``sinks`` (a Tensor, an array or None) as the kernels
+    take it: (q_heads,) float32 or None."""
+    if sinks is None:
+        return None
+    return jnp.asarray(getattr(sinks, "_data", sinks), jnp.float32)
 
 
 class _PagedContext:
@@ -331,14 +350,19 @@ class _PagedContext:
     conv_rows = scan_rows = shift_rows = retain
 
     def attend(self, q: Tensor, k: Tensor, v: Tensor,
-               window: Optional[int] = None, scale=None) -> Tensor:
+               window: Optional[int] = None, scale=None,
+               sinks=None) -> Tensor:
         """q/k/v: (batch, s, heads, head_dim) post-rope.  Writes k/v into
         the pages, returns the attention output (batch, s, q_heads, d).
         ``window``: the calling layer is a sliding-attention layer of
         that width (decode applies it; the dense prefill has none and
-        refuses)."""
+        refuses, as it refuses ``sinks`` and a K wider than V)."""
         cache = self.cache
         layer = self.layer_idx
+        if self.prefill and (sinks is not None or (
+                k is not None and k.shape[-1] != v.shape[-1])):
+            raise NotImplementedError(_NO_SPLIT.format(
+                "the eager prefill's dense flash attention"))
         if window is not None and self.prefill:
             raise NotImplementedError(_NO_WINDOW.format(
                 "the eager prefill's dense flash attention"))
@@ -370,7 +394,7 @@ class _PagedContext:
             lens, tab,
             k_scales=(cache.k_scales[layer] if cache.kv_quant else None),
             v_scales=(cache.v_scales[layer] if cache.kv_quant else None),
-            window=window)
+            window=window, sinks=_sink_array(sinks))
         return wrap_array(out[:, None])      # (batch, 1, q_heads, d)
 
 
@@ -423,7 +447,16 @@ class _TracedPagedContext:
     on a page pool of its own: a slot layer need not be a layer without
     pages).  Only the ragged step carries slots.  ``attend(q, None, None)`` attends WITHOUT
     appending, against the pool ``layer_idx`` names: a layer that reads
-    the pages another layer wrote earlier in the same program."""
+    the pages another layer wrote earlier in the same program.
+    ``attend(q, k, v, window=, scale=, sinks=)``: ``window`` a sliding
+    layer's width; ``scale`` the scores' scale where it is not the K
+    head's width^-1/2; ``sinks`` (q_heads,) float32, a learned sink a query
+    head in the softmax's denominator (``models/mimo_v2_flash.py``).  K and
+    V are handed as the layer's pool holds them (``paged_layout``'s
+    ``pool_shapes``): their heads are the pool's, and K may be wider than
+    V (192 beside 128) — the output is as wide as V.  The decode and the
+    ragged programs' kernels take all of these; the prefill programs'
+    dense attention takes none and refuses at trace time."""
 
     def __init__(self, k_pages, v_pages, pg, sl, lens=None, tables=None,
                  prefill=False, prefix_lens=None, k_scales=None,
@@ -578,7 +611,9 @@ class _TracedPagedContext:
             ks, vs = k8, v8
         else:
             ks_att, vs_att = ks, vs
-        self.k_pages[layer] = append_rows(self.k_pages[layer], pg, sl, ks)
+        self.k_pages[layer] = append_rows(
+            self.k_pages[layer], pg, sl,
+            packed_k_rows(ks, self.k_pages[layer]))
         self.v_pages[layer] = append_rows(self.v_pages[layer], pg, sl, vs)
         return ks_att, vs_att
 
@@ -587,22 +622,32 @@ class _TracedPagedContext:
             return None, None
         return self.k_scales[layer], self.v_scales[layer]
 
-    def attend(self, q, k, v, window=None, scale=None):
+    def attend(self, q, k, v, window=None, scale=None, sinks=None):
         """``window``: the calling layer is a sliding-attention layer of
         that width.  The paged kernels apply it; the prefill modes'
         dense attention has none and refuses at trace time.  ``k`` /
         ``v`` None: nothing is appended, the queries attend what pool
         ``layer_idx`` holds for the rows (ragged step only).  ``scale``:
-        the scores' scale where it is not the page head's width^-1/2."""
+        the scores' scale where it is not the page head's width^-1/2.
+        ``sinks``: a learned sink a query head (class docstring); ``v``
+        may be narrower than ``k``."""
         layer = self.layer_idx
+        sinks = _sink_array(sinks)
         if (k is None or scale is not None) and self.q_lens is None:
             raise NotImplementedError(_NO_SHARED.format(
                 "the prefill / prefix / chunk_prefill programs" if
                 self.prefill else "the decode programs"))
         if k is None:
-            return self._attend_ragged(q, layer, window, scale)
+            return self._attend_ragged(q, layer, window, scale, sinks)
         b, s = k.shape[0], k.shape[1]
         kvh, d = k.shape[2], k.shape[3]
+        dv = v.shape[3]
+        if self.prefill and (sinks is not None or dv != d):
+            raise NotImplementedError(_NO_SPLIT.format(
+                "the prefix-suffix attention of chunk_prefill / "
+                "prefix_prefill / batch_context_prefill"
+                if self.prefix_lens is not None else
+                "the prefill program's dense flash attention"))
         if window is not None and self.prefill:
             raise NotImplementedError(_NO_WINDOW.format(
                 "the prefix-suffix attention of chunk_prefill / "
@@ -610,7 +655,7 @@ class _TracedPagedContext:
                 if self.prefix_lens is not None else
                 "the prefill program's dense flash attention"))
         ks = jnp.swapaxes(k._data.reshape(b * s, kvh, d), 0, 1)
-        vs = jnp.swapaxes(v._data.reshape(b * s, kvh, d), 0, 1)
+        vs = jnp.swapaxes(v._data.reshape(b * s, kvh, dv), 0, 1)
         ks_att, vs_att = self._scatter(layer, ks, vs)
         ksc, vsc = self._layer_scales(layer)
         kp, vp = self.k_pages[layer], self.v_pages[layer]
@@ -636,23 +681,27 @@ class _TracedPagedContext:
         # packed; the pad queries come back as zeros and what the
         # layers behind make of them is discarded by the program's tail
         if self.q_lens is not None:
-            return self._attend_ragged(q, layer, window, scale)
+            return self._attend_ragged(q, layer, window, scale, sinks)
         # decode (``step``, the scan of ``multi_step``): one token a row
         out = paged_attention(q._data[:, 0], kp, vp, self.lens,
                               self.tables, k_scales=ksc,
-                              v_scales=vsc, window=window)
+                              v_scales=vsc, window=window, sinks=sinks)
         return wrap_array(out[:, None])
 
-    def _attend_ragged(self, q, layer, window, scale):
+    def _attend_ragged(self, q, layer, window, scale, sinks=None):
         """The ragged step's kernel call against pool ``layer`` as it
         stands: the packed queries to the (rows, span) rectangle and the
         output back."""
         ksc, vsc = self._layer_scales(layer)
-        rect = _rows_of_packed(q._data[:, 0], self.row_off, self.span)
+        # queries against K rows that hold several heads are packed HERE,
+        # on the step's tokens, not on the rectangle (14 x the positions)
+        rect = _rows_of_packed(
+            packed_queries(q._data[:, 0], self.k_pages[layer],
+                           self.v_pages[layer]), self.row_off, self.span)
         out = paged_attention_ragged(
             rect, self.k_pages[layer], self.v_pages[layer], self.lens,
             self.q_lens, self.tables, scale=scale, k_scales=ksc,
-            v_scales=vsc, window=window)
+            v_scales=vsc, window=window, sinks=sinks)
         return wrap_array(
             _packed_of_rows(out, self.row_off, q.shape[0])[:, None])
 
@@ -752,7 +801,8 @@ class JittedPagedDecoder:
         # what each paged call looks like (``paged_layout``), for the
         # dispatch record's count of the kernels' walk: how many calls
         # there are of each (query heads a page's KV head, window or None,
-        # walks a pool another call opened).  A model whose layers carry a
+        # walks a pool another call opened, the call's pool's own KV heads,
+        # K width and V width, a learned sink).  A model whose layers carry a
         # recurrent state a sequence says so (``recurrent_state``: layers,
         # a slot's arrays, the bytes of them the equations count): its
         # ragged program takes the slot pools as one more donated operand
@@ -763,8 +813,13 @@ class JittedPagedDecoder:
             self.DONATE_ARGNUMS = dict(self.DONATE_ARGNUMS,
                                        ragged=(9, 10, 11, 12, 14))
         self._attn_kinds = {}
-        for heads, window, _, shared in layout["calls"]:
-            kind = (heads // layout["kv_heads"], window, shared)
+        order = {p: i for i, p in enumerate(dict.fromkeys(
+            pool for _, _, pool, _ in layout["calls"]))}
+        for (heads, window, pool, shared), sinks in zip(layout["calls"],
+                                                        layout["sinks"]):
+            kv_heads, k_dim, v_dim = layout["pool_shapes"][order[pool]]
+            kind = (heads // kv_heads, window, shared, kv_heads, k_dim,
+                    v_dim, sinks)
             self._attn_kinds[kind] = self._attn_kinds.get(kind, 0) + 1
         # the names of what the model counts in a ragged step
         # (``_TracedPagedContext.count``), noted when a program is traced
@@ -1872,25 +1927,44 @@ class JittedPagedDecoder:
         (``kv_window_dead_pages``; a page two rows share counts
         twice).  A model some of whose calls walk a pool they do not own
         adds ``kv_tokens_walked_shared``: their part of
-        ``kv_tokens_walked``, in the same unit."""
+        ``kv_tokens_walked``, in the same unit.
+
+        In BYTES, summed over ALL the step's calls and every kv head, each
+        call's pool at its own page shape (K and V, the int8 scales apart):
+        ``kv_bytes_copied_full`` / ``kv_bytes_copied_sliding`` what the
+        full and the sliding calls' walks copy (``kv_pages_copied`` x the
+        pool's bytes a page), ``kv_pinned_bytes`` what the pages the
+        step's real rows map hold in every pool, and ``kv_dead_bytes`` the
+        part of that in the sliding layers' own pools wholly behind the
+        rows' next query's window: what a table a pool kind would free."""
         ps, total = cache.page_size, sum(self._attn_kinds.values())
         if not total:                   # no K/V layer: nothing is walked
             return {}
         means = ["ctx_tokens", "kv_tokens_walked", "q_positions_computed",
                  "page_copies", "head_page_reads"]
         kv_dtype = cache.k_pages[0].dtype
-        heads, pools = cache.kv_heads // cache.tp, 4 if cache.kv_quant else 2
-        if any(shared for _, _, shared in self._attn_kinds):
+        pools = 4 if cache.kv_quant else 2
+        if any(kind[2] for kind in self._attn_kinds):
             means.append("kv_tokens_walked_shared")
         out = dict.fromkeys(means, 0)
-        for (group, window, shared), n in self._attn_kinds.items():
-            block = ps * walk_block_pages(ps, cache.head_dim, span * group,
-                                          kv_dtype)
+        mapped = int((-(-lens[:rows] // ps)).sum())     # pages the rows map
+        out.update(kv_bytes_copied_full=0, kv_bytes_copied_sliding=0,
+                   kv_pinned_bytes=0, kv_dead_bytes=0)
+        for kind, n in self._attn_kinds.items():
+            group, window, shared, kv_heads, k_dim, v_dim, sinks = kind
+            heads = kv_heads // cache.tp
+            page_bytes = kv_heads * ps * (k_dim + v_dim) * kv_dtype.itemsize
+            block = ps * walk_block_pages(ps, k_dim, span * group, kv_dtype,
+                                          v_dim)
             steps = heads // walk_head_group(
-                heads, ps, cache.head_dim, span * group, kv_dtype,
-                cache.compute_dtype)
-            pages = pools * kv_pages_copied(lens, ps, table_pages, window,
-                                            q_lens)
+                heads, ps, k_dim, span * group, kv_dtype,
+                cache.compute_dtype, v_dim, sinks)
+            copied = kv_pages_copied(lens, ps, table_pages, window, q_lens)
+            pages = pools * copied
+            out["kv_bytes_copied_full" if window is None
+                else "kv_bytes_copied_sliding"] += n * copied * page_bytes
+            if not shared:              # the call's own pool
+                out["kv_pinned_bytes"] += n * mapped * page_bytes
             # the real rows' context; a pad row's one position is walked
             seen = kv_tokens_visible(lens[:rows], q_lens[:rows], window)
             walked = kv_tokens_walked(lens, block, window, q_lens, ps)
@@ -1903,12 +1977,15 @@ class JittedPagedDecoder:
             out["page_copies"] += n * steps * pages
             out["head_page_reads"] += n * heads * pages
             if window is not None:
-                dead = np.maximum(lens[:rows] + 1 - window, 0) // ps
+                dead = int((np.maximum(lens[:rows] + 1 - window, 0)
+                            // ps).sum())
                 out.update(ctx_tokens_window=seen,
                            kv_tokens_walked_window=walked,
                            kv_tokens_walked_nowindow=kv_tokens_walked(
                                lens, block),
-                           kv_window_dead_pages=int(dead.sum()))
+                           kv_window_dead_pages=dead)
+                if not shared:
+                    out["kv_dead_bytes"] += n * dead * page_bytes
         for name in means:                                  # a layer's
             out[name] = (out[name] // total if len(self._attn_kinds) == 1
                          else out[name] / total)

@@ -142,9 +142,11 @@ def _ffn_pallas(x_rows, w_rows, block_expert, n_blocks, w_gate, w_up,
     if tiles > 1 and rows * m * 4 > ACC_BYTES:
         raise NotImplementedError(
             f"{rows} rows of {m} would hold {rows * m * 4} bytes of float32 "
-            f"partial sums in VMEM (limit {ACC_BYTES}): an expert wider "
-            f"than a tile of {TILE_WIDTH} is served a step's tokens, not a "
-            "training batch")
+            f"partial sums in VMEM (limit {ACC_BYTES}) beside the weight "
+            f"tiles of {TILE_WIDTH}: an expert wider than a tile is served "
+            "a step's tokens, not a training batch, and a share of few "
+            "experts that wide (16 of 4,096 x 2,048 over 98 tokens at "
+            "top-8) takes the dense product")
     wrow = jnp.broadcast_to(w_rows.astype(jnp.float32)[:, None], (rows, 128))
 
     # the grid's ids, then the two prefetched tables; one tile: (b,)

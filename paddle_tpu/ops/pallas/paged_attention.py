@@ -111,6 +111,63 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
+def k_pack(head_dim):
+    """KV heads whose K rows lie SIDE BY SIDE in one row of a K pool: 1,
+    except for a head wider than a lane tile that is not whole tiles (192:
+    the TPU lays an array's last axis out in tiles of 128 lanes, so a pool
+    ``[..., 192]`` takes 256 in HBM and Mosaic cannot slice a page out of
+    it).  Such heads are stored in the fewest that fill whole tiles (two
+    of 192 = 384 = three tiles), the pool ``(kv_heads / n, pages,
+    page_size, n * head_dim)``: no byte of padding in HBM, and a page of
+    it is one aligned copy.  The kernels read the packing off the pools'
+    shapes (V's head axis over K's)."""
+    if head_dim <= 128 or head_dim % 128 == 0:
+        return 1
+    return 128 // math.gcd(128, head_dim)
+
+
+def _own_lane(q_heads, kv_heads, pack):
+    """(q_heads,) which of a packed K row's ``pack`` heads a query head's
+    own kv head is."""
+    return (jnp.arange(q_heads) // (q_heads // kv_heads)) % pack
+
+
+def packed_queries(q, k_pages, v_pages):
+    """Queries (..., q_heads, d) as the kernels multiply them by a K pool
+    whose rows hold several heads side by side (``k_pack``): (..., q_heads,
+    n * d), a head's values in the lanes of its own kv head and zeros under
+    the row's other heads (exact: their products are zeros).  As they are
+    for a pool of one head a row, or when already so packed.  Cheap on a
+    step's packed tokens, dear on the (rows, span) rectangle: the ragged
+    step packs before it goes to the rectangle."""
+    kv_heads, wide = v_pages.shape[0], k_pages.shape[-1]
+    pack = kv_heads // k_pages.shape[0]
+    if pack == 1 or q.shape[-1] == wide:
+        return q
+    mine = (_own_lane(q.shape[-2], kv_heads, pack)[:, None]
+            == jnp.arange(pack)[None, :])                   # (q_heads, pack)
+    return jnp.where(mine[:, :, None], q[..., None, :], 0) \
+        .reshape(q.shape[:-1] + (wide,))
+
+
+def _unpacked_queries(q, k_pages, v_pages):
+    """The oracles' inverse of :func:`packed_queries`: a head's own lanes."""
+    kv_heads, wide = v_pages.shape[0], k_pages.shape[-1]
+    pack = kv_heads // k_pages.shape[0]
+    if pack == 1 or q.shape[-1] != wide:
+        return q
+    q_heads = q.shape[-2]
+    split = q.reshape(q.shape[:-1] + (pack, wide // pack))
+    lane = _own_lane(q_heads, kv_heads, pack).reshape(
+        (1,) * (q.ndim - 2) + (q_heads, 1, 1))
+    return jnp.take_along_axis(split, lane, axis=-2)[..., 0, :]
+
+
+def _k_head_dim(k_pages, v_pages):
+    """A K head's width, whatever the rows of its pool hold."""
+    return k_pages.shape[-1] * k_pages.shape[0] // v_pages.shape[0]
+
+
 def _page_vmem_bytes(page_size, head_dim, kv_dtype):
     """VMEM one page of one kv head takes in a walk buffer: whole tiles of
     its dtype (8 sublanes of 32 bits, so 8 / 16 / 32 rows by itemsize), and
@@ -123,19 +180,31 @@ def _page_vmem_bytes(page_size, head_dim, kv_dtype):
     return page_bytes
 
 
-def walk_block_pages(page_size, head_dim, rows, kv_dtype):
+def _kv_page_vmem_bytes(page_size, head_dim, v_dim, kv_dtype):
+    """VMEM a page of one kv head takes in the K and the V walk buffers
+    together: a K row holds ``k_pack`` heads, so a head's part of it is
+    unpadded; V by its own width."""
+    pack = k_pack(head_dim)
+    return (_page_vmem_bytes(page_size, pack * head_dim, kv_dtype) // pack
+            + _page_vmem_bytes(page_size, v_dim, kv_dtype))
+
+
+def walk_block_pages(page_size, head_dim, rows, kv_dtype, v_dim=None):
     """Pages one block of the kernel's walk holds, from shapes alone: as
     many as keep the score block (``rows`` x tokens, float32) and ONE kv
     head's double-buffered K and V pages (with the int8 mode's scale pages)
     inside their VMEM budgets, at most 512 tokens, at least one page.
-    ``rows`` is ``n_query * group``.  Whole multiples of 128 tokens where
+    ``rows`` is ``n_query * group``; ``head_dim`` is K's width and
+    ``v_dim`` V's where it is another (each buffer is budgeted by its own
+    width).  Whole multiples of 128 tokens where
     that many fit, so the score block's lane axis is unpadded.  The table's
     width is NOT an input: a row's blocks are cut the same whatever table
     carries them, which is what makes a pinned table free and its results
     bit-identical to a tight one's.  Nor are the heads a grid step owns
     (``walk_head_group``): the buffers grow with them, the block does not."""
-    page_bytes = _page_vmem_bytes(page_size, head_dim, kv_dtype)
-    by_kv = _KV_BUFFER_BYTES // (4 * page_bytes)     # K, V x two slots
+    page_bytes = _kv_page_vmem_bytes(page_size, head_dim, v_dim or head_dim,
+                                     kv_dtype)
+    by_kv = _KV_BUFFER_BYTES // (2 * page_bytes)     # K, V x two slots
     by_score = _SCORE_BLOCK_BYTES // (4 * _round_up(rows, 8) * page_size)
     pages = max(1, min(_MAX_BLOCK_TOKENS // page_size, by_kv, by_score))
     per_128 = 128 // math.gcd(128, page_size)        # pages to 128 tokens
@@ -145,11 +214,14 @@ def walk_block_pages(page_size, head_dim, rows, kv_dtype):
 
 
 @functools.lru_cache(maxsize=None)      # the host asks at every step
-def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype):
+def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype,
+                    v_dim=None, sinks=False):
     """KV heads one grid step of the kernel owns, from shapes alone: the
     largest divisor of the call's ``kv_heads`` whose heads together keep
-    their q and out blocks (two pipeline buffers each), their softmax
-    scratch (m, l, acc in float32) and their double K and V buffers of
+    their q and out blocks (two pipeline buffers each; q as wide as K,
+    out as wide as V: ``v_dim`` where that is not ``head_dim``), their
+    softmax scratch (m, l, acc in float32), with ``sinks`` the rows' sink
+    block (two buffers), and their double K and V buffers of
     ``walk_block_pages`` pages inside ``_HEAD_GROUP_BYTES``.  A page copy
     then serves the whole group: ONE descriptor a (row, group, page, pool)
     where a grid step of one head issued one a head.  All the heads for
@@ -157,16 +229,23 @@ def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype):
     and for the ragged kernel's 512 / 768 / 1,024-row buckets at 8 heads
     (1.75 / 2.1 / 2.75 MB a head); 1 is the grid of one head a step.  The
     walk of a (row, head) does not depend on the group it rides in."""
-    lanes = _round_up(head_dim, 128)
+    v_dim = v_dim or head_dim
+    # packed K rows (``k_pack``): a head's queries ride in a row as wide
+    # as the pack's, zeros beside them, and a step owns whole packs
+    pack = k_pack(head_dim)
+    lanes, v_lanes = _round_up(pack * head_dim, 128), _round_up(v_dim, 128)
     q_item = jnp.dtype(q_dtype).itemsize
-    block_pages = walk_block_pages(page_size, head_dim, rows, kv_dtype)
+    block_pages = walk_block_pages(page_size, head_dim, rows, kv_dtype,
+                                   v_dim)
     per_head = (
-        4 * block_pages * _page_vmem_bytes(page_size, head_dim, kv_dtype)
-        + 4 * _round_up(rows, 32 // q_item) * lanes * q_item   # q, out
-        + _round_up(rows, 8) * (128 + 128 + lanes) * 4)        # m, l, acc
-    return max(g for g in range(1, kv_heads + 1)
+        2 * block_pages * _kv_page_vmem_bytes(page_size, head_dim, v_dim,
+                                              kv_dtype)
+        + 2 * _round_up(rows, 32 // q_item) * (lanes + v_lanes) * q_item
+        + _round_up(rows, 8) * (128 + 128 + v_lanes) * 4       # m, l, acc
+        + (2 * _round_up(rows, 8) * 128 * 4 if sinks else 0))
+    return max(g for g in range(pack, kv_heads + 1, pack)
                if kv_heads % g == 0
-               and (g == 1 or g * per_head <= _HEAD_GROUP_BYTES))
+               and (g == pack or g * per_head <= _HEAD_GROUP_BYTES))
 
 
 def window_first_token(lengths, q_lens, window, page_size):
@@ -270,7 +349,8 @@ def q_positions_computed(q_lens, n_query, group, q_dtype):
 
 def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale, page_size, block_pages, n_query=1, group=1,
-                   quantized=False, ragged=False, window=None, tile=None):
+                   quantized=False, ragged=False, window=None, tile=None,
+                   sinks=False):
     """Online-softmax paged attention for ``n_query`` query tokens per
     sequence, one grid step per (row, group of ``hb`` kv heads: the head
     axis of the blocks and buffers it is handed).  The step WALKS THE
@@ -325,22 +405,39 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     holds the row's first visible key (``window_first_token``) — no copy
     is issued for a page before it — and the scores are masked on both
     sides; ``None`` is the causal walk from page 0, the same program as
-    before the window existed."""
-    if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sems,
-         m_scr, l_scr, acc_scr) = rest
-    else:
-        o_ref, k_buf, v_buf, sems, m_scr, l_scr, acc_scr = rest
-        ks_buf = vs_buf = None
+    before the window existed.
+
+    K and V need not be as wide as each other: the queries and the K
+    buffer are ``k_buf``'s width, the V buffer, the accumulator and the
+    output ``v_buf``'s (MiMo-V2-Flash: 192 and 128).
+
+    ``sinks`` (a learned sink a query head): one more operand, the sink
+    of each of the step's query rows over a lane tile (``sink_ref``
+    (hb, rows, 128) float32, row ``s * group + g`` holds query head
+    ``g``'s).  The softmax's denominator holds ``exp(sink)`` beside the
+    visible keys' terms, and the sink gives no value: a row's running max,
+    sum and accumulator START from it, ``(sink, 1, 0)``, where without it
+    they start from ``(-inf, 0, 0)``; nothing else differs."""
+    # operands: [K and V scales] [sinks] out; scratch: K, V buffers
+    # [scale buffers] semaphores m l acc
+    ins = 2 * quantized + sinks
+    ks_hbm, vs_hbm = rest[:2] if quantized else (None, None)
+    sink_ref = rest[ins - 1] if sinks else None
+    o_ref, k_buf, v_buf = rest[ins:ins + 3]
+    ks_buf, vs_buf = rest[ins + 3:ins + 5] if quantized else (None, None)
+    sems, m_scr, l_scr, acc_scr = rest[-4:]
     # what a page is copied from and to; its scale block travels with it
     pools = [(k_hbm, k_buf), (v_hbm, v_buf)]
     if quantized:
         pools += [(ks_hbm, ks_buf), (vs_hbm, vs_buf)]
     b = pl.program_id(0)
-    hb = k_buf.shape[2]                 # the step's kv heads
-    heads = pl.ds(pl.program_id(1) * hb, hb)
+    hb = v_buf.shape[2]                 # the step's kv heads
+    # a K row may hold several heads side by side (``k_pack``): the K
+    # pool's head axis is then that many times shorter than V's
+    pack = hb // k_buf.shape[2]
+    heads = {n: pl.ds(pl.program_id(1) * n, n)
+             for n in dict.fromkeys(buf.shape[2] for _, buf in pools)}
     block = block_pages * page_size
-    d = k_buf.shape[-1]
 
     length = lens_ref[0, b] if ragged else lens_ref[b]
     # the pages that hold the row's context (never past the table)
@@ -365,7 +462,7 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         def body(i, carry):
             page = tabs_ref[b, page0 + first + i]
             for hbm, buf in pools:
-                act(pltpu.make_async_copy(hbm.at[heads, page],
+                act(pltpu.make_async_copy(hbm.at[heads[buf.shape[2]], page],
                                           buf.at[slot, i], sems.at[slot]))
             return carry
 
@@ -387,7 +484,7 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
             # a page that is not whole tiles of its dtype folds into
             # the token axis as float32, whose 8-row tile it does fill
             x = x.astype(jnp.float32)
-        return x.reshape(block, d).astype(dtype)
+        return x.reshape(block, buf.shape[-1]).astype(dtype)
 
     def each_head(act):
         """``act(j)`` on every kv head of the step, one after another."""
@@ -422,8 +519,12 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def reset(j):
         def tile_reset(rows, row0):
-            m_scr[j, rows] = jnp.full(narrow, -jnp.inf, m_scr.dtype)
-            l_scr[j, rows] = jnp.zeros(narrow, l_scr.dtype)
+            if sinks:       # the sink's own term: exp(sink - m) = 1
+                m_scr[j, rows] = sink_ref[j, rows]
+                l_scr[j, rows] = jnp.ones(narrow, l_scr.dtype)
+            else:
+                m_scr[j, rows] = jnp.full(narrow, -jnp.inf, m_scr.dtype)
+                l_scr[j, rows] = jnp.zeros(narrow, l_scr.dtype)
             acc_scr[j, rows] = jnp.zeros(wide, acc_scr.dtype)
 
         each_tile(tile_reset)
@@ -482,7 +583,10 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
         def head(j):
             def keys():
-                return load(k_buf, ks_buf, slot, j, q_ref.dtype)
+                # a packed row: the head's queries are zeros beside the
+                # lanes of the row's other heads
+                return load(k_buf, ks_buf, slot,
+                            j if pack == 1 else j // pack, q_ref.dtype)
 
             def values():
                 # same rounding rule as the keys, then the SAME dot the
@@ -548,21 +652,29 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                  interpret=False, n_query=1, k_scales=None, v_scales=None,
-                 q_lens=None, window=None, head_group=None):
+                 q_lens=None, window=None, head_group=None, sinks=None):
     """The ``pallas_call`` behind :func:`_decode_pallas` (its arguments).
     ``head_group``: the kv heads a grid step owns where a TEST wants
     another count than the shapes give (``walk_head_group``); no caller of
     the program passes it."""
+    # K rows that hold several heads side by side (``k_pack``): the
+    # queries as wide, zeros under a row's other heads, if the caller has
+    # not packed them yet (the ragged step has, on its packed tokens)
+    q = packed_queries(q, k_pages, v_pages)
     if n_query == 1:
         batch, q_heads, d = q.shape
     else:
         batch, _nq, q_heads, d = q.shape
-    kv_heads, _tot, page_size, _d = k_pages.shape
+    kv_heads, _tot, page_size, dv = v_pages.shape   # the output: V's width
+    pack = kv_heads // k_pages.shape[0]
+    assert k_pages.shape[-1] == d, (k_pages.shape, v_pages.shape, q.shape)
     group = q_heads // kv_heads
     rows = n_query * group
-    block_pages = walk_block_pages(page_size, d, rows, k_pages.dtype)
-    hb = head_group or walk_head_group(kv_heads, page_size, d, rows,
-                                       k_pages.dtype, q.dtype)
+    block_pages = walk_block_pages(page_size, d // pack, rows,
+                                   k_pages.dtype, dv)
+    hb = head_group or walk_head_group(
+        kv_heads, page_size, d // pack, rows, k_pages.dtype, q.dtype, dv,
+        sinks is not None)
 
     # (batch, q_heads, d) -> (batch, kv_heads, group, d): the kv-head
     # group rides as its own FULL axis so the q block's trailing dims
@@ -584,11 +696,14 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
     # broadcast over a tile.  Both are XLA copies of a pool per call
     # (the scale pools' was already there: the one-lane layout was
     # re-tiled for the kernel on every call).
-    lanes = _round_up(d, 128)
+    # (A K pool whose rows hold several heads is whole tiles as it is
+    # stored, and no pool is copied for it.)
+    lanes, v_lanes = _round_up(d, 128), _round_up(dv, 128)
     if lanes != d:
         pad = [(0, 0)] * 3 + [(0, lanes - d)]
-        q4, k_pages, v_pages = (jnp.pad(x, pad)
-                                for x in (q4, k_pages, v_pages))
+        q4, k_pages = jnp.pad(q4, pad), jnp.pad(k_pages, pad)
+    if v_lanes != dv:
+        v_pages = jnp.pad(v_pages, [(0, 0)] * 3 + [(0, v_lanes - dv)])
     if quantized:
         k_scales, v_scales = (
             jnp.broadcast_to(x, x.shape[:-1] + (128,))
@@ -606,58 +721,75 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                                page_size=page_size,
                                block_pages=block_pages, n_query=n_query,
                                group=group, quantized=quantized,
-                               ragged=ragged, window=window, tile=tile)
-    q_spec = pl.BlockSpec((1, hb, rows, lanes),
-                          lambda b, g, lens, tabs: (b, g, 0, 0))
+                               ragged=ragged, window=window, tile=tile,
+                               sinks=sinks is not None)
+
+    def of_row(width):
+        return pl.BlockSpec((1, hb, rows, width),
+                            lambda b, g, lens, tabs: (b, g, 0, 0))
+
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [q_spec, hbm, hbm]
+    in_specs = [of_row(lanes), hbm, hbm]
     inputs = [lengths, page_tables, q4, k_pages, v_pages]
+
     # two buffers a pool: one block computing, the next in flight; a
     # page of the step's ``hb`` heads lands side by side
-    page_buf = pltpu.VMEM((2, block_pages, hb, page_size, lanes),
+    def page_buf(heads, width):
+        return pltpu.VMEM((2, block_pages, heads, page_size, width),
                           k_pages.dtype)
-    scratch = [page_buf, page_buf]
+
+    scratch = [page_buf(hb // pack, lanes), page_buf(hb, v_lanes)]
     if quantized:
         in_specs += [hbm, hbm]
         inputs += [k_scales, v_scales]
         scale_buf = pltpu.VMEM((2, block_pages, hb, page_size, 128),
                                jnp.float32)
         scratch += [scale_buf, scale_buf]
+    if sinks is not None:
+        # query row ``s * group + g`` of kv head ``h`` is query head
+        # ``h * group + g``: its sink over a lane tile, the same for
+        # every row of the batch
+        of_head = jnp.tile(sinks.astype(jnp.float32)
+                           .reshape(kv_heads, 1, group), (1, n_query, 1))
+        in_specs.append(pl.BlockSpec((hb, rows, 128),
+                                     lambda b, g, lens, tabs: (g, 0, 0)))
+        inputs.append(jnp.broadcast_to(
+            of_head.reshape(kv_heads, rows, 1), (kv_heads, rows, 128)))
     scratch += [
         pltpu.SemaphoreType.DMA((2,)),          # one a buffer slot
         pltpu.VMEM((hb, rows, 128), jnp.float32),
         pltpu.VMEM((hb, rows, 128), jnp.float32),
-        pltpu.VMEM((hb, rows, lanes), jnp.float32),
+        pltpu.VMEM((hb, rows, v_lanes), jnp.float32),
     ]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # lengths, page_tables
         grid=(batch, kv_heads // hb),
         in_specs=in_specs,
-        out_specs=q_spec,
+        out_specs=of_row(v_lanes),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         name="paged_attention_ragged" if ragged else "paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, lanes),
+        out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, v_lanes),
                                        q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*inputs)[..., :d]
+    )(*inputs)[..., :dv]
     if n_query == 1:
-        return out.reshape(batch, q_heads, d)
-    return out.reshape(batch, kv_heads, n_query, group, d) \
-        .transpose(0, 2, 1, 3, 4).reshape(batch, n_query, q_heads, d)
+        return out.reshape(batch, q_heads, dv)
+    return out.reshape(batch, kv_heads, n_query, group, dv) \
+        .transpose(0, 2, 1, 3, 4).reshape(batch, n_query, q_heads, dv)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret",
                                              "n_query", "window"))
 def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                    interpret=False, n_query=1, k_scales=None,
-                   v_scales=None, q_lens=None, window=None):
+                   v_scales=None, q_lens=None, window=None, sinks=None):
     """``q`` is (batch, q_heads, d) for n_query == 1, else
     (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
     (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
@@ -677,7 +809,7 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     return _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                         interpret=interpret, n_query=n_query,
                         k_scales=k_scales, v_scales=v_scales,
-                        q_lens=q_lens, window=window)
+                        q_lens=q_lens, window=window, sinks=sinks)
 
 
 def _gather_dequant(pages, scales, page_tables, batch, kv_heads,
@@ -696,6 +828,16 @@ def _gather_dequant(pages, scales, page_tables, batch, kv_heads,
     return out.astype(out_dtype)
 
 
+def _unpacked(k, d):
+    """Gathered K (batch, rows, T, n * d) whose rows hold ``n`` heads
+    side by side (``k_pack``) as (batch, n * rows, T, d): a head a row."""
+    b, rows, t, wide = k.shape
+    if wide == d:
+        return k
+    return k.reshape(b, rows, t, wide // d, d).transpose(0, 1, 3, 2, 4) \
+        .reshape(b, rows * (wide // d), t, d)
+
+
 def _seen(cols, limit, window):
     """The mask every oracle applies: column ``cols`` is seen by a query
     whose causal limit is ``limit`` (its own position + 1), and under a
@@ -706,19 +848,35 @@ def _seen(cols, limit, window):
     return seen
 
 
+def _softmax(s, sinks):
+    """The oracles' softmax over the last axis of ``s`` (batch, q_heads,
+    ..., keys).  ``sinks`` (q_heads,) float32 or None: a learned sink a
+    query head stands in the denominator as one more column that no key
+    carries, dropped after the softmax (it takes mass and gives no
+    value)."""
+    if sinks is None:
+        return jax.nn.softmax(s, axis=-1)
+    col = sinks.astype(s.dtype).reshape((1, -1) + (1,) * (s.ndim - 2))
+    col = jnp.broadcast_to(col, s.shape[:-1] + (1,))
+    return jax.nn.softmax(jnp.concatenate([s, col], axis=-1),
+                          axis=-1)[..., :-1]
+
+
 def _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                k_scales=None, v_scales=None, window=None):
+                k_scales=None, v_scales=None, window=None, sinks=None):
     """Gather + dense masked attention (CPU fallback / correctness ref)."""
+    q = _unpacked_queries(q, k_pages, v_pages)
     batch, q_heads, d = q.shape
-    kv_heads, _tot, page_size, _d = k_pages.shape
+    kv_heads, _tot, page_size, _d = v_pages.shape
     group = q_heads // kv_heads
     max_tokens = page_tables.shape[1] * page_size
 
     def gather(pages, scales):
         return _gather_dequant(pages, scales, page_tables, batch,
-                               kv_heads, max_tokens, d, q.dtype)
+                               pages.shape[0], max_tokens, pages.shape[-1],
+                               q.dtype)
 
-    k = gather(k_pages, k_scales)
+    k = _unpacked(gather(k_pages, k_scales), d)
     v = gather(v_pages, v_scales)
     if group != 1:
         k = jnp.repeat(k, group, axis=1)
@@ -728,24 +886,26 @@ def _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
     cols = jnp.arange(max_tokens)[None, None, :]
     s = jnp.where(_seen(cols, lengths[:, None, None], window), s,
                   DEFAULT_MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1)
+    p = _softmax(s, sinks)
     return jnp.einsum("bhk,bhkd->bhd", p.astype(v.dtype), v).astype(q.dtype)
 
 
 def _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-               k_scales=None, v_scales=None, window=None):
+               k_scales=None, v_scales=None, window=None, sinks=None):
     """Gather + dense masked multi-query attention (CPU fallback /
     correctness reference for the ragged verify path)."""
+    q = _unpacked_queries(q, k_pages, v_pages)
     batch, n_query, q_heads, d = q.shape
-    kv_heads, _tot, page_size, _d = k_pages.shape
+    kv_heads, _tot, page_size, _d = v_pages.shape
     group = q_heads // kv_heads
     max_tokens = page_tables.shape[1] * page_size
 
     def gather(pages, scales):
         return _gather_dequant(pages, scales, page_tables, batch,
-                               kv_heads, max_tokens, d, q.dtype)
+                               pages.shape[0], max_tokens, pages.shape[-1],
+                               q.dtype)
 
-    k = gather(k_pages, k_scales)
+    k = _unpacked(gather(k_pages, k_scales), d)
     v = gather(v_pages, v_scales)
     if group != 1:
         k = jnp.repeat(k, group, axis=1)
@@ -760,13 +920,13 @@ def _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
     limit = (lengths[:, None, None, None]
              - (n_query - 1 - qpos)).astype(jnp.int32)
     s = jnp.where(_seen(cols, limit, window), s, DEFAULT_MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1)
+    p = _softmax(s, sinks)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
 def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
-                k_scales=None, v_scales=None, window=None):
+                k_scales=None, v_scales=None, window=None, sinks=None):
     """Gather + dense masked attention with PER-ROW query spans (CPU
     fallback / correctness oracle for the ragged unified step).  Same
     einsum structure as ``_multi_xla`` — only the causal limit differs
@@ -774,16 +934,18 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
     bit-exactly, and masked columns contribute EXACT zeros (exp of the
     mask value underflows), keeping results identical across bucket
     widths."""
+    q = _unpacked_queries(q, k_pages, v_pages)
     batch, n_query, q_heads, d = q.shape
-    kv_heads, _tot, page_size, _d = k_pages.shape
+    kv_heads, _tot, page_size, _d = v_pages.shape
     group = q_heads // kv_heads
     max_tokens = page_tables.shape[1] * page_size
 
     def gather(pages, scales):
         return _gather_dequant(pages, scales, page_tables, batch,
-                               kv_heads, max_tokens, d, q.dtype)
+                               pages.shape[0], max_tokens, pages.shape[-1],
+                               q.dtype)
 
-    k = gather(k_pages, k_scales)
+    k = _unpacked(gather(k_pages, k_scales), d)
     v = gather(v_pages, v_scales)
     if group != 1:
         k = jnp.repeat(k, group, axis=1)
@@ -800,7 +962,7 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
     ql = q_lens[:, None, None, None].astype(jnp.int32)
     limit = jnp.minimum(kv, kv - ql + 1 + qpos)
     s = jnp.where(_seen(cols, limit, window), s, DEFAULT_MASK_VALUE)
-    p = jax.nn.softmax(s, axis=-1)
+    p = _softmax(s, sinks)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
     out = jnp.where(qpos < ql, out, 0.0)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
@@ -808,11 +970,13 @@ def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
 
 def paged_attention(q, k_pages, v_pages, lengths, page_tables, scale=None,
                     interpret=False, k_scales=None, v_scales=None,
-                    window=None):
+                    window=None, sinks=None):
     """Decode-step attention over a paged KV cache.
 
     q:           (batch, q_heads, head_dim) — ONE new token per sequence
-    k/v_pages:   (kv_heads, total_pages, page_size, head_dim)
+    k/v_pages:   (kv_heads, total_pages, page_size, head_dim); V's pages
+                 may be of another width than K's (and q's): the output
+                 is as wide as V, the scores as wide as K
     lengths:     (batch,) int32 — valid cached tokens per sequence
                  (including the current token, already written to pages)
     page_tables: (batch, max_pages_per_seq) int32
@@ -826,21 +990,26 @@ def paged_attention(q, k_pages, v_pages, lengths, page_tables, scale=None,
                  the page of the first of them.  The same in
                  ``paged_attention_multi`` and ``paged_attention_ragged``
                  for every query of a row.
+    sinks:       None, or (q_heads,) float32: a learned sink a query
+                 head, ``p_j = exp(s_j) / (exp(sink) + sum_j' exp(s_j'))``
+                 over the visible keys; the sink takes mass and gives no
+                 value.  The same in the other two entry points.
     """
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(_k_head_dim(k_pages, v_pages))
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
                               k_scales=k_scales, v_scales=v_scales,
-                              window=window)
+                              window=window, sinks=sinks)
     return _decode_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                       k_scales=k_scales, v_scales=v_scales, window=window)
+                       k_scales=k_scales, v_scales=v_scales, window=window,
+                       sinks=sinks)
 
 
 def paged_attention_multi(q, k_pages, v_pages, lengths, page_tables,
                           scale=None, interpret=False, k_scales=None,
-                          v_scales=None, window=None):
+                          v_scales=None, window=None, sinks=None):
     """Ragged MULTI-QUERY decode attention: ``n_query`` new tokens per
     sequence in one pass — the speculative-decoding verify step's
     attention ("Ragged Paged Attention" shape: [B, k] queries against
@@ -856,25 +1025,27 @@ def paged_attention_multi(q, k_pages, v_pages, lengths, page_tables,
     Returns (batch, n_query, q_heads, head_dim).
     """
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(_k_head_dim(k_pages, v_pages))
     if q.shape[1] == 1:
         out = paged_attention(q[:, 0], k_pages, v_pages, lengths,
                               page_tables, scale=scale,
                               interpret=interpret, k_scales=k_scales,
-                              v_scales=v_scales, window=window)
+                              v_scales=v_scales, window=window, sinks=sinks)
         return out[:, None]
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
                               n_query=q.shape[1], k_scales=k_scales,
-                              v_scales=v_scales, window=window)
+                              v_scales=v_scales, window=window, sinks=sinks)
     return _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
-                      k_scales=k_scales, v_scales=v_scales, window=window)
+                      k_scales=k_scales, v_scales=v_scales, window=window,
+                      sinks=sinks)
 
 
 def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
                            page_tables, scale=None, interpret=False,
-                           k_scales=None, v_scales=None, window=None):
+                           k_scales=None, v_scales=None, window=None,
+                           sinks=None):
     """RAGGED paged attention (ISSUE 17): ONE kernel over a batch whose
     rows carry DIFFERENT query-span lengths — decode rows (q_len 1),
     prefill/chunk spans, and speculative verify blocks mix in a single
@@ -905,23 +1076,23 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
     Returns (batch, max_q, q_heads, head_dim).
     """
     if scale is None:
-        scale = 1.0 / math.sqrt(q.shape[-1])
+        scale = 1.0 / math.sqrt(_k_head_dim(k_pages, v_pages))
     if q.shape[1] == 1:
         # every span is one token: literally the decode step
         out = paged_attention(q[:, 0], k_pages, v_pages, lengths,
                               page_tables, scale=scale,
                               interpret=interpret, k_scales=k_scales,
-                              v_scales=v_scales, window=window)
+                              v_scales=v_scales, window=window, sinks=sinks)
         return out[:, None]
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
                               n_query=q.shape[1], k_scales=k_scales,
                               v_scales=v_scales, q_lens=q_lens,
-                              window=window)
+                              window=window, sinks=sinks)
     return _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables,
                        scale, k_scales=k_scales, v_scales=v_scales,
-                       window=window)
+                       window=window, sinks=sinks)
 
 
 # ------------------------------------------------------------- page cache
@@ -1090,6 +1261,19 @@ def append_rows(pool, pages, slots, vals, interpret=False):
     return _append_xla(pool, pages, slots, vals)
 
 
+def packed_k_rows(vals, pool):
+    """K rows ``vals`` (kv_heads, tokens, d) as ``pool``'s rows take them:
+    where a row of the pool holds several heads side by side (``k_pack``:
+    its head axis is that many times shorter), (kv_heads / n, tokens,
+    n * d); else as they are."""
+    heads, tokens, d = vals.shape
+    n = heads // pool.shape[0]
+    if n == 1:
+        return vals
+    return vals.reshape(heads // n, n, tokens, d).transpose(0, 2, 1, 3) \
+        .reshape(heads // n, tokens, n * d)
+
+
 # the eager cache's append: the pool buffer is DONATED, so the step's
 # rows are written in place instead of into a copy of the pool
 _append_rows_donated = jax.jit(append_rows, donate_argnums=(0,),
@@ -1104,11 +1288,19 @@ def paged_layout(model) -> dict:
       calls' order, from ``model.attention_kinds()`` where the model has it
       (an entry of two names the pool of its own index; an entry of three
       names its pool, and a call on a pool an earlier call opened walks it
-      without appending: ``shared``), else one full-attention call a layer;
+      without appending: ``shared``; a fourth member says the call hands
+      ``attend`` a learned sink a query head: ``sinks``, a flag a call),
+      else one full-attention call a layer;
     * ``pools``: how many page pools that makes;
-    * ``kv_heads`` / ``head_dim``: a page's heads as ``attend`` is handed
-      K and V (``model.kv_page_shape()`` where the model lays them out
-      otherwise than its config says);
+    * ``pool_shapes``: [(kv_heads, k_dim, v_dim)] a pool: a page's heads
+      as ``attend`` is handed K and V for that pool, K's width and V's.
+      From ``model.kv_page_shape()`` where the model lays them out
+      otherwise than its config says: ``(kv_heads, head_dim)`` for pools
+      that are all alike, or one ``(kv_heads, k_dim, v_dim)`` a pool for
+      a model whose pools differ (MiMo-V2-Flash: 8 heads in its sliding
+      pools and 4 in its full ones, K 192 wide beside V of 128);
+    * ``kv_heads`` / ``head_dim``: the one shape of pools that are all
+      alike with K as wide as V, None where they are not;
     * ``state``: ``model.recurrent_state()`` or None, its slot's arrays
       under ``shapes`` (a model that says ``shape`` has one).  A slot is
       whatever arrays the model lists, ``layers`` times over, in the order
@@ -1123,26 +1315,39 @@ def paged_layout(model) -> dict:
         kinds = list(model.attention_kinds())
     else:
         kinds = [(c.num_attention_heads, None)] * c.num_hidden_layers
-    calls, opened = [], set()
+    calls, sinks, opened = [], [], set()
     for i, kind in enumerate(kinds):
         heads, window = kind[0], kind[1]
-        pool = kind[2] if len(kind) > 2 else i
+        pool = kind[2] if len(kind) > 2 and kind[2] is not None else i
         calls.append((heads, window, pool, pool in opened))
+        sinks.append(bool(kind[3]) if len(kind) > 3 else False)
         opened.add(pool)
     if hasattr(model, "kv_page_shape"):
-        kv_heads, head_dim = model.kv_page_shape()
+        shape = model.kv_page_shape()
     else:
-        kv_heads = c.num_key_value_heads
         # a config that states its head_dim means it (2,048 / 48 query
         # heads is not 128)
-        head_dim = (getattr(c, "head_dim", None)
-                    or c.hidden_size // c.num_attention_heads)
+        shape = (c.num_key_value_heads,
+                 getattr(c, "head_dim", None)
+                 or c.hidden_size // c.num_attention_heads)
+    if isinstance(shape[0], (tuple, list)):       # a shape a pool
+        shapes = [tuple(int(x) for x in p) for p in shape]
+        if len(shapes) != len(opened):
+            raise ValueError(
+                f"kv_page_shape() gives {len(shapes)} pool shapes for the "
+                f"{len(opened)} pools the model's calls open")
+        alike = len(set(shapes)) == 1 and shapes[0][1] == shapes[0][2]
+        kv_heads, head_dim = shapes[0][:2] if alike else (None, None)
+    else:
+        kv_heads, head_dim = (int(x) for x in shape)
+        shapes = [(kv_heads, head_dim, head_dim)] * len(opened)
     state = (model.recurrent_state()
              if hasattr(model, "recurrent_state") else None)
     if state is not None and "shapes" not in state:
         state = dict(state, shapes=[tuple(state["shape"])])
-    return {"calls": calls, "pools": len(opened), "kv_heads": int(kv_heads),
-            "head_dim": int(head_dim), "state": state}
+    return {"calls": calls, "sinks": sinks, "pools": len(opened),
+            "pool_shapes": shapes, "kv_heads": kv_heads,
+            "head_dim": head_dim, "state": state}
 
 
 class _PrefixEntry:
@@ -1165,7 +1370,14 @@ class PagedKVCache:
 
     Layout per pool: (kv_heads, total_pages, page_size, head_dim); a pool
     a K/V layer, which later layers may walk without one of their own
-    (:func:`paged_layout`).  Beside the pages, for a model whose layers
+    (:func:`paged_layout`).  A pool's shape is ITS OWN (``pool_shapes``:
+    (kv_heads, k_dim, v_dim) a pool): the KV heads may differ from pool to
+    pool and K's pages need not be as wide as V's — ``k_pages[p]``
+    ``(kv_heads_p, total_pages, page_size, k_dim_p)`` beside ``v_pages[p]``
+    ``(..., v_dim_p)``; a K head of 192 lies two to a row of 384
+    (:func:`k_pack`), so no pool holds a padded lane.  One page table a
+    sequence all the same: a page index is valid in every pool, the pools
+    differ in the bytes a page holds (``page_bytes``).  Beside the pages, for a model whose layers
     carry a recurrent state: slot pools, ``state_slots`` + 1 slots of each
     array of a layer's state (``state_pools``, a layer's arrays side by
     side), a slot a sequence taken and returned with its pages; a layer
@@ -1207,6 +1419,8 @@ class PagedKVCache:
         return cls(
             num_layers=layout["pools"], kv_heads=layout["kv_heads"],
             head_dim=layout["head_dim"],
+            pool_shapes=(None if layout["kv_heads"] is not None
+                         else layout["pool_shapes"]),
             total_pages=total_pages, page_size=page_size,
             dtype=model.model.embed_tokens.weight._data.dtype,
             kv_dtype=kv_dtype, mesh=mesh,
@@ -1218,13 +1432,37 @@ class PagedKVCache:
                  total_pages: int = 256, page_size: int = 16,
                  dtype=jnp.float32, kv_dtype: Optional[str] = None,
                  mesh=None, state_layers: int = 0, state_shape=(),
-                 state_slots: int = 0):
+                 state_slots: int = 0, pool_shapes=None):
         if kv_dtype not in (None, "int8"):
             raise ValueError(
                 f"kv_dtype must be None or 'int8', got {kv_dtype!r}")
         self.num_layers = num_layers
+        # ``kv_heads`` / ``head_dim``: the one shape of pools that are all
+        # alike; None for a cache built from ``pool_shapes``, [(kv_heads,
+        # k_dim, v_dim)] a pool, which then speak for every pool
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        if pool_shapes is None:
+            pool_shapes = [(kv_heads, head_dim, head_dim)] * num_layers
+        self.pool_shapes = [tuple(int(x) for x in p) for p in pool_shapes]
+        if len(self.pool_shapes) != num_layers:
+            raise ValueError(f"{len(self.pool_shapes)} pool shapes for "
+                             f"{num_layers} pools")
+        for heads, k_dim, _ in self.pool_shapes:
+            if heads % k_pack(k_dim):
+                raise ValueError(
+                    f"{heads} KV heads of {k_dim} do not fill whole "
+                    f"128-lane rows {k_pack(k_dim)} at a time")
+        unlike = (len(set(self.pool_shapes)) > 1
+                  or any(k != v for _, k, v in self.pool_shapes))
+        if unlike and (kv_dtype is not None or mesh is not None):
+            raise ValueError(
+                ("kv_dtype='int8'" if kv_dtype is not None else "a tensor "
+                 "mesh") + f": the pools differ in shape "
+                f"({sorted(set(self.pool_shapes))} as (kv heads, K width, V "
+                "width)), and neither the int8 scale pools nor the head-"
+                "axis sharding has been held to a reference for pools of "
+                "unequal heads or a K wider than its V")
         self.page_size = page_size
         self.total_pages = total_pages
         # tensor-parallel serving (ISSUE 20): under a ('tensor',) mesh
@@ -1251,21 +1489,7 @@ class PagedKVCache:
         # attention kernels dequantize toward (the model's dtype)
         self.kv_quant = kv_dtype == "int8"
         self.compute_dtype = dtype
-        store = jnp.int8 if self.kv_quant else dtype
-        shape = (kv_heads, total_pages, page_size, head_dim)
-        sshape = (kv_heads, total_pages, page_size, 1)
-        self.k_pages = [self._zeros(shape, store)
-                        for _ in range(num_layers)]
-        self.v_pages = [self._zeros(shape, store)
-                        for _ in range(num_layers)]
-        if self.kv_quant:
-            self.k_scales = [self._zeros(sshape, jnp.float32)
-                             for _ in range(num_layers)]
-            self.v_scales = [self._zeros(sshape, jnp.float32)
-                             for _ in range(num_layers)]
-        else:
-            self.k_scales = []
-            self.v_scales = []
+        self._page_zeros()
         # recurrent slots: float32 whatever the pages' type (a state is
         # summed over the whole sequence)
         self.state_layers = int(state_layers)
@@ -1297,6 +1521,31 @@ class PagedKVCache:
         # across a failed step to tell a host-side fault (KV intact)
         # from a REAL donated-buffer loss (survivors need replay)
         self.generation = 0
+
+    def _page_zeros(self):
+        """Zeroed page pools (and in the int8 mode their scale pools), a
+        pool by its own shape: K rows as :func:`k_pack` lays them."""
+        store = jnp.int8 if self.kv_quant else self.compute_dtype
+        pages, ps = self.total_pages, self.page_size
+        self.k_pages, self.v_pages = [], []
+        for heads, k_dim, v_dim in self.pool_shapes:
+            n = k_pack(k_dim)
+            self.k_pages.append(self._zeros(
+                (heads // n, pages, ps, n * k_dim), store))
+            self.v_pages.append(self._zeros((heads, pages, ps, v_dim), store))
+        self.k_scales, self.v_scales = [], []
+        if self.kv_quant:
+            for heads, _, _ in self.pool_shapes:
+                sshape = (heads, pages, ps, 1)
+                self.k_scales.append(self._zeros(sshape, jnp.float32))
+                self.v_scales.append(self._zeros(sshape, jnp.float32))
+
+    def page_bytes(self, pool: int) -> int:
+        """Bytes one page index holds in pool ``pool``: K and V of its
+        ``page_size`` positions (the int8 mode's scales apart)."""
+        heads, k_dim, v_dim = self.pool_shapes[pool]
+        return (heads * self.page_size * (k_dim + v_dim)
+                * self.k_pages[pool].dtype.itemsize)
 
     def _state_zeros(self):
         return [jnp.zeros((self.state_slots + 1,) + shape, jnp.float32)
@@ -1454,26 +1703,13 @@ class PagedKVCache:
         is dropped wholesale.  ``generation`` is bumped so the engine
         can see the loss and replay every survivor's KV (ISSUE 8)."""
         self.generation += 1
-        shape = (self.kv_heads, self.total_pages, self.page_size,
-                 self.head_dim)
-        dtype = jnp.int8 if self.kv_quant else self.compute_dtype
-        # _place: a TP cache's rebuilt pools must come back SHARDED on
+        # _zeros: a TP cache's rebuilt pools must come back SHARDED on
         # the same mesh, or the next compiled call would silently
         # re-replicate them (and the decoder's pinned input shardings
-        # would force a transfer per dispatch)
-        self.k_pages = [self._zeros(shape, dtype)
-                        for _ in range(self.num_layers)]
-        self.v_pages = [self._zeros(shape, dtype)
-                        for _ in range(self.num_layers)]
-        if self.kv_quant:
-            # the scale pools are part of the KV state: a rebuild zeroes
-            # them too, and the survivor replay re-registers each page's
-            # scales alongside its int8 values
-            sshape = (self.kv_heads, self.total_pages, self.page_size, 1)
-            self.k_scales = [self._zeros(sshape, jnp.float32)
-                             for _ in range(self.num_layers)]
-            self.v_scales = [self._zeros(sshape, jnp.float32)
-                             for _ in range(self.num_layers)]
+        # would force a transfer per dispatch).  The scale pools are part
+        # of the KV state: a rebuild zeroes them too, and the survivor
+        # replay re-registers each page's scales alongside its int8 values
+        self._page_zeros()
         # the slots' content is gone with the pages': who holds one
         # replays into it from an empty context
         self.state_pools = self._state_zeros()
@@ -1722,7 +1958,8 @@ class PagedKVCache:
             self.v_scales[layer] = _append_rows_donated(
                 self.v_scales[layer], pg, sl, vsc)
         self.k_pages[layer] = _append_rows_donated(
-            self.k_pages[layer], pg, sl, ks)
+            self.k_pages[layer], pg, sl,
+            packed_k_rows(ks, self.k_pages[layer]))
         self.v_pages[layer] = _append_rows_donated(
             self.v_pages[layer], pg, sl, vs)
         if layer == self.num_layers - 1:

@@ -228,6 +228,36 @@ class TestSharesAddUp:
                 total = total + (part(x)._data - y_shared)
             assert rel(total + y_shared, y_whole) < 1e-5
 
+    @pytest.mark.parametrize("tokens", [3, 7, 8, 40])
+    def test_a_share_on_the_line_takes_the_dense_product_at_any_step(
+            self, monkeypatch, tokens):
+        """4 held of 8 at top-2 sits ON the line (count == DENSE_SHARE x
+        top_k) and takes the dense product whatever the step's tokens, a
+        step so sparse that some held expert is chosen by nobody (3 tokens:
+        6 pairs over 8 experts) included (ISSUE 49: measured at 16 of 256,
+        where the grouped product won only at a 32-token step no cell
+        runs).  The rule reads shapes, never the step."""
+        from paddle_tpu.incubate.distributed.models.moe import moe_layer
+        took = []
+        for name in ("_held_experts", "_grouped_experts"):
+            real = getattr(moe_layer, name)
+            monkeypatch.setattr(
+                moe_layer, name,
+                lambda *a, _r=real, _n=name: took.append(_n) or _r(*a))
+        rng = np.random.default_rng(49)
+        layer = self._layer((4, 4), 9)
+        x = paddle.to_tensor(rng.standard_normal((tokens, M))
+                             .astype("float32"))
+        y = layer(x)._data
+        assert took == ["_held_experts"]
+        ex = layer.experts
+        idx, w = layer.gate.route_no_drop(x)
+        want, *_ = G.grouped_swiglu(
+            x._data, idx._data - 4, w._data,
+            (idx._data >= 4) & (idx._data < 8), ex.gate_proj._data,
+            ex.up_proj._data, ex.down_proj._data)
+        assert rel(y, want + layer.shared_expert(x)._data) < 1e-5
+
     @pytest.mark.parametrize("held", [(0, E), (0, 4)])
     def test_token_mask_reaches_either_product(self, held):
         """The grouped product and the dense one of a narrow share leave

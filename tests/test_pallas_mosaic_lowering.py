@@ -647,6 +647,73 @@ class TestLagunaCellLowering:
             rf"bf16\[{e},\d+,\d+\][^ ]* (copy|transpose)\(", text)
 
 
+class TestMiMoCellLowering:
+    """`mimo-v2-flash.serve.mixed32`'s own kernels at its shapes: 32 rows,
+    64 query heads over 8 KV heads (window 128, a sink a query head) or 4
+    (full), K heads of 192 lying two to a row of 384 beside V pages of 128,
+    a table pinned at 512 over 8,192 pages of 16; 16 held experts of
+    4,096 x 2,048 at top-8 (the dense product in the cell; the grouped
+    one, which ``chip_limits_mimo.py --experts`` times against it, lowers
+    at a decode step's 32 positions, where the float32 partial sums of
+    every row fit, and is refused in words at a chunk step's 160 and 288,
+    where they do not)."""
+
+    @pytest.mark.parametrize("nq", [1, 32, 128],
+                             ids=["decode", "span32", "span128"])
+    @pytest.mark.parametrize("kvh,window,sinks", [(8, 128, True),
+                                                  (4, None, False)],
+                             ids=["sliding8", "full4"])
+    def test_paged_kernels(self, chip, kvh, window, sinks, nq):
+        heads, dk, dv, batch = 64, 192, 128, 32
+        assert paged_attention.k_pack(dk) == 2
+
+        def fn(q, kp, vp, lens, tabs, ql, b):
+            return _decode_pallas(
+                q, kp, vp, lens, tabs, 1 / math.sqrt(dk), n_query=nq,
+                q_lens=ql if nq > 1 else None, window=window,
+                sinks=b if sinks else None)
+
+        q = (batch, heads, dk) if nq == 1 else (batch, nq, heads, dk)
+        text = chip.compile(
+            fn, (q,), ((kvh // 2, 8192, 16, 2 * dk),),
+            ((kvh, 8192, 16, dv),), ((batch,), I32), ((batch, 512), I32),
+            ((batch,), I32), ((heads,), F32))
+        assert ("paged_attention_ragged" if nq > 1
+                else "paged_attention") in text
+        # neither pool is copied or re-laid out on the way to the kernel
+        assert not re.search(
+            r"bf16\[\d+,8192,16,\d+\][^ ]* (copy|transpose|pad)\(", text)
+
+    def test_the_append_takes_a_packed_k_pool(self, chip, monkeypatch):
+        monkeypatch.setattr(paged_attention, "_use_pallas", lambda: True)
+        text = chip.compile(
+            lambda pool, pg, sl, vals: append_rows(pool, pg, sl, vals),
+            ((4, 8192, 16, 384),), ((160,), I32), ((160,), I32),
+            ((4, 160, 384),))
+        assert "kv_append_rows" in text
+
+    @pytest.mark.parametrize("tokens", [32, 160, 288])
+    def test_grouped_experts(self, chip, monkeypatch, tokens):
+        monkeypatch.setattr(moe_grouped_ffn, "_use_pallas", lambda: True)
+        m, h, e, k = 4096, 2048, 16, 8
+        rows = moe_grouped_ffn.plan_blocks(tokens * k, e) \
+            * moe_grouped_ffn.BLOCK_ROWS
+        fits = rows * m * 4 <= moe_grouped_ffn.ACC_BYTES
+        assert fits == (tokens == 32)
+        shapes = (((tokens, m),), ((tokens, k), I32), ((tokens, k), F32),
+                  ((tokens, k), jnp.bool_), ((e, m, h),), ((e, m, h),),
+                  ((e, h, m),))
+        if not fits:
+            with pytest.raises(NotImplementedError, match="partial sums"):
+                chip.compile(moe_grouped_ffn.grouped_swiglu, *shapes)
+            return
+        text = chip.compile(moe_grouped_ffn.grouped_swiglu, *shapes)
+        assert [ln for ln in text.splitlines()
+                if "tpu_custom_call" in ln and "%moe_grouped_ffn" in ln]
+        assert not re.search(
+            rf"bf16\[{e},\d+,\d+\][^ ]* (copy|transpose)\(", text)
+
+
 # ------------------------------------ the Kimi-Linear cell's KDA kernels
 class TestKdaChunkLowering:
     """`kimi-linear.train.seq8k`'s chunkwise delta rule at its shape,
