@@ -29,6 +29,7 @@ from ..framework.tensor import Tensor, wrap_array
 from ..framework.tape import no_grad
 from ..ops.pallas.flash_attention import DEFAULT_MASK_VALUE
 from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
+                                          _packed_of_rows, _rows_of_packed,
                                           paged_layout,
                                           _round_up, append_rows,
                                           dequantize_kv, kv_tokens_visible,
@@ -37,6 +38,7 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           paged_attention,
                                           paged_attention_ragged,
                                           q_positions_computed,
+                                          q_positions_moved,
                                           quantize_kv, walk_block_pages,
                                           walk_head_group)
 from ..testing import faults as _faults
@@ -243,46 +245,6 @@ def next_pow2(n: int) -> int:
     return b
 
 
-# ------------------------------------------------ the ragged step's pack
-# The ragged program's dense layers run over the step's tokens PACKED
-# along one axis — row 0's span, then row 1's, ... then pad — and only
-# the paged kernel sees the (rows, span) rectangle.  ``off[r]`` is where
-# row ``r`` starts on the packed axis; ``off=None`` says the packed axis
-# is as long as the rectangle and every row keeps its place in it (a
-# reshape).  Both ways are jitted: a program's layers all call them at
-# the same shapes and share one traced and lowered body.
-
-@functools.partial(jax.jit, static_argnames=("span",))
-def _rows_of_packed(x, off, span):
-    """``x[T, ...]`` packed -> ``[rows, span, ...]``: row ``r`` is the
-    ``span`` entries from ``off[r]`` on.  Past the row's own tokens that
-    is its successors' (or pad): the paged kernel, which is handed the
-    rows' own lengths, never computes with them."""
-    if off is None:
-        return x.reshape((-1, span) + x.shape[1:])
-    at = off[:, None] + jnp.arange(span, dtype=jnp.int32)[None, :]
-    return x[jnp.minimum(at, x.shape[0] - 1)]
-
-
-@functools.partial(jax.jit, static_argnames=("tokens",))
-def _packed_of_rows(x, off, tokens):
-    """``x[rows, span, ...]`` -> ``[tokens, ...]`` packed: position ``t``
-    takes row ``r``'s column ``t - off[r]``, ``r`` the last row that
-    starts at or before ``t``.  Past the step's tokens that is the last
-    row's tail: of the paged kernel's output, its dead queries, which
-    the kernel writes as zeros — the dense layers, the router and the
-    head run over the pad positions too, and the caller discards what
-    they make of them."""
-    rows, span = x.shape[:2]
-    flat = x.reshape((rows * span,) + x.shape[2:])
-    if off is None:
-        return flat
-    at = jnp.arange(tokens, dtype=jnp.int32)
-    row = jnp.sum(at[:, None] >= off[None, :], axis=1) - 1
-    col = jnp.minimum(at - off[row], span - 1)
-    return flat[row * span + col]
-
-
 #: what a path with no window says to a sliding-attention layer
 _NO_WINDOW = ("a sliding-attention layer (window=...) reached {}, which "
               "has no window and would attend the full context: serve "
@@ -425,9 +387,10 @@ class _TracedPagedContext:
     are (tokens,), row ``r``'s ``q_lens[r]`` tokens standing together
     from ``row_off[r]`` on (``row_off=None``: the packed axis is the
     whole rectangle, row-major).  The append takes the packed rows as
-    they come; only the paged kernel's call goes to the (rows, ``span``)
-    rectangle, each row's span LEFT-aligned in it, and its output comes
-    back packed.  ``lens``, ``q_lens`` and ``tables`` are per ROW.
+    they come, and so does the paged kernel: it is told where each row
+    starts and copies the row's own queries from there (off the chip its
+    XLA oracle gathers the (rows, ``span``) rectangle and packs the
+    output back).  ``lens``, ``q_lens`` and ``tables`` are per ROW.
 
     What a model may ask of it beyond ``attend``: ``token_mask`` — which
     positions of the batch axis are tokens (a pad position's write page
@@ -674,12 +637,11 @@ class _TracedPagedContext:
                                        wrap_array(v_att), causal=True)
             return out
         # ragged unified step (ISSUE 17): every row attends its OWN
-        # left-aligned span — decode rows, chunk spans and verify
-        # blocks mix in one kernel call with per-row traced lengths.
-        # The packed tokens (b of them, s == 1) go to the kernel's
-        # (rows, span) rectangle for this call alone and come back
-        # packed; the pad queries come back as zeros and what the
-        # layers behind make of them is discarded by the program's tail
+        # span — decode rows, chunk spans and verify blocks mix in one
+        # kernel call with per-row traced lengths and offsets on the
+        # packed tokens (b of them, s == 1); the pad queries come back
+        # as zeros and what the layers behind make of them is discarded
+        # by the program's tail
         if self.q_lens is not None:
             return self._attend_ragged(q, layer, window, scale, sinks)
         # decode (``step``, the scan of ``multi_step``): one token a row
@@ -690,20 +652,19 @@ class _TracedPagedContext:
 
     def _attend_ragged(self, q, layer, window, scale, sinks=None):
         """The ragged step's kernel call against pool ``layer`` as it
-        stands: the packed queries to the (rows, span) rectangle and the
-        output back."""
+        stands, on the packed tokens: the kernel finds each row's queries
+        at the row's offset and writes its outputs back there."""
         ksc, vsc = self._layer_scales(layer)
         # queries against K rows that hold several heads are packed HERE,
-        # on the step's tokens, not on the rectangle (14 x the positions)
-        rect = _rows_of_packed(
-            packed_queries(q._data[:, 0], self.k_pages[layer],
-                           self.v_pages[layer]), self.row_off, self.span)
+        # on the step's tokens
         out = paged_attention_ragged(
-            rect, self.k_pages[layer], self.v_pages[layer], self.lens,
+            packed_queries(q._data[:, 0], self.k_pages[layer],
+                           self.v_pages[layer]),
+            self.k_pages[layer], self.v_pages[layer], self.lens,
             self.q_lens, self.tables, scale=scale, k_scales=ksc,
-            v_scales=vsc, window=window, sinks=sinks)
-        return wrap_array(
-            _packed_of_rows(out, self.row_off, q.shape[0])[:, None])
+            v_scales=vsc, window=window, sinks=sinks, row_off=self.row_off,
+            span=self.span)
+        return wrap_array(out[:, None])
 
 
 #: rows the feed's index and token vectors are padded to (the rows
@@ -1155,8 +1116,9 @@ class JittedPagedDecoder:
                 ``packed_tokens(B, S)`` positions, as (T, 1): embedding,
                 projections, feed-forward, norms, the head and the
                 argmax compute T positions, the append takes them as
-                they come, and only the paged kernel's call is handed
-                the rectangle (``_TracedPagedContext.attend``).  Where
+                they come, and so does the paged kernel, which is told
+                where each row starts (``_TracedPagedContext.attend``):
+                no activation has the rectangle's size.  Where
                 T is less than B x S the step's tokens are PACKED onto
                 it — row 0's span, row 1's, ..., then pad (id 0, the
                 dropped page, position 0); where it is not, the axis is
@@ -1780,10 +1742,11 @@ class JittedPagedDecoder:
         # what this dispatch computes against what it was asked for: the
         # engine writes it into the step ring as the ``dispatch`` record
         # (the flight's own: a later launch starts another).
-        # ``rows_padded`` x ``span_padded`` is the paged kernel's query
-        # rectangle, ``tokens_padded`` the positions every other layer
-        # computes.  ``kv_tokens_walked`` is what the paged kernel walks
-        # for these rows, each row's context in whole blocks, and
+        # ``rows_padded`` x ``span_padded`` is the program's bucket (no
+        # activation of that size is made: the paged kernel takes a row's
+        # queries from the packed tokens), ``tokens_padded`` the positions
+        # every layer computes.  ``kv_tokens_walked`` is what the paged
+        # kernel walks for these rows, each row's context in whole blocks, and
         # ``q_positions_computed`` the query positions it computes for
         # them, each row's own queries in whole tiles: the kernel's own
         # rules (pad rows are one token long)
@@ -1914,8 +1877,10 @@ class JittedPagedDecoder:
         and ``q_lens`` queries, a LAYER's worth each (the mean over the
         layers where they differ): ``ctx_tokens`` the positions some
         query attends, ``kv_tokens_walked`` what the kernel walks for
-        them in whole blocks and ``q_positions_computed`` the query
-        positions it computes for them in whole tiles, ``page_copies``
+        them in whole blocks, ``q_positions_computed`` the query
+        positions it computes for them in whole tiles and
+        ``q_positions_moved`` those it copies into VMEM (the same tiles,
+        of the packed stream: never the bucket), ``page_copies``
         the copy descriptors its walks issue (one a page for each group of
         kv heads a grid step owns and each pool) and ``head_page_reads``
         the (page, head, pool) reads they serve, all by the kernel's own
@@ -1941,7 +1906,7 @@ class JittedPagedDecoder:
         if not total:                   # no K/V layer: nothing is walked
             return {}
         means = ["ctx_tokens", "kv_tokens_walked", "q_positions_computed",
-                 "page_copies", "head_page_reads"]
+                 "q_positions_moved", "page_copies", "head_page_reads"]
         kv_dtype = cache.k_pages[0].dtype
         pools = 4 if cache.kv_quant else 2
         if any(kind[2] for kind in self._attn_kinds):
@@ -1972,8 +1937,10 @@ class JittedPagedDecoder:
             out["kv_tokens_walked"] += n * walked
             if shared:
                 out["kv_tokens_walked_shared"] += n * walked
-            out["q_positions_computed"] += n * q_positions_computed(
-                q_lens, span, group, cache.compute_dtype)
+            for name, rule in (("q_positions_computed", q_positions_computed),
+                               ("q_positions_moved", q_positions_moved)):
+                out[name] += n * rule(q_lens, span, group,
+                                      cache.compute_dtype)
             out["page_copies"] += n * steps * pages
             out["head_page_reads"] += n * heads * pages
             if window is not None:

@@ -33,6 +33,14 @@ TPU-native design (see /opt/skills/guides/pallas_guide.md):
     softmax and products run for the ``ceil(q_len * group / tile)`` live
     tiles of each context block, so a one-token row of a 128-wide bucket
     costs one tile a block and not the bucket; dead queries are zeros;
+  - and it TAKES THEM FROM THE STEP'S PACKED TOKENS: its query operand is
+    the packed stream, kv-head-major with a token on an untiled major
+    axis (``[kv_heads, tokens, group, d]``, a re-layout of the step's
+    tokens), in HBM like the pools; each row's offset rides with the
+    lengths, a grid step copies its row's live tiles from there and
+    writes the row's own positions of the output back there.  No array
+    has the (rows x span) rectangle's size; the rectangle of the public
+    signature is the packed stream whose rows start a span apart;
   - GQA: the q-head group of each kv head computes together (group x
     head_dim MXU tiles);
   - the append (``append_rows``) keeps a pool in the one layout the
@@ -137,9 +145,9 @@ def packed_queries(q, k_pages, v_pages):
     whose rows hold several heads side by side (``k_pack``): (..., q_heads,
     n * d), a head's values in the lanes of its own kv head and zeros under
     the row's other heads (exact: their products are zeros).  As they are
-    for a pool of one head a row, or when already so packed.  Cheap on a
-    step's packed tokens, dear on the (rows, span) rectangle: the ragged
-    step packs before it goes to the rectangle."""
+    for a pool of one head a row, or when already so packed.  The ragged
+    step packs its tokens' queries before its call; a (rows, span)
+    rectangle handed to ``paged_attention_ragged`` is packed by the call."""
     kv_heads, wide = v_pages.shape[0], k_pages.shape[-1]
     pack = kv_heads // k_pages.shape[0]
     if pack == 1 or q.shape[-1] == wide:
@@ -218,8 +226,10 @@ def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype,
                     v_dim=None, sinks=False):
     """KV heads one grid step of the kernel owns, from shapes alone: the
     largest divisor of the call's ``kv_heads`` whose heads together keep
-    their q and out blocks (two pipeline buffers each; q as wide as K,
-    out as wide as V: ``v_dim`` where that is not ``head_dim``), their
+    their q and out blocks (two buffers each — the pipeline's, or in the
+    ragged kernel the queries as copied and as rows and the outputs'
+    stage, which take less; q as wide as K, out as wide as V: ``v_dim``
+    where that is not ``head_dim``), their
     softmax scratch (m, l, acc in float32), with ``sinks`` the rows' sink
     block (two buffers), and their double K and V buffers of
     ``walk_block_pages`` pages inside ``_HEAD_GROUP_BYTES``.  A page copy
@@ -347,6 +357,19 @@ def q_positions_computed(q_lens, n_query, group, q_dtype):
     return int(tiles.sum()) * (tile // group)
 
 
+def q_positions_moved(q_lens, n_query, group, q_dtype):
+    """Query positions a paged call copies from HBM into VMEM for rows of
+    these ``q_lens`` in an ``n_query`` bucket: the ragged kernel copies
+    what it computes, a row's live tiles from the row's offset on the
+    packed stream (``_decode_kernel``'s ``each_query_copy`` rides the
+    loop of its products, so this IS ``q_positions_computed``), where a
+    block a row moved ``n_query`` positions whatever the row held — the
+    (rows x span) rectangle ``kernel.paged_attn.query_moved_share`` holds
+    this against.  The outputs go back a row's own ``q_len`` positions,
+    never more.  The one-query kernel's block is a position a row."""
+    return q_positions_computed(q_lens, n_query, group, q_dtype)
+
+
 def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                    scale, page_size, block_pages, n_query=1, group=1,
                    quantized=False, ragged=False, window=None, tile=None,
@@ -373,9 +396,19 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     ``cols < length - (n_query - 1 - s)`` — per-row, per-query limits,
     so variable accept lengths cost masking, not padding.
 
-    ``ragged`` (ISSUE 17): ``lens_ref`` is (2, batch) — kv lengths in
-    row 0, PER-ROW query-span lengths in row 1 — and each sequence's
-    real queries sit LEFT-aligned in the n_query bucket.  Query ``j``
+    ``ragged`` (ISSUE 17): ``lens_ref`` is (3, batch) — kv lengths in
+    row 0, PER-ROW query-span lengths in row 1, where each row's queries
+    start on the step's PACKED token axis in row 2 — and ``q_ref`` and
+    ``o_ref`` are that axis whole, in HBM: ``[kv_heads, tokens, group,
+    d]`` (the group padded to whole sublane tiles, ``_staged_group``).
+    The step copies its row's live tiles of positions into a stage (one
+    descriptor a tile for all its heads, started before the first
+    block's pages and waited for behind them), makes rows of them (row =
+    ``s * group + g``: what a block a row held before), and after the
+    walk copies the row's OWN ``qlen`` positions of the output back, by
+    the bits of ``qlen``: the next row's tokens stand right behind this
+    row's, so nothing may land past them, and the positions no row owns
+    keep the zeros the output is aliased onto.  Query ``j``
     of row ``b`` attends ``cols < kv - qlen + j + 1``.  One grid shape
     then serves a batch mixing decode rows (qlen 1), prefill/chunk
     spans, and verify blocks — and THE QUERY WORK OF A GRID STEP FOLLOWS
@@ -389,10 +422,11 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     tiles.  A one-token row is one tile, a full chunk row all of them
     (what the whole block computed before the cut); the walk's blocks
     and their order are the bucket's, so a live query's output does not
-    depend on the tile it falls in.  Dead queries (j >= qlen) are
-    WRITTEN AS ZEROS: whole dead tiles are never computed, and those of
-    the last live tile (which clamp at the full kv length and compute
-    finite values) are zeroed at the end.  The two uniform modes take
+    depend on the tile it falls in.  Dead queries (j >= qlen) COME BACK
+    AS ZEROS: whole dead tiles are never computed, and those of the last
+    live tile (which clamp at the full kv length and compute finite
+    values from whatever the stream holds behind the row) are never
+    copied out.  The two uniform modes take
     the whole block as one static tile: the program they always were.
 
     ``quantized`` (ISSUE 9): the K/V pages arrive as INT8 with their
@@ -418,13 +452,18 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     visible keys' terms, and the sink gives no value: a row's running max,
     sum and accumulator START from it, ``(sink, 1, 0)``, where without it
     they start from ``(-inf, 0, 0)``; nothing else differs."""
-    # operands: [K and V scales] [sinks] out; scratch: K, V buffers
-    # [scale buffers] semaphores m l acc
+    # operands: [K and V scales] [sinks] [the zeros the packed output is
+    # aliased onto] out; scratch: K, V buffers [scale buffers] semaphores
+    # m l acc [the ragged kernel's query and output stages]
     ins = 2 * quantized + sinks
     ks_hbm, vs_hbm = rest[:2] if quantized else (None, None)
     sink_ref = rest[ins - 1] if sinks else None
+    ins += ragged
     o_ref, k_buf, v_buf = rest[ins:ins + 3]
     ks_buf, vs_buf = rest[ins + 3:ins + 5] if quantized else (None, None)
+    if ragged:
+        q_stage, q_rows, o_stage = rest[-3:]
+        rest = rest[:-3]
     sems, m_scr, l_scr, acc_scr = rest[-4:]
     # what a page is copied from and to; its scale block travels with it
     pools = [(k_hbm, k_buf), (v_hbm, v_buf)]
@@ -495,20 +534,63 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
         lax.fori_loop(0, hb, body, 0)
 
     if ragged:
-        # the row's own queries: its first ``qlen * group`` rows are the
-        # live ones (row = s * group + g, left-aligned), in whole tiles
-        qlen = lens_ref[1, b]
+        # the row's own queries: ``qlen`` positions of the step's packed
+        # stream from ``off`` on, which make the first ``qlen * group``
+        # rows of its query block (row = s * group + g), in whole tiles
+        qlen, off = lens_ref[1, b], lens_ref[2, b]
         n_tiles = live_query_tiles(qlen, group, tile)
+        per = tile // group             # query positions a tile
+        q_hbm, o_hbm = q_ref, o_ref     # the packed stream and its twin
+        mine = heads[hb]                # the kv-head axis of both
 
         def each_tile(act):
             """``act(rows, row0)`` on every live tile of the query block:
             ``rows`` indexes the tile in a (rows, ...) ref."""
+            if tile == m_scr.shape[1]:  # a bucket of one tile, of any height
+                pl.when(n_tiles > 0)(lambda: act(slice(None), 0))
+                return
+
             def body(t, carry):
                 row0 = pl.multiple_of(t * tile, tile)
                 act(pl.ds(row0, tile), row0)
                 return carry
 
             lax.fori_loop(0, n_tiles, body, 0)
+
+        def positions(row0, first=0):
+            """The query positions of the tile whose rows start at
+            ``row0``, counted from ``first``."""
+            at = row0 // group if isinstance(row0, int) \
+                else lax.div(row0, group)
+            return pl.ds(first + at, per)
+
+        def each_query_copy(act):
+            """``act`` on the copy of every live tile's positions, all the
+            step's heads a descriptor, from the packed stream into the
+            stage.  A tile's tail past the row's own tokens is its
+            successors' (or the stream's pad): dead queries."""
+            def copy(rows, row0):
+                act(pltpu.make_async_copy(
+                    q_hbm.at[mine, positions(row0, off)],
+                    q_stage.at[:, positions(row0)], sems.at[2]))
+
+            each_tile(copy)
+
+        def each_output_copy(act):
+            """``act`` on the copies of the row's OWN ``qlen`` positions
+            from the stage to the packed output: one a set bit of
+            ``qlen``, so nothing lands on the next row's tokens, which
+            stand right behind this row's.  (A row is no longer than its
+            bucket, nor than the stream.)"""
+            for bit in reversed(range(
+                    min(n_query, o_hbm.shape[1]).bit_length())):
+                at = (qlen >> (bit + 1)) << (bit + 1)   # the higher bits
+                copy = pltpu.make_async_copy(
+                    o_stage.at[:, pl.ds(at, 1 << bit)],
+                    o_hbm.at[mine, pl.ds(off + at, 1 << bit)], sems.at[3])
+                pl.when((qlen >> bit) & 1 == 1)(functools.partial(act, copy))
+
+        each_query_copy(lambda c: c.start())
     else:
         def each_tile(act):             # the whole block, as one
             act(slice(None), None)
@@ -534,6 +616,22 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     @pl.when(n_blocks > 0)
     def _first():
         each_page_copy(0, 0, lambda c: c.start())
+
+    if ragged:
+        # the staged positions as the rows the products take, while the
+        # first block's pages are in flight: (positions, group, d) ->
+        # (rows, d), a re-layout in VMEM where the group is not whole
+        # sublane tiles (``_staged_group``: its padding is dropped here)
+        each_query_copy(lambda c: c.wait())
+
+        def stage_in(j):
+            def tile_in(rows, row0):
+                x = q_stage[j, positions(row0), :group]
+                q_rows[j, rows] = x.reshape(tile, x.shape[-1])
+
+            each_tile(tile_in)
+
+        each_head(stage_in)
 
     def seen_by(shape, row0, blk):
         """Which of block ``blk``'s columns the queries of a ``shape``
@@ -601,7 +699,8 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
                 keys, values = (lambda: k_blk), (lambda: v_blk)
 
             def update(rows, row0):
-                q = q_ref[0, j, rows]               # (tile or rows, d)
+                # (tile or rows, d)
+                q = q_rows[j, rows] if ragged else q_ref[0, j, rows]
                 s = lax.dot_general(q, keys(), (((1,), (1,)), ((), ())),
                                     preferred_element_type=jnp.float32) \
                     * scale
@@ -630,29 +729,48 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
 
     lax.fori_loop(0, n_blocks, walk, 0)
 
-    if ragged:
-        # the dead tiles: zeros, never what the buffer held
-        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
-
     def finish(j):
         def tile_finish(rows, row0):
             l = l_scr[j, rows, :1]
             l_safe = jnp.where(l == 0.0, 1.0, l)
-            out = acc_scr[j, rows] / l_safe
+            out = (acc_scr[j, rows] / l_safe).astype(o_ref.dtype)
             if ragged:
-                # the dead queries of the row's last live tile: zeros too
-                qrow = row0 + lax.broadcasted_iota(jnp.int32, out.shape, 0)
-                out = jnp.where(qrow < qlen * group, out, 0.0)
-            o_ref[0, j, rows] = out.astype(o_ref.dtype)
+                # back to (positions, group, d) for the copies out
+                o_stage[j, positions(row0), :group] = \
+                    out.reshape(per, group, out.shape[-1])
+            else:
+                o_ref[0, j, rows] = out
 
         each_tile(tile_finish)
 
     each_head(finish)
 
+    if ragged:
+        # the row's own positions and no other: the dead queries of its
+        # last live tile stay in the stage, and what no row owns keeps
+        # the zeros the output was handed as
+        each_output_copy(lambda c: c.start())
+        each_output_copy(lambda c: c.wait())
+
+
+def _staged_group(group, q_dtype):
+    """The group axis of the ragged kernel's staged queries and outputs,
+    ``[kv_heads, tokens, group, d]``: a token's (group, d) block is what
+    one copy moves, so it has to be whole tiles of the array as Mosaic
+    lays it out — sublane tiles of the next power of two over ``group``,
+    from one 32-bit row (2 of bfloat16) to the dtype's full tile (8 rows
+    of 32 bits).  2, 4, 8 and 16 of bfloat16 stand as they are; 6 is
+    padded to 8 (Laguna), 3 to 4, 1 to 2; the kernel drops the padding
+    where it makes rows of the stage."""
+    packing = 4 // jnp.dtype(q_dtype).itemsize
+    unit = min(8 * packing, max(packing, 1 << (group - 1).bit_length()))
+    return _round_up(group, unit)
+
 
 def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                  interpret=False, n_query=1, k_scales=None, v_scales=None,
-                 q_lens=None, window=None, head_group=None, sinks=None):
+                 q_lens=None, window=None, head_group=None, sinks=None,
+                 row_off=None):
     """The ``pallas_call`` behind :func:`_decode_pallas` (its arguments).
     ``head_group``: the kv heads a grid step owns where a TEST wants
     another count than the shapes give (``walk_head_group``); no caller of
@@ -661,10 +779,13 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
     # queries as wide, zeros under a row's other heads, if the caller has
     # not packed them yet (the ragged step has, on its packed tokens)
     q = packed_queries(q, k_pages, v_pages)
-    if n_query == 1:
-        batch, q_heads, d = q.shape
-    else:
-        batch, _nq, q_heads, d = q.shape
+    ragged = q_lens is not None
+    batch, handed = lengths.shape[0], q.shape
+    if ragged and q.ndim == 4:
+        # the (rows, span) rectangle IS a packed stream: its rows start
+        # ``span`` apart (a reshape)
+        q = q.reshape((batch * n_query,) + q.shape[2:])
+    q_heads, d = q.shape[-2:]
     kv_heads, _tot, page_size, dv = v_pages.shape   # the output: V's width
     pack = kv_heads // k_pages.shape[0]
     assert k_pages.shape[-1] == d, (k_pages.shape, v_pages.shape, q.shape)
@@ -675,14 +796,41 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
     hb = head_group or walk_head_group(
         kv_heads, page_size, d // pack, rows, k_pages.dtype, q.dtype, dv,
         sinks is not None)
+    lanes, v_lanes = _round_up(d, 128), _round_up(dv, 128)
+    tile = None
 
-    # (batch, q_heads, d) -> (batch, kv_heads, group, d): the kv-head
-    # group rides as its own FULL axis so the q block's trailing dims
-    # (group, d) match the array dims exactly — Mosaic requires trailing
-    # block dims divisible by (8, 128) or spanning the whole axis, and
-    # group (e.g. 3) satisfies neither as a partial slice of q_heads.
-    # Multi-query folds the query axis in as well (row = s*group + g).
-    if n_query == 1:
+    if ragged:
+        # the step's packed tokens, kv-head-major, a token on an UNTILED
+        # major axis: (tokens, q_heads, d) -> (kv_heads, tokens, group,
+        # d), so that a grid step copies its row's positions from
+        # ``row_off`` on by themselves.  A re-layout of the step's tokens,
+        # not of the (rows, span) rectangle; past them stand the ``per -
+        # 1`` positions a whole-tile read at the last row's offset may
+        # take (no rectangle's row needs them: a tile divides the span)
+        tile = query_tile_rows(rows, group, q.dtype)
+        tokens, staged = q.shape[0], _staged_group(group, q.dtype)
+        slack = 0 if row_off is None else tile // group - 1
+        q4 = q.reshape(tokens, kv_heads, group, d).transpose(1, 0, 2, 3)
+        if slack or staged != group or lanes != d:
+            q4 = jnp.pad(q4, [(0, 0), (0, slack), (0, staged - group),
+                              (0, lanes - d)])
+        q_lens = jnp.asarray(q_lens, jnp.int32)
+        if row_off is None:
+            row_off = jnp.arange(batch, dtype=jnp.int32) * n_query
+        # the lengths, the spans and where each starts ride in ONE (3,
+        # batch) scalar-prefetch argument — the index maps never read it.
+        # No copy leaves the stream, whatever the caller's offsets
+        lengths = jnp.stack([
+            jnp.asarray(lengths, jnp.int32), q_lens,
+            jnp.clip(row_off, 0, jnp.maximum(tokens - q_lens, 0))])
+    elif n_query == 1:
+        # (batch, q_heads, d) -> (batch, kv_heads, group, d): the kv-head
+        # group rides as its own FULL axis so the q block's trailing dims
+        # (group, d) match the array dims exactly — Mosaic requires
+        # trailing block dims divisible by (8, 128) or spanning the whole
+        # axis, and group (e.g. 3) satisfies neither as a partial slice of
+        # q_heads.  Multi-query folds the query axis in as well (row =
+        # s*group + g)
         q4 = q.reshape(batch, kv_heads, group, d)
     else:
         q4 = q.reshape(batch, n_query, kv_heads, group, d) \
@@ -698,25 +846,17 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
     # re-tiled for the kernel on every call).
     # (A K pool whose rows hold several heads is whole tiles as it is
     # stored, and no pool is copied for it.)
-    lanes, v_lanes = _round_up(d, 128), _round_up(dv, 128)
     if lanes != d:
         pad = [(0, 0)] * 3 + [(0, lanes - d)]
-        q4, k_pages = jnp.pad(q4, pad), jnp.pad(k_pages, pad)
+        k_pages = jnp.pad(k_pages, pad)
+        if not ragged:
+            q4 = jnp.pad(q4, pad)
     if v_lanes != dv:
         v_pages = jnp.pad(v_pages, [(0, 0)] * 3 + [(0, v_lanes - dv)])
     if quantized:
         k_scales, v_scales = (
             jnp.broadcast_to(x, x.shape[:-1] + (128,))
             for x in (k_scales, v_scales))
-    ragged = q_lens is not None
-    tile = None
-    if ragged:
-        # both length kinds ride in ONE (2, batch) scalar-prefetch
-        # argument — the index maps never read it, so the grid spec is
-        # unchanged from the uniform path
-        lengths = jnp.stack([jnp.asarray(lengths, jnp.int32),
-                             jnp.asarray(q_lens, jnp.int32)])
-        tile = query_tile_rows(rows, group, q.dtype)
     kernel = functools.partial(_decode_kernel, scale=scale,
                                page_size=page_size,
                                block_pages=block_pages, n_query=n_query,
@@ -729,7 +869,7 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                             lambda b, g, lens, tabs: (b, g, 0, 0))
 
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    in_specs = [of_row(lanes), hbm, hbm]
+    in_specs = [hbm if ragged else of_row(lanes), hbm, hbm]
     inputs = [lengths, page_tables, q4, k_pages, v_pages]
 
     # two buffers a pool: one block computing, the next in flight; a
@@ -756,29 +896,48 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
         inputs.append(jnp.broadcast_to(
             of_head.reshape(kv_heads, rows, 1), (kv_heads, rows, 128)))
     scratch += [
-        pltpu.SemaphoreType.DMA((2,)),          # one a buffer slot
+        # one a buffer slot; the ragged kernel's queries in, outputs out
+        pltpu.SemaphoreType.DMA((4 if ragged else 2,)),
         pltpu.VMEM((hb, rows, 128), jnp.float32),
         pltpu.VMEM((hb, rows, 128), jnp.float32),
         pltpu.VMEM((hb, rows, v_lanes), jnp.float32),
     ]
+    out_shape = (batch, kv_heads, rows, v_lanes)
+    aliases = {}
+    if ragged:
+        # the output is the packed stream's twin and stays in HBM too: a
+        # grid step writes its row's own positions, and the rest (the
+        # pack's pad, a rectangle's dead queries) are the zeros it is
+        # aliased onto
+        out_shape = (kv_heads, tokens, staged, v_lanes)
+        in_specs.append(hbm)
+        inputs.append(jnp.zeros(out_shape, q.dtype))
+        aliases = {len(inputs) - 1: 0}
+        scratch += [pltpu.VMEM((hb, n_query, staged, lanes), q.dtype),
+                    pltpu.VMEM((hb, rows, lanes), q.dtype),
+                    pltpu.VMEM((hb, n_query, staged, v_lanes), q.dtype)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # lengths, page_tables
         grid=(batch, kv_heads // hb),
         in_specs=in_specs,
-        out_specs=of_row(v_lanes),
+        out_specs=hbm if ragged else of_row(v_lanes),
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         kernel,
         name="paged_attention_ragged" if ragged else "paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((batch, kv_heads, rows, v_lanes),
-                                       q.dtype),
+        out_shape=jax.ShapeDtypeStruct(out_shape, q.dtype),
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(*inputs)[..., :dv]
+    )(*inputs)
+    if ragged:
+        return out[:, :, :group, :dv].transpose(1, 0, 2, 3) \
+            .reshape(handed[:-1] + (dv,))
+    out = out[..., :dv]
     if n_query == 1:
         return out.reshape(batch, q_heads, dv)
     return out.reshape(batch, kv_heads, n_query, group, dv) \
@@ -789,13 +948,17 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                                              "n_query", "window"))
 def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
                    interpret=False, n_query=1, k_scales=None,
-                   v_scales=None, q_lens=None, window=None, sinks=None):
+                   v_scales=None, q_lens=None, window=None, sinks=None,
+                   row_off=None):
     """``q`` is (batch, q_heads, d) for n_query == 1, else
     (batch, n_query, q_heads, d).  ``k_scales``/``v_scales``
     (kv_heads, total_pages, page_size, 1) f32 mark the int8 KV mode.
     ``q_lens`` (batch,) int32 selects the RAGGED kernel: per-row query
     spans left-aligned in the n_query bucket (ISSUE 17), computed in
-    tiles of :func:`query_tile_rows` rows, a row's live tiles only.
+    tiles of :func:`query_tile_rows` rows, a row's live tiles only; its
+    ``q`` may also be the step's packed tokens (tokens, q_heads, d) with
+    ``row_off`` (batch,) saying where each row's queries start, and the
+    output is then packed the same.
 
     The grid is (batch, kv_heads // hb): a grid step owns a row and
     ``hb`` of its kv heads (:func:`walk_head_group`: all of them where
@@ -809,7 +972,8 @@ def _decode_pallas(q, k_pages, v_pages, lengths, page_tables, scale,
     return _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
                         interpret=interpret, n_query=n_query,
                         k_scales=k_scales, v_scales=v_scales,
-                        q_lens=q_lens, window=window, sinks=sinks)
+                        q_lens=q_lens, window=window, sinks=sinks,
+                        row_off=row_off)
 
 
 def _gather_dequant(pages, scales, page_tables, batch, kv_heads,
@@ -923,6 +1087,46 @@ def _multi_xla(q, k_pages, v_pages, lengths, page_tables, scale,
     p = _softmax(s, sinks)
     out = jnp.einsum("bhst,bhtd->bhsd", p.astype(v.dtype), v)
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+# ------------------------------------------------ the ragged step's pack
+# The ragged program runs over the step's tokens PACKED along one axis —
+# row 0's span, then row 1's, ... then pad.  ``off[r]`` is where row ``r``
+# starts on the packed axis; ``off=None`` says the packed axis is as long
+# as the (rows, span) rectangle and every row keeps its place in it (a
+# reshape).  The ragged KERNEL reads a row's queries from the packed axis
+# itself; these two take the axis to the rectangle and back for its XLA
+# oracle and for the program's tail.  Both are jitted: a program's layers
+# all call them at the same shapes and share one traced and lowered body.
+
+@functools.partial(jax.jit, static_argnames=("span",))
+def _rows_of_packed(x, off, span):
+    """``x[T, ...]`` packed -> ``[rows, span, ...]``: row ``r`` is the
+    ``span`` entries from ``off[r]`` on.  Past the row's own tokens that
+    is its successors' (or pad): whoever is handed the rows' own lengths
+    never computes with them."""
+    if off is None:
+        return x.reshape((-1, span) + x.shape[1:])
+    at = off[:, None] + jnp.arange(span, dtype=jnp.int32)[None, :]
+    return x[jnp.minimum(at, x.shape[0] - 1)]
+
+
+@functools.partial(jax.jit, static_argnames=("tokens",))
+def _packed_of_rows(x, off, tokens):
+    """``x[rows, span, ...]`` -> ``[tokens, ...]`` packed: position ``t``
+    takes row ``r``'s column ``t - off[r]``, ``r`` the last row that
+    starts at or before ``t``.  Past the step's tokens that is the last
+    row's tail: of the ragged oracle's output, its dead queries, which
+    are zeros — the dense layers, the router and the head run over the
+    pad positions too, and the caller discards what they make of them."""
+    rows, span = x.shape[:2]
+    flat = x.reshape((rows * span,) + x.shape[2:])
+    if off is None:
+        return flat
+    at = jnp.arange(tokens, dtype=jnp.int32)
+    row = jnp.sum(at[:, None] >= off[None, :], axis=1) - 1
+    col = jnp.minimum(at - off[row], span - 1)
+    return flat[row * span + col]
 
 
 def _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables, scale,
@@ -1045,7 +1249,7 @@ def paged_attention_multi(q, k_pages, v_pages, lengths, page_tables,
 def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
                            page_tables, scale=None, interpret=False,
                            k_scales=None, v_scales=None, window=None,
-                           sinks=None):
+                           sinks=None, row_off=None, span=None):
     """RAGGED paged attention (ISSUE 17): ONE kernel over a batch whose
     rows carry DIFFERENT query-span lengths — decode rows (q_len 1),
     prefill/chunk spans, and speculative verify blocks mix in a single
@@ -1055,10 +1259,17 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
 
     q:           (batch, max_q, q_heads, head_dim) — row ``b``'s
                  ``q_lens[b]`` real query tokens sit LEFT-aligned in
-                 the ``max_q`` bucket; pad positions come back as ZEROS
-                 (the kernel computes a row's own queries in whole
-                 tiles, :func:`query_tile_rows`, and writes zeros for
-                 the rest; the XLA path zeroes them after the fact)
+                 the ``max_q`` bucket; pad positions come back as ZEROS.
+                 Or, with ``span`` (the bucket ``max_q``) given, the
+                 step's PACKED tokens (tokens, q_heads, head_dim): row
+                 ``b``'s queries stand together from ``row_off[b]`` on
+                 (``None``: ``b * span``, the rectangle row-major), and
+                 the output comes back packed, zeros where no row has a
+                 query.  The kernel is the same: it takes each row's
+                 queries from the packed axis, whole tiles of them
+                 (:func:`query_tile_rows`), and the rectangle is the
+                 packed axis whose rows start ``max_q`` apart; the XLA
+                 path gathers the rectangle and zeroes after the fact
     lengths:     (batch,) int32 — valid cached tokens per sequence
                  INCLUDING the row's whole span (already scattered
                  into the pages)
@@ -1073,26 +1284,28 @@ def paged_attention_ragged(q, k_pages, v_pages, lengths, q_lens,
     reproduces :func:`paged_attention_multi`'s verify mask bit-exactly;
     a ``max_q == 1`` call routes through :func:`paged_attention`
     itself, so the unified step can never drift from the legacy modes.
-    Returns (batch, max_q, q_heads, head_dim).
+    Returns ``q``'s form, as wide as V.
     """
     if scale is None:
         scale = 1.0 / math.sqrt(_k_head_dim(k_pages, v_pages))
-    if q.shape[1] == 1:
+    packed = span is not None
+    kw = dict(k_scales=k_scales, v_scales=v_scales, window=window,
+              sinks=sinks)
+    if (span if packed else q.shape[1]) == 1:
         # every span is one token: literally the decode step
-        out = paged_attention(q[:, 0], k_pages, v_pages, lengths,
-                              page_tables, scale=scale,
-                              interpret=interpret, k_scales=k_scales,
-                              v_scales=v_scales, window=window, sinks=sinks)
-        return out[:, None]
+        out = paged_attention(q if packed else q[:, 0], k_pages, v_pages,
+                              lengths, page_tables, scale=scale,
+                              interpret=interpret, **kw)
+        return out if packed else out[:, None]
     if _use_pallas() or interpret:
         return _decode_pallas(q, k_pages, v_pages, lengths, page_tables,
                               scale, interpret=interpret,
-                              n_query=q.shape[1], k_scales=k_scales,
-                              v_scales=v_scales, q_lens=q_lens,
-                              window=window, sinks=sinks)
-    return _ragged_xla(q, k_pages, v_pages, lengths, q_lens, page_tables,
-                       scale, k_scales=k_scales, v_scales=v_scales,
-                       window=window, sinks=sinks)
+                              n_query=span if packed else q.shape[1],
+                              q_lens=q_lens, row_off=row_off, **kw)
+    rect = _rows_of_packed(q, row_off, span) if packed else q
+    out = _ragged_xla(rect, k_pages, v_pages, lengths, q_lens, page_tables,
+                      scale, **kw)
+    return _packed_of_rows(out, row_off, q.shape[0]) if packed else out
 
 
 # ------------------------------------------------------------- page cache

@@ -647,7 +647,9 @@ class TestRaggedQueryTiles:
     def test_one_loop_more_in_each_of_three_places(self):
         """ONE body: the ragged kernel is the uniform kernel with a loop
         over the live tiles around the scratch reset, the block update
-        and the final division — no second walk, no second program."""
+        and the final division — no second walk, no second program — and
+        four that bring the row's queries (ISSUE 50): the tiles' copies
+        started, waited for, and made rows of (heads x tiles)."""
         def loops(**kw):
             q = jnp.zeros((2, 64, 8, 128), jnp.bfloat16)
             pool = jnp.zeros((2, 8, 16, 128), jnp.bfloat16)
@@ -658,7 +660,7 @@ class TestRaggedQueryTiles:
                 jnp.zeros((2, 4), jnp.int32)))
             return text.count("while[") + text.count("scan[")
 
-        assert loops(q_lens=jnp.ones((2,), jnp.int32)) == loops() + 3
+        assert loops(q_lens=jnp.ones((2,), jnp.int32)) == loops() + 3 + 4
 
     def test_pad_positions_of_the_packed_stream_are_zeros(self):
         """``_packed_of_rows`` hands the last row's dead columns to the
@@ -821,18 +823,22 @@ class TestHeadGroups:
         make = paged_attention_mod.pltpu.make_async_copy
 
         class Counted:
-            def __init__(self, copy):
-                self.copy = copy
+            """A page's copy: (heads, slots, lanes); the copies of the
+            row's queries and outputs have an axis more (``tests/
+            test_ragged_packed_kernel.py`` counts those)."""
+            def __init__(self, src, dst, sem):
+                self.copy, self.page = make(src, dst, sem), len(src.shape) == 3
 
             def start(self):
-                jax.debug.callback(lambda: started.append(1))
+                if self.page:
+                    jax.debug.callback(lambda: started.append(1))
                 self.copy.start()
 
             def wait(self):
                 self.copy.wait()
 
         monkeypatch.setattr(paged_attention_mod.pltpu, "make_async_copy",
-                            lambda *a: Counted(make(*a)))
+                            Counted)
         rng = np.random.default_rng(44)
         lens, q_lens = [0, 16, 100, 700, 1], [0, 1, 8, 3, 1]
         args = TestContextWalk._case(

@@ -254,8 +254,10 @@ class TestHeadGroupLowering:
             *_paged_specs(chip, kvh=10, heads=40, d=128, batch=32,
                           pages=6144, page=16, table=256, nq=nq,
                           ragged=nq > 1))
-        # the q block of a grid step: all ten heads
-        assert f"bf16[32,10,{nq * 4},128]" in text
+        # the q operand: a block a row of all ten heads for the one-query
+        # kernel, the packed tokens kv-head-major for the ragged one
+        assert ("bf16[32,10,4,128]" if nq == 1
+                else "bf16[10,4096,4,128]") in text
 
     @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
     @pytest.mark.parametrize("hb", [1, 2, 8])
@@ -712,6 +714,75 @@ class TestMiMoCellLowering:
                 if "tpu_custom_call" in ln and "%moe_grouped_ffn" in ln]
         assert not re.search(
             rf"bf16\[{e},\d+,\d+\][^ ]* (copy|transpose)\(", text)
+
+
+# ------------------- the ragged kernel on the step's packed tokens
+class TestPackedRaggedLowering:
+    """The ragged kernel as the serving step calls it (ISSUE 50): the
+    queries of the step's PACKED tokens in, each row's own from
+    ``row_off`` on, at every serving cell's (rows, span, query heads, KV
+    heads, K and V widths) and the tokens its engine packs a step to."""
+
+    # name: rows, tokens, q heads, kv heads, K width, V width, window,
+    # sinks, pages, table
+    CELLS = {
+        "mistral": (8, 272, 32, 8, 128, 128, None, False, 4096, 256),
+        "laguna_full": (8, 272, 48, 8, 128, 128, None, False, 8192, 512),
+        "laguna_sliding": (8, 272, 64, 8, 128, 128, 512, False, 8192, 512),
+        "phi4_flash_full": (32, 288, 40, 10, 128, 128, None, False, 6144,
+                            256),
+        "phi4_flash_sliding": (32, 288, 40, 10, 128, 128, 512, False, 6144,
+                               256),
+        "zaya": (64, 320, 8, 2, 128, 128, None, False, 12288, 512),
+        "mimo_full": (32, 288, 64, 4, 192, 128, None, False, 8192, 512),
+        "mimo_sliding": (32, 288, 64, 8, 192, 128, 128, True, 8192, 512),
+    }
+    SPAN = 128
+
+    def _compile(self, chip, monkeypatch, name):
+        (rows, tokens, heads, kvh, dk, dv, window, sinks, pages,
+         table) = self.CELLS[name]
+        monkeypatch.setattr(paged_attention, "_use_pallas", lambda: True)
+        pack = paged_attention.k_pack(dk)
+
+        def fn(q, kp, vp, lens, ql, off, tabs, b):
+            # what ``_TracedPagedContext._attend_ragged`` does
+            return paged_attention.paged_attention_ragged(
+                paged_attention.packed_queries(q, kp, vp), kp, vp, lens, ql,
+                tabs, window=window, sinks=b if sinks else None,
+                row_off=off, span=self.SPAN)
+
+        return chip.compile(
+            fn, ((tokens, heads, dk),),
+            ((kvh // pack, pages, 16, pack * dk),), ((kvh, pages, 16, dv),),
+            ((rows,), I32), ((rows,), I32), ((rows,), I32),
+            ((rows, table), I32), ((heads,), F32))
+
+    @pytest.mark.parametrize("name", sorted(CELLS))
+    def test_each_serving_cell_s_call(self, chip, monkeypatch, name):
+        text = self._compile(chip, monkeypatch, name)
+        assert "paged_attention_ragged" in text
+        # no pool is copied or re-laid out on the way to the kernel
+        pages = self.CELLS[name][8]
+        assert not re.search(
+            rf"bf16\[\d+,{pages},16,\d+\][^ ]* (copy|transpose|pad)\(", text)
+
+    @pytest.mark.parametrize("name", ["mimo_full", "mimo_sliding"])
+    def test_no_array_of_the_rectangle_s_size(self, chip, monkeypatch, name):
+        """The MiMo cell's call: 288 packed tokens of a (32, 128) bucket.
+        Nothing around the kernel is as large as the 4,096 positions of
+        the rectangle, in any of the layouts it used to pass through: the
+        largest array beside the pools is the step's own tokens."""
+        text = self._compile(chip, monkeypatch, name)
+        rows, tokens, heads, kvh = self.CELLS[name][:4]
+        for shape in (f"[{rows},{self.SPAN},{heads},", f"[{rows * self.SPAN},",
+                      f"[{rows},{kvh},", f"[{kvh},{rows * self.SPAN},"):
+            assert f"bf16{shape}" not in text, shape
+        sizes = [math.prod(int(n) for n in dims.split(","))
+                 for dims in re.findall(r"bf16\[([\d,]+)\]", text)]
+        pools = 8192 * 16 * 128
+        assert max(n for n in sizes if n < pools) <= \
+            (tokens + 15) * heads * 384
 
 
 # ------------------------------------ the Kimi-Linear cell's KDA kernels

@@ -415,6 +415,85 @@ class TestScopeOpTable:
         assert "train/model/kda/gates" in capsys.readouterr().out
 
 
+class TestPagedRaggedMicro:
+    """``tools/paged_ragged_micro.py``: the step's ragged call on the
+    packed tokens at the shapes ISSUE 50 added (its timed runs need the
+    chip; ``--rehearse`` drives the kernels through the interpreter at a
+    span of 32) and the table that holds one tree to another."""
+
+    @pytest.fixture(scope="class")
+    def tool(self):
+        sys.path.insert(0, os.path.join(REPO, "tools"))
+        try:
+            import paged_ragged_micro
+        finally:
+            sys.path.pop(0)
+        return paged_ragged_micro
+
+    @pytest.fixture(scope="class")
+    def rehearsed(self, tool, tmp_path_factory):
+        path = tmp_path_factory.mktemp("micro") / "change.json"
+        assert tool.main(["--rehearse", "--out", str(path), "--shapes",
+                          "mimo-full", "mimo-sliding", "zaya"]) == 0
+        return str(path), json.loads(path.read_text())
+
+    @pytest.mark.parametrize("shape,rows,tokens,padded", [
+        ("zaya", 64, 32 + 63, 128), ("mimo-full", 32, 2 * 32 + 30, 96),
+        ("mimo-sliding", 32, 2 * 32 + 30, 96)])
+    def test_rehearsal_of_the_new_shapes(self, tool, rehearsed, shape, rows,
+                                         tokens, padded):
+        """Two chunk rows and 30 one-token rows over MiMo's 4 and 8 KV
+        heads (K rows of 384, a window of 128 and sinks), a chunk row and
+        63 one-token rows over ZAYA's two-head pool: every live query has
+        an output, every position no row owns is zero."""
+        case, = (c for c in rehearsed[1]["cases"] if c["shape"] == shape)
+        assert tool.TRAFFIC[shape][0] == rows
+        assert (case["tokens"], case["tokens_padded"]) == (tokens, padded)
+        assert case["dead_nonzero"] == 0 and case["live_zero"] == 0
+        assert not case["nan"] and len(case["live_sha"]) == 16
+
+    def test_table_holds_one_tree_to_the_other(self, tool, rehearsed,
+                                               tmp_path, capsys):
+        path, data = rehearsed
+        assert tool.table(path, path) is True
+        out = capsys.readouterr().out
+        assert out.count("bit for bit") == 3 and "DIFFER" not in out
+        data["cases"][1]["live_sha"] = "0" * 16
+        other = tmp_path / "parent.json"
+        other.write_text(json.dumps(data))
+        assert tool.table(str(other), path) is False
+        assert "DIFFER" in capsys.readouterr().out
+        data["cases"][1]["dead_nonzero"] = 3
+        other.write_text(json.dumps(data))
+        assert tool.table(str(other), str(other)) is False
+
+    def test_an_older_tree_is_handed_the_rectangle(self, tool):
+        """``--repo`` on a tree whose kernel takes no ``row_off``: the
+        tool gathers the rectangle and packs the output back, as that
+        tree's step did."""
+        import types
+
+        import jax.numpy as jnp
+        import numpy as np
+        seen = {}
+
+        def old_kernel(q, kp, vp, lens, q_lens, tabs, interpret=False,
+                       window=None, sinks=None):
+            seen["q"] = q.shape
+            return q[..., :3]
+
+        pa = types.SimpleNamespace(
+            paged_attention_ragged=old_kernel, paged_attention=None,
+            packed_queries=lambda q, kp, vp: q)
+        ragged, _ = tool.step_calls(pa, None, 4, True)
+        q = np.arange(10 * 2 * 5, dtype=np.float32).reshape(10, 2, 5)
+        off = np.asarray([0, 4, 5], np.int32)
+        out = ragged(jnp.asarray(q), None, None, jnp.asarray(off), None,
+                     None, None, None)
+        assert seen["q"] == (3, 4, 2, 5) and out.shape == (10, 2, 3)
+        np.testing.assert_array_equal(np.asarray(out[:6]), q[:6, :, :3])
+
+
 class TestFlashAttnMicro:
     """``tools/flash_attn_micro.py``'s reductions (its runs need the chip;
     ``--rehearse`` drives the kernels through the interpreter)."""
