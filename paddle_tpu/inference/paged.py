@@ -39,8 +39,7 @@ from ..ops.pallas.paged_attention import (PagedKVCache, _gather_dequant,
                                           paged_attention_ragged,
                                           q_positions_computed,
                                           q_positions_moved,
-                                          quantize_kv, walk_block_pages,
-                                          walk_head_group)
+                                          quantize_kv, walk_cut)
 from ..testing import faults as _faults
 
 
@@ -1919,11 +1918,11 @@ class JittedPagedDecoder:
             group, window, shared, kv_heads, k_dim, v_dim, sinks = kind
             heads = kv_heads // cache.tp
             page_bytes = kv_heads * ps * (k_dim + v_dim) * kv_dtype.itemsize
-            block = ps * walk_block_pages(ps, k_dim, span * group, kv_dtype,
-                                          v_dim)
-            steps = heads // walk_head_group(
-                heads, ps, k_dim, span * group, kv_dtype,
-                cache.compute_dtype, v_dim, sinks)
+            # the call's own cut (a span of one is the one-query kernel)
+            shapes = (heads, ps, k_dim, span, group, kv_dtype,
+                      cache.compute_dtype, v_dim, sinks, span > 1)
+            _tile, block_pages, head_group = walk_cut(*shapes, window)
+            block, steps = ps * block_pages, heads // head_group
             copied = kv_pages_copied(lens, ps, table_pages, window, q_lens)
             pages = pools * copied
             out["kv_bytes_copied_full" if window is None
@@ -1948,8 +1947,9 @@ class JittedPagedDecoder:
                             // ps).sum())
                 out.update(ctx_tokens_window=seen,
                            kv_tokens_walked_window=walked,
+                           # in the blocks of a call with no window
                            kv_tokens_walked_nowindow=kv_tokens_walked(
-                               lens, block),
+                               lens, ps * walk_cut(*shapes)[1]),
                            kv_window_dead_pages=dead)
                 if not shared:
                     out["kv_dead_bytes"] += n * dead * page_bytes

@@ -11,10 +11,13 @@ TPU-native design (see /opt/skills/guides/pallas_guide.md):
     step owns a row and a GROUP of ``hb`` of its kv heads
     (``walk_head_group``: from the shapes and a VMEM budget, all the heads
     where they fit) and WALKS THE ROW'S OWN CONTEXT in blocks of several
-    pages (``walk_block_pages``: 128-512 tokens, from the shapes and a
-    VMEM budget) — ``ceil(length / block)`` blocks whatever the page
-    table's width, so a table pinned wide for a compile-free window
-    costs what a tight one costs;
+    pages (``walk_block_pages``: 128-512 tokens, from the rows of the
+    SCORE TILE the kernel forms, the page's shape and a VMEM budget: 512
+    for every tile of up to 512 rows, so for every ragged bucket, whose
+    tile is at most 128; under a sliding window no more than the tile's
+    reach, 256 at a window of 128) — ``ceil(length / block)`` blocks
+    whatever the page table's width, so a table pinned wide for a
+    compile-free window costs what a tight one costs;
   - the pools stay in HBM (``memory_space=pl.ANY``); the lengths and the
     page table ride in as SCALAR-PREFETCH arguments, and the kernel
     starts one asynchronous copy a page and a pool FOR ALL THE STEP'S
@@ -32,7 +35,10 @@ TPU-native design (see /opt/skills/guides/pallas_guide.md):
     (``query_tile_rows``: 128 rows, 96 for a group of 6) and scores,
     softmax and products run for the ``ceil(q_len * group / tile)`` live
     tiles of each context block, so a one-token row of a 128-wide bucket
-    costs one tile a block and not the bucket; dead queries are zeros;
+    costs one tile a block and not the bucket; a (tile x block) score
+    tile is the largest array of scores there ever is, so the walk's
+    block is cut by the TILE's rows and not by the bucket's; dead
+    queries are zeros;
   - and it TAKES THEM FROM THE STEP'S PACKED TOKENS: its query operand is
     the packed stream, kv-head-major with a token on an untiled major
     axis (``[kv_heads, tokens, group, d]``, a re-layout of the step's
@@ -100,11 +106,11 @@ def dequantize_kv(q, scale, dtype):
 
 
 # ------------------------------------------------------------------ kernel
-#: what one block of the walk may take of VMEM: the float32 score block
-#: (rows x block tokens), and ONE kv head's double K and V buffers with
-#: their scale buffers.  Both sized for the v5e's 16 MB of scoped VMEM with
-#: room for the score block's temporaries (mask, exponentials, their bf16
-#: copy).
+#: what one block of the walk may take of VMEM: the float32 score tile
+#: (the rows the kernel computes at once x block tokens), and ONE kv head's
+#: double K and V buffers with their scale buffers.  Both sized for the
+#: v5e's 16 MB of scoped VMEM with room for the score tile's temporaries
+#: (mask, exponentials, their bf16 copy).
 _SCORE_BLOCK_BYTES = 1 << 20
 _KV_BUFFER_BYTES = 2 << 20
 _MAX_BLOCK_TOKENS = 512
@@ -197,24 +203,37 @@ def _kv_page_vmem_bytes(page_size, head_dim, v_dim, kv_dtype):
             + _page_vmem_bytes(page_size, v_dim, kv_dtype))
 
 
-def walk_block_pages(page_size, head_dim, rows, kv_dtype, v_dim=None):
+def walk_block_pages(page_size, head_dim, tile_rows, kv_dtype, v_dim=None,
+                     reach=None):
     """Pages one block of the kernel's walk holds, from shapes alone: as
-    many as keep the score block (``rows`` x tokens, float32) and ONE kv
-    head's double-buffered K and V pages (with the int8 mode's scale pages)
-    inside their VMEM budgets, at most 512 tokens, at least one page.
-    ``rows`` is ``n_query * group``; ``head_dim`` is K's width and
-    ``v_dim`` V's where it is another (each buffer is budgeted by its own
-    width).  Whole multiples of 128 tokens where
-    that many fit, so the score block's lane axis is unpadded.  The table's
-    width is NOT an input: a row's blocks are cut the same whatever table
-    carries them, which is what makes a pinned table free and its results
-    bit-identical to a tight one's.  Nor are the heads a grid step owns
-    (``walk_head_group``): the buffers grow with them, the block does not."""
+    many as keep the float32 SCORE TILE (``tile_rows`` x tokens) and ONE
+    kv head's double-buffered K and V pages (with the int8 mode's scale
+    pages) inside their VMEM budgets, at most 512 tokens, at least one
+    page.  ``tile_rows`` is the rows of the one score array the kernel
+    forms at a time (``walk_cut``): the ragged kernel's query tile
+    (``query_tile_rows``, at most 128 rows, whatever the bucket: its block
+    is 512 tokens at every bucket a cell runs), the whole ``n_query *
+    group`` block of the one-query and the uniform verify kernels.
+    ``head_dim`` is K's width and ``v_dim`` V's where it is another (each
+    buffer is budgeted by its own width).  Whole multiples of 128 tokens
+    where that many fit, so the score tile's lane axis is unpadded.  The
+    table's width is NOT an input: a row's blocks are cut the same
+    whatever table carries them, which is what makes a pinned table free
+    and its results bit-identical to a tight one's.  Nor are the heads a
+    grid step owns (``walk_head_group``): the buffers grow with them, the
+    block does not.  ``reach`` (a windowed call: ``walk_cut``) is the most
+    columns any score tile of the call can see, and a block holds no more
+    than that in whole 128s: the rest would be columns no query sees
+    (a window of 128 walks in 256, one of 512 in 512)."""
     page_bytes = _kv_page_vmem_bytes(page_size, head_dim, v_dim or head_dim,
                                      kv_dtype)
     by_kv = _KV_BUFFER_BYTES // (2 * page_bytes)     # K, V x two slots
-    by_score = _SCORE_BLOCK_BYTES // (4 * _round_up(rows, 8) * page_size)
-    pages = max(1, min(_MAX_BLOCK_TOKENS // page_size, by_kv, by_score))
+    by_score = _SCORE_BLOCK_BYTES // (4 * _round_up(tile_rows, 8)
+                                      * page_size)
+    pages = min(_MAX_BLOCK_TOKENS // page_size, by_kv, by_score)
+    if reach is not None:
+        pages = min(pages, -(-_round_up(reach, 128) // page_size))
+    pages = max(1, pages)
     per_128 = 128 // math.gcd(128, page_size)        # pages to 128 tokens
     if pages >= per_128:
         pages -= pages % per_128
@@ -223,7 +242,7 @@ def walk_block_pages(page_size, head_dim, rows, kv_dtype, v_dim=None):
 
 @functools.lru_cache(maxsize=None)      # the host asks at every step
 def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype,
-                    v_dim=None, sinks=False):
+                    v_dim=None, sinks=False, tile_rows=None, reach=None):
     """KV heads one grid step of the kernel owns, from shapes alone: the
     largest divisor of the call's ``kv_heads`` whose heads together keep
     their q and out blocks (two buffers each — the pipeline's, or in the
@@ -232,21 +251,27 @@ def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype,
     where that is not ``head_dim``), their
     softmax scratch (m, l, acc in float32), with ``sinks`` the rows' sink
     block (two buffers), and their double K and V buffers of
-    ``walk_block_pages`` pages inside ``_HEAD_GROUP_BYTES``.  A page copy
+    ``walk_block_pages`` pages inside ``_HEAD_GROUP_BYTES``.  ``rows`` is
+    the bucket's ``n_query * group`` (what the blocks and the scratch
+    hold); ``tile_rows`` the score tile's where the kernel cuts the bucket
+    into tiles (the ragged kernel: ``walk_cut``), which with a windowed
+    call's ``reach`` is what the buffers' block is cut by.  A page copy
     then serves the whole group: ONE descriptor a (row, group, page, pool)
     where a grid step of one head issued one a head.  All the heads for
     the one-query and verify kernels (a few KB a head beside the buffers)
     and for the ragged kernel's 512 / 768 / 1,024-row buckets at 8 heads
-    (1.75 / 2.1 / 2.75 MB a head); 1 is the grid of one head a step.  The
-    walk of a (row, head) does not depend on the group it rides in."""
+    (1.8 / 2.5 / 3.1 MB a head) and MiMo's 2,048 rows at 4 (8.0 MB a
+    head, queries of 384 lanes), 4 of its 8 sliding heads (1,024 rows and
+    their sinks: 5.4 MB); 1 is the grid of one head a step.  The walk of
+    a (row, head) does not depend on the group it rides in."""
     v_dim = v_dim or head_dim
     # packed K rows (``k_pack``): a head's queries ride in a row as wide
     # as the pack's, zeros beside them, and a step owns whole packs
     pack = k_pack(head_dim)
     lanes, v_lanes = _round_up(pack * head_dim, 128), _round_up(v_dim, 128)
     q_item = jnp.dtype(q_dtype).itemsize
-    block_pages = walk_block_pages(page_size, head_dim, rows, kv_dtype,
-                                   v_dim)
+    block_pages = walk_block_pages(page_size, head_dim, tile_rows or rows,
+                                   kv_dtype, v_dim, reach)
     per_head = (
         2 * block_pages * _kv_page_vmem_bytes(page_size, head_dim, v_dim,
                                               kv_dtype)
@@ -256,6 +281,33 @@ def walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype, q_dtype,
     return max(g for g in range(pack, kv_heads + 1, pack)
                if kv_heads % g == 0
                and (g == pack or g * per_head <= _HEAD_GROUP_BYTES))
+
+
+def walk_cut(kv_heads, page_size, head_dim, n_query, group, kv_dtype,
+             q_dtype, v_dim=None, sinks=False, ragged=True, window=None):
+    """``(tile_rows, block_pages, head_group)`` of one paged call, from
+    shapes alone: THE rule the call builds its program by
+    (``_decode_call``) and the host counts by (``kv_tokens_walked``,
+    ``page_copies``), so the three cannot disagree.  ``tile_rows`` is the
+    rows of the score tile the kernel forms: the ``ragged`` kernel's query
+    tile (``query_tile_rows``), the whole ``n_query * group`` block of the
+    one-query and the uniform verify kernels (one static tile; a bucket
+    of one query is one tile either way).  The walk's block
+    (``walk_block_pages``) and the heads of a grid step
+    (``walk_head_group``, whose buffers hold that block) follow from
+    it, and under a sliding ``window`` from the tile's REACH: its
+    ``tile / group`` query positions see their own ``window`` keys each,
+    from the page of the first of them (``window_first_token``) — at most
+    ``window + positions - 1 + page_size`` columns."""
+    rows = n_query * group
+    tile = query_tile_rows(rows, group, q_dtype) if ragged else rows
+    reach = None if window is None \
+        else window + tile // group - 1 + page_size
+    return (tile,
+            walk_block_pages(page_size, head_dim, tile, kv_dtype, v_dim,
+                             reach),
+            walk_head_group(kv_heads, page_size, head_dim, rows, kv_dtype,
+                            q_dtype, v_dim, sinks, tile, reach))
 
 
 def window_first_token(lengths, q_lens, window, page_size):
@@ -420,14 +472,19 @@ def _decode_kernel(lens_ref, tabs_ref, q_ref, k_hbm, v_hbm, *rest,
     and the final division run for the ``ceil(qlen * group / tile)``
     live tiles only; a block's K and V are loaded once and shared by its
     tiles.  A one-token row is one tile, a full chunk row all of them
-    (what the whole block computed before the cut); the walk's blocks
-    and their order are the bucket's, so a live query's output does not
-    depend on the tile it falls in.  Dead queries (j >= qlen) COME BACK
-    AS ZEROS: whole dead tiles are never computed, and those of the last
-    live tile (which clamp at the full kv length and compute finite
-    values from whatever the stream holds behind the row) are never
-    copied out.  The two uniform modes take
-    the whole block as one static tile: the program they always were.
+    (what the whole block computed before the cut).  A (tile, block)
+    score tile is the largest array of scores the kernel forms, so the
+    walk's block is cut by the TILE's rows (``walk_cut``: 512 tokens at
+    every tile of up to 512 rows, whatever the bucket; no more than a
+    window's reach); every tile of a
+    row walks the same blocks in the same order, so a live query's output
+    does not depend on the tile it falls in.  Dead queries (j >= qlen)
+    COME BACK AS ZEROS: whole dead tiles are never computed, and those of
+    the last live tile (which clamp at the full kv length and compute
+    finite values from whatever the stream holds behind the row) are
+    never copied out.  The two uniform modes take the whole block as one
+    static tile, and their walk's block is cut by that: the program they
+    always were.
 
     ``quantized`` (ISSUE 9): the K/V pages arrive as INT8 with their
     per-slot f32 scale pages copied alongside — dequantization happens
@@ -791,13 +848,13 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
     assert k_pages.shape[-1] == d, (k_pages.shape, v_pages.shape, q.shape)
     group = q_heads // kv_heads
     rows = n_query * group
-    block_pages = walk_block_pages(page_size, d // pack, rows,
-                                   k_pages.dtype, dv)
-    hb = head_group or walk_head_group(
-        kv_heads, page_size, d // pack, rows, k_pages.dtype, q.dtype, dv,
-        sinks is not None)
+    # the score tile the kernel forms, the walk's block and the heads of a
+    # grid step: one rule, the host's too
+    tile, block_pages, hb = walk_cut(
+        kv_heads, page_size, d // pack, n_query, group, k_pages.dtype,
+        q.dtype, dv, sinks is not None, ragged, window)
+    hb = head_group or hb
     lanes, v_lanes = _round_up(d, 128), _round_up(dv, 128)
-    tile = None
 
     if ragged:
         # the step's packed tokens, kv-head-major, a token on an UNTILED
@@ -807,7 +864,6 @@ def _decode_call(q, k_pages, v_pages, lengths, page_tables, scale,
         # not of the (rows, span) rectangle; past them stand the ``per -
         # 1`` positions a whole-tile read at the last row's offset may
         # take (no rectangle's row needs them: a tile divides the span)
-        tile = query_tile_rows(rows, group, q.dtype)
         tokens, staged = q.shape[0], _staged_group(group, q.dtype)
         slack = 0 if row_off is None else tile // group - 1
         q4 = q.reshape(tokens, kv_heads, group, d).transpose(1, 0, 2, 3)

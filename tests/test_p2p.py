@@ -331,8 +331,11 @@ def _subgroup_proc(rank, world):
         np.testing.assert_allclose(
             res.numpy(), [0 * 10 + me, 2 * 10 + me])
 
-    # cross-process barrier actually synchronizes
+    # cross-process barrier actually synchronizes (the ranks leave a
+    # first barrier together: rank 1 takes no part in the subgroup's
+    # exchanges above and may stand seconds apart from the other two)
     import time
+    dist.barrier()
     t0 = time.monotonic()
     if rank == 0:
         time.sleep(1.0)

@@ -2,6 +2,10 @@
 4b/4c; reference: block_multi_head_attention_kernel.cu, fused_rope_*.cu).
 Pallas kernels run in interpret mode on CPU; on TPU the same code
 compiles via Mosaic."""
+import os
+import sys
+import types
+
 import numpy as np
 import pytest
 import jax
@@ -13,7 +17,7 @@ from paddle_tpu.ops.pallas.paged_attention import (
     paged_attention_ragged, _decode_xla, _multi_xla, _ragged_xla,
     kv_pages_copied, kv_tokens_walked, live_query_tiles,
     q_positions_computed, query_tile_rows, quantize_kv, walk_block_pages,
-    walk_head_group)
+    walk_cut, walk_head_group)
 from paddle_tpu.ops.pallas import paged_attention as paged_attention_mod
 from paddle_tpu.ops.pallas.flash_attention import mha_reference
 from paddle_tpu.ops.pallas.fused_norm_rope import (
@@ -508,10 +512,12 @@ class TestContextWalk:
 
     @pytest.mark.parametrize("page,d,rows,dtype,pages", [
         (16, 128, 4, jnp.bfloat16, 32),      # the cell's decode step
-        (16, 128, 512, jnp.bfloat16, 32),    # its chunk step, span 128
+        (16, 128, 128, jnp.bfloat16, 32),    # a ragged kernel's tile
+        (16, 128, 96, jnp.bfloat16, 32),     # and that of a group of 6
+        (16, 128, 512, jnp.bfloat16, 32),    # the tallest tile at 512
         (16, 128, 512, jnp.int8, 32),
-        (16, 128, 1024, jnp.bfloat16, 16),   # the score block binds
-        (16, 128, 8192, jnp.bfloat16, 2),
+        (16, 128, 1024, jnp.bfloat16, 16),   # the score tile binds: a
+        (16, 128, 8192, jnp.bfloat16, 2),    # verify block of that many
         (16, 128, 1 << 16, jnp.bfloat16, 1),  # down to one page a block
         (128, 128, 4, jnp.bfloat16, 4),
         (8, 64, 3, jnp.float32, 64),
@@ -520,6 +526,8 @@ class TestContextWalk:
     ])
     def test_block_rule_reads_shapes_only(self, page, d, rows, dtype,
                                           pages):
+        """``rows``: those of the score TILE the kernel forms — a ragged
+        bucket's tile, a one-query or verify kernel's whole block."""
         assert walk_block_pages(page, d, rows, dtype) == pages
 
     def test_tokens_walked_is_the_context_in_whole_blocks(self):
@@ -786,26 +794,32 @@ class TestHeadGroups:
             np.asarray(together.astype(jnp.float32)),
             np.asarray(alone.astype(jnp.float32)))
 
-    @pytest.mark.parametrize("kvh,rows,kv,hb,pages", [
-        (10, 4, jnp.bfloat16, 10, 32),      # Phi-4-flash, a decode step
-        (10, 512, jnp.bfloat16, 10, 32),    # its chunk step: 17.5 MB
-        (8, 4, jnp.bfloat16, 8, 32),        # Mistral and Laguna, decode
-        (8, 512, jnp.bfloat16, 8, 32),      # Mistral's chunk step
-        (8, 768, jnp.bfloat16, 8, 16),      # Laguna's full layers
-        (8, 1024, jnp.bfloat16, 8, 16),     # Laguna's sliding layers
-        (8, 512, jnp.int8, 8, 32),
-        (2, 512, jnp.bfloat16, 2, 32),      # a shard of tp = 4
-        (32, 512, jnp.bfloat16, 16, 32),    # 1.75 MB a head: 16 of 32
-        (8, 2048, jnp.bfloat16, 4, 8),      # 5.1 MB a head: half
-        (7, 2048, jnp.bfloat16, 1, 8),      # and no half of seven
-        (8, 1 << 16, jnp.bfloat16, 1, 1),   # nothing fits: a head a step
+    @pytest.mark.parametrize("kvh,rows,tile,kv,hb,pages", [
+        (10, 4, 4, jnp.bfloat16, 10, 32),   # Phi-4-flash, a decode step
+        (10, 512, 128, jnp.bfloat16, 10, 32),   # its chunk step: 18.4 MB
+        (8, 4, 4, jnp.bfloat16, 8, 32),     # Mistral and Laguna, decode
+        (8, 512, 128, jnp.bfloat16, 8, 32),     # Mistral's chunk step
+        (8, 768, 96, jnp.bfloat16, 8, 32),      # Laguna's full layers
+        (8, 1024, 128, jnp.bfloat16, 8, 32),    # Laguna's sliding layers
+        (8, 512, 128, jnp.int8, 8, 32),
+        (2, 512, 128, jnp.bfloat16, 2, 32),     # a shard of tp = 4
+        (32, 512, 128, jnp.bfloat16, 16, 32),   # 1.8 MB a head: 16 of 32
+        (8, 2048, 128, jnp.bfloat16, 4, 32),    # 5.8 MB a head: half
+        (7, 2048, 128, jnp.bfloat16, 1, 32),    # and no half of seven
+        (8, 2048, 2048, jnp.bfloat16, 4, 8),    # a verify block so tall
+        (8, 1 << 16, 128, jnp.bfloat16, 1, 32),  # nothing fits: a head a
+        (8, 1 << 16, 1 << 16, jnp.bfloat16, 1, 1),  # step, whatever block
     ])
-    def test_group_rule_reads_shapes_only(self, kvh, rows, kv, hb, pages):
-        got = walk_head_group(kvh, 16, 128, rows, kv, jnp.bfloat16)
+    def test_group_rule_reads_shapes_only(self, kvh, rows, tile, kv, hb,
+                                          pages):
+        """``rows`` the bucket's, ``tile`` the score tile's: the ragged
+        kernel's 128 (96), the whole block of the other two kernels."""
+        got = walk_head_group(kvh, 16, 128, rows, kv, jnp.bfloat16,
+                              tile_rows=tile)
         assert got == hb and kvh % got == 0
-        # the block of a (row, head) is what it was: the buffers grow
+        # the block of a (row, head) is the tile's: the buffers grow
         # with the group, the block does not
-        assert walk_block_pages(16, 128, rows, kv) == pages
+        assert walk_block_pages(16, 128, tile, kv) == pages
         per_head = (4 * pages * paged_attention_mod._page_vmem_bytes(
             16, 128, kv) + 4 * rows * 128 * 2 + rows * 384 * 4)
         budget = paged_attention_mod._HEAD_GROUP_BYTES
@@ -857,6 +871,112 @@ class TestHeadGroups:
                 "ragged", *args, window=window, head_group=hb))
             jax.effects_barrier()
             assert len(started) == pools * pages * (4 // hb)
+
+
+def _micro_shapes():
+    """``tools/paged_ragged_micro.py::SHAPES``: what the serving cells hand
+    the paged kernels."""
+    tools = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "tools")
+    sys.path.insert(0, tools)
+    try:
+        import paged_ragged_micro
+    finally:
+        sys.path.remove(tools)
+    return paged_ragged_micro.SHAPES
+
+
+class TestWalkCut:
+    """ONE rule cuts a paged call's walk (ISSUE 51): the rows of the score
+    tile the kernel forms (the ragged kernel's query tile, never its
+    bucket), the block that tile, the page's shape and a window's reach
+    allow, the heads whose buffers of that block fit a grid step.
+    ``_decode_call`` builds its program by it, ``walk_head_group`` budgets
+    the same block, and the host's ``kv_tokens_walked`` and ``page_copies``
+    count by it."""
+
+    SHAPES = _micro_shapes()
+    #: the heads of a grid step at spans 1 / 64 / 128: all of them but at
+    #: MiMo's sliding span-128 bucket (1,024 rows of 384 lanes and sinks)
+    HEAD_GROUPS = {"mimo-sliding": (8, 8, 4)}
+    #: a window of 128 reaches 128 + 16 - 1 + 16 columns of a tile: blocks
+    #: of 256; one of 512 reaches past 512
+    BLOCKS = {"mimo-sliding": 256}
+    #: the blocks of the rule before, where the BUCKET's rows cut them
+    BLOCKS_BEFORE = {("laguna-full", 128): 256, ("laguna-sliding", 128): 256,
+                     ("mimo-full", 64): 256, ("mimo-full", 128): 128,
+                     ("mimo-sliding", 128): 256}
+
+    @pytest.mark.parametrize("span", [1, 64, 128])
+    @pytest.mark.parametrize("shape", sorted(SHAPES))
+    def test_the_call_the_group_and_the_host_answer_alike(
+            self, monkeypatch, shape, span):
+        heads, kvh, window, _p, _t, dk, dv, sinks = self.SHAPES[shape]
+        bf16, group, ragged = jnp.bfloat16, heads // kvh, span > 1
+        rows = span * group
+        tile, pages, hb = walk_cut(kvh, 16, dk, span, group, bf16, bf16, dv,
+                                   sinks, ragged, window)
+        assert tile == (query_tile_rows(rows, group, bf16) if ragged
+                        else rows) <= 128
+        # every bucket of a cell walks in the same blocks: 512 tokens
+        assert 16 * pages == self.BLOCKS.get(shape, 512)
+        reach = window and window + tile // group - 1 + 16
+        assert 16 * walk_block_pages(16, dk, rows, bf16, dv) \
+            == self.BLOCKS_BEFORE.get((shape, span), 512)
+        assert hb == self.HEAD_GROUPS.get(shape, (kvh,) * 3)[
+            (1, 64, 128).index(span)]
+        assert pages == walk_block_pages(16, dk, tile, bf16, dv, reach)
+        assert hb == walk_head_group(kvh, 16, dk, rows, bf16, bf16, dv,
+                                     sinks, tile, reach)
+
+        # the call: what its kernel is built with, and its page buffers
+        built, kernel = {}, paged_attention_mod._decode_kernel
+
+        def spy(*refs, **kw):
+            bufs = [r.shape for r in refs if len(r.shape) == 5]
+            built.update(kw, k_buf=bufs[0], v_buf=bufs[1])
+            return kernel(*refs, **kw)
+
+        monkeypatch.setattr(paged_attention_mod, "_decode_kernel", spy)
+        pack = paged_attention_mod.k_pack(dk)
+
+        def call(q, kp, vp, lens, tabs, q_lens, b):
+            return paged_attention_mod._decode_call(
+                q, kp, vp, lens, tabs, 0.1, n_query=span,
+                q_lens=q_lens if ragged else None, window=window,
+                sinks=b if sinks else None)
+
+        i32 = jnp.int32
+        out = jax.eval_shape(
+            call, jax.ShapeDtypeStruct(
+                (3, span, heads, dk) if ragged else (3, heads, dk), bf16),
+            jax.ShapeDtypeStruct((kvh // pack, 8, 16, pack * dk), bf16),
+            jax.ShapeDtypeStruct((kvh, 8, 16, dv), bf16),
+            jax.ShapeDtypeStruct((3,), i32),
+            jax.ShapeDtypeStruct((3, 4), i32),
+            jax.ShapeDtypeStruct((3,), i32),
+            jax.ShapeDtypeStruct((heads,), jnp.float32))
+        assert out.shape[-2:] == (heads, dv)
+        assert built["block_pages"] == pages
+        assert built["tile"] == tile
+        assert built["k_buf"] == (2, pages, hb // pack, 16,
+                                  -(-pack * dk // 128) * 128)
+        assert built["v_buf"] == (2, pages, hb, 16, dv)
+
+        # the host: a row of one token walks one block, and its one page
+        # costs a descriptor a pool and a group of heads
+        from paddle_tpu.inference.paged import JittedPagedDecoder
+        decoder = types.SimpleNamespace(_attn_kinds={
+            (group, window, False, kvh, dk, dv, sinks): 1})
+        cache = types.SimpleNamespace(
+            page_size=16, kv_quant=None, tp=1, compute_dtype=bf16,
+            k_pages=[types.SimpleNamespace(dtype=jnp.dtype(bf16))])
+        one = np.ones(1, np.int64)
+        counts = JittedPagedDecoder._walk_counts(decoder, cache, one, one,
+                                                 span, 1, 4)
+        assert counts["kv_tokens_walked"] == 16 * pages
+        assert counts["page_copies"] == 2 * (kvh // hb)
+        assert counts["head_page_reads"] == 2 * kvh
 
 
 class TestFusedNormRope:

@@ -18,7 +18,7 @@ from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas.paged_attention import (
     PagedKVCache, _decode_call, _decode_xla, _ragged_xla, k_pack,
     packed_k_rows, paged_attention, paged_attention_ragged, walk_block_pages,
-    walk_head_group)
+    walk_cut, walk_head_group)
 
 F32, I32 = jnp.float32, jnp.int32
 PAGE, PAGES, TABLE = 4, 48, 10
@@ -114,6 +114,53 @@ class TestWidthsAndSinks:
         assert np.abs(np.asarray(oracle) - want).max() < 1e-4
         assert np.abs(np.asarray(got) - want).max() < 1e-4
 
+    @pytest.mark.parametrize("group,span,window,sinks", [
+        (16, 64, None, False), (8, 128, 128, True), (8, 128, 700, True)],
+        ids=["full-g16", "sliding-g8-w128", "sliding-g8-w700"])
+    def test_rows_over_several_blocks_of_512(self, group, span, window,
+                                             sinks):
+        """MiMo's full and sliding calls in little (two KV heads of 192
+        beside 128, a pool row of 384) at buckets whose 1,024 rows cut
+        the walk at 256 tokens before ISSUE 51: tiles of 128 rows walk
+        blocks of 512.  Rows whose context ends inside the third block,
+        on the second's last token, a token into the second, inside the
+        first; a chunk row, a few queries, one; the engine's pad row.  A
+        window of 128 reaches 159 columns of a tile and keeps blocks of
+        256 (a chunk row's 255 visible keys cross two of them), one of
+        700 walks two blocks of 512 from the page of the first visible
+        key."""
+        page, kvh, dk, dv = 16, 2, 192, 128
+        tile, pages, _hb = walk_cut(kvh, page, dk, span, group, F32, F32,
+                                    dv, sinks, window=window)
+        assert (tile, page * pages) == (128, 256 if window == 128 else 512)
+        assert page * walk_block_pages(page, dk, span * group, F32, dv) == 256
+        rng = np.random.default_rng(51)
+        lens = np.asarray([1100, 1300, 1024, 530, 513, 40, 1])
+        q_lens = np.asarray([span, 1, 5, span // 2, 1, 3, 1])
+        need = -(-lens // page)
+        total = int(need.sum()) + 3
+        k = jnp.asarray(rng.normal(size=(kvh, total, page, dk)), F32)
+        v = jnp.asarray(rng.normal(size=(kvh, total, page, dv)), F32)
+        tabs, perm, at = np.zeros((len(lens), 96), np.int32), \
+            rng.permutation(total), 0
+        for r, n in enumerate(need):
+            tabs[r, :n] = perm[at:at + n]
+            at += n
+        q = jnp.asarray(rng.normal(size=(len(lens), span, kvh * group, dk)),
+                        F32)
+        b = jnp.asarray(rng.normal(size=(kvh * group,)) * 2, F32) \
+            if sinks else None
+        args = (q, packed(k), v, jnp.asarray(lens, I32),
+                jnp.asarray(q_lens, I32), jnp.asarray(tabs))
+        scale = 1 / math.sqrt(dk)
+        got = np.asarray(paged_attention_ragged(
+            *args, scale, interpret=True, window=window, sinks=b))
+        want = np.asarray(_ragged_xla(*args, scale, window=window, sinks=b))
+        live = np.arange(span)[None, :] < q_lens[:, None]
+        assert np.abs(got[live] - want[live]).max() < 1e-4
+        assert np.abs(want[live]).max() > 0.1
+        assert not got[~live].any() and not want[~live].any()
+
     def test_a_sink_takes_mass_and_gives_no_value(self):
         """A sink far above the scores leaves nearly nothing of the
         values; one far below changes nothing."""
@@ -194,19 +241,28 @@ class TestPoolsOfUnequalShape:
             PagedKVCache(1, None, None, total_pages=2,
                          pool_shapes=[(3, 192, 128)])
 
-    @pytest.mark.parametrize("kvh,rows,sinks,hb,pages", [
-        (8, 8, True, 8, 32), (8, 1024, True, 4, 16), (4, 16, False, 4, 32),
-        (4, 2048, False, 4, 8)])
-    def test_the_cell_s_head_groups_and_blocks(self, kvh, rows, sinks, hb,
-                                               pages):
+    @pytest.mark.parametrize("kvh,span,window,hb,pages,before", [
+        (8, 1, 128, 8, 16, 32), (8, 128, 128, 4, 16, 16),
+        (4, 1, None, 4, 32, 32), (4, 128, None, 4, 32, 8)])
+    def test_the_cell_s_head_groups_and_blocks(self, kvh, span, window, hb,
+                                               pages, before):
         """MiMo-V2-Flash's calls at a decode step (8 or 16 query rows a KV
-        head: blocks of 512 tokens, every head a grid step) and at a
-        128-token span: blocks of 256 and 128 tokens, all 4 full heads a
-        grid step, and 4 of the 8 sliding ones (the sink block and the
-        384-wide queries count)."""
-        assert walk_block_pages(16, 192, rows, jnp.bfloat16, 128) == pages
-        assert walk_head_group(kvh, 16, 192, rows, jnp.bfloat16,
-                               jnp.bfloat16, 128, sinks) == hb
+        head, every head a grid step) and at a 128-token span (tiles of
+        128 rows; all 4 full heads a grid step, and 4 of the 8 sliding
+        ones: the sink block and the 384-wide queries count).  The full
+        layers walk in blocks of 512 tokens at both, where the span's
+        2,048 bucket rows cut them at 128; the sliding ones, whose window
+        of 128 reaches 144 or 159 columns of a tile, in 256 at both, where
+        the decode step walked 512 (ISSUE 51)."""
+        bf16, group, sinks = jnp.bfloat16, 64 // kvh, window is not None
+        tile, got, heads = walk_cut(kvh, 16, 192, span, group, bf16, bf16,
+                                    128, sinks, span > 1, window)
+        assert (tile, got, heads) == (min(span * group, 128), pages, hb)
+        reach = window and window + tile // group - 1 + 16
+        assert walk_block_pages(16, 192, tile, bf16, 128, reach) == pages
+        assert walk_block_pages(16, 192, span * group, bf16, 128) == before
+        assert walk_head_group(kvh, 16, 192, span * group, bf16, bf16, 128,
+                               sinks, tile, reach) == hb
 
 
 class TestAlikePoolsAreWhatTheyWere:

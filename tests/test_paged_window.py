@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 import test_paged_attention as tpa
 from paddle_tpu.ops.pallas.paged_attention import (
-    kv_tokens_visible, kv_tokens_walked, walk_block_pages,
+    kv_tokens_visible, kv_tokens_walked, walk_block_pages, walk_cut,
     window_first_token)
 
 MODES = ["decode", "multi", "ragged"]
@@ -176,5 +176,16 @@ class TestWindowedCount:
         assert kv_tokens_visible(lens, ql, window=512) == 512 + 100 + 639
 
     def test_block_rule_of_the_two_groups_at_the_cell_s_span(self):
-        for group in (6, 8):
-            assert walk_block_pages(16, 128, 128 * group, jnp.bfloat16) == 16
+        """The tile's rows cut the block (96 and 128 rows: 512 tokens; the
+        sliding layers' window of 512 reaches past that), where the
+        bucket's 768 and 1,024 cut it at 256.  A window's reach bounds it:
+        128 + 16 positions - 1 + a page are 159 columns, blocks of 256."""
+        bf16 = jnp.bfloat16
+        for group, tile, window in ((6, 96, None), (8, 128, 512)):
+            assert walk_cut(8, 16, 128, 128, group, bf16, bf16,
+                            window=window) == (tile, 32, 8)
+            assert walk_block_pages(16, 128, 128 * group, bf16) == 16
+        assert walk_cut(8, 16, 128, 128, 8, bf16, bf16, window=128) \
+            == (128, 16, 8)
+        assert walk_block_pages(16, 128, 128, bf16, reach=159) == 16
+        assert walk_block_pages(16, 128, 128, bf16, reach=100) == 8

@@ -686,6 +686,45 @@ class TestMiMoCellLowering:
         assert not re.search(
             r"bf16\[\d+,8192,16,\d+\][^ ]* (copy|transpose|pad)\(", text)
 
+    @pytest.mark.parametrize(
+        "heads,kvh,window,dk,sinks,nq,hb,block,before", [
+            (48, 8, None, 128, False, 128, 8, 512, 256),
+            (64, 8, 512, 128, False, 128, 8, 512, 256),
+            (64, 4, None, 192, False, 64, 4, 512, 256),
+            (64, 4, None, 192, False, 128, 4, 512, 128),
+            (64, 8, 128, 192, True, 64, 8, 256, 512)],
+        ids=["laguna-full", "laguna-sliding", "mimo-full-span64",
+             "mimo-full", "mimo-sliding-span64"])
+    def test_blocks_cut_by_the_tile(self, chip, heads, kvh, window, dk,
+                                    sinks, nq, hb, block, before):
+        """The five ragged programs whose walk ISSUE 51 cuts anew, at
+        their cells' shapes: the score tile's rows (128, 96 at Laguna's
+        group of 6) allow blocks of 512 tokens where the bucket's rows
+        allowed ``before`` — K and V buffers of 32 pages for every head of
+        the grid step, beside the bucket's stages and scratch, are what
+        Mosaic is asked to fit — and MiMo's window of 128 reaches no
+        further than 256."""
+        dv, group, batch = 128, heads // kvh, 8
+        pack = paged_attention.k_pack(dk)
+        tile, pages, got = paged_attention.walk_cut(
+            kvh, 16, dk, nq, group, BF16, BF16, dv, sinks, window=window)
+        assert (tile, 16 * pages, got) == (96 if group == 6 else 128, block,
+                                           hb)
+        assert 16 * paged_attention.walk_block_pages(
+            16, dk, nq * group, BF16, dv) == before
+
+        def fn(q, kp, vp, lens, tabs, ql, b):
+            return _decode_pallas(
+                q, kp, vp, lens, tabs, 1 / math.sqrt(dk), n_query=nq,
+                q_lens=ql, window=window, sinks=b if sinks else None)
+
+        text = chip.compile(
+            fn, ((batch, nq, heads, dk),),
+            ((kvh // pack, 8192, 16, pack * dk),), ((kvh, 8192, 16, dv),),
+            ((batch,), I32), ((batch, 512), I32), ((batch,), I32),
+            ((heads,), F32))
+        assert "paged_attention_ragged" in text
+
     def test_the_append_takes_a_packed_k_pool(self, chip, monkeypatch):
         monkeypatch.setattr(paged_attention, "_use_pallas", lambda: True)
         text = chip.compile(

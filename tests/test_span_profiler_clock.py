@@ -212,14 +212,15 @@ class TestEngineStepUnderTrace:
         applied to the dispatch's padded rows (a pad row is one token
         long): ``kernel.paged_attn.walk_useful`` divides by it."""
         from paddle_tpu.ops.pallas.paged_attention import (
-            kv_tokens_walked, walk_block_pages)
+            kv_tokens_walked, walk_cut)
         _events, records, _ = run
         disp = [r for r in records if r["kind"] == "dispatch"]
         assert len(disp) == len(self.row_lengths)
         for r, rows in zip(disp, self.row_lengths):
             # tiny_model: 4 query heads over 2 KV heads of 8, f32 pages
-            block = r["page_size"] * walk_block_pages(
-                r["page_size"], 8, r["span_padded"] * 2, np.float32)
+            block = r["page_size"] * walk_cut(
+                2, r["page_size"], 8, r["span_padded"], 2, np.float32,
+                np.float32, ragged=r["span_padded"] > 1)[1]
             padded = rows + [1] * (r["rows_padded"] - r["rows"])
             assert r["kv_tokens_walked"] == kv_tokens_walked(padded, block)
             assert r["kv_tokens_walked"] >= r["ctx_tokens"]
@@ -234,7 +235,7 @@ class TestEngineStepUnderTrace:
         import json
         import sys
         from paddle_tpu.ops.pallas.paged_attention import (
-            kv_pages_copied, walk_head_group)
+            kv_pages_copied, walk_cut)
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         sys.path.insert(0, os.path.join(root, "benchmark"))
         try:
@@ -244,9 +245,9 @@ class TestEngineStepUnderTrace:
         _events, records, _ = run
         disp = [r for r in records if r["kind"] == "dispatch"]
         for r, rows in zip(disp, self.row_lengths):
-            assert walk_head_group(2, r["page_size"], 8,
-                                   r["span_padded"] * 2, np.float32,
-                                   np.float32) == 2
+            assert walk_cut(2, r["page_size"], 8, r["span_padded"], 2,
+                            np.float32, np.float32,
+                            ragged=r["span_padded"] > 1)[2] == 2
             padded = rows + [1] * (r["rows_padded"] - r["rows"])
             pages = kv_pages_copied(padded, r["page_size"],
                                     r["table_pages"])

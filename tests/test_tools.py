@@ -467,6 +467,30 @@ class TestPagedRaggedMicro:
         other.write_text(json.dumps(data))
         assert tool.table(str(other), str(other)) is False
 
+    def test_table_holds_another_block_to_the_oracle(self, tool, rehearsed,
+                                                     tmp_path, capsys):
+        """Two trees that cut a case's walk in other blocks cannot share
+        a digest: the change has to stand as close to the XLA oracle as
+        the parent (a tenth of room), the one-query call bit for bit."""
+        path, data = rehearsed
+        for case in data["cases"]:
+            # a window of 128 reaches 128 + 16 - 1 + 16 columns of a tile
+            assert case["block_tokens"] == (
+                256 if case["shape"] == "mimo-sliding" else 512)
+            assert 0 < case["oracle_rms_gap"] < case["oracle_max_gap"] < 0.02
+        parent = json.loads(json.dumps(data))
+        parent["cases"][1].update(block_tokens=128, live_sha="0" * 16)
+        other = tmp_path / "parent.json"
+        other.write_text(json.dumps(parent))
+        assert tool.table(str(other), path) is True
+        out = capsys.readouterr().out
+        assert out.count("bit for bit") == 2 and "DIFFER" not in out
+        assert "another block: the oracle's as the parent's" in out
+        parent["cases"][1]["oracle_rms_gap"] /= 2
+        other.write_text(json.dumps(parent))
+        assert tool.table(str(other), path) is False
+        assert "FURTHER FROM THE ORACLE" in capsys.readouterr().out
+
     def test_an_older_tree_is_handed_the_rectangle(self, tool):
         """``--repo`` on a tree whose kernel takes no ``row_off``: the
         tool gathers the rectangle and packs the output back, as that
